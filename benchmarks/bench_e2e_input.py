@@ -1,4 +1,4 @@
-"""End-to-end input pipeline bench (round-3 verdict item 7): ConvNet
+"""End-to-end input pipeline bench: ConvNet
 training FED by the multiprocessing DataLoader from host memory —
 augment -> batchify -> device feed -> TrainStep — the steady-state
 images/sec a real user gets, input included.

@@ -1,4 +1,4 @@
-"""Legacy Module/KVStore training loop (round-4 verdict weak #7: the
+"""Legacy Module/KVStore training loop (the
 reference's §3.3/§3.4 path — symbol simple_bind executor +
 forward/backward + per-param updater through the Module API — had no
 perf floor; every other bench runs TrainStep).

@@ -1,13 +1,14 @@
 """Roofline accounting for a BASELINE config's compiled training step.
 
-Answers the round-3 verdict's ResNet-50 question with measurements
-instead of hope: XLA's own ``cost_analysis`` (flops + bytes accessed) on
-the exact compiled step vs the chip's peaks, side by side with the
-traced device time and the top individual device ops.
+XLA's own ``cost_analysis`` (flops + bytes accessed) on the exact
+compiled step vs the attached chip's published peaks, side by side with
+the traced device time and the top individual device ops.
 
     python -m benchmarks.roofline --config resnet50 [--layout NHWC]
 
-v5e (TPU v5 lite) peaks used: 197 TFLOP/s bf16, 819 GB/s HBM.
+The peaks come from ``benchmarks.common.DEVICE_PEAKS``, keyed by the
+``device_kind`` the device reports; a kind that is not in the table (the
+CPU included) is an error, not a default.
 """
 
 from __future__ import annotations
@@ -19,9 +20,6 @@ import gzip
 import json
 import shutil
 import tempfile
-
-PEAK_FLOPS = 197e12
-PEAK_BW = 819e9
 
 
 def top_ops(trace_dir, steps, k=25):
@@ -53,7 +51,10 @@ def main():
     args = ap.parse_args()
 
     from . import trace_config as tc
+    from .common import device_peaks
     from .trace_bert import capture
+
+    peaks = device_peaks()
 
     if args.config == "resnet50":
         step, x, y, items = tc.build_resnet50(args.batch or 64, args.layout)
@@ -69,8 +70,8 @@ def main():
     c = step.cost_analysis()
     flops = c.get("flops", 0.0) / spc
     bytes_ = c.get("bytes accessed", 0.0) / spc
-    t_f = flops / PEAK_FLOPS * 1e3
-    t_b = bytes_ / PEAK_BW * 1e3
+    t_f = flops / peaks["flops_bf16"] * 1e3
+    t_b = bytes_ / peaks["hbm_bytes_per_s"] * 1e3
     print(f"XLA cost_analysis (per optimizer step, steps_per_call={spc}): "
           f"{flops / 1e12:.3f} TFLOP, {bytes_ / 1e9:.3f} GB accessed")
     print(f"roofline floors: compute {t_f:.2f} ms, memory {t_b:.2f} ms "

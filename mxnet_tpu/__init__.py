@@ -30,10 +30,7 @@ import jax as _jax
 # jax impl name, or "default") to opt out before import.
 _prng = _os.environ.get("MXNET_TPU_PRNG", "rbg")
 if _prng != "default":
-    try:
-        _jax.config.update("jax_default_prng_impl", _prng)
-    except Exception:  # pragma: no cover - ancient jax without the flag
-        pass
+    _jax.config.update("jax_default_prng_impl", _prng)
 
 from . import base
 from .base import MXNetError
@@ -99,6 +96,7 @@ if "initializer" in globals():
 if "optimizer" in globals():
     lr_scheduler = optimizer.lr_scheduler
 if "compile_cache" in globals():
-    # persistent XLA compilation cache: default-on under the convention
-    # dir; MXTPU_COMPILE_CACHE_DIR pins/paranoid-persists/disables
+    # persistent XLA compilation cache: default-on, at
+    # JAX_COMPILATION_CACHE_DIR when that is set, else at the fixed
+    # .mxtpu_cache/xla of the checkout; MXTPU_COMPILE_CACHE_DIR=off skips
     compile_cache.setup()

@@ -2,7 +2,8 @@
 
 The reference ships its IO hot path in C++ (dmlc RecordIOReader +
 ``src/io`` image pipeline [unverified]); here ``src/librecordio.cc`` is
-compiled once per machine into a cached ``.so`` and bound via ctypes. Every
+compiled once per checkout into a cached ``.so`` (under the git-ignored
+``.mxtpu_cache/`` next to the package) and bound via ctypes. Every
 entry point has a pure-Python fallback — the native path is an
 acceleration, never a requirement (machines without g++/libjpeg still
 work)."""
@@ -22,16 +23,11 @@ _TRIED = False
 _SRC = os.path.join(os.path.dirname(__file__), "..", "src", "librecordio.cc")
 
 
-def _cache_dir() -> str:
-    base = os.environ.get("MXNET_TPU_CACHE",
-                          os.path.join(os.path.expanduser("~"), ".cache",
-                                       "mxnet_tpu"))
-    os.makedirs(base, exist_ok=True)
-    return base
-
-
 def _build() -> Optional[str]:
-    so = os.path.join(_cache_dir(), "libmxtpu_io.so")
+    from .base import GENERATED_DIR
+
+    os.makedirs(GENERATED_DIR, exist_ok=True)
+    so = os.path.join(GENERATED_DIR, "libmxtpu_io.so")
     src = os.path.abspath(_SRC)
     if not os.path.exists(src):
         return None

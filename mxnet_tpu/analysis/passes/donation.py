@@ -15,9 +15,9 @@ stale copy on backends that snapshot. Three rules:
   ``scaler_state`` when the variant carries it) for the train step,
   ``state`` (the KV cache / paged pools) for the decode programs — and
   never donates non-carried inputs (``batch``/``label``/
-  ``frozen_vals``/``src``). Conditional ``donate = () if cpu else
-  (1,)`` resolves to the non-empty branch (the CPU test backend cannot
-  alias; the contract is about the real backend).
+  ``frozen_vals``/``src``). A conditional ``donate = () if c else
+  (1,)`` resolves to the non-empty branch; since PR 22 the programs
+  donate unconditionally, so the CPU suite runs what the chip runs.
 - **aliasable** (jaxpr) — on the REAL lowered programs: each donated
   leaf is consumed by the program, and (for programs that return their
   carry) its aval appears among the outputs so XLA can actually alias

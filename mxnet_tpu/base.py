@@ -28,6 +28,12 @@ __all__ = [
 
 logger = logging.getLogger("mxnet_tpu")
 
+# every generated artifact (the XLA compile cache, the native IO library)
+# lands under this one git-ignored directory of the checkout
+GENERATED_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".mxtpu_cache")
+
 numeric_types = (float, int)
 string_types = (str,)
 

@@ -9,14 +9,15 @@ half is ``gluon.data.bucketing``; the ahead-of-time half is
 ``TrainStep.warmup`` / ``CachedOp.warmup``):
 
 - **Persistent compilation cache** — wires JAX's on-disk cache so XLA
-  binaries outlive the process. Enabled by default under a conventional
-  cache directory (``~/.cache/mxnet_tpu/xla-cache``, honoring
-  ``XDG_CACHE_HOME``) with JAX's stock write thresholds (only compiles
-  worth caching are written); setting ``MXTPU_COMPILE_CACHE_DIR`` to a
-  path pins the directory AND drops the thresholds to zero so *every*
-  program is persisted — the elastic-restart / multi-process launch mode
-  where the second process must hit, not recompile. ``0``/``off``
-  disables entirely.
+  binaries outlive the process. The directory is placed from OUTSIDE:
+  where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it and
+  this module sets no directory at all (it only reports that one);
+  otherwise the cache lives at one fixed, git-ignored path inside the
+  checkout (``.mxtpu_cache/xla`` — the path is part of the cache key, so
+  it never carries a pid, a time or a temporary name). Write thresholds
+  are JAX's own (``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS`` /
+  ``JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES``).
+  ``MXTPU_COMPILE_CACHE_DIR=0``/``off`` wires nothing.
 
 - **Cache hit/miss telemetry** — a ``jax.monitoring`` event listener
   lands ``compile/cache_hits`` and ``compile/cache_misses`` counters in
@@ -31,9 +32,10 @@ half is ``gluon.data.bucketing``; the ahead-of-time half is
   steady, a new signature is an *accidental* recompile: it warns, or
   raises once the count exceeds ``MXTPU_RECOMPILE_LIMIT``.
 
-Env knobs: ``MXTPU_COMPILE_CACHE_DIR`` (path | ``0``/``off`` | unset =
-convention dir), ``MXTPU_RECOMPILE_LIMIT`` (unset = warn-only; ``N`` =
-raise after N steady-state recompiles; negative = silence the guard).
+Env knobs: ``MXTPU_COMPILE_CACHE_DIR`` (``0``/``off`` only; the directory
+itself is ``JAX_COMPILATION_CACHE_DIR``'s to name),
+``MXTPU_RECOMPILE_LIMIT`` (unset = warn-only; ``N`` = raise after N
+steady-state recompiles; negative = silence the guard).
 """
 
 from __future__ import annotations
@@ -44,14 +46,13 @@ import warnings
 from typing import Optional
 
 from . import telemetry as _tel
-from .base import MXNetError
+from .base import GENERATED_DIR, MXNetError
 
 __all__ = [
-    "setup", "enable", "disable", "is_enabled", "cache_dir", "cache_stats",
+    "setup", "is_enabled", "cache_dir", "cache_stats",
     "recompile_limit", "RecompileGuard",
 ]
 
-_LOCK = threading.RLock()
 _ENABLED = False
 _DIR: Optional[str] = None
 _LISTENER_INSTALLED = False
@@ -60,12 +61,6 @@ _LISTENER_INSTALLED = False
 # a staged cache holding more programs than this is almost certainly
 # shape churn, not intent
 _DEFAULT_SIG_WARN = 32
-
-
-def _default_dir() -> str:
-    base = os.environ.get("XDG_CACHE_HOME") or os.path.join(
-        os.path.expanduser("~"), ".cache")
-    return os.path.join(base, "mxnet_tpu", "xla-cache")
 
 
 def recompile_limit() -> Optional[int]:
@@ -90,57 +85,16 @@ def _install_metrics_listener():
     global _LISTENER_INSTALLED
     if _LISTENER_INSTALLED:
         return
-    try:
-        from jax import monitoring as _mon
+    from jax import monitoring as _mon
 
-        def _on_event(event, **kwargs):
-            if event.endswith("/cache_hits"):
-                _tel.registry().counter("compile/cache_hits").inc()
-            elif event.endswith("/cache_misses"):
-                _tel.registry().counter("compile/cache_misses").inc()
+    def _on_event(event, **kwargs):
+        if event.endswith("/cache_hits"):
+            _tel.registry().counter("compile/cache_hits").inc()
+        elif event.endswith("/cache_misses"):
+            _tel.registry().counter("compile/cache_misses").inc()
 
-        _mon.register_event_listener(_on_event)
-        _LISTENER_INSTALLED = True
-    except Exception:  # noqa: BLE001 - jax without monitoring
-        _LISTENER_INSTALLED = True  # don't retry every enable()
-
-
-def enable(directory: Optional[str] = None,
-           min_compile_time_secs: Optional[float] = None,
-           min_entry_size_bytes: Optional[int] = None) -> str:
-    """Point JAX's persistent compilation cache at ``directory`` (created
-    on demand by jax) and install the hit/miss counters. Threshold args
-    of None keep jax's defaults (write only compiles that took >= 1s) —
-    pass 0 to persist everything (what an explicit
-    ``MXTPU_COMPILE_CACHE_DIR`` does)."""
-    global _ENABLED, _DIR
-    import jax
-
-    with _LOCK:
-        directory = directory or _default_dir()
-        jax.config.update("jax_compilation_cache_dir", directory)
-        if min_compile_time_secs is not None:
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              float(min_compile_time_secs))
-        if min_entry_size_bytes is not None:
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              int(min_entry_size_bytes))
-        _install_metrics_listener()
-        _ENABLED = True
-        _DIR = directory
-        _tel.registry().gauge("compile/persistent_cache_enabled").set(1)
-    return directory
-
-
-def disable():
-    global _ENABLED, _DIR
-    import jax
-
-    with _LOCK:
-        jax.config.update("jax_compilation_cache_dir", None)
-        _ENABLED = False
-        _DIR = None
-        _tel.registry().gauge("compile/persistent_cache_enabled").set(0)
+    _mon.register_event_listener(_on_event)
+    _LISTENER_INSTALLED = True
 
 
 def is_enabled() -> bool:
@@ -152,24 +106,27 @@ def cache_dir() -> Optional[str]:
 
 
 def setup():
-    """Import-time wiring from ``MXTPU_COMPILE_CACHE_DIR``:
+    """Import-time wiring of the persistent cache:
 
-    - unset        -> convention dir, jax's stock write thresholds
-    - ``0``/``off``/``false`` -> disabled
-    - a path       -> that dir, thresholds dropped to zero (persist all)
+    - ``MXTPU_COMPILE_CACHE_DIR=0``/``off`` -> nothing is wired
+    - ``JAX_COMPILATION_CACHE_DIR`` set -> JAX reads it itself; no
+      directory is set here, that one is reported
+    - otherwise -> the fixed ``.mxtpu_cache/xla`` inside the checkout
     """
-    v = os.environ.get("MXTPU_COMPILE_CACHE_DIR")
-    try:
-        if v is None:
-            enable(_default_dir())
-        elif v.strip().lower() in ("0", "off", "false", "none", ""):
-            return
-        else:
-            enable(v, min_compile_time_secs=0.0, min_entry_size_bytes=0)
-    except Exception as e:  # noqa: BLE001 - cache must never block import
-        warnings.warn(
-            f"persistent compilation cache setup failed ({e}); continuing "
-            "without it", RuntimeWarning)
+    global _ENABLED, _DIR
+    off = os.environ.get("MXTPU_COMPILE_CACHE_DIR", "").strip().lower()
+    if off in ("0", "off", "false", "none"):
+        return
+    directory = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not directory:
+        import jax
+
+        directory = os.path.join(GENERATED_DIR, "xla")
+        jax.config.update("jax_compilation_cache_dir", directory)
+    _install_metrics_listener()
+    _ENABLED = True
+    _DIR = directory
+    _tel.registry().gauge("compile/persistent_cache_enabled").set(1)
 
 
 def cache_stats() -> dict:

@@ -75,10 +75,7 @@ class Engine:
     @staticmethod
     def wait_for_all():
         """Reference: ``Engine::WaitForAll`` — barrier on all pending work."""
-        try:
-            jax.effects_barrier()
-        except Exception:  # pragma: no cover - older jax fallback
-            pass
+        jax.effects_barrier()
         for dev in jax.devices():
             # synchronize per device; jax has no public global barrier, so
             # run a trivial computation and block on it.

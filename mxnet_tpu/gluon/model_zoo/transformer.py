@@ -378,9 +378,10 @@ class TransformerModel(HybridBlock):
             D = layer.cross_attn._head_dim
             dt = dtype if dtype is not None \
                 else layer.cross_attn.out_proj.weight.dtype
-            z = jnp.zeros((int(slots), int(mem_len), H, D), jnp.dtype(dt))
-            cross_k.append(z)
-            cross_v.append(z)
+            # distinct buffers: the state is a donated carry
+            shape = (int(slots), int(mem_len), H, D)
+            cross_k.append(jnp.zeros(shape, jnp.dtype(dt)))
+            cross_v.append(jnp.zeros(shape, jnp.dtype(dt)))
         return {
             "k_pools": tuple(k_pools), "v_pools": tuple(v_pools),
             "cross_k": tuple(cross_k), "cross_v": tuple(cross_v),

@@ -97,9 +97,8 @@ class MultiHeadAttention(HybridBlock):
             qkv = qkv.reshape(B, S, self._num_heads, 3 * self._head_dim)
             if use_bshd:
                 # transpose-free layout: slices stay (B, S, H, D) and the
-                # bshd attention path consumes them directly (measured
-                # perf-neutral on v5e — see traces/README round-4 copy
-                # audit; kept for the simpler graphs)
+                # bshd attention path consumes them directly (kept for
+                # the simpler graphs, not for speed)
                 d = self._head_dim
                 q = qkv[:, :, :, 0 * d:1 * d]
                 k = qkv[:, :, :, 1 * d:2 * d]
@@ -178,9 +177,8 @@ class MultiHeadAttention(HybridBlock):
         return out
 
     def _use_bshd(self) -> bool:
-        """Transpose-free (B, S, H, D) attention layout — measured
-        perf-neutral on v5e (traces/README round-4 copy audit), kept as
-        default for the simpler graphs; ring/ulysses shard over explicit
+        """Transpose-free (B, S, H, D) attention layout, kept as
+        default for the simpler graphs (not for speed); ring/ulysses shard over explicit
         head-major arrays, so they keep BHSD. MXTPU_ATTN_BSHD=0 restores
         head-major."""
         import os
@@ -314,8 +312,9 @@ class MultiHeadAttention(HybridBlock):
             dtype = self.out_proj.weight.dtype
         shape = (int(max_len), int(batch_size), self._num_heads,
                  self._head_dim)
-        z = jnp.zeros(shape, jnp.dtype(dtype))
-        return z, z
+        # two buffers, never one array twice: the pair is donated
+        return (jnp.zeros(shape, jnp.dtype(dtype)),
+                jnp.zeros(shape, jnp.dtype(dtype)))
 
     # ------------------------------------------------------------ paged mode
     # Paged KV cache (Kwon et al., PagedAttention, SOSP 2023): instead of a
@@ -337,8 +336,11 @@ class MultiHeadAttention(HybridBlock):
             dtype = self.out_proj.weight.dtype
         shape = (int(num_pages), int(page_size), self._num_heads,
                  self._head_dim)
-        z = jnp.zeros(shape, jnp.dtype(dtype))
-        return z, z
+        # two buffers, never one array twice: the serving engine donates
+        # the pools into every dispatch, and a buffer donated twice in one
+        # call is refused off the CPU
+        return (jnp.zeros(shape, jnp.dtype(dtype)),
+                jnp.zeros(shape, jnp.dtype(dtype)))
 
     def paged_step(self, query, k_pool, v_pool, page_table, pos, active):
         """One incremental self-attention step through a paged KV cache.

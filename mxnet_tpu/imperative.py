@@ -296,7 +296,7 @@ class _BulkQueue:
         return outs, multi
 
     def flush(self):
-        # re-entrance guard (ADVICE r5): two queues holding mutually
+        # re-entrance guard: two queues holding mutually
         # dependent pendings (A reads B's, B reads A's) would otherwise
         # recurse A.flush -> B.flush -> A.flush ... to RecursionError —
         # the per-queue RLock is re-entrant, so nothing breaks the cycle.

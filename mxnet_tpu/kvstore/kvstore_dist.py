@@ -34,7 +34,7 @@ class KVStoreDist(KVStore):
         self._rank = 0
         self._num_workers = 1
         self._initialized_dist = False
-        # dist_async: bounded-staleness mode (round-4 verdict item 8).
+        # dist_async: bounded-staleness mode.
         # The reference's async let each worker hit the parameter server
         # without waiting; with collectives as the only transport, the
         # TPU-native analogue is LOCAL apply (push returns without any

@@ -490,10 +490,9 @@ def _dense_attention_bshd(q, k, v, valid_length, causal, sm_scale,
                           q_offset=None):
     """Exact softmax attention over (B, S, H, D) operands: the einsums
     carry the head batch dim in place, so the model never writes a head
-    transpose. Measured perf-NEUTRAL on v5e (the per-layer QKV copies
-    in the BERT trace are XLA's backward-residual layout choice, not
-    the transposes — see traces/README round-4 copy audit); kept as the
-    default for the simpler graphs."""
+    transpose. Not a speed claim (the per-layer QKV copies in a BERT
+    trace are XLA's backward-residual layout choice, not the
+    transposes); kept as the default for the simpler graphs."""
     # score dot in operand dtype, f32 after (see _dense_attention: keeps
     # the backward's dq/dk matmuls low-precision under AMP)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * sm_scale

@@ -1,8 +1,8 @@
 """Fused BatchNorm reductions: Pallas TPU kernels (channel-last layout).
 
 Replaces the stat passes of the reference's hand-written BN kernel
-(``src/operator/nn/batch_norm.cu`` [unverified]) the TPU way. Round-3
-profiling (benchmarks/traces/README.md) showed ResNet-50's BN reductions
+(``src/operator/nn/batch_norm.cu`` [unverified]) the TPU way. Profiling
+on an earlier machine showed ResNet-50's BN reductions
 running at XLA's HBM roofline with the *two-pass* centered statistics:
 one full read of x for the mean, a second for the variance. The obvious
 one-pass rewrite (E[x^2]-E[x]^2) was built and REVERTED in round 3 — it
@@ -48,18 +48,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _use_interpret() -> bool:
-    """``MXTPU_FLASH_INTERPRET``: force (``1``) or forbid (``0``) Pallas
-    interpret mode; default ``auto`` interprets off-TPU (CPU testing)."""
-    import os
-
-    v = os.environ.get("MXTPU_FLASH_INTERPRET", "").strip().lower()
-    if v in ("1", "true", "force", "on"):
-        return True
-    if v in ("0", "false", "off"):
-        return False
-    return jax.default_backend() != "tpu"
+from . import _use_interpret
 
 
 def _scratch(shapes):

@@ -22,30 +22,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU backend module; absent in some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from . import _use_interpret
 
 __all__ = ["flash_attention"]
 
 _NEG_INF = -1e30
-
-
-def _use_interpret() -> bool:
-    """``MXTPU_FLASH_INTERPRET``: force (``1``) or forbid (``0``) Pallas
-    interpret mode; default ``auto`` interprets off-TPU (CPU testing)."""
-    import os
-
-    v = os.environ.get("MXTPU_FLASH_INTERPRET", "").strip().lower()
-    if v in ("1", "true", "force", "on"):
-        return True
-    if v in ("0", "false", "off"):
-        return False
-    return jax.default_backend() != "tpu"
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *refs, sm_scale, block_k, kv_len,

@@ -21,18 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _use_interpret() -> bool:
-    """``MXTPU_FLASH_INTERPRET``: force (``1``) or forbid (``0``) Pallas
-    interpret mode; default ``auto`` interprets off-TPU (CPU testing)."""
-    import os
-
-    v = os.environ.get("MXTPU_FLASH_INTERPRET", "").strip().lower()
-    if v in ("1", "true", "force", "on"):
-        return True
-    if v in ("0", "false", "off"):
-        return False
-    return jax.default_backend() != "tpu"
+from . import _partitionable, _use_interpret
 
 
 def _fwd_kernel(x_ref, g_ref, b_ref, o_ref, mean_ref, rstd_ref, *, eps):
@@ -171,7 +160,8 @@ def supports(data, axis) -> bool:
     """Can the fused kernel serve this call?
 
     Bounds C so the backward's three (block_rows, C) f32 VMEM buffers fit
-    the ~16 MB budget; wider norms fall back to the jnp path."""
+    the ~16 MB budget; wider norms fall back to the jnp path, and so does
+    a trace under a multi-device mesh (``_partitionable``)."""
     C = data.shape[-1]
     return (axis in (-1, data.ndim - 1)) and C % 128 == 0 \
-        and 128 <= C <= 4096 and data.ndim >= 2
+        and 128 <= C <= 4096 and data.ndim >= 2 and _partitionable()

@@ -11,17 +11,20 @@ one page directly out of the pool, and an online-softmax carry in VMEM
 scratch accumulates across the sequential page dimension — the gather
 never materializes and scores never leave VMEM.
 
-Two variants, mirroring ``flash_attention.py``'s forward:
+One kernel, two entry points:
 
-- ``paged_decode_attention`` — single query token per row (the decode
-  hot path). Grid ``(B, pages_per_row)``; row ``b``'s step ``p`` reads
-  pool block ``page_table[b, p]`` and masks keys at absolute positions
-  ``> pos[b]``.
 - ``paged_window_attention`` — an S-token query window per row, each
   query ``i`` at absolute position ``q_offset[b] + i`` (causal within
-  and across the window). This is the q_offset-aware PREFILL variant:
-  suffix-only prefix-cache replay and speculative verification both
-  score a short window against a long paged history in one pass.
+  and across the window). Grid ``(B, pages_per_row)``; row ``b``'s step
+  ``p`` reads pool block ``page_table[b, p]``. This is the
+  q_offset-aware PREFILL variant: suffix-only prefix-cache replay and
+  speculative verification both score a short window against a long
+  paged history in one pass.
+- ``paged_decode_attention`` — single query token per row (the decode
+  hot path): the window kernel at ``S = 1`` with ``q_offset = pos``. A
+  separate 2-D ``(H, D)`` query kernel is not expressible for the chip:
+  Mosaic refuses a batched ``dot_general`` whose lhs has no free
+  dimension, so the query keeps its unit window axis.
 
 Both keep ``MXTPU_FLASH_INTERPRET`` (force/forbid/auto, shared with
 ``flash_attention.py``) and ship a dense jnp reference
@@ -42,16 +45,10 @@ import os as _os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _NEG_INF, _use_interpret
-
-try:  # TPU backend module; absent in some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from . import _partitionable, _use_interpret
+from .flash_attention import _NEG_INF
 
 __all__ = ["paged_decode_attention", "paged_window_attention",
            "paged_decode_reference", "paged_window_reference",
@@ -69,121 +66,25 @@ def flash_paged_enabled() -> bool:
     kernels (``1``/``true``/``force``/``on``), keep the dense
     gather fallback (``0``/``false``/``off``), or — default auto —
     kernels only on a real TPU backend (interpreted kernels on the CPU
-    rig are slower than the dense path they replace)."""
+    rig are slower than the dense path they replace). Under a
+    multi-device mesh a compiled kernel is never routed to
+    (``_partitionable``): the gather path is what XLA can partition."""
     v = _os.environ.get("MXTPU_FLASH_PAGED", "").strip().lower()
+    if v in ("0", "false", "off") or not _partitionable():
+        return False
     if v in ("1", "true", "force", "on"):
         return True
-    if v in ("0", "false", "off"):
-        return False
-    return _HAS_PLTPU and jax.default_backend() == "tpu"
-
-
-def _require_pltpu():
-    if pltpu is None:  # pragma: no cover - CPU builds ship pltpu
-        raise RuntimeError(
-            "MXTPU_FLASH_PAGED forced the paged Pallas kernels on, but "
-            "jax.experimental.pallas.tpu is not importable in this "
-            "build — unset MXTPU_FLASH_PAGED to use the dense fallback")
-
-
-def _decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, page_size, sm_scale):
-    """Grid (B, pages_per_row), pages sequential per row: one pool page
-    per step, online-softmax carry (m, l, acc) in VMEM scratch."""
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-    n_pages = pl.num_programs(1)
-
-    @pl.when(p == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    pos = pos_ref[b]
-
-    # pages whose first slot is already past this row's position hold
-    # nothing visible — skip the whole block (page 0 is never skipped,
-    # so l is never all-zero for a live row)
-    @pl.when(p * page_size <= pos)
-    def _accumulate():
-        q = q_ref[0].astype(jnp.float32)          # (H, D)
-        k = k_ref[0].astype(jnp.float32)          # (ps, H, D)
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale                               # (H, ps)
-        key_abs = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        s = jnp.where(key_abs <= pos, s, _NEG_INF)
-        m_prev = m_ref[...]                        # (H, LANES)
-        l_prev = l_ref[...]
-        m_cur = jnp.max(s, axis=1, keepdims=True)  # (H, 1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p_act = jnp.exp(s - m_new[:, :1])          # (H, ps)
-        l_new = alpha * l_prev + jnp.sum(p_act, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha[:, :1] + jax.lax.dot_general(
-            p_act, v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-        )                                          # (H, D)
-        m_ref[...] = m_new
-        l_ref[...] = l_new
-
-    @pl.when(p == n_pages - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
-
-
-def paged_decode_attention(q, k_pool, v_pool, page_table, pos, *,
-                           sm_scale):
-    """Single-token paged attention, pools read in place.
-
-    q ``(B, H, D)``; pools ``(num_pages, page_size, H, D)``;
-    ``page_table`` ``(B, P)`` int32; ``pos`` ``(B,)`` int32 — row ``b``
-    attends keys at absolute positions ``<= pos[b]`` (the caller has
-    already scattered position ``pos`` into the pool). Returns
-    ``(B, H, D)``."""
-    _require_pltpu()
-    B, H, D = q.shape
-    ps = k_pool.shape[1]
-    P = page_table.shape[1]
-    kernel = functools.partial(_decode_kernel, page_size=ps,
-                               sm_scale=sm_scale)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, P),
-        in_specs=[
-            pl.BlockSpec((1, H, D), lambda b, p, pt, ps_: (b, 0, 0)),
-            pl.BlockSpec((1, ps, H, D),
-                         lambda b, p, pt, ps_: (pt[b, p], 0, 0, 0)),
-            pl.BlockSpec((1, ps, H, D),
-                         lambda b, p, pt, ps_: (pt[b, p], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, H, D), lambda b, p, pt, ps_: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, _LANES), jnp.float32),
-            pltpu.VMEM((H, _LANES), jnp.float32),
-            pltpu.VMEM((H, D), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-        interpret=_use_interpret(),
-    )(page_table.astype(jnp.int32), pos.astype(jnp.int32),
-      q, k_pool, v_pool)
+    return jax.default_backend() == "tpu"
 
 
 def _window_kernel(pt_ref, off_ref, vl_ref, q_ref, k_ref, v_ref, o_ref,
                    m_ref, l_ref, acc_ref, *, page_size, sm_scale,
                    window):
-    """Like ``_decode_kernel`` but an S-query window rides each row:
-    query ``i`` sits at absolute position ``off + i`` and masks keys
-    above it; queries ``>= vl`` are padding and finalize to zero."""
+    """Grid (B, pages_per_row), pages sequential per row: one pool page
+    per step, online-softmax carry (m, l, acc) in VMEM scratch. An
+    S-query window rides each row: query ``i`` sits at absolute position
+    ``off + i`` and masks keys above it; queries ``>= vl`` are padding
+    and finalize to zero."""
     b = pl.program_id(0)
     p = pl.program_id(1)
     n_pages = pl.num_programs(1)
@@ -198,7 +99,8 @@ def _window_kernel(pt_ref, off_ref, vl_ref, q_ref, k_ref, v_ref, o_ref,
     vl = vl_ref[b]
 
     # the window's LAST query (off + window - 1) bounds what any query
-    # can see — pages wholly past it contribute nothing
+    # can see — pages wholly past it contribute nothing (page 0 is never
+    # skipped, so l is never all-zero for a live row)
     @pl.when(p * page_size <= off + window - 1)
     def _accumulate():
         q = q_ref[0].astype(jnp.float32)          # (H, S, D)
@@ -246,7 +148,6 @@ def paged_window_attention(q, k_pool, v_pool, page_table, q_offset,
     caller has already scattered the window's K/V into the pool).
     ``window_vl`` ``(B,)`` optionally marks queries ``>= window_vl[b]``
     as padding (their outputs are zeroed). Returns ``(B, S, H, D)``."""
-    _require_pltpu()
     B, S, H, D = q.shape
     ps = k_pool.shape[1]
     P = page_table.shape[1]
@@ -282,6 +183,19 @@ def paged_window_attention(q, k_pool, v_pool, page_table, q_offset,
     )(page_table.astype(jnp.int32), q_offset.astype(jnp.int32),
       window_vl.astype(jnp.int32), qt, k_pool, v_pool)
     return jnp.swapaxes(out, 1, 2)                 # (B, S, H, D)
+
+
+def paged_decode_attention(q, k_pool, v_pool, page_table, pos, *,
+                           sm_scale):
+    """Single-token paged attention, pools read in place.
+
+    q ``(B, H, D)``; pools ``(num_pages, page_size, H, D)``;
+    ``page_table`` ``(B, P)`` int32; ``pos`` ``(B,)`` int32 — row ``b``
+    attends keys at absolute positions ``<= pos[b]`` (the caller has
+    already scattered position ``pos`` into the pool). Returns
+    ``(B, H, D)``."""
+    return paged_window_attention(q[:, None], k_pool, v_pool, page_table,
+                                  pos, sm_scale=sm_scale)[:, 0]
 
 
 # ------------------------------------------------------------ references

@@ -139,10 +139,7 @@ def init_process_group(coordinator_address: str, num_processes: int,
     the coordination service itself."""
     if jax.distributed.is_initialized():
         return  # idempotent: a second KVStore/TrainStep must not re-join
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # older jaxlib without gloo: single-node CPU fallback
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,

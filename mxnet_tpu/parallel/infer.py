@@ -319,12 +319,12 @@ class InferStep:
                                 jnp.int32(max_new)).astype(jnp.int32)
             return buf, lengths
 
-        # the cache pytree (argument 1) is DONATED into the loop: decode
-        # reuses the prefill-seeded buffers instead of copying them. The
-        # CPU test backend can't alias pass-through leaves (the static
-        # cross_kv projections) and warns per dispatch — skip there.
-        donate = () if jax.default_backend() == "cpu" else (1,)
-        fn = jax.jit(decode, donate_argnums=donate)
+        # the cache pytree (argument 1) is DONATED into the loop, on every
+        # backend alike. It is not an output, so a single-device program
+        # has nothing to alias it with and jax says so once per compile
+        # ("Some donated buffers were not usable"); a program partitioned
+        # over a mesh hands XLA the buffers to reuse.
+        fn = jax.jit(decode, donate_argnums=(1,))
         self._decode_fns[cfg] = fn
         return fn
 
@@ -480,8 +480,7 @@ class InferStep:
                                   top_k, temperature)
             return tok0, new_state
 
-        donate = () if jax.default_backend() == "cpu" else (1,)
-        fn = jax.jit(prefill, donate_argnums=donate)
+        fn = jax.jit(prefill, donate_argnums=(1,))
         self._paged_fns[cfg] = fn
         return fn
 
@@ -504,8 +503,7 @@ class InferStep:
                                   top_k, temperature)
             return tok0, new_state
 
-        donate = () if jax.default_backend() == "cpu" else (1,)
-        fn = jax.jit(prefill, donate_argnums=donate)
+        fn = jax.jit(prefill, donate_argnums=(1,))
         self._paged_fns[cfg] = fn
         return fn
 
@@ -543,8 +541,7 @@ class InferStep:
                 0, steps, body, (tokens, fin0, state, key, buf))
             return buf, state
 
-        donate = () if jax.default_backend() == "cpu" else (1,)
-        fn = jax.jit(decode, donate_argnums=donate)
+        fn = jax.jit(decode, donate_argnums=(1,))
         self._paged_fns[cfg] = fn
         return fn
 
@@ -798,8 +795,7 @@ class InferStep:
             count = jnp.where(active, n_acc + 1, 0).astype(jnp.int32)
             return jnp.concatenate([t, count[:, None]], axis=1), state
 
-        donate = () if jax.default_backend() == "cpu" else (1,)
-        fn = jax.jit(verify, donate_argnums=donate)
+        fn = jax.jit(verify, donate_argnums=(1,))
         self._paged_fns[cfg] = fn
         return fn
 

@@ -293,21 +293,18 @@ def _install_jax_compile_listener():
     global _JAX_LISTENER_INSTALLED
     if _JAX_LISTENER_INSTALLED:
         return
-    try:
-        from jax import monitoring as _mon
+    from jax import monitoring as _mon
 
-        def _on_duration(event, duration, **kwargs):
-            if not _ENABLED:
-                return
-            key = event.strip("/").replace("/", "_")
-            _REGISTRY.histogram(f"jax/{key}").observe(duration)
-            if "compil" in event or "backend_compile" in event:
-                _REGISTRY.histogram("jax/compile_time_s").observe(duration)
+    def _on_duration(event, duration, **kwargs):
+        if not _ENABLED:
+            return
+        key = event.strip("/").replace("/", "_")
+        _REGISTRY.histogram(f"jax/{key}").observe(duration)
+        if "compil" in event or "backend_compile" in event:
+            _REGISTRY.histogram("jax/compile_time_s").observe(duration)
 
-        _mon.register_event_duration_secs_listener(_on_duration)
-        _JAX_LISTENER_INSTALLED = True
-    except Exception:  # noqa: BLE001 - jax without monitoring
-        _JAX_LISTENER_INSTALLED = True  # don't retry every enable()
+    _mon.register_event_duration_secs_listener(_on_duration)
+    _JAX_LISTENER_INSTALLED = True
 
 
 # ------------------------------------------------------------------ report

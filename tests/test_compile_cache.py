@@ -281,13 +281,18 @@ class TestPersistentCache:
     def test_env_setup_modes(self, monkeypatch):
         assert compile_cache.recompile_limit() is None or isinstance(
             compile_cache.recompile_limit(), int)
-        # default-on convention dir (set up at import)
+        # default-on (set up at import)
         assert compile_cache.is_enabled()
         assert compile_cache.cache_dir()
 
     def test_subprocess_warm_start_hits(self, tmp_path):
         env = dict(os.environ)
-        env["MXTPU_COMPILE_CACHE_DIR"] = str(tmp_path)
+        env.pop("MXTPU_COMPILE_CACHE_DIR", None)
+        # the directory is placed from outside through JAX's own
+        # variables; zero thresholds persist even this tiny program
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+        env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
+        env["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "0"
         env["JAX_PLATFORMS"] = "cpu"
         outs = []
         for _ in range(2):

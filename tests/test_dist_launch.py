@@ -78,7 +78,7 @@ def _launch_with_env(n, command, env):
 
 
 def test_two_process_global_mesh_trainstep(tmp_path):
-    """Round-4 verdict missing #2: 2 processes x 4 local CPU devices form
+    """2 processes x 4 local CPU devices form
     ONE global 8-device mesh (jax.distributed -> jax.devices() global)
     and execute the dp x tp BERT TrainStep as a single GSPMD program
     spanning processes — with a cross-process sharded checkpoint

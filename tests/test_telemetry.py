@@ -349,7 +349,7 @@ def test_env_var_enables_telemetry(tmp_path):
 
 # ----------------------------------------------------- bench watchdog rc
 def test_bench_watchdog_exits_nonzero():
-    """Regression (ADVICE bench.py:153): a hard bench hang must exit
+    """Regression: a hard bench hang must exit
     nonzero AND still print the error JSON line."""
     code = (
         "import time\n"

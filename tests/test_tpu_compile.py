@@ -1,0 +1,245 @@
+"""The chip's compiler, asked without the chip.
+
+Every Pallas kernel on the main path is compiled here for a DESCRIBED
+TPU v5e at the widths BERT-base training and Transformer-base serving
+use, with interpret mode forbidden — what Mosaic refuses costs no chip
+time. Nothing runs: a compile that passes says nothing about results or
+speed (``chip_smoke.py`` checks results on the chip).
+
+All of these live in this ONE file and the topology is described inside
+a module-scoped fixture: only one process may load the TPU library, so
+under several test workers only the worker that is handed this file may
+touch it, and never while a module is imported (no top-level call, no
+``skipif`` condition, no ``parametrize`` argument, no child process).
+"""
+
+import importlib
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# Transformer-base serving shapes as chip_smoke.py drives them
+HEADS, HEAD_DIM, PAGE = 8, 64, 16
+SLOTS, PAGES_PER_SLOT = 4, 2
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "not here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but can never be read back without one: keep the cache out of it.
+    # Traces are cached per shape, not per interpret mode: start clean and
+    # leave nothing compiled-for-the-chip behind for the CPU tests
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    jax.clear_caches()
+    yield t
+    jax.clear_caches()
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def for_chip(one_chip, monkeypatch):
+    """Forbid interpret mode and hand out shapes placed on the described
+    chip plus a compile-and-check helper."""
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "0")
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    def compile_(fn, *specs):
+        compiled = jax.jit(fn).lower(*specs).compile()
+        assert "tpu_custom_call" in compiled.as_text(), \
+            "the kernel did not reach the chip's compiler"
+        return compiled
+
+    return spec, compile_
+
+
+def _mod(name):
+    # the package attribute `flash_attention` is the function, not the
+    # module: fetch kernel modules through importlib
+    return importlib.import_module(f"mxnet_tpu.ops.pallas.{name}")
+
+
+@pytest.mark.parametrize("rows,C,dtype", [
+    (8192, 768, "bfloat16"),   # BERT-base, batch 64 x seq 128
+    (8192, 768, "float32"),
+    (4096, 512, "bfloat16"),   # Transformer-base
+    (4096, 512, "float32"),
+])
+def test_layer_norm_fused_compiles(for_chip, rows, C, dtype):
+    spec, compile_ = for_chip
+    ln = _mod("layer_norm")
+    x, g = spec((rows, C), dtype), spec((C,), dtype)
+
+    def fwd(x, g, b):
+        return ln.layer_norm_fused(x, g, b, 1e-5)
+
+    def loss(x, g, b):
+        return fwd(x, g, b).astype(jnp.float32).sum()
+
+    compile_(fwd, x, g, g)
+    compile_(jax.grad(loss, argnums=(0, 1, 2)), x, g, g)
+
+
+def test_layer_norm_under_a_mesh_takes_the_partitionable_form(
+        topo, monkeypatch):
+    """GSPMD cannot partition a Mosaic kernel (found on four chips, PR
+    22): under a multi-device mesh scope the LayerNorm op must lower to
+    its jnp form, and the bare kernel is refused as the chip refuses it."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from mxnet_tpu.ops import nn as ops_nn
+    from mxnet_tpu.parallel import mesh_scope
+
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "0")
+    ln = _mod("layer_norm")
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("data",))
+    x = jax.ShapeDtypeStruct((8192, 768), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("data")))
+    g = jax.ShapeDtypeStruct((768,), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P()))
+    pfa = _mod("paged_flash_attention")
+    monkeypatch.setenv("MXTPU_FLASH_PAGED", "force")
+    assert pfa.flash_paged_enabled()
+    with mesh_scope(mesh):
+        assert not ln.supports(x, -1)
+        assert not pfa.flash_paged_enabled()  # even when forced on
+        compiled = jax.jit(ops_nn.layer_norm).lower(x, g, g).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert ln.supports(x, -1)  # no mesh scope: one chip takes the kernel
+    with pytest.raises(NotImplementedError, match="automatically partitioned"):
+        jax.jit(lambda x, g, b: ln.layer_norm_fused(x, g, b, 1e-5)
+                ).lower(x, g, g).compile()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_paged_decode_attention_compiles(for_chip, dtype):
+    spec, compile_ = for_chip
+    pfa = _mod("paged_flash_attention")
+    pool = spec((SLOTS * PAGES_PER_SLOT + 1, PAGE, HEADS, HEAD_DIM), dtype)
+    compile_(
+        lambda q, k, v, pt, pos: pfa.paged_decode_attention(
+            q, k, v, pt, pos, sm_scale=HEAD_DIM ** -0.5),
+        spec((SLOTS, HEADS, HEAD_DIM), dtype), pool, pool,
+        spec((SLOTS, PAGES_PER_SLOT), "int32"), spec((SLOTS,), "int32"))
+
+
+@pytest.mark.parametrize("window", [1, 2, 4, 16])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_paged_window_attention_compiles(for_chip, dtype, window):
+    spec, compile_ = for_chip
+    pfa = _mod("paged_flash_attention")
+    pool = spec((SLOTS * PAGES_PER_SLOT + 1, PAGE, HEADS, HEAD_DIM), dtype)
+    rows = spec((SLOTS,), "int32")
+    compile_(
+        lambda q, k, v, pt, off, vl: pfa.paged_window_attention(
+            q, k, v, pt, off, vl, sm_scale=HEAD_DIM ** -0.5),
+        spec((SLOTS, window, HEADS, HEAD_DIM), dtype), pool, pool,
+        spec((SLOTS, PAGES_PER_SLOT), "int32"), rows, rows)
+
+
+@pytest.mark.parametrize("backward", ["xla", "pallas"])
+def test_flash_attention_compiles_above_dense_max(for_chip, monkeypatch,
+                                                  backward):
+    """``examples/long_context_attention.py`` depends on the kernel path
+    that ``ops/contrib.py`` takes above ``MXTPU_ATTN_DENSE_MAX``."""
+    from mxnet_tpu.ops.contrib import _dense_max_seq
+
+    spec, compile_ = for_chip
+    fa = _mod("flash_attention")
+    S = 1024
+    assert S > _dense_max_seq()
+    monkeypatch.setattr(fa, "_BWD_IMPL", backward)
+    q = spec((2, HEADS, S, HEAD_DIM), "bfloat16")
+    vl = spec((2,), "int32")
+
+    def fwd(q, k, v, vl):
+        return fa.flash_attention(q, k, v, vl, True)
+
+    def loss(q, k, v, vl):
+        return fwd(q, k, v, vl).astype(jnp.float32).sum()
+
+    compile_(fwd, q, q, q, vl)
+    compile_(jax.grad(loss, argnums=(0, 1, 2)), q, q, q, vl)
+
+
+# ------------------------------------------------------------- chip_smoke
+def _run_smoke(*args):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    return subprocess.run(
+        [sys.executable, os.path.join(REPO_ROOT, "chip_smoke.py"), *args],
+        cwd=REPO_ROOT, env=env, capture_output=True, text=True, timeout=600)
+
+
+def test_chip_smoke_rehearses_every_phase():
+    proc = _run_smoke("--rehearse")
+    assert proc.returncode == 0, (proc.stdout[-2000:], proc.stderr[-2000:])
+    rows = [json.loads(ln) for ln in proc.stdout.splitlines()
+            if ln.startswith("{")]
+    assert [r.get("phase") for r in rows[:-1]] == \
+        ["device", "train", "serve", "total"]
+    last = rows[-1]
+    # a rehearsal reports its platform truthfully and is never a success
+    assert last == {"rehearsal": "passed",
+                    "device": {"platform": "cpu", "kind": "cpu", "count": 1}}
+    serve = rows[2]
+    assert serve["steady_state_recompiles"] == 0
+    assert serve["free_pages"] == serve["num_pages"]
+
+
+def test_chip_smoke_without_a_chip_fails():
+    proc = _run_smoke()
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout
+    assert "no TPU" in proc.stdout
+
+
+# ---------------------------------------------------------- compile cache
+def test_setup_leaves_cache_dir_to_jax_when_placed_from_outside(
+        monkeypatch, tmp_path):
+    from mxnet_tpu import compile_cache
+
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: calls.append(name))
+    monkeypatch.setattr(compile_cache, "_DIR", None)
+    monkeypatch.setattr(compile_cache, "_ENABLED", False)
+    monkeypatch.delenv("MXTPU_COMPILE_CACHE_DIR", raising=False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    compile_cache.setup()
+    assert "jax_compilation_cache_dir" not in calls
+    assert compile_cache.cache_dir() == str(tmp_path)
+    assert compile_cache.cache_stats()["dir"] == str(tmp_path)
+    # unset: one fixed, git-ignored path inside the checkout
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    compile_cache.setup()
+    assert calls == ["jax_compilation_cache_dir"]
+    assert compile_cache.cache_dir() == os.path.join(
+        REPO_ROOT, ".mxtpu_cache", "xla")

@@ -12,7 +12,10 @@ stream their output, and propagate failures.
 
 Launchers:
   local  spawn all N processes on this machine (testing / single-host
-         multi-process; the reference's ``--launcher local``).
+         multi-process; the reference's ``--launcher local``). Each child
+         gets the parent's whole environment plus the three rendezvous
+         variables and NO chip assignment: a chip belongs to one process,
+         so this is a CPU launcher until chips are assigned per child.
   ssh    one process per line of --hostfile via ssh (multi-host; the
          reference's ssh tracker). Assumes a shared working directory and
          passwordless ssh, like the reference.
