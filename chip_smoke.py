@@ -94,7 +94,7 @@ def phase_device(rehearse):
         sys.exit(2)
     import mxnet_tpu as mx
 
-    mx.telemetry.disable()  # telemetry/events.jsonl is a tracked file
+    mx.telemetry.disable()  # a smoke run writes no events.jsonl
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices())}
     say("device", **device, jax=jax.__version__, jaxlib=jaxlib.__version__,

@@ -10,8 +10,8 @@ closes the loop:
 
 - any ``counter("x/...")``/``gauge``/``histogram`` emission whose family
   ``x`` is not in ``KNOWN_METRIC_FAMILIES`` is an orphan;
-- any ``span("y....")``/``instant`` emission whose family ``y`` is not
-  in ``KNOWN_SPAN_FAMILIES`` is an orphan;
+- any ``span("y....")``/``instant``/``phase`` emission whose family
+  ``y`` is not in ``KNOWN_SPAN_FAMILIES`` is an orphan;
 - any family the tool declares but nothing emits is dead registry.
 
 Only literal names are collected (f-string families are already pinned
@@ -30,7 +30,7 @@ REPORT_TOOL = "tools/telemetry_report.py"
 SCAN_DIRS = ("mxnet_tpu", "tools", "benchmarks")
 
 METRIC_EMITTERS = {"counter", "gauge", "histogram"}
-SPAN_EMITTERS = {"span", "instant"}
+SPAN_EMITTERS = {"span", "instant", "phase"}  # phase: "mxtpu." + name
 
 
 def collect_emissions(index: _ad.AstIndex):

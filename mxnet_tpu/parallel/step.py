@@ -753,6 +753,7 @@ class TrainStep:
         from ..imperative import flush_bulk
 
         flush_bulk()  # donated operands may be captured in the eager queue
+        host = [0.0]
         if len(batch_and_label) == 1 and \
                 isinstance(batch_and_label[0], DeviceBatch):
             db = batch_and_label[0]
@@ -761,9 +762,17 @@ class TrainStep:
                     "DeviceBatch was staged by a different TrainStep; its "
                     "split axes/shardings may not match — feed it to the "
                     "step whose device_put_batch produced it")
-            return self._dispatch(db.batch, db.label)
-        batch, label = self._stage(batch_and_label)
-        return self._dispatch(batch, label)
+            batch, label = db.batch, db.label
+        else:
+            with _tel.phase("train.stage", host, 0):
+                batch, label = self._stage(batch_and_label)
+        with _tel.phase("train.dispatch", host, 0):
+            loss = self._dispatch(batch, label)
+        # what the host spends on one step (staging, device_put,
+        # enqueue): always on, like the batcher's infer/* histograms
+        _tel.registry().histogram("trainstep/host_ms").observe(
+            host[0] * 1e3)
+        return loss
 
     # -------------------------------------------------------------- feeding
     def feed_spec(self) -> dict:

@@ -22,7 +22,6 @@ import atexit
 import json
 import os
 import threading
-import time
 from typing import Dict, Optional
 
 import jax
@@ -168,22 +167,23 @@ def dump(finished=True, profile_process="worker"):
 
 
 class Scope:
-    """Annotation scope; shows up in the XProf timeline (reference: profiler
-    scopes / NVTX ranges)."""
+    """Annotation scope; shows up in the XProf timeline as
+    ``mxtpu.<name>`` (reference: profiler scopes / NVTX ranges). It is
+    ``telemetry.phase`` with the ``op/`` histogram as its accumulator's
+    reader."""
 
     def __init__(self, name="<unk>", append_mode=True):
         self._name = name
-        self._ctx = None
 
     def __enter__(self):
-        self._ctx = jax.profiler.TraceAnnotation(self._name)
-        self._ctx.__enter__()
-        self._t0 = time.perf_counter()
+        self._spent = [0.0]
+        self._phase = _telemetry.phase(self._name, self._spent, 0)
+        self._phase.__enter__()
         return self
 
     def __exit__(self, *exc):
-        self._ctx.__exit__(*exc)
-        record_host_op(self._name, time.perf_counter() - self._t0)
+        self._phase.__exit__(*exc)
+        record_host_op(self._name, self._spent[0])
         return False
 
 

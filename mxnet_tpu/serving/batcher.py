@@ -884,7 +884,24 @@ class ContinuousBatcher(_BatcherBase):
                       # tokens served from cache instead of recomputed,
                       # and copy-on-write page copies
                       "prefix_hits": 0, "prefix_lookups": 0,
-                      "prefix_tokens_saved": 0, "cow_copies": 0}
+                      "prefix_tokens_saved": 0, "cow_copies": 0,
+                      # cumulative seconds of the scheduler's phases
+                      # (``telemetry.phase`` spans ``mxtpu.sched.*``):
+                      # ``step_s`` is a whole working pass; intake,
+                      # retire, admit, capacity, dispatch, readback and
+                      # collect lie side by side inside it;
+                      # register_prefix (with its read-back) lies inside
+                      # retire, prefill inside admit
+                      "step_s": 0.0, "intake_s": 0.0, "retire_s": 0.0,
+                      "register_prefix_s": 0.0,
+                      "register_readback_s": 0.0, "admit_s": 0.0,
+                      "prefill_s": 0.0, "capacity_s": 0.0,
+                      "dispatch_s": 0.0, "readback_s": 0.0,
+                      "collect_s": 0.0}
+        # what one pass adds to ``stats`` (phase seconds, and the
+        # iteration's counts): summed here by the scheduler thread alone
+        # and published in ONE ``_stats_lock`` hold at the pass's end
+        self._pass = collections.Counter()
         if warmup:
             self._warmup()
         if start:
@@ -1044,33 +1061,54 @@ class ContinuousBatcher(_BatcherBase):
     def _step_once(self) -> bool:
         """One scheduler iteration: retire -> admit -> decode -> collect.
         Returns False when there was nothing to do (idle)."""
-        while True:
-            try:
-                self._pending.append(self._queue.get_nowait())
-            except queue.Empty:
-                break
-        if self._pending:
-            self._pending = collections.deque(
-                self._expire(list(self._pending)))
+        if self._drained():
+            return False
+        acc = self._pass
+        with _tel.phase("sched.step", acc, "step_s",
+                        {"iter": self._iter + 1}):
+            busy = self._pass_once()
+        with self._stats_lock:
+            for k, v in acc.items():
+                self.stats[k] += v
+        acc.clear()
+        return busy
+
+    def _pass_once(self) -> bool:
+        """The pass inside its ``sched.step`` span; every phase adds its
+        seconds to ``self._pass``."""
+        acc = self._pass
+        with _tel.phase("sched.intake", acc, "intake_s"):
+            while True:
+                try:
+                    self._pending.append(self._queue.get_nowait())
+                except queue.Empty:
+                    break
+            if self._pending:
+                self._pending = collections.deque(
+                    self._expire(list(self._pending)))
         try:
             # the whole iteration is one poison domain: an exception
             # anywhere (a partial admit that staged pages, a prefix
             # insert mid-refcount, a collect on poisoned state) must
             # release every page and fail every slot, not kill the
             # scheduler thread with pages still referenced.
-            self._retire()
-            admitted = self._admit()
+            with _tel.phase("sched.retire", acc, "retire_s"):
+                self._retire()
+            with _tel.phase("sched.admit", acc, "admit_s"):
+                admitted = self._admit()
             live = [i for i, s in enumerate(self._slots)
                     if s is not None and not s.finished]
             if not live:
                 return admitted > 0
-            self._ensure_capacity(live)
+            with _tel.phase("sched.capacity", acc, "capacity_s"):
+                self._ensure_capacity(live)
             live = [i for i, s in enumerate(self._slots)
                     if s is not None and not s.finished]
             if not live:
                 return True
             t0 = time.perf_counter()
-            out = self._dispatch(live)
+            with _tel.phase("sched.dispatch", acc, "dispatch_s"):
+                out = self._dispatch(live)
             self._collect(live, out, t0)
         except Exception as e:  # noqa: BLE001 - fail the slots, not the thread
             self._poison(e)
@@ -1097,7 +1135,10 @@ class ContinuousBatcher(_BatcherBase):
             # donate the retiring chain to the prefix trie BEFORE the
             # release: the trie's cache_acquire keeps the pages alive
             # (refcounted) while the slot's own references go away
-            self._register_prefix(i, s)
+            with _tel.phase("sched.register_prefix", self._pass,
+                            "register_prefix_s",
+                            {"request_id": r.future.request_id}):
+                self._register_prefix(i, s)
             self.pool.release(i)
             self._slots[i] = None
             if not r.future.done():
@@ -1296,10 +1337,12 @@ class ContinuousBatcher(_BatcherBase):
             # each paid a separate sync against the async dispatch queue
             st = self._state
             n = len(st["cross_k"])
-            got = jax.device_get(
-                [st["mem_vl"][slot]]
-                + [c[slot] for c in st["cross_k"]]
-                + [c[slot] for c in st["cross_v"]])
+            with _tel.phase("sched.register_prefix.readback", self._pass,
+                            "register_readback_s"):
+                got = jax.device_get(
+                    [st["mem_vl"][slot]]
+                    + [c[slot] for c in st["cross_k"]]
+                    + [c[slot] for c in st["cross_v"]])
             mem_vl = int(got[0])
             if mem_vl < 1:
                 return
@@ -1557,18 +1600,21 @@ class ContinuousBatcher(_BatcherBase):
             t0 = time.perf_counter()
             try:
                 _faults.fire("batcher.dispatch", tag=self.name)
-                tok0, self._state = self._engine.prefill_paged(
-                    self._state, src, vl, slot_ids, first_pages, active,
-                    seed=self._iter, **self._sampling)
-                if self._spec_on:
-                    # prime the draft's KV over the same prompt rows;
-                    # best-effort — prefix-hit/adopted rows skip this
-                    # (an unprimed draft only lowers acceptance, never
-                    # correctness: verification is always the target)
-                    _, self._dstate = self._engine.draft.prefill_paged(
-                        self._dstate, src, vl, slot_ids, first_pages,
+                with _tel.phase("sched.admit.prefill", self._pass,
+                                "prefill_s"):
+                    tok0, self._state = self._engine.prefill_paged(
+                        self._state, src, vl, slot_ids, first_pages,
                         active, seed=self._iter, **self._sampling)
-                tok0 = tok0.asnumpy()
+                    if self._spec_on:
+                        # prime the draft's KV over the same prompt rows;
+                        # best-effort — prefix-hit/adopted rows skip this
+                        # (an unprimed draft only lowers acceptance,
+                        # never correctness: verification is always the
+                        # target)
+                        _, self._dstate = self._engine.draft.prefill_paged(
+                            self._dstate, src, vl, slot_ids, first_pages,
+                            active, seed=self._iter, **self._sampling)
+                    tok0 = tok0.asnumpy()
             except Exception as e:  # noqa: BLE001 - fail futures, not thread
                 for slot, r, _hit in picked:
                     if not r.future.done():
@@ -1615,11 +1661,13 @@ class ContinuousBatcher(_BatcherBase):
             t1 = time.perf_counter()
             try:
                 _faults.fire("batcher.dispatch", tag=self.name)
-                tokS, self._state = self._engine.prefill_suffix_paged(
-                    self._state, toks, vl_s, q_off, tables, sids, act,
-                    seed=self._iter, wide=self.suffix_wide,
-                    **self._sampling)
-                tokS = tokS.asnumpy()
+                with _tel.phase("sched.admit.prefill", self._pass,
+                                "prefill_s"):
+                    tokS, self._state = self._engine.prefill_suffix_paged(
+                        self._state, toks, vl_s, q_off, tables, sids, act,
+                        seed=self._iter, wide=self.suffix_wide,
+                        **self._sampling)
+                    tokS = tokS.asnumpy()
             except Exception as e:  # noqa: BLE001 - fail futures, not thread
                 for slot, r, _hit in picked:
                     if not r.future.done():
@@ -1786,58 +1834,63 @@ class ContinuousBatcher(_BatcherBase):
         else:
             buf, version = out
             draft_ms = None
-        toks = buf.asnumpy()
+        acc = self._pass
+        with _tel.phase("sched.collect.readback", acc, "readback_s"):
+            toks = buf.asnumpy()
         iter_ms = (time.perf_counter() - t0) * 1e3
-        reg = _tel.registry()
-        emitted_total = 0
-        eos = self._engine._eos
-        if draft_ms is not None:
-            reg.histogram("infer/spec_draft_ms").observe(draft_ms)
-        for i in live:
-            s = self._slots[i]
-            fresh = []
-            if self._spec_on:
-                # row layout: [t_0..t_k, count]; count = accepted
-                # drafts + the bonus token (0 for inactive rows).
-                # Every emitted token is the target's own greedy
-                # argmax — acceptance only decides how many land per
-                # round, never which.
-                burst = int(toks[i, self.spec_k + 1])
-                reg.histogram("infer/spec_accept_len").observe(
-                    max(burst - 1, 0))
-            else:
-                burst = self.iter_tokens
-            for j in range(burst):
-                tok = int(toks[i, j])
-                s.length += 1  # this step cached the previous carry
-                s.carry = tok
-                fresh.append(tok)
-                if tok == eos or len(s.emitted) + len(fresh) \
-                        >= s.req.max_new:
-                    s.finished = True
-                    break
-            s.emitted.extend(fresh)
-            s.version = version
-            emitted_total += len(fresh)
-            s.req.future._stream_tokens(fresh)
-        occupancy = len(live) / self.slots
-        with self._stats_lock:
-            self.stats["iterations"] += 1
-            self.stats["occupancy_sum"] += occupancy
-            self.stats["tokens"] += emitted_total
-        reg.gauge("infer/batch_occupancy").set(occupancy)
-        reg.gauge("infer/pages_in_use").set(self.pool.pages_in_use)
-        reg.gauge("infer/page_fragmentation").set(self.pool.fragmentation(
-            [s.length if s is not None else 0 for s in self._slots]))
-        if emitted_total:
-            reg.histogram("infer/decode_ms_per_token").observe(
-                iter_ms / emitted_total)
-            reg.gauge("infer/tokens_per_sec").set(
-                emitted_total / (iter_ms / 1e3))
-        wd = self._watchdog
-        if wd is not None:
-            wd.notify_step(seconds=iter_ms / 1e3)
-            wd.note_request(inflight=len(live) + len(self._pending))
+        with _tel.phase("sched.collect", acc, "collect_s"):
+            reg = _tel.registry()
+            emitted_total = 0
+            eos = self._engine._eos
+            if draft_ms is not None:
+                reg.histogram("infer/spec_draft_ms").observe(draft_ms)
+            for i in live:
+                s = self._slots[i]
+                fresh = []
+                if self._spec_on:
+                    # row layout: [t_0..t_k, count]; count = accepted
+                    # drafts + the bonus token (0 for inactive rows).
+                    # Every emitted token is the target's own greedy
+                    # argmax — acceptance only decides how many land per
+                    # round, never which.
+                    burst = int(toks[i, self.spec_k + 1])
+                    reg.histogram("infer/spec_accept_len").observe(
+                        max(burst - 1, 0))
+                else:
+                    burst = self.iter_tokens
+                for j in range(burst):
+                    tok = int(toks[i, j])
+                    s.length += 1  # this step cached the previous carry
+                    s.carry = tok
+                    fresh.append(tok)
+                    if tok == eos or len(s.emitted) + len(fresh) \
+                            >= s.req.max_new:
+                        s.finished = True
+                        break
+                s.emitted.extend(fresh)
+                s.version = version
+                emitted_total += len(fresh)
+                s.req.future._stream_tokens(fresh)
+            occupancy = len(live) / self.slots
+            # published with the pass's phase seconds in the one
+            # ``_stats_lock`` hold at the end of ``_step_once``
+            acc["iterations"] += 1
+            acc["occupancy_sum"] += occupancy
+            acc["tokens"] += emitted_total
+            reg.gauge("infer/batch_occupancy").set(occupancy)
+            reg.gauge("infer/pages_in_use").set(self.pool.pages_in_use)
+            reg.gauge("infer/page_fragmentation").set(
+                self.pool.fragmentation([s.length if s is not None else 0
+                                         for s in self._slots]))
+            if emitted_total:
+                reg.histogram("infer/decode_ms_per_token").observe(
+                    iter_ms / emitted_total)
+                reg.gauge("infer/tokens_per_sec").set(
+                    emitted_total / (iter_ms / 1e3))
+            wd = self._watchdog
+            if wd is not None:
+                wd.notify_step(seconds=iter_ms / 1e3)
+                wd.note_request(inflight=len(live) + len(self._pending))
 
     def _poison(self, err):
         """A decode dispatch failed: the donated pool state is gone, so

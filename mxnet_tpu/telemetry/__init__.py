@@ -4,6 +4,9 @@ One spine for "why was this step slow?" and "is the run alive?":
 
 - ``events``   — structured spans (Chrome-trace ``.json`` + append-only
   JSONL), thread-safe nesting, zero overhead when disabled.
+- ``phase``    — the hot paths' span (scheduler pass, train step,
+  ``profiler.Scope``): one interval, three sinks — the profiler's
+  timeline, an always-on accumulator, and the ``events`` stream.
 - ``metrics``  — process-global registry (counters/gauges/rolling
   histograms): per-step wall time, samples/sec, JAX compile events
   (``jax.monitoring``), device memory, kvstore allreduce bytes/latency,
@@ -33,14 +36,18 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 from typing import Optional
 
-from .events import EventLog, NULL_SPAN, now_us as _events_now_us
+from jax.profiler import TraceAnnotation
+
+from .events import EventLog, NULL_SPAN, _T0 as _EVENTS_T0, \
+    now_us as _events_now_us
 from .metrics import Counter, Gauge, Histogram, Registry, merge_summaries
 from .watchdog import Watchdog
 
 __all__ = [
-    "enable", "disable", "enabled", "span", "instant", "complete",
+    "enable", "disable", "enabled", "span", "instant", "complete", "phase",
     "clock_us", "registry", "report", "dump", "record_step",
     "start_watchdog", "stop_watchdog", "hbm_peak_bytes",
     "hbm_limit_bytes", "hbm_headroom_bytes", "device_memory_stats",
@@ -155,6 +162,48 @@ def complete(name: str, ts_us: float, dur_us: float,
     log = _LOG
     if _ENABLED and log is not None:
         log.complete(name, ts_us, dur_us, args)
+
+
+class phase:
+    """One phase of a hot path as ONE interval with three sinks:
+
+    - always a ``jax.profiler.TraceAnnotation("mxtpu." + name, **args)``:
+      inside a profiler session the span lies on the profiler's timeline
+      beside the device's operations (one clock, by construction); outside
+      one, TraceMe is a flag check;
+    - always ``acc[key] += seconds`` when an accumulator is given (a dict
+      slot or a one-element list; no lock here — the owner of ``acc``
+      publishes it under its own);
+    - while ``enable()`` is in force, the same interval under the same
+      name and ``args`` as one complete event of the JSONL / Chrome
+      stream, so either trace can be laid over the other.
+
+    With tracing off: two clock reads, one flag check, one float add.
+    """
+
+    __slots__ = ("_name", "_acc", "_key", "_args", "_ann", "_t0")
+
+    def __init__(self, name: str, acc=None, key=None,
+                 args: Optional[dict] = None):
+        self._name = "mxtpu." + name
+        self._acc, self._key, self._args = acc, key, args
+
+    def __enter__(self):
+        self._ann = TraceAnnotation(self._name, **(self._args or {}))
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t0, t1 = self._t0, time.perf_counter()
+        self._ann.__exit__(*exc)
+        if self._acc is not None:
+            self._acc[self._key] += t1 - t0
+        log = _LOG
+        if _ENABLED and log is not None:
+            log.complete(self._name, (t0 - _EVENTS_T0) * 1e6,
+                         (t1 - t0) * 1e6, self._args)
+        return False
 
 
 def clock_us() -> float:

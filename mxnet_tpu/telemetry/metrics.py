@@ -139,6 +139,17 @@ class Histogram:
             vals = self._ring[: self._filled]
         return sorted(vals)
 
+    def last(self, n: int) -> list:
+        """The newest ``n`` observations the window still holds, oldest
+        first (fewer when fewer were made or kept): a reader that knows
+        how many of them are its own takes those and no others."""
+        with self._lock:
+            n = max(0, min(n, self._filled))
+            start = self._idx - n
+            if start >= 0:
+                return self._ring[start:self._idx]
+            return self._ring[start:] + self._ring[:self._idx]
+
     def percentile(self, p: float) -> Optional[float]:
         """Nearest-rank-with-interpolation percentile of the rolling
         window; None when nothing was observed."""

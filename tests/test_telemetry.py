@@ -136,6 +136,24 @@ def test_histogram_rolling_window_with_cumulative_totals():
     assert h.sum == sum(range(100))
 
 
+@pytest.mark.parametrize("made,n,want", [
+    (0, 3, []),                          # nothing observed
+    (3, 2, [2.0, 3.0]),                  # window not yet full
+    (3, 9, [1.0, 2.0, 3.0]),             # fewer made than asked for
+    (6, 3, [4.0, 5.0, 6.0]),             # wrapped: 5, 6 | 3, 4 in the ring
+    (6, 4, [3.0, 4.0, 5.0, 6.0]),        # the whole ring across the seam
+    (6, 10, [3.0, 4.0, 5.0, 6.0]),       # fewer kept than asked for
+    (8, 1, [8.0]),                       # the write index back at 0
+    (8, 0, []),
+])
+def test_histogram_last_gives_the_newest_observations_in_order(made, n,
+                                                               want):
+    h = Histogram(window=4)
+    for v in range(1, made + 1):
+        h.observe(v)
+    assert h.last(n) == want
+
+
 def test_empty_histogram_is_null_safe():
     h = Histogram()
     assert h.percentile(50) is None

@@ -35,6 +35,7 @@ KNOWN_METRIC_FAMILIES = {
     "disagg": "Disaggregated serving",
     "shard": "SPMD sharding",
     "trainer": "Host-side training",
+    "trainstep": "Host-side training",
     "kvstore": "Host-side training",
     "input": "Host-side training",
     "device": "Host-side training",
@@ -48,8 +49,8 @@ KNOWN_METRIC_FAMILIES = {
 # surface the consistency pass checks, not a formatting choice.
 KNOWN_SPAN_FAMILIES = {
     "checkpoint", "dataloader", "disagg", "estimator", "imperative",
-    "infer", "input", "kvstore", "launch", "serve", "trace", "trainer",
-    "trainstep", "transport", "watchdog",
+    "infer", "input", "kvstore", "launch", "sched", "serve", "trace",
+    "train", "trainer", "trainstep", "transport", "watchdog",
 }
 
 
