@@ -32,7 +32,10 @@ FAST_PATH_FUNCS = ("__call__", "_dispatch")
 # ContinuousBatcher._collect+_admit are the designated sync points and
 # stay unlinted). ContinuousBatcher._step_once — the scheduler loop body
 # — is linted too: its syncs must stay delegated to those named phases,
-# never inlined next to a dispatch.
+# never inlined next to a dispatch. So is the whole retire path (_retire,
+# the root registration and its store dispatch, each request's trie
+# insert): a new root's cross frames stay on the device, and retiring a
+# request reads nothing back.
 TARGETS = (
     (STEP_PY, "TrainStep", FAST_PATH_FUNCS),
     (INFER_PY, "InferStep", ("__call__", "_dispatch", "decode_n",
@@ -40,7 +43,9 @@ TARGETS = (
                              "prefill_suffix_paged", "spec_draft",
                              "spec_verify")),
     (BATCHER_PY, "DynamicBatcher", ("_dispatch",)),
-    (BATCHER_PY, "ContinuousBatcher", ("_dispatch", "_step_once")),
+    (BATCHER_PY, "ContinuousBatcher", ("_dispatch", "_step_once", "_retire",
+                                       "_register_roots", "_store_rows",
+                                       "_register_prefix")),
 )
 
 # method attributes that force a device->host readback / host sync

@@ -852,7 +852,11 @@ class ContinuousBatcher(_BatcherBase):
         self.cache = _prefix.PrefixCache(
             self.pool, self.page_size, enabled=prefix_cache)
         self._cache_tag = getattr(engine, "weights_version", None)
-        # compiled batched hit-adoption program (traced once by warmup)
+        # device-resident frames of the trie's roots, and the two
+        # compiled programs that fill and read them (traced once by
+        # warmup): slot -> row at retire, row -> slot at a hit
+        self._store = self._new_store()
+        self._store_fn = None
         self._hits_fn = None
         # suffix-length bucket menu for the forced-prefix replay program
         # (same powers-of-2 discipline as the admission-row menu)
@@ -885,13 +889,18 @@ class ContinuousBatcher(_BatcherBase):
                       # and copy-on-write page copies
                       "prefix_hits": 0, "prefix_lookups": 0,
                       "prefix_tokens_saved": 0, "cow_copies": 0,
+                      # store programs dispatched and store rows written:
+                      # ``prefix_rows_stored`` over ``retired`` is the
+                      # share of requests that brought a new root
+                      "prefix_store_dispatches": 0, "prefix_rows_stored": 0,
                       # cumulative seconds of the scheduler's phases
                       # (``telemetry.phase`` spans ``mxtpu.sched.*``):
                       # ``step_s`` is a whole working pass; intake,
                       # retire, admit, capacity, dispatch, readback and
                       # collect lie side by side inside it;
-                      # register_prefix (with its read-back) lies inside
-                      # retire, prefill inside admit
+                      # register_prefix (with its device step, the
+                      # store dispatch, under the read-back's name) lies
+                      # inside retire, prefill inside admit
                       "step_s": 0.0, "intake_s": 0.0, "retire_s": 0.0,
                       "register_prefix_s": 0.0,
                       "register_readback_s": 0.0, "admit_s": 0.0,
@@ -974,10 +983,12 @@ class ContinuousBatcher(_BatcherBase):
                     _np.zeros((srows,), bool), wide=self.suffix_wide,
                     **self._sampling)
                 jax.block_until_ready(tokS.data)
-        # the batched hit-adoption program (inert here: TRASH->TRASH
-        # COW self-copies, out-of-bounds cross rows — shapes are padded
-        # to `slots`, so this one trace covers every admission group)
+        # the root-store and batched hit-adoption programs (inert here:
+        # TRASH->TRASH COW self-copies, out-of-bounds store and cross
+        # rows — shapes are padded to `slots`, so one trace of each
+        # covers every retire pass and every admission group)
         if self.cache.enabled:
+            self._store_rows({})
             self._apply_prefix_hits([])
         # warm the disaggregated-handoff adoption scatters too: the
         # first `.at[].set` per pool array otherwise compiles on the
@@ -1119,6 +1130,7 @@ class ContinuousBatcher(_BatcherBase):
         between-dispatches safe point."""
         now = time.perf_counter()
         reg = _tel.registry()
+        done = []
         for i, s in enumerate(self._slots):
             if s is None:
                 continue
@@ -1130,8 +1142,12 @@ class ContinuousBatcher(_BatcherBase):
                     f"request deadline passed after {len(s.emitted)} of "
                     f"{r.max_new} tokens — retired mid-decode"))
                 s.finished = True
-            if not s.finished:
-                continue
+            if s.finished:
+                done.append((i, s))
+        if done and self.cache.enabled:
+            self._register_roots(done)
+        for i, s in done:
+            r = s.req
             # donate the retiring chain to the prefix trie BEFORE the
             # release: the trie's cache_acquire keeps the pages alive
             # (refcounted) while the slot's own references go away
@@ -1238,79 +1254,111 @@ class ContinuousBatcher(_BatcherBase):
             return False
 
     # ------------------------------------------------------ prefix caching
-    def _cross_frames_fit(self, mem_vl: int, ck, cv) -> bool:
-        """Host-side geometry check for a cached root's cross frames —
-        the validation half of the old per-request adoption, run at
-        staging time so the batched apply never has to fail a single
-        row. False sends the request down the cold path."""
-        try:
-            mvl = int(mem_vl)
-            st = self._state
-            if mvl < 1 or mvl > self.mem_len \
-                    or ck is None or cv is None \
-                    or len(ck) != len(st["cross_k"]) \
-                    or len(cv) != len(st["cross_v"]):
-                return False
-            for i, c_k in enumerate(st["cross_k"]):
-                want = (mvl,) + tuple(c_k.shape[2:])
-                if tuple(_np.asarray(ck[i]).shape) != want \
-                        or tuple(_np.asarray(cv[i]).shape) != want:
-                    return False
-            return True
-        except Exception:  # noqa: BLE001 - torn frames = cold prefill
-            return False
+    def _new_store(self):
+        """The device-resident store of root frames, built as the state
+        is: for each cross buffer ``[slots, mem_len, H, D]`` (every
+        layer's K, then every layer's V) one ``[max_roots, mem_len, H,
+        D]`` array of its dtype, and the rows' valid lengths. A trie
+        root pins one row. None with the cache off."""
+        if not self.cache.enabled:
+            return None
+        import jax.numpy as jnp
+
+        st, n = self._state, self.cache.max_roots
+        return (tuple(jnp.zeros((n,) + tuple(c.shape[1:]), c.dtype)
+                      for c in st["cross_k"] + st["cross_v"]),
+                jnp.zeros((n,), st["mem_vl"].dtype))
+
+    def _store_rows(self, by_row) -> None:
+        """ONE compiled program copies the whole cross rows and valid
+        lengths of slots into store rows (``by_row``: row -> slot),
+        device to device: nothing is read back, nothing waited for. The
+        index lists are padded to ``slots`` with out-of-bounds rows that
+        the scatter drops, so one program covers every retire pass; the
+        store is donated. It is enqueued before the admission prefill
+        that may overwrite those slots: device order keeps it sound."""
+        import jax
+        import jax.numpy as jnp
+
+        sids = _np.zeros((self.slots,), _np.int32)
+        rows = _np.full((self.slots,), self.cache.max_roots, _np.int32)
+        for j, (row, slot) in enumerate(by_row.items()):
+            rows[j] = row
+            sids[j] = slot
+        if self._store_fn is None:
+            def _store(cross, mem, frames, vl, sids, rows):
+                return (tuple(f.at[rows].set(c[sids], mode="drop")
+                              for f, c in zip(frames, cross)),
+                        vl.at[rows].set(mem[sids], mode="drop"))
+            self._store_fn = jax.jit(_store, donate_argnums=(2, 3))
+        st = self._state
+        self._store = self._store_fn(
+            st["cross_k"] + st["cross_v"], st["mem_vl"], *self._store,
+            jnp.asarray(sids), jnp.asarray(rows))
+        if by_row:
+            self._pass["prefix_store_dispatches"] += 1
+            self._pass["prefix_rows_stored"] += len(by_row)
+
+    def _register_roots(self, done) -> None:
+        """Give every retiring prompt that is new to the trie its root
+        and fill the new roots' store rows in one dispatch for the pass.
+        Where the trie evicted a root of this very pass for its row, the
+        row's later slot wins (the earlier prompt has no root left)."""
+        acc = self._pass
+        with _tel.phase("sched.register_prefix.store", acc,
+                        "register_prefix_s"):
+            by_row = {}
+            for i, s in done:
+                row = self.cache.add_root(s.req.prompt)
+                if row is not None:
+                    by_row[row] = i
+            if by_row:
+                with _tel.phase("sched.register_prefix.readback", acc,
+                                "register_readback_s"):
+                    self._store_rows(by_row)
 
     def _apply_prefix_hits(self, hits) -> None:
         """ONE batched device update for every prefix hit admitted this
         iteration: a single gather/scatter duplicates all COW pages
-        across every layer's K/V pool, and a single scatter lands the
-        adopted cross frames + ``mem_vl`` rows. The per-request
-        ``.at[].set`` chains this replaces ran sequentially on the
-        scheduler thread and were measured at ~9 ms per hit on the CPU
-        rig — more than the batched cold replay they were saving.
-        Rows are padded to ``slots`` (COW pads as TRASH self-copies,
-        cross rows as out-of-bounds drops), so one compiled program
-        covers every admission-group size."""
+        across every layer's K/V pool, and a single gather/scatter
+        copies the roots' store rows (cross frames + ``mem_vl``) into
+        the adopting slots, whole rows, device to device. The
+        per-request ``.at[].set`` chains this replaces ran sequentially
+        on the scheduler thread and were measured at ~9 ms per hit on
+        the CPU rig — more than the batched cold replay they were
+        saving. Rows are padded to ``slots`` (COW pads as TRASH
+        self-copies, cross rows as out-of-bounds drops), so one compiled
+        program covers every admission-group size."""
         import jax
         import jax.numpy as jnp
 
         st = self._state
-        rows = self.slots
-        src = _np.zeros((rows,), _np.int32)   # TRASH -> TRASH no-ops
-        dst = _np.zeros((rows,), _np.int32)
-        sids = _np.full((rows,), rows, _np.int32)  # OOB rows dropped
-        mvl = _np.zeros((rows,), _np.int32)
-        cks = [_np.zeros((rows, self.mem_len) + tuple(c.shape[2:]),
-                         _np.dtype(c.dtype)) for c in st["cross_k"]]
-        cvs = [_np.zeros((rows, self.mem_len) + tuple(c.shape[2:]),
-                         _np.dtype(c.dtype)) for c in st["cross_v"]]
+        n = self.slots
+        src = _np.zeros((n,), _np.int32)   # TRASH -> TRASH no-ops
+        dst = _np.zeros((n,), _np.int32)
+        sids = _np.full((n,), n, _np.int32)  # OOB rows dropped
+        rows = _np.zeros((n,), _np.int32)
         for i, (slot, hit) in enumerate(hits):
             if hit.cow is not None:
                 src[i] = int(hit.cow[0])
                 dst[i] = int(self.pool.table[slot, len(hit.full_pages)])
             sids[i] = slot
-            mvl[i] = int(hit.mem_vl)
-            for li in range(len(cks)):
-                cks[li][i, :mvl[i]] = _np.asarray(hit.ck[li])
-                cvs[li][i, :mvl[i]] = _np.asarray(hit.cv[li])
+            rows[i] = hit.row
         if self._hits_fn is None:
-            def _apply(kps, vps, c_k, c_v, mem, src, dst, sids, mvl,
-                       cks, cvs):
+            def _apply(kps, vps, c_k, c_v, mem, frames, vl, src, dst,
+                       sids, rows):
                 kps = tuple(kp.at[dst].set(kp[src]) for kp in kps)
                 vps = tuple(vp.at[dst].set(vp[src]) for vp in vps)
-                c_k = tuple(c.at[sids].set(f, mode="drop")
-                            for c, f in zip(c_k, cks))
-                c_v = tuple(c.at[sids].set(f, mode="drop")
-                            for c, f in zip(c_v, cvs))
-                mem = mem.at[sids].set(mvl, mode="drop")
-                return kps, vps, c_k, c_v, mem
+                cross = tuple(c.at[sids].set(f[rows], mode="drop")
+                              for c, f in zip(c_k + c_v, frames))
+                mem = mem.at[sids].set(vl[rows], mode="drop")
+                return kps, vps, cross[:len(c_k)], cross[len(c_k):], mem
             self._hits_fn = jax.jit(_apply)
         out = self._hits_fn(st["k_pools"], st["v_pools"],
                             st["cross_k"], st["cross_v"], st["mem_vl"],
+                            *self._store,
                             jnp.asarray(src), jnp.asarray(dst),
-                            jnp.asarray(sids), jnp.asarray(mvl),
-                            [jnp.asarray(a) for a in cks],
-                            [jnp.asarray(a) for a in cvs])
+                            jnp.asarray(sids), jnp.asarray(rows))
         st = dict(st)
         (st["k_pools"], st["v_pools"], st["cross_k"], st["cross_v"],
          st["mem_vl"]) = out
@@ -1319,54 +1367,36 @@ class ContinuousBatcher(_BatcherBase):
     def _register_prefix(self, slot: int, s) -> None:
         """Donate a retiring slot's page chain to the prefix trie so a
         later request sharing the prompt + target history adopts instead
-        of recomputing. Cross frames are read back from the device only
-        when the prompt is new to the trie (one sync per new root, on
-        the retire path — never on the dispatch path)."""
-        if not self.cache.enabled or s.length < 1:
+        of recomputing. Pure bookkeeping: the prompt's root and its
+        frames are ``_register_roots``' (a prompt whose root went since
+        is skipped)."""
+        if not self.cache.enabled:
             return
         r = s.req
-        pre = [] if r.prefix is None else [int(t) for t in r.prefix]
-        target = ([self._engine._bos] + pre
-                  + [int(t) for t in s.emitted])[:s.length]
-        mem_vl = ck = cv = None
-        if not self.cache.has_root(r.prompt):
-            import jax
-
-            # ONE device round trip for the whole readback (mem_vl +
-            # every layer's cross row) — per-layer ``asarray`` pulls
-            # each paid a separate sync against the async dispatch queue
-            st = self._state
-            n = len(st["cross_k"])
-            with _tel.phase("sched.register_prefix.readback", self._pass,
-                            "register_readback_s"):
-                got = jax.device_get(
-                    [st["mem_vl"][slot]]
-                    + [c[slot] for c in st["cross_k"]]
-                    + [c[slot] for c in st["cross_v"]])
-            mem_vl = int(got[0])
-            if mem_vl < 1:
-                return
-            ck = [g[:mem_vl] for g in got[1:1 + n]]
-            cv = [g[:mem_vl] for g in got[1 + n:]]
+        target = [self._engine._bos,
+                  *(() if r.prefix is None else r.prefix),
+                  *s.emitted][:s.length]
         pages = list(self.pool.owned(slot))[
             :_pages.pages_for(s.length, self.page_size)]
-        self.cache.insert(r.prompt, target, pages, mem_vl=mem_vl,
-                          ck=ck, cv=cv)
+        self.cache.insert(r.prompt, target, pages)
 
     def _seed_from_frames(self, slot: int, r, fr: dict) -> None:
         """A disaggregated handoff just adopted prefilled KV into
         ``slot``: register it in the prefix trie too, so later
-        same-prompt requests on this decode worker hit the cache."""
+        same-prompt requests on this decode worker hit the cache. The
+        root's store row is filled from the slot's cross rows, which
+        ``_adopt`` has just written, not from the host copy."""
         if not self.cache.enabled:
             return
+        row = self.cache.add_root(r.prompt)
+        if row is not None:
+            self._store_rows({row: slot})
         L = int(fr["length"])
         target = ([self._engine._bos]
                   + [int(t) for t in fr["emitted"]])[:L]
         pages = list(self.pool.owned(slot))[
             :_pages.pages_for(L, self.page_size)]
-        self.cache.insert(r.prompt, target, pages,
-                          mem_vl=int(fr["mem_vl"]),
-                          ck=fr["ck"], cv=fr["cv"])
+        self.cache.insert(r.prompt, target, pages)
 
     def _ensure_with_evict(self, slot: int, upto: int) -> bool:
         """``pool.ensure`` with one retry after asking the trie to evict
@@ -1403,11 +1433,7 @@ class ContinuousBatcher(_BatcherBase):
                 # prime re-runs the encoder anyway — nothing to win
                 hit = None
         if hit is not None:
-            # geometry first: a root with torn cross frames must fall
-            # back to the cold path BEFORE it acquires any pages
-            ok = self._cross_frames_fit(hit.mem_vl, hit.ck, hit.cv)
-            if ok:
-                ok = self.pool.adopt_ref(slot, hit.full_pages)
+            ok = self.pool.adopt_ref(slot, hit.full_pages)
             if ok:
                 ok = self._ensure_with_evict(slot, target_len)
             if ok and hit.cow is not None:
@@ -1906,6 +1932,7 @@ class ContinuousBatcher(_BatcherBase):
         self.pool.reset()
         self._state = self._engine.init_paged_state(
             self.slots, self.num_pages, self.page_size, self.mem_len)
+        self._store = self._new_store()  # donated by a dispatch that failed
         if self._spec_on:
             self._dstate = self._engine.init_draft_state(
                 self.slots, self.num_pages, self.page_size, self.mem_len)

@@ -10,12 +10,23 @@ position's encoding) — source-side prefix reuse would be unsound. What
 IS causally invariant is the decode side: the target sequence
 ([BOS] + re-sent history + emitted tokens) attends causally, so its KV
 pages are determined by (exact prompt, target tokens so far). The trie
-therefore maps an **exact prompt** to a root holding the host-side
-cross-attention frames (a root hit skips the encoder entirely — the
-dominant prefill cost) and, under each root, a radix tree of
-page-aligned **target-token blocks** mapping to physical page ids in the
-``PagePool`` (multi-turn requests that re-send their history adopt those
-pages instead of re-prefilling them).
+therefore maps an **exact prompt** to a root and, under each root, a
+radix tree of page-aligned **target-token blocks** mapping to physical
+page ids in the ``PagePool`` (multi-turn requests that re-send their
+history adopt those pages instead of re-prefilling them).
+
+A root holds a **row number** and no array: the row of the batcher's
+device-resident store that keeps the prompt's cross-attention frames and
+their valid length (a root hit skips the encoder entirely — the dominant
+prefill cost). The batcher fills the row from the retiring slot's cross
+buffers and copies it into an adopter's slot, device to device, one
+compiled program either way (``ContinuousBatcher._store_rows`` /
+``_apply_prefix_hits``); no frame reaches the host. The trie hands rows
+out (``add_root``) and takes them back when a root goes (LRU,
+``flush``); what a row holds is the batcher's business. The store's
+bytes, fixed at build: ``MXTPU_PREFIX_MAX_ROOTS x mem_len x heads x head
+size x 2 (K, V) x layers x bytes of the cache dtype`` (64 x 128 x 16 x
+64 x 2 x 6 x 2 = 201 MB for Transformer-big in bf16, widest bucket 128).
 
 Sharing protocol (see ``PagePool``): every cached page carries one cache
 reference; adopters map it read-only via ``adopt_ref``. Pages are
@@ -33,8 +44,9 @@ Eviction: nodes are LRU-stamped on every match/insert touch.
 remaining reference is the cache's (releasing those actually frees
 memory); the batcher calls it under the admission free-page watermark
 and before resorting to preemption. ``MXTPU_PREFIX_MAX_PAGES`` caps the
-trie's page footprint and ``MXTPU_PREFIX_MAX_ROOTS`` its root count
-(whole LRU roots evict when over).
+trie's page footprint and ``MXTPU_PREFIX_MAX_ROOTS`` its root count,
+which is the store's row count (whole LRU roots evict when over, and
+their rows are handed out again).
 
 All public methods take the cache lock and do pure bookkeeping — no
 device dispatch, no blocking call ever runs under it (lock-order pass).
@@ -83,8 +95,9 @@ def prefix_max_pages(default: int = 0) -> int:
 
 
 def prefix_max_roots(default: int = 64) -> int:
-    """``MXTPU_PREFIX_MAX_ROOTS``: distinct prompts the trie caches
-    cross-attention frames for; LRU roots evict whole over the cap."""
+    """``MXTPU_PREFIX_MAX_ROOTS``: distinct prompts the trie keeps a
+    root for, each pinning one row of the batcher's device store of
+    cross-attention frames; LRU roots evict whole over the cap."""
     v = os.environ.get("MXTPU_PREFIX_MAX_ROOTS", "").strip()
     try:
         return max(int(v), 1) if v else default
@@ -118,6 +131,11 @@ def prompt_digest(prompt_ids) -> int:
     return zlib.crc32(np.asarray(prompt_ids, np.int32).tobytes()) & 0xFFFFFFFF
 
 
+def _tokens(ids) -> Tuple[int, ...]:
+    """Token ids as the hashable tuple the trie keys on."""
+    return tuple(int(t) for t in np.asarray(ids).reshape(-1))
+
+
 class _Node:
     """One cached page: the target-token block it holds and its children
     (keyed by their block tuples). Only full (page_size) blocks may have
@@ -133,19 +151,16 @@ class _Node:
 
 
 class _Root:
-    """One exact prompt: host-side cross-attention frames (the encoder
-    output this prompt maps to) + the target-block radix tree."""
+    """One exact prompt: the row of the batcher's device store that
+    holds its cross-attention frames (the encoder output this prompt
+    maps to) + the target-block radix tree."""
 
-    __slots__ = ("key", "digest", "mem_vl", "ck", "cv", "children",
-                 "touch")
+    __slots__ = ("key", "digest", "row", "children", "touch")
 
-    def __init__(self, key: Tuple[int, ...], mem_vl: int, ck, cv,
-                 touch: int):
+    def __init__(self, key: Tuple[int, ...], row: int, touch: int):
         self.key = key
         self.digest = prompt_digest(key)
-        self.mem_vl = int(mem_vl)
-        self.ck = ck  # per-layer (mem_vl, H, D) host arrays, read-only
-        self.cv = cv
+        self.row = row
         self.children: Dict[Tuple[int, ...], _Node] = {}
         self.touch = touch
 
@@ -156,21 +171,17 @@ class PrefixHit:
     ``matched`` target positions [0, matched) are covered: ``full_pages``
     (adopt read-only, in depth order) plus optionally ``cow`` =
     ``(src_page, used)`` — copy ``src_page`` and treat its first ``used``
-    entries as valid. Cross frames (``ck``/``cv``/``mem_vl``) replace the
-    encoder pass entirely.
+    entries as valid. ``row`` is the root's row of the batcher's device
+    store: the cross frames there replace the encoder pass entirely.
     """
 
-    __slots__ = ("matched", "full_pages", "cow", "mem_vl", "ck", "cv",
-                 "digest")
+    __slots__ = ("matched", "full_pages", "cow", "row")
 
-    def __init__(self, matched, full_pages, cow, mem_vl, ck, cv, digest):
+    def __init__(self, matched, full_pages, cow, row):
         self.matched = matched
         self.full_pages = full_pages
         self.cow = cow
-        self.mem_vl = mem_vl
-        self.ck = ck
-        self.cv = cv
-        self.digest = digest
+        self.row = row
 
 
 class PrefixCache:
@@ -197,6 +208,8 @@ class PrefixCache:
             else bool(enabled)
         self._lock = threading.Lock()
         self._roots: Dict[Tuple[int, ...], _Root] = {}
+        # store rows no root pins (popped lowest first)
+        self._free_rows = list(range(self.max_roots - 1, -1, -1))
         self._clock = 0
         self._pages = 0  # nodes (== cached pages) currently held
         self.stats = {"hits": 0, "misses": 0, "tokens_saved": 0,
@@ -235,10 +248,8 @@ class PrefixCache:
             return [r.digest for r in roots[:limit]]
 
     def has_root(self, prompt_ids) -> bool:
-        """True when this exact prompt already has a trie root — lets
-        the batcher skip the device readback of cross frames at
-        insert time."""
-        key = tuple(int(t) for t in np.asarray(prompt_ids).reshape(-1))
+        """True when this exact prompt has a trie root."""
+        key = _tokens(prompt_ids)
         with self._lock:
             return key in self._roots
 
@@ -265,8 +276,8 @@ class PrefixCache:
         None (and counts a miss) when the prompt has no root."""
         if not self.enabled:
             return None
-        key = tuple(int(t) for t in np.asarray(prompt_ids).reshape(-1))
-        target = tuple(int(t) for t in np.asarray(target_ids).reshape(-1))
+        key = _tokens(prompt_ids)
+        target = _tokens(target_ids)
         ps = self.page_size
         with self._lock:
             root = self._roots.get(key)
@@ -311,35 +322,50 @@ class PrefixCache:
             # savings: the skipped encoder pass (prompt tokens) plus the
             # target positions adopted instead of re-prefilled
             self.stats["tokens_saved"] += len(key) + matched
-            return PrefixHit(matched, tuple(full_pages), cow, root.mem_vl,
-                             root.ck, root.cv, root.digest)
+            return PrefixHit(matched, tuple(full_pages), cow, root.row)
 
     # ----------------------------------------------------------- insertion
-    def insert(self, prompt_ids, target_ids, pages, mem_vl=None,
-               ck=None, cv=None) -> int:
-        """Register a slot's computed prefix: ``target_ids`` are the
-        cached decode-side tokens (positions [0, len)), ``pages`` the
-        slot's pages in depth order. Creates the root from the cross
-        frames (``ck``/``cv``/``mem_vl``) when this prompt is new —
-        without frames an unknown prompt is skipped (nothing to serve a
-        future encoder-skip from). Existing blocks are deduplicated;
-        new ones take a cache reference on their page. Returns how many
+    def add_root(self, prompt_ids) -> Optional[int]:
+        """Give an exact prompt that has no root one and return the
+        store row it pins, for the caller to fill before the next
+        ``match`` (both run on the scheduler thread). None when the
+        prompt has its root already: two slots retiring one prompt take
+        one row. Over the cap the least-recently-used roots go first,
+        and the new root may take a row just freed."""
+        if not self.enabled:
+            return None
+        key = _tokens(prompt_ids)
+        with self._lock:
+            if key in self._roots:
+                return None
+            self._clock += 1
+            while len(self._roots) >= self.max_roots:
+                lru = min(self._roots, key=lambda k: self._roots[k].touch)
+                self._drop_root_locked(lru)
+                self.stats["evicted_roots"] += 1
+            root = _Root(key, self._free_rows.pop(), self._clock)
+            self._roots[key] = root
+            return root.row
+
+    def insert(self, prompt_ids, target_ids, pages) -> int:
+        """Register a slot's computed prefix under its prompt's root:
+        ``target_ids`` are the cached decode-side tokens (positions
+        [0, len)), ``pages`` the slot's pages in depth order. A prompt
+        with no root (``add_root``) is skipped — nothing to serve a
+        future encoder-skip from. Existing blocks are deduplicated; new
+        ones take a cache reference on their page. Returns how many
         pages were newly cached."""
         if not self.enabled:
             return 0
-        key = tuple(int(t) for t in np.asarray(prompt_ids).reshape(-1))
-        target = tuple(int(t) for t in np.asarray(target_ids).reshape(-1))
+        key = _tokens(prompt_ids)
+        target = _tokens(target_ids)
         pages = [int(p) for p in pages]
         ps = self.page_size
         with self._lock:
-            self._clock += 1
             root = self._roots.get(key)
             if root is None:
-                if ck is None or cv is None or mem_vl is None:
-                    return 0
-                root = _Root(key, mem_vl, ck, cv, self._clock)
-                self._roots[key] = root
-                self._evict_roots_locked()
+                return 0
+            self._clock += 1
             root.touch = self._clock
             node: object = root
             added = 0
@@ -433,14 +459,9 @@ class PrefixCache:
     def _pages_over_cap_locked(self) -> bool:
         return bool(self.max_pages) and self._pages > self.max_pages
 
-    def _evict_roots_locked(self):
-        while len(self._roots) > self.max_roots:
-            key = min(self._roots, key=lambda k: self._roots[k].touch)
-            self._drop_root_locked(key)
-            self.stats["evicted_roots"] += 1
-
     def _drop_root_locked(self, key):
         root = self._roots.pop(key)
+        self._free_rows.append(root.row)
         stack = list(root.children.values())
         while stack:
             n = stack.pop()
@@ -450,8 +471,9 @@ class PrefixCache:
 
     def flush(self) -> int:
         """Drop everything (weights swapped or state poisoned): every
-        cache reference is released; pages still mapped by live slots
-        stay alive under their own references. Returns roots dropped."""
+        cache reference is released and every store row freed; pages
+        still mapped by live slots stay alive under their own
+        references. Returns roots dropped."""
         with self._lock:
             n = len(self._roots)
             for key in list(self._roots):
@@ -483,3 +505,8 @@ class PrefixCache:
             if count != self._pages:
                 raise MXNetError(
                     f"trie page ledger {self._pages} != {count} nodes")
+            rows = [r.row for r in self._roots.values()] + self._free_rows
+            if sorted(rows) != list(range(self.max_roots)):
+                raise MXNetError(
+                    f"store rows pinned and free are not 0.."
+                    f"{self.max_roots - 1} once each: {sorted(rows)}")

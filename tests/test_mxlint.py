@@ -223,6 +223,9 @@ class TestNoSync:
         cont = covered[("batcher.py", "ContinuousBatcher")]
         assert "_dispatch" in cont
         assert "_step_once" in cont  # the scheduler loop body
+        # the retire path: a new root's frames never come to the host
+        assert {"_retire", "_register_roots", "_store_rows",
+                "_register_prefix"} <= cont
 
     def test_lint_catches_a_violation(self, tmp_path):
         bad = tmp_path / "step_bad.py"

@@ -134,7 +134,8 @@ def recorded(batcher, train_step, tmp_path_factory):
 # ------------------------------------------------- sink 1: the profiler
 @pytest.mark.parametrize("name", [
     "mxtpu.sched.step", "mxtpu.sched.intake", "mxtpu.sched.retire",
-    "mxtpu.sched.register_prefix", "mxtpu.sched.register_prefix.readback",
+    "mxtpu.sched.register_prefix", "mxtpu.sched.register_prefix.store",
+    "mxtpu.sched.register_prefix.readback",
     "mxtpu.sched.admit", "mxtpu.sched.admit.prefill",
     "mxtpu.sched.capacity", "mxtpu.sched.dispatch",
     "mxtpu.sched.collect.readback", "mxtpu.sched.collect",

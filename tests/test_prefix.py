@@ -2,11 +2,17 @@
 
 Contracts under test:
 
-- TRIE: ``PrefixCache.insert`` registers page-aligned blocks under an
-  exact-prompt root (dedup on re-insert, partial tail as a leaf, no root
-  without cross frames), ``match`` returns the longest cached cover
-  capped at ``len(target) - 1`` with the partial page flagged for COW,
-  and ``check_invariants`` proves the trie's page ledger exact.
+- TRIE: ``PrefixCache.add_root`` gives an exact prompt its root and the
+  store row it pins; ``insert`` registers page-aligned blocks under it
+  (dedup on re-insert, partial tail as a leaf, nothing for a prompt
+  with no root), ``match`` returns the longest cached cover capped at
+  ``len(target) - 1`` with the partial page flagged for COW and the
+  root's row, and ``check_invariants`` proves the trie's page ledger
+  and its row ledger exact.
+- ROOT STORE: a retire copies the slot's cross rows into the new root's
+  row of the batcher's device store bit for bit, one dispatch a pass
+  and one row a prompt; an evicted root's row is handed out again;
+  ``flush`` frees every row.
 - REFCOUNTS: every page's refcount equals its slot mappings plus cache
   membership through arbitrary alloc / adopt_ref / cache_acquire /
   release / evict interleavings — ``PagePool.check_invariants(...,
@@ -42,17 +48,18 @@ from mxnet_tpu.gluon.model_zoo.transformer import TransformerModel
 from mxnet_tpu.parallel import InferStep
 from mxnet_tpu.serving import (ContinuousBatcher, PagePool, PrefillEngine,
                                PrefixCache, Replica, Router, prompt_digest)
-from mxnet_tpu.serving.batcher import GenerationResult
+from mxnet_tpu.serving.batcher import GenerationResult, _Request
 from mxnet_tpu.serving.pages import TRASH_PAGE, pages_for
 
 V = 61
 
 
-def _make_net(seed=0, prefix="pfx_net_"):
+def _make_net(seed=0, prefix="pfx_net_", num_layers=1):
     np.random.seed(seed)
     mx.random.seed(seed)
     net = TransformerModel(src_vocab=V, tgt_vocab=V, units=16,
-                           hidden_size=32, num_layers=1, num_heads=2,
+                           hidden_size=32, num_layers=num_layers,
+                           num_heads=2,
                            max_length=64, dropout=0.0, prefix=prefix)
     net.initialize(mx.initializer.Xavier())
     net._probe_shapes(nd.zeros((2, 8), dtype="int32"),
@@ -95,9 +102,11 @@ def _pool_cache(num_pages=12, page_size=4, slots=3, pages_per_slot=6,
     return pool, cache
 
 
-def _frames():
-    return dict(mem_vl=3, ck=np.zeros((1, 3, 16), np.float32),
-                cv=np.zeros((1, 3, 16), np.float32))
+def _retire(cache, prompt, target, pages):
+    """A retire as the batcher makes it: the prompt's root first (a store
+    row, where the prompt is new), then its page chain under the root."""
+    cache.add_root(prompt)
+    return cache.insert(prompt, target, pages)
 
 
 def _audit(pool, cache, live=()):
@@ -106,7 +115,7 @@ def _audit(pool, cache, live=()):
 
 
 class TestTrie:
-    def test_insert_without_frames_creates_no_root(self):
+    def test_insert_without_a_root_creates_none(self):
         pool, cache = _pool_cache()
         assert pool.alloc(0, 2)
         assert cache.insert([5, 6], range(1, 8), pool.owned(0)) == 0
@@ -120,7 +129,9 @@ class TestTrie:
         prompt, target = [5, 9, 11], [1, 2, 3, 4, 5, 6, 7]  # 1 full + tail
         assert pool.alloc(0, pages_for(len(target), 4))
         pages = pool.owned(0)
-        assert cache.insert(prompt, target, pages, **_frames()) == 2
+        row = cache.add_root(prompt)
+        assert row == 0 and cache.add_root(prompt) is None  # one row
+        assert cache.insert(prompt, target, pages) == 2
         assert cache.has_root(prompt)
         assert prompt_digest(prompt) in cache.digests()
         hit = cache.match(prompt, target)
@@ -129,17 +140,17 @@ class TestTrie:
         assert hit.matched == 6
         assert hit.full_pages == (pages[0],)
         assert hit.cow == (pages[1], 2)
-        assert hit.mem_vl == 3 and hit.ck is not None
+        assert hit.row == row
         _audit(pool, cache, live=(0,))
 
     def test_reinsert_dedups_blocks(self):
         pool, cache = _pool_cache()
         target = list(range(1, 9))  # exactly 2 full blocks
         assert pool.alloc(0, 2)
-        assert cache.insert([7], target, pool.owned(0), **_frames()) == 2
+        assert _retire(cache, [7], target, pool.owned(0)) == 2
         assert pool.alloc(1, 2)
         # same prompt+target from another slot: nothing new is cached
-        assert cache.insert([7], target, pool.owned(1), **_frames()) == 0
+        assert _retire(cache, [7], target, pool.owned(1)) == 0
         assert cache.total_pages == 2
         pool.release(1)  # its pages were never adopted by the trie
         assert pool.free_pages == 12 - 2
@@ -150,9 +161,9 @@ class TestTrie:
         a = [1, 2, 3, 4, 5, 6, 7, 8]
         b = [1, 2, 3, 4, 9, 9, 9, 9]  # shares block 0 only
         assert pool.alloc(0, 2) and pool.alloc(1, 2)
-        assert cache.insert([7], a, pool.owned(0), **_frames()) == 2
+        assert _retire(cache, [7], a, pool.owned(0)) == 2
         # block 0 dedups against slot 0's page; block 1 branches
-        assert cache.insert([7], b, pool.owned(1), **_frames()) == 1
+        assert _retire(cache, [7], b, pool.owned(1)) == 1
         assert cache.total_pages == 3
         ha, hb = cache.match([7], a), cache.match([7], b)
         assert ha.full_pages[0] == hb.full_pages[0]
@@ -168,7 +179,7 @@ class TestTrie:
         # that SAME page and re-registers the grown chain at retire —
         # the longer block supersedes the node instead of
         # double-acquiring its page
-        assert cache.insert([5], [1], (p0,), **_frames()) == 1
+        assert _retire(cache, [5], [1], (p0,)) == 1
         assert cache.insert([5], [1, 2, 3, 4, 9], (p0, p1)) == 1
         assert cache.total_pages == 2 and pool.ref(p0) == 2
         hit = cache.match([5], [1, 2, 3, 4, 9])
@@ -179,7 +190,7 @@ class TestTrie:
         pool, cache = _pool_cache()
         target = [1, 2, 3, 4]  # one exactly-full block
         assert pool.alloc(0, 1)
-        assert cache.insert([3], target, pool.owned(0), **_frames()) == 1
+        assert _retire(cache, [3], target, pool.owned(0)) == 1
         hit = cache.match([3], target)
         # the final position must still run to produce first-token
         # logits: a full-block cover degrades to a 3-token COW
@@ -189,14 +200,16 @@ class TestTrie:
 
     def test_max_roots_evicts_lru_root(self):
         pool, cache = _pool_cache(max_roots=2)
+        rows = []
         for i in range(3):
             assert pool.alloc(i, 1)
-            assert cache.insert([i], [1, 2, 3], pool.owned(i),
-                                **_frames()) == 1
+            rows.append(cache.add_root([i]))
+            assert cache.insert([i], [1, 2, 3], pool.owned(i)) == 1
             pool.release(i)
             _audit(pool, cache)
         assert len(cache) == 2
         assert not cache.has_root([0])  # LRU root dropped, pages freed
+        assert rows == [0, 1, 0]  # ... and its row handed out again
         assert cache.snapshot()["evicted_roots"] == 1
         assert pool.free_pages == 12 - 2
         _audit(pool, cache)
@@ -204,11 +217,12 @@ class TestTrie:
     def test_flush_returns_every_page(self):
         pool, cache = _pool_cache()
         assert pool.alloc(0, 3)
-        cache.insert([5], list(range(1, 12)), pool.owned(0), **_frames())
+        _retire(cache, [5], list(range(1, 12)), pool.owned(0))
         pool.release(0)
         assert pool.free_pages == 12 - 3
         assert cache.flush() == 1
         assert pool.free_pages == 12 and cache.total_pages == 0
+        assert sorted(cache._free_rows) == list(range(cache.max_roots))
         _audit(pool, cache)
 
 
@@ -217,7 +231,7 @@ class TestRefcounts:
         pool, cache = _pool_cache()
         assert pool.alloc(0, 2)
         p0, p1 = pool.owned(0)
-        cache.insert([9], list(range(1, 8)), (p0, p1), **_frames())
+        _retire(cache, [9], list(range(1, 8)), (p0, p1))
         assert pool.ref(p0) == pool.ref(p1) == 2
         _audit(pool, cache, live=(0,))
         assert pool.release(0) == 0  # cache still holds both
@@ -228,7 +242,7 @@ class TestRefcounts:
         pool, cache = _pool_cache()
         assert pool.alloc(0, 2)
         pages = pool.owned(0)
-        cache.insert([9], list(range(1, 8)), pages, **_frames())
+        _retire(cache, [9], list(range(1, 8)), pages)
         pool.release(0)
         # two readers adopt the cached chain (shared, read-only) …
         for s in (1, 2):
@@ -247,7 +261,7 @@ class TestRefcounts:
         pool, cache = _pool_cache()
         assert pool.alloc(0, 2)
         pages = pool.owned(0)
-        cache.insert([9], list(range(1, 8)), pages, **_frames())
+        _retire(cache, [9], list(range(1, 8)), pages)
         pool.release(0)
         assert pool.adopt_ref(1, pages)  # a live reader
         assert cache.evict(2) == 0  # nothing is sole-ref
@@ -275,13 +289,13 @@ class TestEviction:
         held = {}
         for i in range(3):
             assert pool.alloc(i, 1)
-            cache.insert([i], [1, 2, 3], pool.owned(i), **_frames())
+            _retire(cache, [i], [1, 2, 3], pool.owned(i))
             held[i] = pool.owned(i)[0]
             pool.release(i)
         cache.match([0], [1, 2, 3])  # refresh root 0: root 1 is now LRU
         assert cache.evict(1) == 1
-        # root 1's page went back to the pool (the frame-only root
-        # stays for encoder-skip); root 0's refreshed page survives
+        # root 1's page went back to the pool (the root and its store
+        # row stay for encoder-skip); root 0's refreshed page survives
         assert held[1] not in cache.pages()
         assert held[0] in cache.pages()
         assert cache.match([1], [1, 2, 3]).matched == 0
@@ -294,7 +308,7 @@ class TestEviction:
         pool, cache = _pool_cache(max_pages=2)
         for i in range(3):
             assert pool.alloc(i, 1)
-            cache.insert([i], [1, 2, 3], pool.owned(i), **_frames())
+            _retire(cache, [i], [1, 2, 3], pool.owned(i))
             pool.release(i)
             assert cache.total_pages <= 2
             _audit(pool, cache)
@@ -407,6 +421,129 @@ class TestEndToEnd:
             assert eng.compile_guard.steady_state_recompiles == 0
         finally:
             bat.stop()
+
+
+def _drive(bat, prompts, prefix=None):
+    """Queue requests on an UNSTARTED batcher and run scheduler passes on
+    this thread until it is drained: which requests share a pass is then
+    the test's to decide, not the clock's."""
+    futs = []
+    for p in prompts:
+        fut = GenerationResult()
+        bat._queue.put(_Request(
+            np.asarray(p, np.int32), 6, fut,
+            prefix=None if prefix is None else np.asarray(prefix, np.int32)))
+        futs.append(fut)
+    while bat._step_once():
+        pass
+    return [list(f.result(timeout=0)) for f in futs]
+
+
+@pytest.fixture(scope="module")
+def store_pair():
+    """A cached and a cold batcher over one two-layer engine, neither
+    started, the cached one with a store of two rows."""
+    eng = InferStep(_make_net(1, prefix="pfx_store_", num_layers=2),
+                    max_len=64)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("MXTPU_PREFIX_MAX_ROOTS", "2")
+        bats = [ContinuousBatcher(eng, (8,), slots=3, max_new_tokens=6,
+                                  page_size=4, iter_tokens=2,
+                                  max_prefix_tokens=16, prefix_cache=on,
+                                  warmup=True, start=False,
+                                  name=f"pfx-store-{on}")
+                for on in (True, False)]
+    yield eng, bats[0], bats[1]
+    for bat in bats:
+        bat.stop()
+
+
+class TestRootStore:
+    A, B, C = [5, 9, 11, 2, 7], [8, 3, 14], [4, 12, 9, 33, 6, 20]
+
+    @staticmethod
+    def _row(bat, prompt):
+        return bat.cache._roots[tuple(prompt)].row
+
+    def test_store_row_is_the_slots_cross_rows_bit_for_bit(self,
+                                                           store_pair):
+        _, bat, _ = store_pair
+        assert bat.cache.max_roots == 2
+        bat.cache.flush()
+        _drive(bat, [self.A])  # an empty batcher serves it in slot 0
+        row, st = self._row(bat, self.A), bat._state
+        frames, vl = bat._store
+        bufs = st["cross_k"] + st["cross_v"]
+        assert len(frames) == len(bufs) == 4  # two layers' K, then V
+        for buf, rows in zip(bufs, frames):
+            assert rows.dtype == buf.dtype
+            assert rows.shape == (2,) + buf.shape[1:]
+            got = np.asarray(rows[row])
+            assert got.any()
+            assert np.array_equal(got, np.asarray(buf[0]))
+        assert vl.shape == (2,)
+        assert int(vl[row]) == int(st["mem_vl"][0]) == len(self.A)
+        _settled_audit(bat)
+
+    def test_one_dispatch_a_pass_and_one_row_a_prompt(self, store_pair):
+        _, bat, _ = store_pair
+        bat.cache.flush()
+        before = dict(bat.stats)
+        # three slots admitted in one pass retire in one pass: each
+        # serves its six tokens (no early EOS), two of them one prompt
+        outs = _drive(bat, [self.A, self.B, self.A])
+        assert [len(o) for o in outs] == [6, 6, 6]
+        after = dict(bat.stats)
+        assert after["retired"] - before["retired"] == 3
+        assert after["prefix_store_dispatches"] \
+            - before["prefix_store_dispatches"] == 1
+        assert after["prefix_rows_stored"] \
+            - before["prefix_rows_stored"] == 2
+        assert {self._row(bat, self.A), self._row(bat, self.B)} == {0, 1}
+        # a prompt the trie knows retires with no device step at all
+        _drive(bat, [self.A, self.B])
+        assert bat.stats["prefix_store_dispatches"] \
+            == after["prefix_store_dispatches"]
+        _settled_audit(bat)
+
+    def test_third_prompt_reuses_the_oldest_roots_row(self, store_pair):
+        _, bat, cold = store_pair
+        bat.cache.flush()
+        (turn1,) = _drive(bat, [self.A])
+        _drive(bat, [self.B])
+        _drive(bat, [self.A])  # refresh A: B is the oldest root now
+        rows = {p: self._row(bat, getattr(self, p)) for p in "AB"}
+        _drive(bat, [self.C])
+        assert not bat.cache.has_root(self.B)
+        assert bat.cache.snapshot()["evicted_roots"] >= 1
+        assert self._row(bat, self.C) == rows["B"]
+        assert self._row(bat, self.A) == rows["A"]
+        # the surviving root still serves its hit (adopted page, COW
+        # tail, cross rows out of the store) bit-identical to cold
+        base = bat.prefix_stats()
+        assert _drive(bat, [self.A], prefix=turn1) \
+            == _drive(cold, [self.A], prefix=turn1)
+        stats = bat.prefix_stats()
+        assert stats["hits"] == base["hits"] + 1
+        assert stats["cow_copies"] == base["cow_copies"] + 1
+        _settled_audit(bat)
+
+    def test_flush_frees_every_row(self, store_pair):
+        _, bat, _ = store_pair
+        _drive(bat, [self.A, self.B])
+        assert len(bat.cache) == 2 and bat.cache._free_rows == []
+        assert bat.cache.flush() == 2
+        assert sorted(bat.cache._free_rows) == [0, 1]
+        assert _drive(bat, [self.C]) and self._row(bat, self.C) in (0, 1)
+        _settled_audit(bat)
+
+    def test_two_programs_and_no_recompile(self, store_pair):
+        # runs after the retire, hit, COW and eviction traffic above
+        eng, bat, _ = store_pair
+        assert eng.compile_guard.steady
+        assert eng.compile_guard.steady_state_recompiles == 0
+        assert bat._store_fn._cache_size() == 1
+        assert bat._hits_fn._cache_size() == 1
 
 
 class _StubBatcher:
