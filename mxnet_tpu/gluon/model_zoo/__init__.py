@@ -4,7 +4,8 @@ language models mirror the GluonNLP-era workloads in BASELINE.md)."""
 from . import vision  # noqa: F401
 from . import bert  # noqa: F401
 from . import transformer  # noqa: F401
+from . import keye  # noqa: F401
 from . import ssd  # noqa: F401
 from . import faster_rcnn  # noqa: F401
 
-__all__ = ["vision", "bert", "transformer", "ssd", "faster_rcnn"]
+__all__ = ["vision", "bert", "transformer", "keye", "ssd", "faster_rcnn"]

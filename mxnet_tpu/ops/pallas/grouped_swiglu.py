@@ -1,0 +1,212 @@
+"""Expert layer: tokens grouped by expert and ONE grouped SwiGLU product
+over the experts that have tokens.
+
+A mixture-of-experts layer sends each token to ``k`` of ``E`` experts. No
+token is dropped, whatever the load: the (token, expert) pairs are sorted
+by expert, each expert's run of rows is padded to a whole number of row
+tiles, and the kernel walks the tiles. A tile belongs to exactly one
+expert; the tile-to-expert map rides the grid as a scalar-prefetch operand
+and picks the weight blocks, so an expert's weights are read once for its
+run of tiles and an expert with no tokens is never read. Tiles past the
+last used one keep the previous block indices (no new DMA) and skip the
+product. In decode 16 rows touch about 82 of 128 experts a layer and only
+those 82 are read; a dense product over all experts with masking reads all
+128.
+
+``moe_experts`` is the layer (router, grouping, product, combine);
+``grouped_swiglu`` the product alone, a Pallas kernel on the TPU
+(``MXTPU_FLASH_INTERPRET`` as for every kernel of this package) with
+``grouped_swiglu_reference``, its jnp form, where a compiled kernel cannot
+be partitioned (``_partitionable``) and as the tolerance tests' oracle.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import _partitionable, _use_interpret
+
+__all__ = ["moe_experts", "route", "group_by_expert", "grouped_swiglu",
+           "grouped_swiglu_reference", "row_tile", "f_block"]
+
+
+def row_tile(pairs: int, experts: int) -> int:
+    """Rows a tile: 128 where an expert's share of the pairs fills it
+    (prefill), else 16 (decode: the bf16 sublane tile)."""
+    return 128 if pairs >= 64 * experts else 16
+
+
+def f_block(width: int) -> int:
+    """Columns of the expert's hidden width a grid step: the largest
+    multiple of 128 that divides it and is at most 512 (768 -> 384), so
+    that three weight blocks, double-buffered, fit the scoped VMEM."""
+    for fb in (512, 384, 256, 128):
+        if width % fb == 0:
+            return fb
+    return width
+
+
+def route(u, router, k):
+    """``(experts (T, k) int32, weights (T, k) float32)``: softmax over all
+    experts in float32, the ``k`` largest, renormalised."""
+    logits = jnp.dot(u, router, preferred_element_type=jnp.float32)
+    prob = jax.nn.softmax(logits, axis=-1)
+    top, idx = jax.lax.top_k(prob, k)
+    return idx.astype(jnp.int32), top / jnp.sum(top, -1, keepdims=True)
+
+
+def group_by_expert(experts, num_experts, tile):
+    """Rows of the padded, expert-sorted layout for ``(T, k)`` expert ids.
+
+    Returns ``(dest (T, k), src_token (M,), tile_expert (M // tile,),
+    n_tiles (1,), counts (E,))``: pair ``(t, j)`` sits at row ``dest[t,
+    j]``; row ``m`` holds token ``src_token[m]`` (0 for padding rows, whose
+    result nobody reads); tile ``i`` belongs to ``tile_expert[i]`` (tiles
+    past ``n_tiles`` repeat the last used expert)."""
+    T, k = experts.shape
+    n = T * k
+    flat = experts.reshape(n)
+    counts = jnp.zeros((num_experts,), jnp.int32).at[flat].add(1)
+    padded = (counts + tile - 1) // tile * tile
+    start = jnp.cumsum(counts) - counts          # in the sorted order
+    pstart = jnp.cumsum(padded) - padded         # in the padded layout
+    order = jnp.argsort(flat, stable=True)
+    sorted_e = flat[order]
+    dest_sorted = pstart[sorted_e] + jnp.arange(n, dtype=jnp.int32) \
+        - start[sorted_e]
+    M = (n + num_experts * (tile - 1) + tile - 1) // tile * tile
+    dest = jnp.zeros((n,), jnp.int32).at[order].set(dest_sorted)
+    src_token = jnp.zeros((M,), jnp.int32).at[dest_sorted].set(
+        (order // k).astype(jnp.int32))
+    ends = jnp.cumsum(padded)
+    n_tiles = (ends[-1] // tile).astype(jnp.int32)
+    tile_at = jnp.minimum(jnp.arange(M // tile, dtype=jnp.int32),
+                          jnp.maximum(n_tiles - 1, 0)) * tile
+    tile_expert = jnp.searchsorted(ends, tile_at, side="right") \
+        .astype(jnp.int32)
+    tile_expert = jnp.minimum(tile_expert, num_experts - 1)
+    return dest.reshape(T, k), src_token, tile_expert, \
+        n_tiles.reshape(1), counts
+
+
+def _dot(a, b):
+    # a process-wide "highest" matmul precision (the float32 parity tests
+    # set it) is not one Mosaic takes for bfloat16 operands
+    return jnp.dot(a, b, preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.DEFAULT
+                   if a.dtype == jnp.bfloat16 else None)
+
+
+def _swiglu_kernel(te_ref, nt_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref,
+                   acc_ref):
+    i, f = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(f == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(i < nt_ref[0])
+    def _product():
+        x = x_ref[...]
+        g = _dot(x, wg_ref[0])
+        u = _dot(x, wu_ref[0])
+        h = (g * jax.nn.sigmoid(g) * u).astype(x.dtype)
+        acc_ref[...] += _dot(h, wd_ref[0])
+
+    @pl.when(f == pl.num_programs(1) - 1)
+    def _store():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("tile",))
+def _moe_grouped_swiglu_impl(x, tile_expert, n_tiles, w_gate, w_up, w_down,
+                             tile):
+    M, H = x.shape
+    F = w_gate.shape[2]
+    fb = f_block(F)
+    nf = F // fb
+
+    def col(i, f, te, nt):          # an unused tile re-reads nothing
+        return jnp.where(i < nt[0], f, nf - 1)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(M // tile, nf),
+        in_specs=[
+            pl.BlockSpec((tile, H), lambda i, f, te, nt: (i, 0)),
+            pl.BlockSpec((1, H, fb),
+                         lambda i, f, te, nt: (te[i], 0, col(i, f, te, nt))),
+            pl.BlockSpec((1, H, fb),
+                         lambda i, f, te, nt: (te[i], 0, col(i, f, te, nt))),
+            pl.BlockSpec((1, fb, H),
+                         lambda i, f, te, nt: (te[i], col(i, f, te, nt), 0)),
+        ],
+        out_specs=pl.BlockSpec((tile, H), lambda i, f, te, nt: (i, 0)),
+        scratch_shapes=[pltpu.VMEM((tile, H), jnp.float32)],
+    )
+    return pl.pallas_call(
+        _swiglu_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((M, H), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=48 * 1024 * 1024),
+        interpret=_use_interpret(),
+        name="moe_grouped_swiglu",
+    )(tile_expert, n_tiles, x, w_gate, w_up, w_down)
+
+
+def grouped_swiglu_reference(x, tile_expert, n_tiles, w_gate, w_up, w_down,
+                             tile):
+    """jnp form of ``grouped_swiglu``: each tile against its expert's
+    gathered weights (tiny sizes, and under a multi-device mesh)."""
+    M, H = x.shape
+    xt = x.reshape(M // tile, tile, H)
+    g = jnp.einsum("nth,nhf->ntf", xt, w_gate[tile_expert],
+                   preferred_element_type=jnp.float32)
+    u = jnp.einsum("nth,nhf->ntf", xt, w_up[tile_expert],
+                   preferred_element_type=jnp.float32)
+    h = (g * jax.nn.sigmoid(g) * u).astype(x.dtype)
+    y = jnp.einsum("ntf,nfh->nth", h, w_down[tile_expert],
+                   preferred_element_type=jnp.float32)
+    used = jnp.arange(M // tile)[:, None, None] < n_tiles[0]
+    return jnp.where(used, y, 0.0).astype(x.dtype).reshape(M, H)
+
+
+def grouped_swiglu(x, tile_expert, n_tiles, w_gate, w_up, w_down, tile):
+    """``w_down[e] (silu(x w_gate[e]) * (x w_up[e]))`` for every row tile
+    of ``x (M, H)`` against the expert ``e = tile_expert[i]`` of its tile;
+    weights ``(E, H, F)``, ``(E, H, F)``, ``(E, F, H)``. Rows of tiles past
+    ``n_tiles`` come back zero."""
+    if not _partitionable():
+        return grouped_swiglu_reference(x, tile_expert, n_tiles, w_gate,
+                                        w_up, w_down, tile)
+    return _moe_grouped_swiglu_impl(x, tile_expert, n_tiles, w_gate, w_up,
+                                    w_down, tile=tile)
+
+
+def moe_experts(u, router, w_gate, w_up, w_down, k, valid=None):
+    """The expert layer on tokens ``u (T, H)``: ``sum_{e in top-k} a_e
+    w_down[e] (silu(u w_gate[e]) * (u w_up[e]))`` with ``a`` the
+    renormalised router probabilities. ``valid (T,)`` marks padding tokens,
+    which are computed and not counted. Returns ``(out (T, H), counts (E,)
+    int32)``: tokens routed to each expert."""
+    T, H = u.shape
+    E = router.shape[1]
+    experts, weights = route(u, router, k)
+    tile = row_tile(T * k, E)
+    dest, src_token, tile_expert, n_tiles, counts = group_by_expert(
+        experts, E, tile)
+    y = grouped_swiglu(u[src_token], tile_expert, n_tiles, w_gate, w_up,
+                       w_down, tile)
+    picked = y[dest.reshape(T * k)].reshape(T, k, H).astype(jnp.float32)
+    out = jnp.einsum("tkh,tk->th", picked, weights).astype(u.dtype)
+    if valid is not None:
+        counts = jnp.zeros((E,), jnp.int32).at[experts.reshape(T * k)].add(
+            jnp.repeat(valid.astype(jnp.int32), k))
+    return out, counts

@@ -26,7 +26,15 @@ One kernel, two entry points:
   Mosaic refuses a batched ``dot_general`` whose lhs has no free
   dimension, so the query keeps its unit window axis.
 
-Both keep ``MXTPU_FLASH_INTERPRET`` (force/forbid/auto, shared with
+- ``paged_selected_window_attention`` — the window over a SELECTED set
+  of cached positions (learned sparse attention), for grouped-query
+  heads: ``Hq`` query heads over ``Hkv`` key/value heads. A mask ``(B, C,
+  L)`` says which cached positions each query reads; it rides the grid
+  in blocks beside the pages. Grid ``(B, query blocks, pages)``; pages
+  past the last one a query block can see are neither fetched nor
+  computed.
+
+All keep ``MXTPU_FLASH_INTERPRET`` (force/forbid/auto, shared with
 ``flash_attention.py``) and ship a dense jnp reference
 (``*_reference``) used by the tolerance tests; the MODULE-level
 fallback when the kernel gate is off is the attention layer's existing
@@ -52,7 +60,8 @@ from .flash_attention import _NEG_INF
 
 __all__ = ["paged_decode_attention", "paged_window_attention",
            "paged_decode_reference", "paged_window_reference",
-           "flash_paged_enabled"]
+           "paged_selected_window_attention",
+           "paged_selected_window_reference", "flash_paged_enabled"]
 
 # online-softmax m/l scratch is lane-replicated to the TPU register
 # width (the flash-kernel convention): every lane of a row holds the
@@ -196,6 +205,146 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, pos, *,
     ``(B, H, D)``."""
     return paged_window_attention(q[:, None], k_pool, v_pool, page_table,
                                   pos, sm_scale=sm_scale)[:, 0]
+
+
+# ------------------------------------------------ selected window (GQA)
+def _selected_window_kernel(pt_ref, off_ref, q_ref, k_ref, v_ref, mask_ref,
+                            o_ref, m_ref, l_ref, acc_ref, *, page_size,
+                            sm_scale, tq, groups):
+    """Grid (B, query blocks, pages), pages sequential: one pool page per
+    step, online-softmax carry in VMEM scratch. The query block holds, for
+    each key/value head, its ``groups`` query heads' ``tq`` queries in
+    turn (rows ``g * tq + t``); the mask block ``(tq, page)`` is the same
+    for every head."""
+    b, i, p = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+
+    @pl.when(p == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    # the block's LAST query bounds what any of its queries can see
+    @pl.when(p * page_size <= off_ref[b] + (i + 1) * tq - 1)
+    def _accumulate():
+        k = k_ref[0]                               # (ps, Hkv, D)
+        v = v_ref[0]
+        keep = mask_ref[0] != 0                    # (tq, ps)
+        # a process-wide "highest" precision is not one Mosaic takes for
+        # bfloat16 operands
+        prec = jax.lax.Precision.DEFAULT if k.dtype == jnp.bfloat16 else None
+        for g in range(groups):
+            rows = slice(g * tq, (g + 1) * tq)
+            s = jax.lax.dot_general(
+                q_ref[0, 0, :, rows, :], k, (((2,), (2,)), ((0,), (1,))),
+                preferred_element_type=jnp.float32,
+                precision=prec) * sm_scale         # (Hkv, tq, ps)
+            s = jnp.where(keep[None], s, _NEG_INF)
+            m_prev = m_ref[:, rows, :]             # (Hkv, tq, LANES)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p_act = jnp.where(keep[None], jnp.exp(s - m_new[:, :, :1]), 0.0)
+            l_ref[:, rows, :] = alpha * l_ref[:, rows, :] \
+                + jnp.sum(p_act, axis=2, keepdims=True)
+            acc_ref[:, rows, :] = acc_ref[:, rows, :] * alpha[:, :, :1] \
+                + jax.lax.dot_general(
+                    p_act.astype(v.dtype), v,
+                    (((2,), (0,)), ((0,), (1,))),
+                    preferred_element_type=jnp.float32,
+                    precision=prec)                # (Hkv, tq, D)
+            m_ref[:, rows, :] = m_new
+
+    @pl.when(p == pl.num_programs(2) - 1)
+    def _finalize():
+        l = jnp.maximum(l_ref[:, :, :1], 1e-30)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "tq"))
+def _dsa_selected_window_impl(q, k_pool, v_pool, page_table, q_offset,
+                              mask, sm_scale, tq):
+    B, C, Hq, D = q.shape
+    ps, Hkv = k_pool.shape[1], k_pool.shape[2]
+    P = page_table.shape[1]
+    G, nq = Hq // Hkv, C // tq
+    # (B, nq, Hkv, G * tq, D): a block's rows are (query head of the
+    # group, query) for each key/value head
+    qb = q.reshape(B, nq, tq, Hkv, G, D).transpose(0, 1, 3, 4, 2, 5) \
+        .reshape(B, nq, Hkv, G * tq, D)
+
+    def page(b, i, p, pt, off):     # an unseen page re-reads nothing
+        return jnp.minimum(p, jnp.minimum(
+            (off[b] + (i + 1) * tq - 1) // ps, P - 1))
+
+    kernel = functools.partial(_selected_window_kernel, page_size=ps,
+                               sm_scale=sm_scale, tq=tq, groups=G)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, nq, P),
+        in_specs=[
+            pl.BlockSpec((1, 1, Hkv, G * tq, D),
+                         lambda b, i, p, pt, off: (b, i, 0, 0, 0)),
+            pl.BlockSpec((1, ps, Hkv, D), lambda b, i, p, pt, off:
+                         (pt[b, page(b, i, p, pt, off)], 0, 0, 0)),
+            pl.BlockSpec((1, ps, Hkv, D), lambda b, i, p, pt, off:
+                         (pt[b, page(b, i, p, pt, off)], 0, 0, 0)),
+            pl.BlockSpec((1, tq, ps), lambda b, i, p, pt, off:
+                         (b, i, page(b, i, p, pt, off))),
+        ],
+        out_specs=pl.BlockSpec((1, 1, Hkv, G * tq, D),
+                               lambda b, i, p, pt, off: (b, i, 0, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((Hkv, G * tq, _LANES), jnp.float32),
+            pltpu.VMEM((Hkv, G * tq, _LANES), jnp.float32),
+            pltpu.VMEM((Hkv, G * tq, D), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, nq, Hkv, G * tq, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=48 * 1024 * 1024),
+        interpret=_use_interpret(),
+        name="dsa_selected_window",
+    )(page_table.astype(jnp.int32), q_offset.astype(jnp.int32), qb,
+      k_pool, v_pool, mask.astype(jnp.int8))
+    return out.reshape(B, nq, Hkv, G, tq, D).transpose(0, 1, 4, 2, 3, 5) \
+        .reshape(B, C, Hq * D)
+
+
+def paged_selected_window_attention(q, k_pool, v_pool, page_table,
+                                    q_offset, mask, *, sm_scale):
+    """A ``C``-query window a row over a SELECTED set of its cached
+    positions, pools read in place. ``q (B, C, Hq, D)``; pools
+    ``(num_pages, page, Hkv, D)`` with ``Hq`` a multiple of ``Hkv`` (query
+    head ``i`` reads key/value head ``i // (Hq // Hkv)``); ``mask (B, C,
+    L)`` true where query ``c`` of row ``b`` reads cached position ``l``
+    (the caller keeps it causal: nothing past ``q_offset[b] + c``).
+    Returns ``(B, C, Hq * D)``."""
+    C = q.shape[1]
+    # queries a block: 256 reads a chunk's last 2,048 queries over 16k keys
+    # in 13.3 ms on a v5e where 128 takes 19.1 (PERF.md, PR 27)
+    tq = next((t for t in (256, 128) if C % t == 0), C)
+    return _dsa_selected_window_impl(q, k_pool, v_pool, page_table,
+                                     q_offset, mask, sm_scale=sm_scale,
+                                     tq=tq)
+
+
+def paged_selected_window_reference(q, k_pool, v_pool, page_table,
+                                    q_offset, mask, *, sm_scale):
+    """Dense jnp reference for ``paged_selected_window_attention``."""
+    B, C, Hq, D = q.shape
+    ps, Hkv = k_pool.shape[1], k_pool.shape[2]
+    P = page_table.shape[1]
+    k = k_pool[page_table].reshape(B, P * ps, Hkv, D).astype(jnp.float32)
+    v = v_pool[page_table].reshape(B, P * ps, Hkv, D).astype(jnp.float32)
+    qg = q.astype(jnp.float32).reshape(B, C, Hkv, Hq // Hkv, D)
+    s = jnp.einsum("bcngd,blnd->bngcl", qg, k) * sm_scale
+    probs = jax.nn.softmax(jnp.where(mask[:, None, None], s, _NEG_INF), -1)
+    out = jnp.einsum("bngcl,blnd->bcngd", probs, v)
+    return out.reshape(B, C, Hq * D).astype(q.dtype)
 
 
 # ------------------------------------------------------------ references

@@ -1,0 +1,229 @@
+"""Learned sparse attention over a paged cache: an indexer scores every
+cached position for a query, the ``topk`` best are selected, and attention
+reads the selected set alone.
+
+``I[t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s]) / sqrt(Di * J)`` for
+``s <= t``; the selected set of ``t`` is every ``s <= t`` while ``t + 1 <=
+topk``, else the ``topk`` positions of largest ``I[t, .]``, ties to the
+lower position. Below ``topk + 1`` cached positions both programs are plain
+causal attention.
+
+Two shapes, as the serving plane has them:
+
+- the WINDOW (a prefill chunk): ``C`` queries a row at ``q_offset + i``
+  over the row's pages, which already hold the chunk's own keys. Index
+  scores and attention walk the cached keys in blocks and stop at the last
+  block any query can see; the selection is a mask, and attention is a
+  flash pass over the pages masked to it (a Pallas kernel on the TPU,
+  ``ops/pallas/paged_flash_attention.paged_selected_window_attention``).
+- DECODE: one query a row; the scores of the row's cached keys come
+  through the page table, the ``topk`` positions are gathered from the
+  pools BY TOKEN (not by page), and attention reads those alone.
+
+Everything accumulates in float32 (scores, softmax); the pools and the
+queries keep their own dtype.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+NEG = -1e30
+
+
+def kv_block(length: int, chunk: int = 512) -> int:
+    """Keys a block of the window loops: the published ``kv_chunk_size``
+    where it divides a row's cached length, else their largest common
+    divisor."""
+    return math.gcd(int(length), int(chunk))
+
+
+def gather_row_pages(pool, page_tables):
+    """``pool (num_pages, page, ...)`` through ``page_tables (R, P)`` as
+    ``(R, P * page, ...)``: a row's cached positions in order."""
+    got = pool[page_tables]
+    return got.reshape((got.shape[0], got.shape[1] * got.shape[2])
+                       + got.shape[3:])
+
+
+def token_rows(page_tables, positions, page_size):
+    """Rows of the flattened pool ``(num_pages * page, ...)`` that hold
+    ``positions (R, K)`` of each row: the gather by token."""
+    page = jnp.take_along_axis(page_tables, positions // page_size, axis=1)
+    return page * page_size + positions % page_size
+
+
+def write_rows(pool, rows, values):
+    """``pool`` with ``values (N, ...)`` written at flattened rows ``rows
+    (N,)`` (rows of the trash page for what must not land)."""
+    flat = pool.reshape((-1,) + pool.shape[2:])
+    return flat.at[rows].set(values.astype(pool.dtype)).reshape(pool.shape)
+
+
+# ----------------------------------------------------------------- window
+def window_index_scores(qi, wi, ki_all, q_pos, n_blocks, block):
+    """``I (R, C, L)`` float32 of window queries ``qi (R, C, J, Di)`` with
+    head weights ``wi (R, C, J)`` against the row's cached indexer keys
+    ``ki_all (R, L, Di)``; -inf where ``s > q_pos`` and past the
+    ``n_blocks`` blocks walked."""
+    R, C, J, Di = qi.shape
+    L = ki_all.shape[1]
+    scale = 1.0 / math.sqrt(Di * J)
+    wi = wi.astype(jnp.float32)
+
+    def body(j, buf):
+        kb = jax.lax.dynamic_slice(ki_all, (0, j * block, 0),
+                                   (R, block, Di))
+        hit = jax.nn.relu(jnp.einsum("rcjd,rsd->rcjs", qi, kb,
+                                     preferred_element_type=jnp.float32))
+        blk = jnp.einsum("rcjs,rcj->rcs", hit, wi) * scale
+        return jax.lax.dynamic_update_slice(buf, blk, (0, 0, j * block))
+
+    scores = jax.lax.fori_loop(
+        0, n_blocks, body, jnp.full((R, C, L), -jnp.inf, jnp.float32))
+    seen = jnp.arange(L)[None, None, :] <= q_pos[:, :, None]
+    return jnp.where(seen, scores, -jnp.inf)
+
+
+def _ordered_bits(x):
+    """float32 as uint32 whose order is the floats' (-inf lowest)."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    key = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+    return jax.lax.bitcast_convert_type(key, jnp.uint32) \
+        ^ jnp.uint32(0x80000000)
+
+
+def kth_largest_bits(keys, k):
+    """The ``k``-th largest of each row of ``keys (..., L)`` uint32, as
+    ``(..., 1)``: the largest ``T`` with ``count(keys >= T) >= k``, found
+    two bits a pass from the top (sixteen counting passes over the rows;
+    a sort of every row, which is what ``lax.top_k`` lowers to on the
+    TPU, costs many times that at 2,048 rows of 16,640)."""
+    def body(i, prefix):
+        shift = (30 - 2 * i).astype(jnp.uint32)
+        best = prefix
+        for c in (1, 2, 3):        # counts fall as the candidate rises
+            cand = prefix | (jnp.uint32(c) << shift)
+            enough = jnp.sum(keys >= cand, -1, keepdims=True) >= k
+            best = jnp.where(enough, cand, best)
+        return best
+
+    return jax.lax.fori_loop(
+        0, 16, body, jnp.zeros(keys.shape[:-1] + (1,), jnp.uint32))
+
+
+def select_mask(scores, q_pos, topk):
+    """The selected set of each query as a mask ``(R, C, L)``: every seen
+    position while there are at most ``topk``, else the ``topk`` of largest
+    score, ties to the lower position. ``scores`` is -inf where unseen."""
+    L = scores.shape[-1]
+    seen = jnp.arange(L)[None, None, :] <= q_pos[:, :, None]
+    if L <= topk:
+        return seen
+    keys = _ordered_bits(scores)
+    kth = kth_largest_bits(keys, topk)
+    above = keys > kth
+    tie = (keys == kth) & seen
+    room = topk - jnp.sum(above, -1, keepdims=True)
+    # nearly always the ties at the cut are exactly as many as there is
+    # room for (one key equals the k-th value): only where a row has more
+    # does their order matter, and the running count is paid for
+    crowded = jnp.any(jnp.sum(tie, -1, keepdims=True) > room)
+    return jax.lax.cond(
+        crowded,
+        lambda: seen & (above | (tie & (jnp.cumsum(tie, -1) <= room))),
+        lambda: seen & (above | tie))
+
+
+def selected_window_attention(q, k_pool, v_pool, page_tables, q_offset,
+                              mask, n_blocks, block, sm_scale):
+    """Attention of window queries ``q (R, C, Hq, D)`` at ``q_offset[r] +
+    c`` over the row's cached positions that ``mask (R, C, L)`` selects;
+    query head ``i`` reads key/value head ``i // (Hq // Hkv)``. The Pallas
+    kernel reads the pools in place where the paged kernels are on
+    (``flash_paged_enabled``: a TPU, no multi-device mesh); else a flash
+    loop in jnp over the first ``n_blocks`` blocks of ``block`` gathered
+    keys. Returns ``(R, C, Hq * D)`` in ``q``'s dtype."""
+    from .pallas import paged_flash_attention as _pfa
+
+    if _pfa.flash_paged_enabled():
+        return _pfa.paged_selected_window_attention(
+            q, k_pool, v_pool, page_tables, q_offset, mask,
+            sm_scale=sm_scale)
+    R, C, Hq, D = q.shape
+    k_all = gather_row_pages(k_pool, page_tables)
+    v_all = gather_row_pages(v_pool, page_tables)
+    Hkv = k_all.shape[2]
+    G = Hq // Hkv
+    qg = q.reshape(R, C, Hkv, G, D)
+
+    def body(j, carry):
+        m, l, acc = carry
+        kb = jax.lax.dynamic_slice(k_all, (0, j * block, 0, 0),
+                                   (R, block, Hkv, D))
+        vb = jax.lax.dynamic_slice(v_all, (0, j * block, 0, 0),
+                                   (R, block, Hkv, D))
+        mb = jax.lax.dynamic_slice(mask, (0, 0, j * block),
+                                   (R, C, block))[:, None, None]
+        s = jnp.einsum("rcngd,rsnd->rngcs", qg, kb,
+                       preferred_element_type=jnp.float32) * sm_scale
+        s = jnp.where(mb, s, NEG)
+        m_new = jnp.maximum(m, jnp.max(s, -1))
+        p = jnp.where(mb, jnp.exp(s - m_new[..., None]), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l = alpha * l + jnp.sum(p, -1)
+        acc = alpha[..., None] * acc + jnp.einsum(
+            "rngcs,rsnd->rngcd", p.astype(v_all.dtype), vb,
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    m0 = jnp.full((R, Hkv, G, C), NEG, jnp.float32)
+    l0 = jnp.zeros((R, Hkv, G, C), jnp.float32)
+    a0 = jnp.zeros((R, Hkv, G, C, D), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, a0))
+    out = acc / jnp.maximum(l, 1e-30)[..., None]          # (R,Hkv,G,C,D)
+    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(R, C, Hq * D) \
+        .astype(q.dtype)
+
+
+# ----------------------------------------------------------------- decode
+def decode_select(qi, wi, ik_pool, page_tables, pos, topk):
+    """``(positions (B, K) int32, valid (B, K))`` of the selected set of
+    one query a row at position ``pos (B,)``: ``qi (B, J, Di)``, ``wi (B,
+    J)``, the row's indexer keys read through ``page_tables``. ``K`` is
+    ``topk``, or every cached position where a row holds no more."""
+    B, J, Di = qi.shape
+    ki = gather_row_pages(ik_pool, page_tables)             # (B, L, Di)
+    L = ki.shape[1]
+    hit = jax.nn.relu(jnp.einsum("bjd,bsd->bjs", qi, ki,
+                                 preferred_element_type=jnp.float32))
+    scores = jnp.einsum("bjs,bj->bs", hit, wi.astype(jnp.float32)) \
+        / math.sqrt(Di * J)
+    seen = jnp.arange(L)[None, :] <= pos[:, None]
+    if L <= topk:
+        return jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32),
+                                (B, L)), seen
+    vals, idx = jax.lax.top_k(jnp.where(seen, scores, -jnp.inf), topk)
+    return idx.astype(jnp.int32), vals > -jnp.inf
+
+
+def selected_decode_attention(q, k_pool, v_pool, page_tables, positions,
+                              valid, sm_scale):
+    """One query a row, ``q (B, Hq, D)``, over the ``positions (B, K)`` of
+    its row that ``valid`` marks, gathered from the pools by token.
+    Returns ``(B, Hq * D)``."""
+    B, Hq, D = q.shape
+    page_size, Hkv = k_pool.shape[1], k_pool.shape[2]
+    rows = token_rows(page_tables, positions, page_size)
+    ks = k_pool.reshape((-1, Hkv, D))[rows]                  # (B, K, Hkv, D)
+    vs = v_pool.reshape((-1, Hkv, D))[rows]
+    qg = q.reshape(B, Hkv, Hq // Hkv, D)
+    s = jnp.einsum("bngd,bsnd->bngs", qg, ks,
+                   preferred_element_type=jnp.float32) * sm_scale
+    p = jax.nn.softmax(jnp.where(valid[:, None, None, :], s, NEG), -1)
+    out = jnp.einsum("bngs,bsnd->bngd", p.astype(vs.dtype), vs,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(B, Hq * D).astype(q.dtype)

@@ -74,6 +74,28 @@ def _sample_tokens(logits, key, method, top_k, temperature):
                      "use greedy/top_k/sample")
 
 
+def _take_counts(tokens, state):
+    """Device-side counts ride the read-back the scheduler already makes:
+    where a net keeps ``state["counts"]`` (int32, added to by its layers),
+    they are appended to the tokens a paged program hands back, as extra
+    columns of a ``(rows, n)`` block or at the end of a ``(rows,)``
+    vector, and zeroed in the state. A state without them (the
+    encoder-decoder's) passes through untouched, so its programs keep
+    their form."""
+    counts = state.get("counts")
+    if counts is None:
+        return tokens, state
+    if tokens.ndim == 2:
+        rows = tokens.shape[0]
+        width = -(-counts.shape[0] // rows)
+        pad = jnp.zeros((rows * width - counts.shape[0],), counts.dtype)
+        tokens = jnp.concatenate(
+            [tokens, jnp.concatenate([counts, pad]).reshape(rows, width)], 1)
+    else:
+        tokens = jnp.concatenate([tokens, counts])
+    return tokens, dict(state, counts=jnp.zeros_like(counts))
+
+
 class InferStep:
     """Compile a net's inference paths into jitted, shape-stable programs.
 
@@ -205,9 +227,40 @@ class InferStep:
         """Whether the net speaks the PAGED protocol (``prefill_paged`` /
         ``decode_step_paged`` / ``init_paged_state``) — the continuous-
         batching engine path (``serving.ContinuousBatcher``)."""
-        return hasattr(self._net, "prefill_paged") and \
-            hasattr(self._net, "decode_step_paged") and \
-            hasattr(self._net, "init_paged_state")
+        return hasattr(self._net, "decode_step_paged") and \
+            hasattr(self._net, "init_paged_state") and \
+            (hasattr(self._net, "prefill_paged")
+             or not self.slot_state["encoder_memory"])
+
+    @property
+    def slot_state(self) -> dict:
+        """What a serving slot keeps for this net, as the net declares it
+        (``paged_slot_state``): ``pools``, the names of the paged arrays a
+        layer under the one page table, and ``encoder_memory``, whether
+        the slot also holds static encoder memory (per-slot ``cross_k`` /
+        ``cross_v`` buffers and ``mem_vl``). A net that declares nothing
+        is an encoder-decoder: K and V pools, and encoder memory. The
+        batcher builds cross buffers, valid lengths and the cross-frame
+        store only where ``encoder_memory`` is true; without it the prompt
+        lives in the pages and enters in chunks."""
+        return getattr(self._net, "paged_slot_state", None) or \
+            {"pools": ("k_pools", "v_pools"), "encoder_memory": True}
+
+    def _need_encoder_memory(self, what: str):
+        if not self.slot_state["encoder_memory"]:
+            raise MXNetError(
+                f"{what} is not built for {type(self._net).__name__}: its "
+                "slots keep no encoder memory (paged_slot_state), and "
+                f"{what} is written against per-slot cross buffers")
+
+    def _state_sig(self, state):
+        """The part of a paged state that names a compiled program: the
+        shape of the first paged array the net declares, and the first
+        cross buffer's where it keeps encoder memory."""
+        decl = self.slot_state
+        return (state[decl["pools"][0]][0].shape,
+                state["cross_k"][0].shape if decl["encoder_memory"]
+                else None)
 
     @property
     def weights_version(self) -> str:
@@ -501,7 +554,7 @@ class InferStep:
             key, sub = jax.random.split(key)
             tok0 = _sample_tokens(logits.astype(jnp.float32), sub, method,
                                   top_k, temperature)
-            return tok0, new_state
+            return _take_counts(tok0, new_state)
 
         fn = jax.jit(prefill, donate_argnums=(1,))
         self._paged_fns[cfg] = fn
@@ -539,7 +592,7 @@ class InferStep:
 
             _, _, state, _, buf = jax.lax.fori_loop(
                 0, steps, body, (tokens, fin0, state, key, buf))
-            return buf, state
+            return _take_counts(buf, state)
 
         fn = jax.jit(decode, donate_argnums=(1,))
         self._paged_fns[cfg] = fn
@@ -561,6 +614,8 @@ class InferStep:
         (``tools/check_no_sync_in_step.py``) — the scheduler reads the
         returned tokens at its designated sync point. Returns
         ``(tok0 (slots,) NDArray, new_state)``."""
+        self._need_encoder_memory("prefill_paged (a whole-bucket prefill "
+                                  "primed with BOS)")
         src = jnp.asarray(src, jnp.int32)
         vl = jnp.asarray(src_valid_length, jnp.int32)
         slot_ids = jnp.asarray(slot_ids, jnp.int32)
@@ -569,7 +624,7 @@ class InferStep:
         method, top_k, seed, _ = self._paged_cfg(method, top_k, seed)
         cfg = (method, top_k)
         sig = ("paged_prefill", cfg, (src.shape, src.dtype.name),
-               state["k_pools"][0].shape, state["cross_k"][0].shape)
+               *self._state_sig(state))
         self.compile_guard.observe(
             sig, lambda: f"paged_prefill{cfg} " + _cc.aval_summary((src,)))
         fn = self._get_paged_prefill_fn(*cfg)
@@ -602,8 +657,7 @@ class InferStep:
         wide = True if wide else False
         cfg = (method, top_k, wide)
         sig = ("paged_suffix", cfg, (tokens.shape, tokens.dtype.name),
-               page_tables.shape, state["k_pools"][0].shape,
-               state["cross_k"][0].shape)
+               page_tables.shape, *self._state_sig(state))
         self.compile_guard.observe(
             sig, lambda: f"paged_suffix{cfg} "
             + _cc.aval_summary((tokens,)))
@@ -633,7 +687,7 @@ class InferStep:
                                                      steps)
         cfg = (steps, method, top_k)
         sig = ("decode_iter", cfg, (page_tables.shape, tokens.shape),
-               state["k_pools"][0].shape, state["cross_k"][0].shape)
+               *self._state_sig(state))
         self.compile_guard.observe(
             sig, lambda: f"decode_iter{cfg} "
             + _cc.aval_summary((page_tables, tokens)))
@@ -667,6 +721,7 @@ class InferStep:
         (target params, draft params, version) as ONE tuple, reassigned
         atomically by ``swap_params`` — a spec round can therefore never
         observe mixed draft/target versions."""
+        self._need_encoder_memory("speculative decoding (attach_draft)")
         draft = InferStep(draft_net, mesh=self._mesh, amp=self._amp,
                           max_len=self._max_len, bos_id=self._bos,
                           eos_id=self._eos, pad_id=self._pad)
@@ -1035,6 +1090,7 @@ class InferStep:
         dtype and placed under its sharding, so flipping to the staged
         set can never change a dispatch signature (zero recompiles by
         construction)."""
+        self._need_encoder_memory("hot weight swap (stage_params)")
         live = self._values
         vals = {}
         for name, _ in self._params:

@@ -146,7 +146,7 @@ def make_batcher(engine, bucket_keys, **kwargs):
         return ContinuousBatcher(engine, bucket_keys, **kwargs)
     for k in ("page_size", "num_pages", "iter_tokens",
               "max_prefix_tokens", "prefix_cache", "spec_k",
-              "spec_wide", "suffix_wide"):
+              "spec_wide", "suffix_wide", "prefill_chunk"):
         kwargs.pop(k, None)
     return DynamicBatcher(engine, bucket_keys, **kwargs)
 
@@ -293,10 +293,11 @@ class _BatcherBase:
                  max_new_tokens: int = 32, sampling: Optional[dict] = None,
                  pad_id: Optional[int] = None, start: bool = True,
                  name: Optional[str] = None, watchdog=None):
-        if not getattr(engine, "supports_decode", False):
+        if not (getattr(engine, "supports_decode", False)
+                or getattr(engine, "supports_paged", False)):
             raise MXNetError(
                 f"{type(self).__name__} needs a decode-capable InferStep "
-                "(net with prefill/decode_step)")
+                "(net with prefill/decode_step, or the paged protocol)")
         self._engine = engine
         self.bucket_keys = sorted(int(k) for k in bucket_keys)
         if not self.bucket_keys:
@@ -709,7 +710,8 @@ class _Slot:
     """Host-side record of one OCCUPIED decode slot."""
 
     __slots__ = ("req", "carry", "length", "emitted", "finished",
-                 "admitted_seq", "version", "active_at")
+                 "admitted_seq", "version", "active_at", "base",
+                 "entered", "admitted_at")
 
     def __init__(self, req, admitted_seq):
         self.req = req
@@ -720,6 +722,19 @@ class _Slot:
         self.admitted_seq = admitted_seq
         self.version = None
         self.active_at = None    # perf_counter at activation (decode_ms)
+        # cached positions before the first generated token: the prime
+        # and the forced prefix (encoder-decoder), or the prompt itself
+        # (a net with no encoder, whose prompt lives in the pages)
+        self.base = 1 + (0 if req.prefix is None
+                         else int(req.prefix.shape[0]))
+        # prompt tokens written into the pages so far; None where the
+        # prompt is encoder memory. The slot decodes once all are in
+        self.entered = None
+        self.admitted_at = None
+
+    @property
+    def decoding(self) -> bool:
+        return not self.finished and self.carry is not None
 
 
 class ContinuousBatcher(_BatcherBase):
@@ -764,6 +779,15 @@ class ContinuousBatcher(_BatcherBase):
         bit-exact sequential verifier.
     suffix_wide : replay prefix-cache suffixes through the one-pass
         q_offset-aware window program instead of the sequential stream.
+    prefill_chunk : for a net whose slots keep no encoder memory
+        (``InferStep.slot_state``): tokens a dispatch of the chunk
+        program takes. The prompt lives in the slot's pages, which cover
+        ``largest bucket + max_new_tokens``; it enters in chunks of this
+        length, one chunk a pass, and between two chunks the decoding
+        slots take their burst. Default: the largest bucket (one chunk).
+        No ``cross`` buffers, ``mem_vl`` or cross-frame store are built
+        for such a net; the prefix cache, forced prefixes, speculation
+        and handoff frames are refused for it by name.
     warmup : compile the admission-prefill program per bucket plus the
         decode-iteration program at construction (inert rows — the pools
         only ever see trash-page writes).
@@ -786,6 +810,7 @@ class ContinuousBatcher(_BatcherBase):
                  prefix_cache: Optional[bool] = None,
                  spec_k: Optional[int] = None, spec_wide: bool = False,
                  suffix_wide: bool = False,
+                 prefill_chunk: Optional[int] = None,
                  warmup: bool = False, start: bool = True,
                  name: Optional[str] = None, watchdog=None):
         super().__init__(engine, bucket_keys, slots=slots,
@@ -815,9 +840,27 @@ class ContinuousBatcher(_BatcherBase):
                                                 "greedy") == "greedy")
         self.spec_wide = bool(spec_wide)
         self.suffix_wide = bool(suffix_wide)
-        self.pages_per_slot = _pages.pages_for(
-            1 + self.max_prefix + self.max_new
-            + (self.spec_k if self._spec_on else 0), self.page_size)
+        # the one seam: what a slot keeps is the net's to declare
+        self._enc_mem = bool(engine.slot_state["encoder_memory"])
+        if self._enc_mem:
+            self.chunk = None
+            cached = 1 + self.max_prefix + self.max_new \
+                + (self.spec_k if self._spec_on else 0)
+        else:
+            for what, on in (("the prefix cache", prefix_cache),
+                             ("a forced prefix (max_prefix_tokens)",
+                              self.max_prefix),
+                             ("speculative decoding", self._spec_on)):
+                if on:
+                    raise MXNetError(
+                        f"{what} is not built for a net whose slots keep "
+                        "no encoder memory: sharing or replaying pages "
+                        "that hold a PROMPT is a later issue")
+            prefix_cache = False
+            self.chunk = int(prefill_chunk) if prefill_chunk is not None \
+                else self.bucket_keys[-1]
+            cached = self.bucket_keys[-1] + self.max_new
+        self.pages_per_slot = _pages.pages_for(cached, self.page_size)
         self.num_pages = int(num_pages) if num_pages is not None \
             else _pages.num_pages_default(self.slots, self.pages_per_slot)
         if self.pages_per_slot > self.num_pages:
@@ -906,7 +949,26 @@ class ContinuousBatcher(_BatcherBase):
                       "register_readback_s": 0.0, "admit_s": 0.0,
                       "prefill_s": 0.0, "capacity_s": 0.0,
                       "dispatch_s": 0.0, "readback_s": 0.0,
-                      "collect_s": 0.0}
+                      "collect_s": 0.0,
+                      # a prompt that lives in the pages: chunk
+                      # dispatches (span ``sched.admit.prefill_chunk``,
+                      # dispatch to its token on the host) and the
+                      # prompt tokens they wrote
+                      "prefill_chunk_s": 0.0, "prompt_chunks": 0,
+                      "prompt_tokens": 0}
+        counts = self._state.get("counts")
+        if counts is not None:
+            # device-side counts that rode the tokens' read-backs
+            # (``InferStep._take_counts``), by the program they came
+            # from: tokens routed to each expert of each layer; distinct
+            # experts touched and expert layers run; keys seen and keys
+            # selected by the sparse attention
+            for k in ("prefill", "decode"):
+                self.stats.update({
+                    k + "_expert_tokens": _np.zeros(
+                        (int(counts.shape[0]) - 4,), _np.int64),
+                    k + "_experts_touched": 0, k + "_expert_layers": 0,
+                    k + "_keys_seen": 0, k + "_keys_selected": 0})
         # what one pass adds to ``stats`` (phase seconds, and the
         # iteration's counts): summed here by the scheduler thread alone
         # and published in ONE ``_stats_lock`` hold at the pass's end
@@ -927,6 +989,24 @@ class ContinuousBatcher(_BatcherBase):
         eng = self._engine
         reg = _tel.registry()
         before = eng.compile_guard.signatures
+        if not self._enc_mem:
+            # the chunk program (one row, inert) and the decode burst
+            tok, self._state = eng.prefill_suffix_paged(
+                self._state, _np.zeros((1, self.chunk), _np.int32),
+                _np.ones((1,), _np.int32), _np.zeros((1,), _np.int32),
+                _np.zeros((1, self.pages_per_slot), _np.int32),
+                _np.full((1,), self.slots, _np.int32),
+                _np.zeros((1,), bool), wide=True, **self._sampling)
+            zeros = _np.zeros((self.slots,), _np.int32)
+            buf, self._state = eng.decode_iter(
+                self._state, self.pool.table, zeros, zeros,
+                _np.zeros((self.slots,), bool), steps=self.iter_tokens,
+                **self._sampling)
+            jax.block_until_ready((tok.data, buf.data))
+            reg.counter("compile/warmup_compiles").inc(
+                eng.compile_guard.signatures - before)
+            eng.compile_guard.mark_steady()
+            return
         rows_menu = []
         rows = 1
         while rows < self.slots:
@@ -1080,7 +1160,7 @@ class ContinuousBatcher(_BatcherBase):
             busy = self._pass_once()
         with self._stats_lock:
             for k, v in acc.items():
-                self.stats[k] += v
+                self.stats[k] = self.stats[k] + v
         acc.clear()
         return busy
 
@@ -1107,14 +1187,12 @@ class ContinuousBatcher(_BatcherBase):
                 self._retire()
             with _tel.phase("sched.admit", acc, "admit_s"):
                 admitted = self._admit()
-            live = [i for i, s in enumerate(self._slots)
-                    if s is not None and not s.finished]
+            live = self._live()
             if not live:
                 return admitted > 0
             with _tel.phase("sched.capacity", acc, "capacity_s"):
                 self._ensure_capacity(live)
-            live = [i for i, s in enumerate(self._slots)
-                    if s is not None and not s.finished]
+            live = self._live()
             if not live:
                 return True
             t0 = time.perf_counter()
@@ -1124,6 +1202,13 @@ class ContinuousBatcher(_BatcherBase):
         except Exception as e:  # noqa: BLE001 - fail the slots, not the thread
             self._poison(e)
         return True
+
+    def _live(self):
+        """Slots that take part in the decode burst: occupied, not
+        finished, and (where the prompt lives in the pages) all of the
+        prompt in."""
+        return [i for i, s in enumerate(self._slots)
+                if s is not None and s.decoding]
 
     def _retire(self):
         """Resolve finished/expired slots and free their pages — the
@@ -1487,6 +1572,8 @@ class ContinuousBatcher(_BatcherBase):
         forced prefix join the suffix replay afterwards); stream each
         admitted row's first token. Respects the free-page watermark,
         evicting idle cached pages before refusing admission."""
+        if not self._enc_mem:
+            return self._admit_prompts()
         free = [i for i, s in enumerate(self._slots) if s is None]
         if not free or not self._pending:
             return 0
@@ -1713,15 +1800,121 @@ class ContinuousBatcher(_BatcherBase):
             reg.gauge("infer/pages_shared").set(self.pool.shared_pages)
         return n_admitted
 
+    def submit(self, prompt_ids, max_new_tokens: Optional[int] = None,
+               deadline_ms: Optional[float] = None,
+               frames: Optional[dict] = None, prefix_ids=None,
+               request_id: Optional[str] = None) -> GenerationResult:
+        if not self._enc_mem and (frames is not None
+                                  or prefix_ids is not None):
+            raise MXNetError(
+                "handoff frames and forced prefixes are not built for a "
+                "net whose slots keep no encoder memory: both are written "
+                "against per-slot cross buffers (serving.disagg "
+                "pack_frames, ContinuousBatcher._adopt)")
+        return super().submit(prompt_ids, max_new_tokens, deadline_ms,
+                              frames, prefix_ids, request_id)
+
+    # ----------------------------------------- a prompt that lives in pages
+    def _admit_prompts(self) -> int:
+        """Admission for a net with no encoder memory. A waiting request
+        takes a free slot and the pages for its whole prompt; then ONE
+        chunk of the oldest prompt still entering goes through the chunk
+        program (queries at ``entered`` over the history pages plus the
+        chunk), which writes the slot's pages. The decoding slots take
+        their burst between two chunks; a prompt's last chunk samples its
+        first token. Returns requests placed plus chunks dispatched."""
+        reg = _tel.registry()
+        version = getattr(self._engine, "weights_version", None)
+        placed = 0
+        for slot, s in enumerate(self._slots):
+            if s is not None or not self._pending:
+                continue
+            if self.pool.free_pages <= self._admit_free_pages \
+                    and self.pool.pages_in_use > 0:
+                break  # keep headroom for requests already decoding
+            r = self._pending[0]
+            n = int(r.prompt.shape[0])
+            if not (self.pool.alloc(slot, 1)
+                    and self.pool.ensure(slot, n + 1)):
+                self.pool.release(slot)
+                break
+            self._pending.popleft()
+            s = _Slot(r, self._seq)
+            self._seq += 1
+            s.base, s.entered, s.version = n, 0, version
+            s.admitted_at = time.perf_counter()
+            self._slots[slot] = s
+            placed += 1
+        reg.histogram("infer/admitted_per_iter").observe(placed)
+        entering = [i for i, s in enumerate(self._slots)
+                    if s is not None and not s.finished
+                    and s.carry is None]
+        if not entering:
+            return placed
+        slot = min(entering, key=lambda i: self._slots[i].admitted_seq)
+        s = self._slots[slot]
+        r = s.req
+        part = r.prompt[s.entered:s.entered + self.chunk]
+        toks = _np.full((1, self.chunk), self._pad, _np.int32)
+        toks[0, :len(part)] = part
+        t0 = time.perf_counter()
+        try:
+            _faults.fire("batcher.dispatch", tag=self.name)
+            with _tel.phase("sched.admit.prefill_chunk", self._pass,
+                            "prefill_chunk_s",
+                            {"slot": slot,
+                             "chunk": s.entered // self.chunk}):
+                out, self._state = self._engine.prefill_suffix_paged(
+                    self._state, toks,
+                    _np.full((1,), len(part), _np.int32),
+                    _np.full((1,), s.entered, _np.int32),
+                    self.pool.table[slot:slot + 1],
+                    _np.full((1,), slot, _np.int32), _np.ones((1,), bool),
+                    seed=self._iter, wide=True, **self._sampling)
+                out = out.asnumpy()
+        except Exception as e:  # noqa: BLE001 - fail futures, not thread
+            self._poison(e)
+            return 0
+        chunk_s = time.perf_counter() - t0
+        reg.histogram("infer/prefill_ms").observe(chunk_s * 1e3)
+        # a chunk is this net's admission prefill: it counts there too,
+        # so that ``admit_s - prefill_s`` stays admit's own time
+        self._pass["prefill_s"] += chunk_s
+        self._pass["prompt_chunks"] += 1
+        self._pass["prompt_tokens"] += len(part)
+        self._note_counts("prefill", out[1:])
+        s.entered += len(part)
+        if s.entered >= s.base:
+            self._activate(slot, r, int(out[0]), s.admitted_at, version,
+                           s.base, s=s)
+            self._pass["admitted"] += 1
+        return placed + 1
+
+    def _note_counts(self, program: str, counts) -> None:
+        """Device-side counts that rode a read-back, into the pass's sums
+        (published with the phase seconds in the one lock hold): tokens an
+        expert a layer, then distinct experts touched, expert layers run,
+        keys seen, keys selected."""
+        n = self.stats[program + "_expert_tokens"].shape[0]
+        acc = self._pass
+        acc[program + "_expert_tokens"] = \
+            acc[program + "_expert_tokens"] + counts[:n].astype(_np.int64)
+        for k, v in zip(("_experts_touched", "_expert_layers", "_keys_seen",
+                         "_keys_selected"), counts[n:n + 4]):
+            acc[program + k] += int(v)
+
     def _activate(self, slot: int, r, first_tok: int, t0: float,
-                  version, length: int) -> None:
+                  version, length: int, s=None) -> None:
         """Install the freshly-prefilled request into its slot and
         stream its first sampled token (TTFT instant): shared by the
-        cold-prefill and suffix-replay admission paths."""
+        cold-prefill and suffix-replay admission paths, and by the last
+        chunk of a prompt that entered its pages in chunks (``s``, the
+        slot it has held since ``t0``)."""
         reg = _tel.registry()
-        s = _Slot(r, self._seq)
-        self._seq += 1
-        s.length = length  # cached target positions (prime + prefix)
+        if s is None:
+            s = _Slot(r, self._seq)
+            self._seq += 1
+        s.length = length  # cached positions (prime + prefix, or prompt)
         s.carry = first_tok
         s.version = version
         s.active_at = time.perf_counter()
@@ -1764,8 +1957,7 @@ class ContinuousBatcher(_BatcherBase):
             # trash page, so the cap is safe. A speculative round writes
             # up to spec_k entries ahead and ACCEPTED entries must land
             # in real pages, so the cap stretches by spec_k too.
-            base = 1 + (0 if s.req.prefix is None
-                        else int(s.req.prefix.shape[0]))
+            base = s.base
             if self._spec_on:
                 grow = self.spec_k + 1
                 cap = base + s.req.max_new + self.spec_k
@@ -1778,9 +1970,7 @@ class ContinuousBatcher(_BatcherBase):
                 # preempted — the trie is a cache, not a tenant
                 if self.cache.evict(1) > 0:
                     continue
-                victims = [j for j in range(self.slots)
-                           if self._slots[j] is not None
-                           and not self._slots[j].finished and j != i]
+                victims = [j for j in self._live() if j != i]
                 if not victims:
                     # nothing left to preempt: this request cannot make
                     # progress right now — bounce it back to the caller
@@ -1863,6 +2053,8 @@ class ContinuousBatcher(_BatcherBase):
         acc = self._pass
         with _tel.phase("sched.collect.readback", acc, "readback_s"):
             toks = buf.asnumpy()
+        if "decode_expert_tokens" in self.stats:
+            self._note_counts("decode", toks[:, self.iter_tokens:].ravel())
         iter_ms = (time.perf_counter() - t0) * 1e3
         with _tel.phase("sched.collect", acc, "collect_s"):
             reg = _tel.registry()

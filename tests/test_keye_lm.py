@@ -1,0 +1,383 @@
+"""The decoder-only language model (``model_zoo/keye.py``) against its plain
+reference (``perf/reference/keye-vl2-30b-a3b.py``) at the tiny preset, on
+seeded random weights: full forward, chunked prefill and paged decode,
+selected sets, rotary components, routing, and the whole path through
+``ContinuousBatcher``."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx  # noqa: F401 - the package sets JAX up
+from mxnet_tpu import nd
+from mxnet_tpu.base import MXNetError
+from mxnet_tpu.gluon.model_zoo.keye import KeyeLM
+from mxnet_tpu.ops import sparse_attention as dsa
+from mxnet_tpu.ops.pallas import grouped_swiglu as moe
+from mxnet_tpu.parallel import InferStep
+from mxnet_tpu.serving import make_batcher
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+from perf.harness.loader import load_module  # noqa: E402
+
+TINY = {
+    "vocab_size": 128, "hidden_size": 64, "num_hidden_layers": 2,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "num_experts": 8, "num_experts_per_tok": 2, "moe_intermediate_size": 32,
+    "rope_theta": 1e7, "rope_scaling": {"mrope_section": [2, 2, 4]},
+    "sa_config": {"indexer_num_heads": 2, "indexer_head_dim": 8,
+                  "indexer_num_kv_heads": 1, "topk": 8, "kv_chunk_size": 4,
+                  "q_chunk_size": 4}}
+PAGE, CHUNK, SEED = 4, 8, 11
+
+
+@pytest.fixture(scope="module")
+def ref():
+    return load_module(os.path.join(REPO, "perf", "reference",
+                                    "keye-vl2-30b-a3b.py"))
+
+
+@pytest.fixture(autouse=True)
+def highest_precision():
+    """The program's products in float32 proper, on every thread (the
+    scheduler's too), as the reference has them."""
+    old = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", "highest")
+    yield
+    jax.config.update("jax_default_matmul_precision", old)
+
+
+def build(ref, cfg=TINY, seed=SEED, dtype="float32"):
+    sa = cfg["sa_config"]
+    net = KeyeLM(
+        vocab_size=cfg["vocab_size"], hidden_size=cfg["hidden_size"],
+        num_layers=cfg["num_hidden_layers"],
+        num_heads=cfg["num_attention_heads"],
+        num_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
+        num_experts=cfg["num_experts"],
+        experts_per_tok=cfg["num_experts_per_tok"],
+        expert_width=cfg["moe_intermediate_size"],
+        index_heads=sa["indexer_num_heads"],
+        index_head_dim=sa["indexer_head_dim"], index_topk=sa["topk"],
+        kv_chunk=sa["kv_chunk_size"], rope_theta=cfg["rope_theta"],
+        mrope_section=cfg["rope_scaling"]["mrope_section"], dtype=dtype)
+    params = net._collect_params_with_prefix()
+    assert set(params) == set(ref.tensor_specs(cfg))
+    for name, p in params.items():
+        p.set_data(nd.NDArray(ref.tensor(seed, cfg, name).astype(dtype)))
+    return net
+
+
+@pytest.fixture(scope="module")
+def net(ref):
+    return build(ref)
+
+
+def tokens(n, seed=0):
+    return np.random.default_rng(seed).integers(3, TINY["vocab_size"], n) \
+        .astype(np.int32)
+
+
+# ------------------------------------------------------------ full forward
+@pytest.mark.parametrize("length", [5, 8, 20])     # under, at, over topk
+def test_full_forward_logits(ref, net, length):
+    toks = tokens(length, length)
+    got = net(nd.array(toks[None], dtype="int32")).asnumpy()[0]
+    want = np.asarray(ref.forward(SEED, TINY, toks))
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_mrope_with_unequal_components(ref, net):
+    toks = tokens(12, 3)
+    rng = np.random.default_rng(4)
+    pos3 = np.stack([np.arange(12), rng.integers(0, 9, 12),
+                     rng.integers(0, 9, 12)], -1).astype(np.int32)
+    got = net(nd.array(toks[None], dtype="int32"),
+              nd.array(pos3[None], dtype="int32")).asnumpy()[0]
+    want = np.asarray(ref.forward(SEED, TINY, toks, positions=pos3))
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    text = np.asarray(ref.forward(SEED, TINY, toks))
+    assert np.abs(want - text).max() > 1e-2     # the components matter
+
+
+# ------------------------------------------- chunked prefill, paged decode
+def _serve_by_hand(net, prompt, n_new, slots=2, slot=1):
+    """Chunked prefill then one-step decodes through the engine's paged
+    programs; returns the logits' argmax path and the counts read."""
+    eng = InferStep(net)
+    pages = -(-(len(prompt) + n_new) // PAGE)
+    state = eng.init_paged_state(slots, slots * pages, PAGE, 0)
+    table = np.zeros((slots, pages), np.int32)
+    table[slot] = 1 + slot * pages + np.arange(pages)
+    counts = np.zeros((net.counts_size,), np.int64)
+    at = 0
+    while at < len(prompt):
+        part = prompt[at:at + CHUNK]
+        toks = np.zeros((1, CHUNK), np.int32)
+        toks[0, :len(part)] = part
+        out, state = eng.prefill_suffix_paged(
+            state, toks, [len(part)], [at], table[slot:slot + 1], [slot],
+            [True], wide=True)
+        out = out.asnumpy()
+        counts += out[1:]
+        at += len(part)
+    served = [int(out[0])]
+    active = np.arange(slots) == slot
+    for j in range(n_new - 1):
+        carry = np.where(active, served[-1], 0).astype(np.int32)
+        lengths = np.where(active, len(prompt) + j, 0).astype(np.int32)
+        buf, state = eng.decode_iter(state, table, carry, lengths, active,
+                                     steps=1)
+        buf = buf.asnumpy()
+        counts += buf[:, 1:].ravel()[:net.counts_size]
+        served.append(int(buf[slot, 0]))
+    return served, counts
+
+
+@pytest.mark.parametrize("length", [3, 8, 9, 21])   # the last chunk ragged
+def test_chunked_prefill_and_decode_follow_the_reference(ref, net, length):
+    prompt, n_new = tokens(length, 10 + length), 6
+    served, counts = _serve_by_hand(net, prompt, n_new)
+    seq = np.concatenate([prompt, served[:-1]])
+    want_at = len(prompt) - 1 + np.arange(n_new)
+    tap = {}
+    logits = np.asarray(ref.forward(SEED, TINY, seq, want=want_at, tap=tap))
+    assert served == [int(t) for t in logits.argmax(-1)]
+    gaps = ref.served_token_gaps(SEED, TINY, prompt, served)
+    assert gaps.max() < 1e-5
+    # the counts that rode the read-backs are the reference's own
+    E, L = TINY["num_experts"], TINY["num_hidden_layers"]
+    n = len(seq)
+    for i in range(L):
+        np.testing.assert_array_equal(counts[i * E:(i + 1) * E],
+                                      tap[f"l{i}_counts"])
+    selected = sum(int(np.concatenate(tap[f"l{i}_selected"])[:n].sum())
+                   for i in range(L))
+    assert counts[L * E + 2] == L * n * (n + 1) // 2      # keys seen
+    assert counts[L * E + 3] == selected
+
+
+def test_selected_sets_equal_the_references(ref, net):
+    """Layer 0's selected sets, from the program's own projections."""
+    toks = tokens(24, 5)
+    tap = {}
+    ref.forward(SEED, TINY, toks, tap=tap)
+    want = np.concatenate(tap["l0_selected"])[:24, :24]
+    pos = jnp.arange(24, dtype=jnp.int32)[None]
+    pos3 = jnp.broadcast_to(pos[..., None], (1, 24, 3))
+    x = jnp.take(net._w("embed"), jnp.asarray(toks)[None], axis=0)
+    _, _, _, qi, ki, wi = net._project(0, x, pos3)
+    scores = dsa.window_index_scores(qi, wi, ki, pos, 24 // 4, 4)
+    got = np.asarray(dsa.select_mask(scores, pos, TINY["sa_config"]["topk"]))
+    np.testing.assert_array_equal(got[0], want)
+    assert (want.sum(-1) == np.minimum(np.arange(24) + 1, 8)).all()
+    # decode: the last query's set, as positions
+    picked, valid = dsa.decode_select(
+        qi[:, -1], wi[:, -1], ki.reshape(6, 4, 8), jnp.arange(6)[None],
+        jnp.asarray([23]), 8)
+    assert sorted(np.asarray(picked)[0][np.asarray(valid)[0]]) == \
+        sorted(np.nonzero(want[-1])[0])
+
+
+def test_selection_breaks_ties_to_the_lower_position(ref):
+    scores = np.full((1, 3, 12), -np.inf, np.float32)
+    scores[0, 0, :4] = [1, 1, 1, 1]
+    scores[0, 1, :10] = [5, 2, 2, 2, 2, 9, 2, 2, 2, 2]
+    scores[0, 2, :12] = 0.0
+    pos = np.asarray([[3, 9, 11]], np.int32)
+    got = np.asarray(dsa.select_mask(jnp.asarray(scores), jnp.asarray(pos), 4))
+    want = np.stack([np.asarray(ref.select(jnp.asarray(scores[0, q:q + 1]),
+                                           jnp.asarray(pos[0, q:q + 1]), 4))[0]
+                     for q in range(3)])
+    np.testing.assert_array_equal(got[0], want)
+    assert list(np.nonzero(got[0, 1])[0]) == [0, 1, 2, 5]
+    assert list(np.nonzero(got[0, 2])[0]) == [0, 1, 2, 3]
+
+
+# ------------------------------------------------------------ expert layer
+def test_router_weights_and_counts(ref):
+    rng = np.random.default_rng(2)
+    u = jnp.asarray(rng.normal(size=(40, 64)), jnp.float32)
+    w = {"l0_router": jnp.asarray(rng.normal(size=(64, 8)) / 8, jnp.float32)}
+    idx, a, _ = ref.route(w, "l0_", u, TINY, None)
+    got_idx, got_a = moe.route(u, w["l0_router"], 2)
+    np.testing.assert_array_equal(np.asarray(got_idx), np.asarray(idx))
+    np.testing.assert_allclose(np.asarray(got_a), np.asarray(a), atol=1e-6)
+    np.testing.assert_allclose(np.asarray(got_a).sum(-1), 1.0, atol=1e-6)
+    dest, src, tile_e, n_tiles, counts = moe.group_by_expert(got_idx, 8, 16)
+    np.testing.assert_array_equal(
+        np.asarray(counts), np.bincount(np.asarray(idx).ravel(), minlength=8))
+    dest, src, tile_e = map(np.asarray, (dest, src, tile_e))
+    assert len(set(dest.ravel())) == dest.size          # no row twice
+    for t in range(40):
+        for j in range(2):
+            assert src[dest[t, j]] == t
+            assert tile_e[dest[t, j] // 16] == int(idx[t, j])
+    assert int(n_tiles[0]) == sum(-(-c // 16) for c in np.asarray(counts))
+
+
+@pytest.mark.parametrize("tokens_,tile", [(16, 16), (1024, 128)])
+def test_grouped_swiglu_kernel_against_its_jnp_form(tokens_, tile,
+                                                    monkeypatch):
+    """The Pallas kernel (interpreted here) and the jnp form agree, and no
+    token is dropped whatever the load: every pair's row is its expert's
+    product."""
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    rng = np.random.default_rng(tokens_)
+    E, H, F, k = 8, 128, 256, 2
+    assert moe.row_tile(tokens_ * k, E) == tile
+    u = jnp.asarray(rng.normal(size=(tokens_, H)), jnp.float32)
+    wg = jnp.asarray(rng.normal(size=(E, H, F)) / 11, jnp.float32)
+    wu = jnp.asarray(rng.normal(size=(E, H, F)) / 11, jnp.float32)
+    wd = jnp.asarray(rng.normal(size=(E, F, H)) / 16, jnp.float32)
+    # a skewed router: most pairs land on two experts
+    experts = jnp.asarray(np.where(rng.random((tokens_, k)) < 0.7,
+                                   [[0, 1]], rng.integers(2, E, (tokens_, k))),
+                          jnp.int32)
+    experts = experts.at[:, 1].set(jnp.where(experts[:, 1] == experts[:, 0],
+                                             (experts[:, 0] + 1) % E,
+                                             experts[:, 1]))
+    dest, src, tile_e, n_tiles, _ = moe.group_by_expert(experts, E, tile)
+    x = u[src]
+    got = moe._moe_grouped_swiglu_impl(x, tile_e, n_tiles, wg, wu, wd,
+                                       tile=tile)
+    want = moe.grouped_swiglu_reference(x, tile_e, n_tiles, wg, wu, wd, tile)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
+    t, j = 3, 1
+    e = int(experts[t, j])
+    plain = (jax.nn.silu(u[t] @ wg[e]) * (u[t] @ wu[e])) @ wd[e]
+    np.testing.assert_allclose(np.asarray(got[dest[t, j]]),
+                               np.asarray(plain), atol=1e-4)
+
+
+def test_kernel_name_the_benchmark_keys_on():
+    """The trace names a Mosaic call after its ``pallas_call(name=...)``
+    (``%moe_grouped_swiglu.<n>``, ``%dsa_selected_window.<n>``: read on
+    the chip in PR 27); ``perf/layer_metrics/moe_*`` and ``dsa_*`` key on
+    those names (``perf/harness/lm_counts.py``)."""
+    import inspect
+
+    from mxnet_tpu.ops.pallas import paged_flash_attention as pfa
+
+    assert 'name="moe_grouped_swiglu"' in inspect.getsource(
+        moe._moe_grouped_swiglu_impl.__wrapped__)
+    assert 'name="dsa_selected_window"' in inspect.getsource(
+        pfa._dsa_selected_window_impl.__wrapped__)
+    from perf.harness import lm_counts
+    import re
+    assert re.search(lm_counts.MOE_KERNEL, "%moe_grouped_swiglu.6 = bf16[")
+    assert re.search(lm_counts.DSA_KERNEL, "%dsa_selected_window.11 = bf")
+
+
+# ------------------------------------------------- through the batcher
+def test_batcher_serves_the_references_greedy_tokens(ref, net):
+    """Five requests through two slots: slots retire and refill while
+    another slot's prompt is still entering in chunks."""
+    eng = InferStep(net)
+    assert eng.slot_state == {"pools": ("k_pools", "v_pools", "ik_pools"),
+                              "encoder_memory": False}
+    bat = make_batcher(eng, [8, 32], slots=2, max_new_tokens=6,
+                       page_size=PAGE, prefill_chunk=CHUNK, iter_tokens=2,
+                       prefix_cache=False, warmup=True, name="keye")
+    assert bat._store is None and "cross_k" not in bat._state
+    assert bat.pages_per_slot == (32 + 6 + PAGE - 1) // PAGE
+    lengths, news = [21, 3, 30, 9, 17], [6, 4, 5, 6, 3]
+    prompts = [tokens(n, 40 + n) for n in lengths]
+    try:
+        futs = [bat.submit(p, max_new_tokens=m)
+                for p, m in zip(prompts, news)]
+        got = [f.result(timeout=300) for f in futs]
+    finally:
+        bat.stop()
+    for p, m, g in zip(prompts, news, got):
+        assert [int(t) for t in g] == ref.greedy(SEED, TINY, p, m)
+    assert bat.pool.free_pages == bat.pool.num_pages
+    bat.pool.check_invariants(set())
+    assert eng.compile_guard.steady_state_recompiles == 0
+    st = bat.stats
+    assert st["prompt_tokens"] == sum(lengths)
+    assert st["prompt_chunks"] == sum(-(-n // CHUNK) for n in lengths)
+    assert st["admitted"] == st["retired"] == 5
+    assert st["prefill_chunk_s"] > 0
+    k, L = TINY["num_experts_per_tok"], TINY["num_hidden_layers"]
+    assert st["prefill_expert_tokens"].sum() == sum(lengths) * k * L
+    assert st["prefill_keys_selected"] <= st["prefill_keys_seen"]
+    assert st["decode_expert_layers"] == st["iterations"] * 2 * L
+    assert 0 < st["decode_experts_touched"] <= \
+        st["decode_expert_layers"] * TINY["num_experts"]
+
+
+def test_what_the_serving_plane_refuses_for_this_net(ref, net):
+    eng = InferStep(net)
+    with pytest.raises(MXNetError, match="speculative decoding"):
+        eng.attach_draft(net)
+    with pytest.raises(MXNetError, match="hot weight swap"):
+        eng.stage_params({})
+    with pytest.raises(MXNetError, match="prefill_paged"):
+        eng.prefill_paged(None, np.zeros((1, 8)), [8], [0], [0], [True])
+    with pytest.raises(MXNetError, match="prefix cache"):
+        make_batcher(eng, [8], slots=1, prefix_cache=True, start=False)
+    with pytest.raises(MXNetError, match="forced prefix"):
+        make_batcher(eng, [8], slots=1, max_prefix_tokens=4, start=False)
+    bat = make_batcher(eng, [8], slots=1, page_size=PAGE, prefill_chunk=8,
+                       start=False)
+    with pytest.raises(MXNetError, match="handoff frames"):
+        bat.submit([3, 4], frames={"length": 1})
+
+
+@pytest.mark.parametrize("offset", [0, 70])
+def test_selected_window_kernel_against_its_reference(offset, monkeypatch):
+    """The Pallas window over a selected set (interpreted here), grouped
+    query heads over fewer key/value heads, pools read through a shuffled
+    page table, against the dense jnp form."""
+    from mxnet_tpu.ops.pallas import paged_flash_attention as pfa
+
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    rng = np.random.default_rng(offset)
+    B, C, Hq, Hkv, D, ps, P = 2, 256, 4, 2, 32, 16, 24
+    q = jnp.asarray(rng.normal(size=(B, C, Hq, D)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(1 + B * P, ps, Hkv, D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(1 + B * P, ps, Hkv, D)), jnp.float32)
+    table = jnp.asarray(1 + rng.permutation(B * P).reshape(B, P), jnp.int32)
+    off = jnp.asarray([offset, offset // 2], jnp.int32)
+    q_pos = off[:, None] + jnp.arange(C)[None]
+    seen = jnp.arange(P * ps)[None, None, :] <= q_pos[:, :, None]
+    mask = seen & jnp.asarray(rng.random((B, C, P * ps)) < 0.4)
+    mask = mask | (jnp.arange(P * ps)[None, None, :] == q_pos[:, :, None])
+    got = pfa.paged_selected_window_attention(q, kp, vp, table, off, mask,
+                                              sm_scale=D ** -0.5)
+    want = pfa.paged_selected_window_reference(q, kp, vp, table, off, mask,
+                                               sm_scale=D ** -0.5)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    loop = dsa.selected_window_attention(q, kp, vp, table, off, mask,
+                                         P * ps // 16, 16, D ** -0.5)
+    np.testing.assert_allclose(np.asarray(loop), np.asarray(want), atol=2e-5)
+
+
+def test_bfloat16_weights_and_caches_serve(ref):
+    """The serving dtype end to end on the CPU (the chip's run decides
+    `correct`; here: dtypes flow, pools are bfloat16, tokens stay close).
+    At these widths one bfloat16 flip of a key or an expert moves a logit
+    by half, so only a loose mean gap is asked for."""
+    net = build(ref, dtype="bfloat16")
+    eng = InferStep(net, amp="bfloat16")
+    bat = make_batcher(eng, [32], slots=2, max_new_tokens=5, page_size=PAGE,
+                       prefill_chunk=CHUNK, iter_tokens=2,
+                       prefix_cache=False, name="keye-bf16")
+    assert bat._state["ik_pools"][0].dtype == jnp.bfloat16
+    prompts = [tokens(n, 70 + n) for n in (19, 6)]
+    try:
+        got = [bat.submit(p, max_new_tokens=5).result(timeout=300)
+               for p in prompts]
+    finally:
+        bat.stop()
+    gaps = np.concatenate([ref.served_token_gaps(SEED, TINY, p, g)
+                           for p, g in zip(prompts, got)])
+    assert len(gaps) == 10 and np.isfinite(gaps).all()
+    assert gaps.mean() < 0.3
+    assert bat.pool.free_pages == bat.pool.num_pages

@@ -150,6 +150,39 @@ def test_paged_decode_attention_compiles(for_chip, dtype):
         spec((SLOTS, PAGES_PER_SLOT), "int32"), spec((SLOTS,), "int32"))
 
 
+@pytest.mark.parametrize("tokens,tile", [(16, 16), (2048, 128)])
+def test_grouped_expert_product_compiles(for_chip, tokens, tile):
+    """The expert layer's grouped SwiGLU product at the published widths
+    (128 experts of 768 over hidden 2048, 8 a token): a decode batch of 16
+    rows (row tile 16) and a prefill chunk of 2,048 (row tile 128)."""
+    spec, compile_ = for_chip
+    gs = _mod("grouped_swiglu")
+    E, H, F, k = 128, 2048, 768, 8
+    assert gs.row_tile(tokens * k, E) == tile and gs.f_block(F) == 384
+    M = -(-(tokens * k + E * (tile - 1)) // tile) * tile
+    compile_(
+        lambda x, te, nt, wg, wu, wd: gs.grouped_swiglu(
+            x, te, nt, wg, wu, wd, tile),
+        spec((M, H), "bfloat16"), spec((M // tile,), "int32"),
+        spec((1,), "int32"), spec((E, H, F), "bfloat16"),
+        spec((E, H, F), "bfloat16"), spec((E, F, H), "bfloat16"))
+
+
+def test_selected_window_attention_compiles(for_chip):
+    """The window over a selected set at the published widths: a chunk of
+    2,048 queries, 32 query heads over 4 key/value heads of 128, pages of
+    128 positions, 130 pages a row."""
+    spec, compile_ = for_chip
+    pfa = _mod("paged_flash_attention")
+    pool = spec((16 * 130 + 1, 128, 4, 128), "bfloat16")
+    compile_(
+        lambda q, k, v, pt, off, m: pfa.paged_selected_window_attention(
+            q, k, v, pt, off, m, sm_scale=128 ** -0.5),
+        spec((1, 2048, 32, 128), "bfloat16"), pool, pool,
+        spec((1, 130), "int32"), spec((1,), "int32"),
+        spec((1, 2048, 130 * 128), "bool"))
+
+
 @pytest.mark.parametrize("window", [1, 2, 4, 16])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_paged_window_attention_compiles(for_chip, dtype, window):
