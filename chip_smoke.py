@@ -348,12 +348,10 @@ def phase_serve(size, on_tpu, seed):
     pool.check_invariants(set())
     # the decode-iteration program the scheduler dispatched, lowered again
     # from the engine's own jitted function: the kernel must be IN it
-    import jax
-
     idle = np.zeros((SLOTS,), np.int32)
     decode_text = eng._get_decode_iter_fn(bat.iter_tokens, "greedy", 0).lower(
         eng._values, state, np.asarray(pool.table, np.int32), idle, idle,
-        idle.astype(bool), jax.random.PRNGKey(0), np.float32(1.0)).as_text()
+        idle.astype(bool), np.int32(0), np.float32(1.0)).as_text()
     kernels_in_decode = decode_text.count("tpu_custom_call")
     if on_tpu:
         check(kernels_in_decode > 0, "the serving decode program holds no "

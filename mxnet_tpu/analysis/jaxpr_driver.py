@@ -172,21 +172,20 @@ class ProgramIndex:
         if self._decode is not None:
             return self._decode
         import jax
-        import jax.numpy as jnp
         import numpy as np
 
         eng = self.infer_engine
         src = np.zeros((2, 8), np.int32)
         vl = np.full((2,), 8, np.int32)
         prime = np.full((2, 1), eng._bos, np.int32)
-        key = jax.random.PRNGKey(0)
-        temp = jnp.float32(1.0)
+        seed = np.int32(0)  # the programs make their key from it
+        temp = np.float32(1.0)
         prefill_fn = eng._get_prefill_fn(eng._max_len)
-        prefill_args = (eng._values, src, vl, prime, key, temp)
+        prefill_args = (eng._values, src, vl, prime, seed, temp)
         prefill_jaxpr = jax.make_jaxpr(prefill_fn)(*prefill_args)
         logits, state = prefill_fn(*prefill_args)
         decode_fn = eng._get_decode_fn(max_new, "greedy", 0)
-        decode_args = (eng._values, state, logits, jnp.int32(1), key, temp)
+        decode_args = (eng._values, state, logits, np.int32(1), seed, temp)
         decode_jaxpr = jax.make_jaxpr(decode_fn)(*decode_args)
         self._decode = (prefill_jaxpr, decode_jaxpr,
                         prefill_args, decode_args)
@@ -199,7 +198,6 @@ class ProgramIndex:
         if self._paged is not None:
             return self._paged
         import jax
-        import jax.numpy as jnp
         import numpy as np
 
         eng = self.infer_engine
@@ -209,18 +207,18 @@ class ProgramIndex:
         slot_ids = np.arange(slots, dtype=np.int32)
         first_pages = np.ones((slots,), np.int32)
         active = np.ones((slots,), bool)
-        key = jax.random.PRNGKey(0)
-        temp = jnp.float32(1.0)
+        seed = np.int32(0)
+        temp = np.float32(1.0)
         pfn = eng._get_paged_prefill_fn("greedy", 0)
         pargs = (eng._values, state, src, vl, slot_ids, first_pages,
-                 active, key, temp)
+                 active, seed, temp)
         prefill_jaxpr = jax.make_jaxpr(pfn)(*pargs)
         tables = np.zeros((slots, 2), np.int32)
         tokens = np.zeros((slots,), np.int32)
         lengths = np.ones((slots,), np.int32)
         dfn = eng._get_decode_iter_fn(steps, "greedy", 0)
         dargs = (eng._values, state, tables, tokens, lengths, active,
-                 key, temp)
+                 seed, temp)
         decode_jaxpr = jax.make_jaxpr(dfn)(*dargs)
         self._paged = (prefill_jaxpr, decode_jaxpr, pargs, dargs)
         return self._paged

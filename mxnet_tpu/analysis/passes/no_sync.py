@@ -7,6 +7,15 @@ device array, ``block_until_ready``, ``time.sleep``) inside a dispatch
 path silently serializes the pipeline against the device; this walks the
 AST of the listed (file, class, methods) targets and flags blocking
 calls. The tool remains as a thin CLI shim importing from here.
+
+The serving targets carry a second rule set (``DISPATCH_TARGETS``): a
+dispatch on the scheduler's pass is exactly one enqueue, so no *eager
+device constructor* may stand before the compiled call. Each
+``jnp.asarray`` of a host array is a host-to-device put of its own, each
+``jax.random.PRNGKey`` a string of tiny programs, and all of them wait in
+the device queue the pass's program waits in. Host operands go to the
+compiled call as numpy, and the key is made inside the program from an
+integer seed.
 """
 
 from __future__ import annotations
@@ -35,7 +44,10 @@ FAST_PATH_FUNCS = ("__call__", "_dispatch")
 # never inlined next to a dispatch. So is the whole retire path (_retire,
 # the root registration and its store dispatch, each request's trie
 # insert): a new root's cross frames stay on the device, and retiring a
-# request reads nothing back.
+# request reads nothing back. _apply_prefix_hits is the one batched
+# adoption dispatch of an admission group.
+SCHEDULER_FUNCS = ("_dispatch", "_step_once", "_retire", "_register_roots",
+                   "_store_rows", "_register_prefix", "_apply_prefix_hits")
 TARGETS = (
     (STEP_PY, "TrainStep", FAST_PATH_FUNCS),
     (INFER_PY, "InferStep", ("__call__", "_dispatch", "decode_n",
@@ -43,9 +55,18 @@ TARGETS = (
                              "prefill_suffix_paged", "spec_draft",
                              "spec_verify")),
     (BATCHER_PY, "DynamicBatcher", ("_dispatch",)),
-    (BATCHER_PY, "ContinuousBatcher", ("_dispatch", "_step_once", "_retire",
-                                       "_register_roots", "_store_rows",
-                                       "_register_prefix")),
+    (BATCHER_PY, "ContinuousBatcher", SCHEDULER_FUNCS),
+)
+
+# the serving dispatch paths, where the second rule set holds too: the
+# paged entry points of the engine and everything the scheduler runs
+# around its own compiled calls. (decode_n and __call__ stage their
+# operands on the device by design and stay under the first set alone.)
+DISPATCH_TARGETS = (
+    (INFER_PY, "InferStep", ("decode_iter", "prefill_paged",
+                             "prefill_suffix_paged", "spec_draft",
+                             "spec_verify")),
+    (BATCHER_PY, "ContinuousBatcher", SCHEDULER_FUNCS),
 )
 
 # method attributes that force a device->host readback / host sync
@@ -61,6 +82,14 @@ BLOCKING_QUALIFIED = {
     ("np", "asarray"), ("_np", "asarray"), ("numpy", "asarray"),
     ("np", "array"), ("_np", "array"), ("numpy", "array"),
     ("jax", "device_get"), ("time", "sleep"), ("_time", "sleep"),
+}
+# eager device constructors, by the dotted name's last two parts: each
+# call is a put or a small program of its own launched from Python
+EAGER_MARK = "eager device constructor"  # in every message of this set
+EAGER_CONSTRUCTORS = {
+    ("jnp", "asarray"), ("jnp", "array"), ("jnp", "float32"),
+    ("jnp", "int32"), ("jnp", "bool_"), ("jax", "device_put"),
+    ("random", "PRNGKey"), ("random", "key"),
 }
 
 
@@ -88,11 +117,31 @@ def blocking_calls_in(fn: ast.FunctionDef, label: str):
     return out
 
 
+def eager_constructors_in(fn: ast.FunctionDef, label: str):
+    """[(lineno, message)] for eager device constructors anywhere in
+    ``fn`` (``jax.random.PRNGKey`` matches by its last two names)."""
+    out = []
+    for node in ast.walk(fn):
+        f = node.func if isinstance(node, ast.Call) else None
+        if not isinstance(f, ast.Attribute):
+            continue
+        owner = f.value
+        owner = owner.id if isinstance(owner, ast.Name) else \
+            owner.attr if isinstance(owner, ast.Attribute) else None
+        if (owner, f.attr) in EAGER_CONSTRUCTORS:
+            out.append((node.lineno,
+                        f"{label}: {EAGER_MARK} "
+                        f"{ast.unparse(f)}(...) is an enqueue of its own "
+                        "before the dispatch"))
+    return out
+
+
 def find_violations(path=None, class_name: str = "TrainStep",
-                    funcs=FAST_PATH_FUNCS):
+                    funcs=FAST_PATH_FUNCS, dispatch_funcs=()):
     """Return [(lineno, message)] for blocking calls inside the given
-    class's listed method bodies (tool-compatible entry point; ``path``
-    may be absolute or repo-relative)."""
+    class's listed method bodies, and for eager device constructors
+    inside those of them that ``dispatch_funcs`` names (tool-compatible
+    entry point; ``path`` may be absolute or repo-relative)."""
     if path is None:
         path = os.path.join(REPO_ROOT, STEP_PY)
     elif not os.path.isabs(path):
@@ -115,14 +164,19 @@ def find_violations(path=None, class_name: str = "TrainStep",
                     "renamed"))
     for fn in fns:
         out.extend(blocking_calls_in(fn, f"{class_name}.{fn.name}"))
+        if fn.name in dispatch_funcs:
+            out.extend(eager_constructors_in(fn, f"{class_name}.{fn.name}"))
     return sorted(out)
 
 
 def find_all_violations():
-    """Lint every TARGETS entry; returns [(path, lineno, message)]."""
+    """Lint every TARGETS entry, the serving ones under both rule sets;
+    returns [(path, lineno, message)]."""
+    dispatch = {(p, cls): funcs for p, cls, funcs in DISPATCH_TARGETS}
     out = []
     for path, cls, funcs in TARGETS:
-        for lineno, msg in find_violations(path, cls, funcs):
+        for lineno, msg in find_violations(path, cls, funcs,
+                                           dispatch.get((path, cls), ())):
             out.append((path, lineno, msg))
     return out
 
@@ -132,14 +186,13 @@ class NoSyncPass(AnalysisPass):
     name = "no-sync"
     ir = "ast"
     description = ("jitted train/inference/serving hot paths stay free "
-                   "of blocking host syncs")
+                   "of blocking host syncs, serving dispatches of eager "
+                   "device constructors")
 
     def run(self, ctx):
-        findings = []
-        for path, cls, funcs in TARGETS:
-            for lineno, msg in find_violations(path, cls, funcs):
-                findings.append(self.finding(
-                    "blocking-call", path, lineno,
-                    key=msg.split(":")[0] + ":" + msg.split(":", 2)[-1][:60],
-                    message=msg))
-        return findings
+        return [self.finding(
+            "eager-constructor" if EAGER_MARK in msg
+            else "blocking-call", path, lineno,
+            key=msg.split(":")[0] + ":" + msg.split(":", 2)[-1][:60],
+            message=msg)
+            for path, lineno, msg in find_all_violations()]

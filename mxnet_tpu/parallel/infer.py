@@ -35,6 +35,7 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..base import MXNetError
@@ -313,7 +314,9 @@ class InferStep:
             return fn
         net, cache_dtype = self._net, self._cache_dtype
 
-        def prefill(values, src, vl, prime, key, temperature):
+        def prefill(values, src, vl, prime, seed, temperature):
+            # the second half of the seed's key, as decode_n always split it
+            _, key = jax.random.split(jax.random.PRNGKey(seed))
             with self._net_scope(values, key):
                 logits, state = net.prefill(
                     NDArray(src), NDArray(prime),
@@ -332,9 +335,10 @@ class InferStep:
             return fn
         net, eos, pad = self._net, self._eos, self._pad
 
-        def decode(values, state, first_logits, prefix_len, key,
+        def decode(values, state, first_logits, prefix_len, seed,
                    temperature):
             B = first_logits.shape[0]
+            key, _ = jax.random.split(jax.random.PRNGKey(seed))
             key, sub = jax.random.split(key)
             tok0 = _sample_tokens(first_logits, sub, method, top_k,
                                   temperature)
@@ -422,12 +426,13 @@ class InferStep:
     @staticmethod
     def _decode_cfg(max_new_tokens, method, top_k, seed):
         """Host-side config normalization (kept out of the linted decode
-        dispatch — these are Python-value coercions, never device reads)."""
+        dispatch — these are Python-value coercions, never device reads).
+        The seed comes back as the int32 operand of ``_seed_operand``."""
         max_new = int(max_new_tokens)
         if max_new < 1:
             raise MXNetError("max_new_tokens must be >= 1")
         return max_new, str(method), int(top_k), \
-            0 if seed is None else int(seed)
+            InferStep._seed_operand(seed)
 
     def _stage_src(self, src, src_valid_length):
         src = src.data if isinstance(src, NDArray) else jnp.asarray(src)
@@ -451,7 +456,9 @@ class InferStep:
         dispatch; returns ``(tokens (B, max_new), lengths (B,))`` as
         NDArrays, asynchronously (no host sync — the decode hot path is
         linted). ``prefix`` overrides the BOS priming column with an
-        explicit (B, Lp) target prefix."""
+        explicit (B, Lp) target prefix. ``seed`` goes to both programs
+        as an integer operand; each takes its half of the split key
+        inside (``_seed_operand`` says how a seed past int32 folds)."""
         if not self.supports_decode:
             raise MXNetError(
                 f"{type(self._net).__name__} does not implement the "
@@ -470,8 +477,7 @@ class InferStep:
                 f"prefix {prime.shape[1]} + max_new_tokens {max_new} "
                 f"exceeds the decode cache capacity max_len={self._max_len} "
                 "(MXTPU_DECODE_MAX_LEN / InferStep(max_len=...))")
-        key = jax.random.PRNGKey(seed)
-        temp = jnp.float32(temperature)
+        temp = np.float32(temperature)
         cfg = (max_new, method, top_k)
         sig = ("decode", cfg, (src.shape, src.dtype.name),
                (prime.shape, prime.dtype.name))
@@ -479,14 +485,13 @@ class InferStep:
             sig, lambda: f"decode{cfg} " + _cc.aval_summary((src, prime)))
         prefill_fn = self._get_prefill_fn(self._max_len)
         decode_fn = self._get_decode_fn(*cfg)
-        key, pk = jax.random.split(key)
         # snapshot the live buffer ONCE: a concurrent hot swap flips
         # self._values between dispatches, and this request's prefill and
         # decode must run on the same weights
         vals = self._values
-        logits, state = prefill_fn(vals, src, vl, prime, pk, temp)
+        logits, state = prefill_fn(vals, src, vl, prime, seed, temp)
         toks, lengths = decode_fn(vals, state, logits,
-                                  jnp.int32(prime.shape[1]), key, temp)
+                                  np.int32(prime.shape[1]), seed, temp)
         return NDArray(toks), NDArray(lengths)
 
     # ---------------------------------------------------------- paged decode
@@ -520,9 +525,10 @@ class InferStep:
         net, bos = self._net, self._bos
 
         def prefill(values, state, src, vl, slot_ids, first_pages, active,
-                    key, temperature):
+                    seed, temperature):
             B = src.shape[0]
             prime = jnp.full((B, 1), bos, jnp.int32)
+            key = jax.random.PRNGKey(seed)
             with self._net_scope(values, key):
                 logits, new_state = net.prefill_paged(
                     NDArray(src), NDArray(prime), NDArray(vl), state,
@@ -545,7 +551,8 @@ class InferStep:
         net, wide = self._net, bool(wide)
 
         def prefill(values, state, tokens, token_vl, q_offset,
-                    page_tables, slot_ids, active, key, temperature):
+                    page_tables, slot_ids, active, seed, temperature):
+            key = jax.random.PRNGKey(seed)
             with self._net_scope(values, key):
                 logits, new_state = net.prefill_suffix_paged(
                     NDArray(tokens), token_vl, q_offset, state,
@@ -568,8 +575,9 @@ class InferStep:
         net, eos, pad = self._net, self._eos, self._pad
 
         def decode(values, state, page_tables, tokens, lengths, active,
-                   key, temperature):
+                   seed, temperature):
             B = tokens.shape[0]
+            key = jax.random.PRNGKey(seed)
             buf = jnp.full((B, steps), pad, jnp.int32)
             fin0 = jnp.logical_not(active)
 
@@ -601,9 +609,45 @@ class InferStep:
     @staticmethod
     def _paged_cfg(method, top_k, seed, steps=1):
         """Host-side config normalization (kept out of the linted paged
-        dispatches — Python-value coercions, never device reads)."""
-        return str(method), int(top_k), 0 if seed is None else int(seed), \
+        dispatches — Python-value coercions, never device reads). The
+        seed comes back as the int32 operand of ``_seed_operand``."""
+        return str(method), int(top_k), InferStep._seed_operand(seed), \
             max(int(steps), 1)
+
+    @staticmethod
+    def _seed_operand(seed):
+        """The seed as the compiled programs take it: an ``np.int32``
+        scalar, from which they make ``jax.random.PRNGKey(seed)`` inside
+        (no eager key before a dispatch; a greedy program drops it). For
+        every seed an int32 holds, that key equals the eager
+        ``jax.random.PRNGKey(seed)`` bit for bit. A larger Python integer
+        is folded on the host the way the eager form folds it with 64-bit
+        mode off: its low 32 bits, read as a signed int32. One that no
+        int64 holds raises ``OverflowError``, as the eager form did."""
+        return np.int64(0 if seed is None else int(seed)).astype(np.int32)
+
+    @staticmethod
+    def _operands(dtype, *xs):
+        """Per-dispatch operands as the ONE compiled call takes them
+        (kept out of the linted dispatches: host coercions live here).
+        A host operand (numpy array, list, scalar) becomes a PRIVATE
+        numpy copy of ``dtype``, which the call itself uploads: no eager
+        put stands before the program, and the copy matters because the
+        call may read host memory after it returns (the CPU client
+        aliases an aligned numpy buffer outright) while the scheduler
+        rewrites ``pool.table`` and its staging arrays for the next pass.
+        An operand already on the device (``jax.Array``, ``NDArray``)
+        passes through untouched and is never pulled to the host; only a
+        dtype that differs is cast there."""
+        out = []
+        for x in xs:
+            if isinstance(x, NDArray):
+                x = x.data
+            if isinstance(x, jax.Array):
+                out.append(x if x.dtype == dtype else x.astype(dtype))
+            else:
+                out.append(np.array(x, dtype))
+        return out
 
     def prefill_paged(self, state, src, src_valid_length, slot_ids,
                       first_pages, active, method="greedy", top_k=0,
@@ -613,14 +657,23 @@ class InferStep:
         token. Pure staging + dispatch, sync-free by lint
         (``tools/check_no_sync_in_step.py``) — the scheduler reads the
         returned tokens at its designated sync point. Returns
-        ``(tok0 (slots,) NDArray, new_state)``."""
+        ``(tok0 (slots,) NDArray, new_state)``.
+
+        Like every paged entry point this is ONE enqueue: the per-
+        dispatch operands may be host arrays (numpy, lists: copied on the
+        host and uploaded by the compiled call itself, so the caller may
+        rewrite them as soon as this returns) or device arrays
+        (``jax.Array`` / ``NDArray``: handed on untouched, never read
+        back). ``seed`` is an integer operand: the program makes
+        ``jax.random.PRNGKey(seed)`` inside, bit for bit the eager key
+        for an int32 seed; a larger one folds to its low 32 bits
+        (``_seed_operand``), as the eager form folded it."""
         self._need_encoder_memory("prefill_paged (a whole-bucket prefill "
                                   "primed with BOS)")
-        src = jnp.asarray(src, jnp.int32)
-        vl = jnp.asarray(src_valid_length, jnp.int32)
-        slot_ids = jnp.asarray(slot_ids, jnp.int32)
-        first_pages = jnp.asarray(first_pages, jnp.int32)
-        active = jnp.asarray(active, jnp.bool_)
+        src, vl, slot_ids, first_pages = self._operands(
+            np.int32, src, src_valid_length, slot_ids, first_pages)
+        active, = self._operands(np.bool_, active)
+        temp, = self._operands(np.float32, temperature)
         method, top_k, seed, _ = self._paged_cfg(method, top_k, seed)
         cfg = (method, top_k)
         sig = ("paged_prefill", cfg, (src.shape, src.dtype.name),
@@ -630,8 +683,7 @@ class InferStep:
         fn = self._get_paged_prefill_fn(*cfg)
         vals = self._values  # one coherent weight snapshot per dispatch
         tok0, new_state = fn(vals, state, src, vl, slot_ids, first_pages,
-                             active, jax.random.PRNGKey(seed),
-                             jnp.float32(temperature))
+                             active, seed, temp)
         return NDArray(tok0), new_state
 
     def prefill_suffix_paged(self, state, tokens, token_vl, q_offset,
@@ -645,14 +697,13 @@ class InferStep:
         a prior prefill). ``wide`` routes the replay through the ONE-pass
         q_offset-aware window program (paged flash kernel when enabled)
         instead of the bit-exact sequential stream. Same staging/guard/
-        donation contract as ``prefill_paged``; sync-free by lint.
+        donation contract as ``prefill_paged`` (one enqueue; host or
+        device operands; ``seed`` an integer operand); sync-free by lint.
         Returns ``(tok0 (B,) NDArray, new_state)``."""
-        tokens = jnp.asarray(tokens, jnp.int32)
-        token_vl = jnp.asarray(token_vl, jnp.int32)
-        q_offset = jnp.asarray(q_offset, jnp.int32)
-        page_tables = jnp.asarray(page_tables, jnp.int32)
-        slot_ids = jnp.asarray(slot_ids, jnp.int32)
-        active = jnp.asarray(active, jnp.bool_)
+        tokens, token_vl, q_offset, page_tables, slot_ids = self._operands(
+            np.int32, tokens, token_vl, q_offset, page_tables, slot_ids)
+        active, = self._operands(np.bool_, active)
+        temp, = self._operands(np.float32, temperature)
         method, top_k, seed, _ = self._paged_cfg(method, top_k, seed)
         wide = True if wide else False
         cfg = (method, top_k, wide)
@@ -664,9 +715,7 @@ class InferStep:
         fn = self._get_suffix_fn(*cfg)
         vals = self._values  # one coherent weight snapshot per dispatch
         tok0, new_state = fn(vals, state, tokens, token_vl, q_offset,
-                             page_tables, slot_ids, active,
-                             jax.random.PRNGKey(seed),
-                             jnp.float32(temperature))
+                             page_tables, slot_ids, active, seed, temp)
         return NDArray(tok0), new_state
 
     def decode_iter(self, state, page_tables, tokens, lengths, active,
@@ -675,14 +724,17 @@ class InferStep:
         """One decode ITERATION over the slot batch: ``steps`` incremental
         tokens per live row in a single jitted dispatch, K/V read and
         written through ``page_tables``. The big pool state is the
-        donated carry; tokens/lengths/active are small per-dispatch host
-        operands. Sync-free by lint — the scheduler's collect phase is
-        the sync point. Returns ``(tok_block (slots, steps) NDArray,
-        new_state)``."""
-        page_tables = jnp.asarray(page_tables, jnp.int32)
-        tokens = jnp.asarray(tokens, jnp.int32)
-        lengths = jnp.asarray(lengths, jnp.int32)
-        active = jnp.asarray(active, jnp.bool_)
+        donated carry; tokens/lengths/active and the page table are
+        small per-dispatch operands, host arrays (copied, then uploaded
+        by the one compiled call) or device arrays (passed through).
+        ``seed`` is an integer operand, the key is made in the program
+        (``prefill_paged`` says how a seed past int32 folds). Sync-free
+        by lint — the scheduler's collect phase is the sync point.
+        Returns ``(tok_block (slots, steps) NDArray, new_state)``."""
+        page_tables, tokens, lengths = self._operands(
+            np.int32, page_tables, tokens, lengths)
+        active, = self._operands(np.bool_, active)
+        temp, = self._operands(np.float32, temperature)
         method, top_k, seed, steps = self._paged_cfg(method, top_k, seed,
                                                      steps)
         cfg = (steps, method, top_k)
@@ -694,8 +746,7 @@ class InferStep:
         fn = self._get_decode_iter_fn(steps, method, top_k)
         vals = self._values
         buf, new_state = fn(vals, state, page_tables, tokens, lengths,
-                            active, jax.random.PRNGKey(seed),
-                            jnp.float32(temperature))
+                            active, seed, temp)
         return NDArray(buf), new_state
 
     # ---------------------------------------------------- speculative decode
@@ -765,12 +816,14 @@ class InferStep:
         in ONE jitted call (the draft's donated-carry decode_iter).
         ``tokens`` are the slots' carry tokens; returns ``(buf (slots,
         k+1) NDArray, new_dstate)`` — proposals are ``buf[:, :k]``, the
-        last column is the hole-closing extra step. Sync-free by lint;
-        pass the whole buf to ``spec_verify``."""
-        page_tables = jnp.asarray(page_tables, jnp.int32)
-        tokens = jnp.asarray(tokens, jnp.int32)
-        lengths = jnp.asarray(lengths, jnp.int32)
-        active = jnp.asarray(active, jnp.bool_)
+        last column is the hole-closing extra step. Sync-free by lint
+        and one enqueue (host or device operands, ``seed`` an integer
+        operand, as ``decode_iter``); pass the whole buf to
+        ``spec_verify``."""
+        page_tables, tokens, lengths = self._operands(
+            np.int32, page_tables, tokens, lengths)
+        active, = self._operands(np.bool_, active)
+        temp, = self._operands(np.float32, 1.0)
         method, top_k, seed, steps = self._paged_cfg("greedy", 0, seed,
                                                      k + 1)
         cfg = (steps, method, top_k)
@@ -782,8 +835,7 @@ class InferStep:
         fn = self._get_spec_draft_fn(steps, method, top_k)
         vals = pair[1] if pair is not None else self.draft._values
         buf, new_dstate = fn(vals, dstate, page_tables, tokens, lengths,
-                             active, jax.random.PRNGKey(seed),
-                             jnp.float32(1.0))
+                             active, seed, temp)
         return NDArray(buf), new_dstate
 
     @staticmethod
@@ -863,15 +915,13 @@ class InferStep:
         NDArray, new_state)``: columns 0..k are the target greedy tokens
         t_0..t_k, column k+1 the per-row emit count — the scheduler
         emits ``t_0..t_{count-1}`` and advances length by count.
-        Sync-free by lint; greedy only (spec never engages for sampled
-        requests)."""
-        page_tables = jnp.asarray(page_tables, jnp.int32)
-        drafts = drafts.data if isinstance(drafts, NDArray) \
-            else jnp.asarray(drafts)
-        drafts = drafts.astype(jnp.int32)
-        tokens = jnp.asarray(tokens, jnp.int32)
-        lengths = jnp.asarray(lengths, jnp.int32)
-        active = jnp.asarray(active, jnp.bool_)
+        Sync-free by lint and one enqueue: ``drafts`` stays on the device
+        (it is never pulled to the host), the host operands go in as
+        numpy. Greedy only (spec never engages for sampled requests), so
+        it takes no seed."""
+        page_tables, drafts, tokens, lengths = self._operands(
+            np.int32, page_tables, drafts, tokens, lengths)
+        active, = self._operands(np.bool_, active)
         k, wide = self._spec_cfg(drafts.shape[1], wide)
         cfg = (k, wide)
         sig = ("spec_verify", cfg, (page_tables.shape, drafts.shape),

@@ -1361,9 +1361,10 @@ class ContinuousBatcher(_BatcherBase):
         index lists are padded to ``slots`` with out-of-bounds rows that
         the scatter drops, so one program covers every retire pass; the
         store is donated. It is enqueued before the admission prefill
-        that may overwrite those slots: device order keeps it sound."""
+        that may overwrite those slots: device order keeps it sound. The
+        index vectors are built here for this one call and go to it as
+        numpy: the call uploads them, nothing eager stands before it."""
         import jax
-        import jax.numpy as jnp
 
         sids = _np.zeros((self.slots,), _np.int32)
         rows = _np.full((self.slots,), self.cache.max_roots, _np.int32)
@@ -1379,7 +1380,7 @@ class ContinuousBatcher(_BatcherBase):
         st = self._state
         self._store = self._store_fn(
             st["cross_k"] + st["cross_v"], st["mem_vl"], *self._store,
-            jnp.asarray(sids), jnp.asarray(rows))
+            sids, rows)
         if by_row:
             self._pass["prefix_store_dispatches"] += 1
             self._pass["prefix_rows_stored"] += len(by_row)
@@ -1413,9 +1414,9 @@ class ContinuousBatcher(_BatcherBase):
         the CPU rig — more than the batched cold replay they were
         saving. Rows are padded to ``slots`` (COW pads as TRASH
         self-copies, cross rows as out-of-bounds drops), so one compiled
-        program covers every admission-group size."""
+        program covers every admission-group size. The four index
+        vectors are this call's own and go to it as numpy."""
         import jax
-        import jax.numpy as jnp
 
         st = self._state
         n = self.slots
@@ -1425,8 +1426,8 @@ class ContinuousBatcher(_BatcherBase):
         rows = _np.zeros((n,), _np.int32)
         for i, (slot, hit) in enumerate(hits):
             if hit.cow is not None:
-                src[i] = int(hit.cow[0])
-                dst[i] = int(self.pool.table[slot, len(hit.full_pages)])
+                src[i] = hit.cow[0]
+                dst[i] = self.pool.table[slot, len(hit.full_pages)]
             sids[i] = slot
             rows[i] = hit.row
         if self._hits_fn is None:
@@ -1441,9 +1442,7 @@ class ContinuousBatcher(_BatcherBase):
             self._hits_fn = jax.jit(_apply)
         out = self._hits_fn(st["k_pools"], st["v_pools"],
                             st["cross_k"], st["cross_v"], st["mem_vl"],
-                            *self._store,
-                            jnp.asarray(src), jnp.asarray(dst),
-                            jnp.asarray(sids), jnp.asarray(rows))
+                            *self._store, src, dst, sids, rows)
         st = dict(st)
         (st["k_pools"], st["v_pools"], st["cross_k"], st["cross_v"],
          st["mem_vl"]) = out

@@ -662,3 +662,200 @@ class TestDynamicBatcher:
         finally:
             bat.stop()
         assert eng.compile_guard.steady_state_recompiles == 0
+
+
+# ---------------------------------------- a paged dispatch is one enqueue
+# ISSUE 28: host operands reach the compiled program as they are (numpy,
+# uploaded by the one call), the key is made inside it from an int32 seed.
+def _key_bits(key):
+    return np.asarray(jax.random.key_data(key))
+
+
+class TestSeedOperand:
+    @pytest.mark.parametrize("seed", [
+        0, 1, 7, 123456, 2**31 - 1, -1, -2**31,          # an int32 holds
+        2**31, 2**32 + 5, 2**40 + 7, -2**33 - 3])         # folded
+    def test_in_program_key_equals_eager_key(self, seed):
+        operand = InferStep._seed_operand(seed)
+        assert type(operand) is np.int32
+        inside = jax.jit(lambda s: jax.random.key_data(
+            jax.random.PRNGKey(s)))(operand)
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore")     # the eager fold may warn
+            eager = jax.random.PRNGKey(seed)
+        np.testing.assert_array_equal(np.asarray(inside), _key_bits(eager))
+
+    def test_none_is_zero_and_a_seed_no_int64_holds_is_refused(self):
+        assert InferStep._seed_operand(None) == 0
+        with pytest.raises(OverflowError):
+            InferStep._seed_operand(2**64)
+        with pytest.raises(OverflowError):
+            jax.random.PRNGKey(2**64)           # as the eager form did
+
+    def test_operand_avals_are_what_the_eager_forms_gave(self):
+        from jax.api_util import shaped_abstractify as aval
+
+        assert aval(InferStep._seed_operand(3)) == aval(jnp.int32(3))
+        t, = InferStep._operands(np.float32, 0.7)
+        assert aval(t) == aval(jnp.float32(0.7))
+        assert not aval(t).weak_type
+        x, = InferStep._operands(np.int32, [[1, 2], [3, 4]])
+        assert aval(x) == aval(jnp.asarray([[1, 2], [3, 4]], jnp.int32))
+        b, = InferStep._operands(np.bool_, np.ones((3,), bool))
+        assert aval(b) == aval(jnp.asarray(np.ones((3,), bool), jnp.bool_))
+
+
+def _aligned(arr, boundary=64):
+    """A copy of ``arr`` whose memory starts on a ``boundary``: what the
+    CPU client takes as a device buffer without copying."""
+    raw = np.empty(arr.nbytes + boundary, np.uint8)
+    at = -raw.ctypes.data % boundary
+    out = raw[at:at + arr.nbytes].view(arr.dtype).reshape(arr.shape)
+    out[...] = arr
+    return out
+
+
+class TestOneEnqueueDispatch:
+    SLOTS, PAGE, PAGES, MEM = 4, 4, 3, 8
+
+    def _primed(self, tmodel, **sampling):
+        """An engine, its paged state after one admission prefill of
+        every slot, and that dispatch's host operands and first tokens."""
+        eng = InferStep(tmodel, max_len=24)
+        n = self.SLOTS
+        state = eng.init_paged_state(n, n * self.PAGES, self.PAGE, self.MEM)
+        rng = np.random.RandomState(3)
+        ops = {
+            "src": rng.randint(3, 61, (n, self.MEM)).astype(np.int32),
+            "vl": np.array([8, 5, 7, 3], np.int32),
+            "slot_ids": np.arange(n, dtype=np.int32),
+            "table": (1 + np.arange(n * self.PAGES, dtype=np.int32)
+                      ).reshape(n, self.PAGES),
+            "active": np.ones((n,), bool)}
+        tok0, state = eng.prefill_paged(
+            state, ops["src"], ops["vl"], ops["slot_ids"],
+            ops["table"][:, 0], ops["active"], **sampling)
+        return eng, state, ops, tok0.asnumpy()
+
+    @pytest.mark.parametrize("seed", [7, 123456])
+    @pytest.mark.parametrize("sampling", [
+        dict(method="sample", temperature=1.3),
+        dict(method="top_k", top_k=4, temperature=0.7)],
+        ids=["sample", "top_k"])
+    def test_sampled_streams_equal_the_eager_key_form(
+            self, tmodel, monkeypatch, sampling, seed):
+        """The same program bodies fed an EAGER key, as every dispatch
+        was before: ``PRNGKey`` lets a raw key through, so the engine's
+        own programs trace with the key as their operand."""
+        eng, state, ops, tok0 = self._primed(tmodel, seed=seed, **sampling)
+        lengths = np.ones((self.SLOTS,), np.int32)
+        buf, _ = eng.decode_iter(state, ops["table"], tok0, lengths,
+                                 ops["active"], steps=3, seed=seed + 1,
+                                 **sampling)
+        make_key = jax.random.PRNGKey
+
+        def key_or_seed(s, **kw):
+            raw = getattr(s, "dtype", None) == np.uint32 and s.ndim == 1
+            return s if raw else make_key(s, **kw)
+
+        monkeypatch.setattr(jax.random, "PRNGKey", key_or_seed)
+        ref = InferStep(tmodel, max_len=24)
+        cfg = (sampling["method"], sampling.get("top_k", 0))
+        temp = np.float32(sampling["temperature"])
+        rstate = ref.init_paged_state(self.SLOTS, self.SLOTS * self.PAGES,
+                                      self.PAGE, self.MEM)
+        rtok0, rstate = ref._get_paged_prefill_fn(*cfg)(
+            ref._values, rstate, ops["src"], ops["vl"], ops["slot_ids"],
+            ops["table"][:, 0], ops["active"], make_key(seed), temp)
+        np.testing.assert_array_equal(tok0, np.asarray(rtok0))
+        rbuf, _ = ref._get_decode_iter_fn(3, *cfg)(
+            ref._values, rstate, ops["table"], tok0, lengths,
+            ops["active"], make_key(seed + 1), temp)
+        np.testing.assert_array_equal(buf.asnumpy(), np.asarray(rbuf))
+        # and the key is read: another seed, another stream
+        eng2, state2, _, tok0b = self._primed(tmodel, seed=seed, **sampling)
+        np.testing.assert_array_equal(tok0, tok0b)
+        other, _ = eng2.decode_iter(state2, ops["table"], tok0, lengths,
+                                    ops["active"], steps=3, seed=seed + 2,
+                                    **sampling)
+        assert (other.asnumpy() != buf.asnumpy()).any()
+
+    @pytest.mark.parametrize("entry", ["decode_iter", "prefill_paged",
+                                       "prefill_suffix_paged"])
+    def test_host_operands_may_be_rewritten_once_the_call_returns(
+            self, tmodel, entry):
+        """``pool.table`` and the staging arrays are rewritten for the
+        next pass while the device may not have started this one."""
+        def run(mutate):
+            eng, state, ops, tok0 = self._primed(tmodel)
+            n = self.SLOTS
+            table, active = _aligned(ops["table"]), _aligned(ops["active"])
+            toks, ones = _aligned(tok0), _aligned(np.ones((n,), np.int32))
+            if entry == "decode_iter":
+                host = (table, toks, ones, active)
+                out, _ = eng.decode_iter(state, *host, steps=3)
+            elif entry == "prefill_paged":
+                src, vl = _aligned(ops["src"][:, ::-1]), _aligned(ops["vl"])
+                host = (src, vl, _aligned(ops["slot_ids"]),
+                        _aligned(table[:, 0]), active)
+                out, _ = eng.prefill_paged(state, *host)
+            else:
+                replay = _aligned(np.stack([toks, toks + 1], 1))
+                host = (replay, _aligned(2 * ones), ones, table,
+                        _aligned(ops["slot_ids"]), active)
+                out, _ = eng.prefill_suffix_paged(state, *host)
+            if mutate:
+                for a in host:
+                    a[...] = 0
+            return out.asnumpy()
+
+        np.testing.assert_array_equal(run(mutate=True), run(mutate=False))
+
+    def test_host_operands_are_private_copies(self):
+        table = np.arange(12, dtype=np.int32).reshape(4, 3)
+        for given in (table, table[:, 0], table.astype(np.int64),
+                      [1, 2, 3]):
+            got, = InferStep._operands(np.int32, given)
+            assert type(got) is np.ndarray and got.dtype == np.int32
+            assert got.flags.owndata
+            assert not np.shares_memory(got, np.asarray(given))
+            np.testing.assert_array_equal(got, np.asarray(given))
+
+    @pytest.mark.parametrize("wrap", [lambda a: a, nd.NDArray],
+                             ids=["jax.Array", "NDArray"])
+    def test_device_operand_reaches_the_program_without_a_read_back(
+            self, tmodel, monkeypatch, wrap):
+        from jax._src.array import ArrayImpl
+
+        eng, state, ops, tok0 = self._primed(tmodel)
+        eng2, state2, _, _ = self._primed(tmodel)
+        lengths = np.ones((self.SLOTS,), np.int32)
+        want, _ = eng2.decode_iter(state2, ops["table"], tok0, lengths,
+                                   ops["active"], steps=2)
+        want = want.asnumpy()
+        on_device = [jnp.asarray(ops["table"]), jnp.asarray(tok0),
+                     jnp.asarray(lengths), jnp.asarray(ops["active"])]
+        same = InferStep._operands(np.int32, *map(wrap, on_device[:3]))
+        assert all(a is b for a, b in zip(same, on_device))
+        # a read-back goes through ``__array__`` / ``_value`` (on the CPU
+        # numpy may take the buffer instead, so the program's own
+        # arguments are held against the given arrays as well)
+        pulled, given = [], []
+        read, value = ArrayImpl.__array__, ArrayImpl._value
+        monkeypatch.setattr(
+            ArrayImpl, "__array__",
+            lambda self, *a, **kw: (pulled.append(self.shape),
+                                    read(self, *a, **kw))[1])
+        monkeypatch.setattr(
+            ArrayImpl, "_value", property(
+                lambda self: (pulled.append(self.shape),
+                              value.fget(self))[1]))
+        fn = eng._get_decode_iter_fn(2, "greedy", 0)
+        monkeypatch.setitem(
+            eng._paged_fns, ("decode_iter", 2, "greedy", 0),
+            lambda *args: (given.extend(args[2:6]), fn(*args))[1])
+        got, _ = eng.decode_iter(state, *map(wrap, on_device), steps=2)
+        assert pulled == []
+        assert all(a is b for a, b in zip(given, on_device))
+        monkeypatch.undo()
+        np.testing.assert_array_equal(got.asnumpy(), want)

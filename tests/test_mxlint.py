@@ -291,6 +291,86 @@ class TestNoSync:
         assert any("sleep" in m for _, m in violations)
         assert any("tolist" in m for _, m in violations)
 
+    # ---- the second rule set: eager device constructors on a dispatch path
+    def test_dispatch_targets_are_linted_targets(self):
+        covered = {(p, cls): set(funcs) for p, cls, funcs in no_sync.TARGETS}
+        assert "_apply_prefix_hits" in \
+            covered[(no_sync.BATCHER_PY, "ContinuousBatcher")]
+        for path, cls, funcs in no_sync.DISPATCH_TARGETS:
+            assert set(funcs) <= covered[(path, cls)]
+        paged = dict(((p, c), f) for p, c, f in no_sync.DISPATCH_TARGETS)
+        assert {"decode_iter", "prefill_paged", "prefill_suffix_paged",
+                "spec_draft", "spec_verify"} == \
+            set(paged[(no_sync.INFER_PY, "InferStep")])
+        assert {"_store_rows", "_apply_prefix_hits", "_dispatch"} <= \
+            set(paged[(no_sync.BATCHER_PY, "ContinuousBatcher")])
+
+    def test_clean_dispatch_passes_both_rule_sets(self, tmp_path):
+        good = tmp_path / "infer_clean.py"
+        good.write_text(
+            "import numpy as np\n"
+            "import jax\n"
+            "import jax.numpy as jnp\n"
+            "class InferStep:\n"
+            "    @staticmethod\n"
+            "    def _operands(dtype, *xs):\n"
+            "        return [np.array(x, dtype) for x in xs]\n"
+            "    def decode_iter(self, state, tables, tokens, seed):\n"
+            "        tables, tokens = self._operands(np.int32, tables,\n"
+            "                                        tokens)\n"
+            "        return self._fn(state, tables, tokens, seed)\n"
+            "    def _program(self, state, tables, tokens, seed):\n"
+            "        key = jax.random.PRNGKey(seed)\n"
+            "        return jnp.asarray(tokens), key\n"
+        )
+        assert not no_sync.find_violations(
+            str(good), "InferStep", ("decode_iter",), ("decode_iter",))
+
+    @pytest.mark.parametrize("call,shown", [
+        ("jnp.asarray(tokens, jnp.int32)", "jnp.asarray"),
+        ("jnp.array(tokens)", "jnp.array"),
+        ("jnp.float32(temperature)", "jnp.float32"),
+        ("jnp.int32(seed)", "jnp.int32"),
+        ("jnp.bool_(active)", "jnp.bool_"),
+        ("jax.device_put(tokens)", "jax.device_put"),
+        ("jax.random.PRNGKey(seed)", "jax.random.PRNGKey"),
+        ("jax.random.key(seed)", "jax.random.key"),
+    ])
+    def test_lint_catches_eager_constructor(self, tmp_path, call, shown):
+        bad = tmp_path / "infer_eager.py"
+        bad.write_text(
+            "import jax\n"
+            "import jax.numpy as jnp\n"
+            "class InferStep:\n"
+            "    def decode_iter(self, state, tokens, seed, temperature,\n"
+            "                    active):\n"
+            f"        x = {call}\n"
+            "        return self._fn(state, x)\n"
+        )
+        violations = no_sync.find_violations(
+            str(bad), "InferStep", ("decode_iter",), ("decode_iter",))
+        assert len(violations) == 1
+        assert "eager device constructor" in violations[0][1]
+        assert shown + "(" in violations[0][1]
+        # the first rule set alone (decode_n, TrainStep) does not mind it
+        assert not no_sync.find_violations(
+            str(bad), "InferStep", ("decode_iter",))
+
+    def test_eager_constructor_is_its_own_rule(self, ctx, monkeypatch,
+                                               tmp_path):
+        bad = tmp_path / "batcher_eager.py"
+        bad.write_text(
+            "import jax.numpy as jnp\n"
+            "class ContinuousBatcher:\n"
+            "    def _store_rows(self, by_row):\n"
+            "        return self._store_fn(jnp.asarray(by_row))\n"
+        )
+        target = ((str(bad), "ContinuousBatcher", ("_store_rows",)),)
+        monkeypatch.setattr(no_sync, "TARGETS", target)
+        monkeypatch.setattr(no_sync, "DISPATCH_TARGETS", target)
+        findings = no_sync.NoSyncPass().run(ctx)
+        assert [f.rule for f in findings] == ["eager-constructor"]
+
 
 # =========================================== amp-purity (ported coverage)
 class TestAmpPurity:

@@ -18,8 +18,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 from mxnet_tpu.analysis.passes.no_sync import (  # noqa: E402,F401
     BATCHER_PY, BLOCKING_ATTRS, BLOCKING_BUILTINS, BLOCKING_QUALIFIED,
-    FAST_PATH_FUNCS, INFER_PY, STEP_PY, TARGETS, find_all_violations,
-    find_violations,
+    DISPATCH_TARGETS, EAGER_CONSTRUCTORS, FAST_PATH_FUNCS, INFER_PY, STEP_PY,
+    TARGETS, find_all_violations, find_violations,
 )
 
 
