@@ -36,10 +36,10 @@ FAST_PATH_FUNCS = ("__call__", "_dispatch")
 
 # every linted (file, class, methods) hot path. The inference engine's
 # decode_n is the whole generation dispatch and decode_iter/prefill_paged
-# are the continuous-batching iteration dispatches; the batchers'
-# _dispatch methods assemble and fire batches (DynamicBatcher._resolve /
-# ContinuousBatcher._collect+_admit are the designated sync points and
-# stay unlinted). ContinuousBatcher._step_once — the scheduler loop body
+# are the continuous-batching iteration dispatches; the scheduler's
+# _dispatch assembles and fires the decode burst (ContinuousBatcher.
+# _collect and _admit are the designated sync points and stay
+# unlinted). ContinuousBatcher._step_once — the scheduler loop body
 # — is linted too: its syncs must stay delegated to those named phases,
 # never inlined next to a dispatch. So is the whole retire path (_retire,
 # the root registration and its store dispatch, each request's trie
@@ -54,7 +54,6 @@ TARGETS = (
                              "decode_iter", "prefill_paged",
                              "prefill_suffix_paged", "spec_draft",
                              "spec_verify")),
-    (BATCHER_PY, "DynamicBatcher", ("_dispatch",)),
     (BATCHER_PY, "ContinuousBatcher", SCHEDULER_FUNCS),
 )
 

@@ -316,9 +316,8 @@ class Estimator:
         the eager/hybridized path.
 
         ``engine`` may also be a serving BATCHER (anything with
-        ``submit()`` — the ``serving.make_batcher`` default is the
-        paged-KV ``ContinuousBatcher``; ``MXTPU_BATCHER=fixed`` falls
-        back to ``DynamicBatcher``): each batch's rows are then submitted
+        ``submit()`` — ``serving.make_batcher`` builds the paged-KV
+        ``ContinuousBatcher``): each batch's rows are then submitted
         as individual generation requests through iteration-level
         scheduling and the per-batch output is a ``(tokens (B, max_new),
         lengths (B,))`` NDArray pair, trimmed/padded exactly like

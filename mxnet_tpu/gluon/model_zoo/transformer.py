@@ -600,9 +600,8 @@ class TransformerModel(HybridBlock):
 
         Greedy calls route through a cached ``serving.ContinuousBatcher``
         (iteration-level scheduling over the paged KV pool — rows that hit
-        EOS free their slot and pages immediately) unless
-        ``MXTPU_BATCHER=fixed`` (the PR-5 fixed-dispatch ``decode_n``
-        path). Sampling with an explicit ``seed`` keeps the direct path:
+        EOS free their slot and pages immediately). Sampling, or an
+        explicit ``seed``, keeps the direct path (``engine.generate``):
         its key schedule is per-dispatch and only reproducible there.
         Returns ``(tokens, lengths)`` NDArrays either way."""
         from ...parallel.infer import InferStep
@@ -617,7 +616,7 @@ class TransformerModel(HybridBlock):
         if cache_key not in steps:
             steps[cache_key] = InferStep(self, **eng_kw)
         engine = steps[cache_key]
-        if self._use_batcher_path(engine, kwargs):
+        if self._use_batcher_path(kwargs):
             return self._generate_batched(engine, cache_key, src_ids,
                                           src_valid_length,
                                           max_new_tokens, **kwargs)
@@ -626,15 +625,10 @@ class TransformerModel(HybridBlock):
             **kwargs)
 
     @staticmethod
-    def _use_batcher_path(engine, kwargs) -> bool:
-        from ...serving.batcher import batcher_kind
-
-        if batcher_kind() in ("fixed", "off", "direct"):
-            return False
-        if kwargs.get("method", "greedy") != "greedy" or \
-                kwargs.get("seed") is not None:
-            return False  # per-dispatch key schedule: direct path only
-        return getattr(engine, "supports_paged", False)
+    def _use_batcher_path(kwargs) -> bool:
+        # a sampled run's key schedule is per-dispatch: direct path only
+        return kwargs.get("method", "greedy") == "greedy" and \
+            kwargs.get("seed") is None
 
     def _generate_batched(self, engine, cache_key, src_ids,
                           src_valid_length, max_new_tokens, **kwargs):

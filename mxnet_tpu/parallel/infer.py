@@ -266,7 +266,7 @@ class InferStep:
     @property
     def weights_version(self) -> str:
         """Tag of the param set serving new dispatches. Responses carry
-        the version their dispatch ran on (``serving.DynamicBatcher``
+        the version of their final iteration (``serving.ContinuousBatcher``
         stamps it onto each ``GenerationResult``)."""
         return self._weights_version
 

@@ -1,4 +1,4 @@
-"""Serving front-end: dynamic batching, hot weight swap, multi-replica
+"""Serving front-end: continuous batching, hot weight swap, multi-replica
 routing with failover — the self-healing serving plane.
 
 The inference engine (``parallel.infer.InferStep``) turns one *batch* of
@@ -6,20 +6,17 @@ prompts into tokens at O(1)/token; this package turns *concurrent
 requests* into those batches and keeps doing so across weight updates
 and replica failures:
 
-- ``ContinuousBatcher`` (the ``MXTPU_BATCHER=continuous`` default) runs
-  Orca-style ITERATION-LEVEL scheduling over a paged KV cache
+- ``ContinuousBatcher``, the one scheduler (``make_batcher`` builds it),
+  runs Orca-style ITERATION-LEVEL scheduling over a paged KV cache
   (``serving.pages`` + the paged attention mode): between decode
   iterations it retires EOS/deadline rows, frees their pages, and
   admits queued requests into the vacated slots via a jitted
   prefill-into-pages dispatch — occupancy is dynamic, shapes are
   static, tokens stream per iteration, and admission control rejects
-  with ``Backpressure`` when the pool can't absorb more work.
-- ``DynamicBatcher`` (``MXTPU_BATCHER=fixed``) admits requests into
-  fixed ``(batch, bucket)`` slots — pad-to-bucket prompts,
-  timeout-or-full dispatch, per-request future resolution, per-request
-  deadlines — the strict one-weight-version-per-request fallback (Yu
-  et al., Orca, OSDI 2022: between decode dispatches is the safe point
-  for everything below).
+  with ``Backpressure`` when the pool can't absorb more work. Requests
+  carry their own deadlines and resolve their own futures (Yu et al.,
+  Orca, OSDI 2022: between decode dispatches is the safe point for
+  everything below).
 - ``CheckpointWatcher`` hot-swaps newly committed checkpoints into live
   engines between dispatches (double-buffered device params,
   version-tagged responses, zero dropped requests).
@@ -70,12 +67,10 @@ and replica failures:
   (``GenerationResult.phases`` — queue/handoff/prefill/decode/retry
   breakdown summing to the observed end-to-end latency).
 
-Env knobs: ``MXTPU_BATCHER`` (scheduler kind, default ``continuous``),
-``MXTPU_PAGE_SIZE``/``MXTPU_PAGES`` (KV pool geometry),
+Env knobs: ``MXTPU_PAGE_SIZE``/``MXTPU_PAGES`` (KV pool geometry),
 ``MXTPU_ITER_TOKENS`` (decode tokens per scheduler iteration),
 ``MXTPU_ADMIT_*`` (backpressure thresholds — see ``serving.pages``),
 ``MXTPU_BATCHER_SLOTS`` (batch slots per dispatch, default 8),
-``MXTPU_BATCHER_TIMEOUT_MS`` (admission window, default 10),
 ``MXTPU_DECODE_MAX_LEN`` (engine cache capacity — see ``parallel.infer``),
 ``MXTPU_SWAP_POLL_S`` (checkpoint poll period), ``MXTPU_RETRY_MAX``
 (router resubmissions per request), ``MXTPU_RESTART_BACKOFF_S`` (restart
@@ -97,8 +92,7 @@ from . import pages
 from . import prefix
 from . import tracing
 from .batcher import Backpressure, ContinuousBatcher, DeadlineExceeded, \
-    DynamicBatcher, GenerationResult, batcher_kind, batcher_slots, \
-    batcher_timeout_ms, iter_tokens_default, make_batcher
+    GenerationResult, batcher_slots, iter_tokens_default, make_batcher
 from .disagg import HandoffStash, PrefillEngine, kv_spill_dir, \
     worker_role
 from .pages import PagePool
@@ -115,13 +109,12 @@ from .transport import RpcClient, RpcServer, TransportError, \
     rpc_connect_s, rpc_timeout_s, serve_port
 from .watcher import CheckpointWatcher, swap_poll_s, version_for
 
-__all__ = ["DynamicBatcher", "ContinuousBatcher", "GenerationResult",
+__all__ = ["ContinuousBatcher", "GenerationResult",
            "DeadlineExceeded", "Backpressure", "PagePool", "pages",
            "Router", "Replica", "ReplicaUnavailable", "CheckpointWatcher",
            "RemoteReplica", "RemoteEngineHandle", "RpcClient", "RpcServer",
            "TransportError", "faults", "batcher_slots",
-           "batcher_timeout_ms", "batcher_kind", "iter_tokens_default",
-           "make_batcher", "swap_poll_s", "version_for", "retry_max",
+           "iter_tokens_default", "make_batcher", "swap_poll_s", "version_for", "retry_max",
            "restart_backoff_s", "shed_queue_depth", "shed_wait_ms",
            "shed_max_queue", "rpc_timeout_s", "rpc_connect_s",
            "serve_port", "disagg", "PrefillEngine", "HandoffStash",

@@ -1,26 +1,19 @@
-"""Serving batchers: concurrent generation requests -> fixed-shape
+"""The serving scheduler: concurrent generation requests -> fixed-shape
 engine dispatches.
 
-Two schedulers share one admission/lifecycle spine (``_BatcherBase``):
-
-- ``ContinuousBatcher`` (default, ``MXTPU_BATCHER=continuous``) —
-  Orca-style ITERATION-LEVEL scheduling (Yu et al., OSDI 2022) over a
-  PAGED KV cache (Kwon et al., SOSP 2023). The decode batch is a static
-  menu of ``slots``; each iteration dispatches one jitted
-  ``InferStep.decode_iter`` burst, then — between dispatches — retires
-  rows that hit EOS / their ``max_new_tokens`` / their deadline, frees
-  their pages back to the pool, and admits queued requests into the
-  vacated slots through a jitted prefill-into-pages dispatch. Slot count,
-  page-table shape and pool shape never change, so occupancy is dynamic
-  while the program menu stays exactly two entries per prompt bucket.
-  Tokens stream per iteration (``GenerationResult.tokens_iter``), and
-  admission control rejects with ``Backpressure`` when the queue or the
-  free-page watermark says the pool can't absorb more work.
-- ``DynamicBatcher`` (``MXTPU_BATCHER=fixed``) — the PR-5 fallback:
-  timeout-or-full admission into whole-batch ``decode_n`` dispatches; a
-  finished row idles its slot until the batch drains. Kept as the strict
-  per-dispatch-coherent path (one weight version per request) and the
-  baseline the open-loop bench measures against.
+``ContinuousBatcher`` is the one scheduler: Orca-style ITERATION-LEVEL
+scheduling (Yu et al., OSDI 2022) over a PAGED KV cache (Kwon et al.,
+SOSP 2023). The decode batch is a static menu of ``slots``; each
+iteration dispatches one jitted ``InferStep.decode_iter`` burst, then —
+between dispatches — retires rows that hit EOS / their
+``max_new_tokens`` / their deadline, frees their pages back to the pool,
+and admits queued requests into the vacated slots through a jitted
+prefill-into-pages dispatch. Slot count, page-table shape and pool shape
+never change, so occupancy is dynamic while the program menu stays
+exactly two entries per prompt bucket. Tokens stream per iteration
+(``GenerationResult.tokens_iter``), and admission control rejects with
+``Backpressure`` when the queue or the free-page watermark says the pool
+can't absorb more work.
 
 Telemetry (``infer/`` family): ``queue_wait_ms``/``ttft_ms`` per request,
 ``batch_occupancy``/``pages_in_use``/``page_fragmentation``/
@@ -54,10 +47,10 @@ def _evus(t_pc: float) -> float:
     by the process's event-log origin)."""
     return _tracing.clock_us() - (time.perf_counter() - t_pc) * 1e6
 
-__all__ = ["DynamicBatcher", "ContinuousBatcher", "GenerationResult",
+__all__ = ["ContinuousBatcher", "GenerationResult",
            "DeadlineExceeded", "Backpressure", "batcher_slots",
-           "batcher_timeout_ms", "batcher_kind", "iter_tokens_default",
-           "spec_k_default", "spec_draft_enabled", "make_batcher"]
+           "iter_tokens_default", "spec_k_default", "spec_draft_enabled",
+           "make_batcher"]
 
 
 class DeadlineExceeded(MXNetError):
@@ -78,25 +71,6 @@ def batcher_slots(default: int = 8) -> int:
         return int(v) if v else default
     except ValueError:
         return default
-
-
-def batcher_timeout_ms(default: float = 10.0) -> float:
-    """``MXTPU_BATCHER_TIMEOUT_MS``: admission window after the first
-    request of a batch arrives."""
-    v = os.environ.get("MXTPU_BATCHER_TIMEOUT_MS", "").strip()
-    try:
-        return float(v) if v else default
-    except ValueError:
-        return default
-
-
-def batcher_kind(default: str = "continuous") -> str:
-    """``MXTPU_BATCHER``: which scheduler fronts the serving engine —
-    ``continuous`` (iteration-level, paged KV; the default) or ``fixed``
-    (the PR-5 whole-batch ``DynamicBatcher``). ``off``/``direct`` makes
-    ``model.generate`` bypass batching entirely (raw ``decode_n``)."""
-    v = os.environ.get("MXTPU_BATCHER", "").strip().lower()
-    return v if v in ("continuous", "fixed", "off", "direct") else default
 
 
 def iter_tokens_default(default: int = 4) -> int:
@@ -136,19 +110,10 @@ def spec_draft_enabled(default: bool = True) -> bool:
 
 
 def make_batcher(engine, bucket_keys, **kwargs):
-    """Build the process-default batcher over ``engine``:
-    ``ContinuousBatcher`` unless ``MXTPU_BATCHER=fixed`` (or the net
-    lacks the paged protocol), then ``DynamicBatcher``. Kwargs the chosen
-    class doesn't take are dropped."""
-    if batcher_kind() != "fixed" and getattr(engine, "supports_paged",
-                                             False):
-        kwargs.pop("timeout_ms", None)
-        return ContinuousBatcher(engine, bucket_keys, **kwargs)
-    for k in ("page_size", "num_pages", "iter_tokens",
-              "max_prefix_tokens", "prefix_cache", "spec_k",
-              "spec_wide", "suffix_wide", "prefill_chunk"):
-        kwargs.pop(k, None)
-    return DynamicBatcher(engine, bucket_keys, **kwargs)
+    """Build the serving scheduler over ``engine``: a
+    ``ContinuousBatcher`` with every keyword argument handed on as it
+    is."""
+    return ContinuousBatcher(engine, bucket_keys, **kwargs)
 
 
 class GenerationResult:
@@ -157,11 +122,10 @@ class GenerationResult:
     ``result(timeout)`` blocks until the request finished and returns the
     full generated token list (trimmed at EOS); ``exception()`` surfaces
     a failure. ``tokens_iter(timeout)`` STREAMS instead: it yields token
-    chunks as the scheduler emits them (per decode iteration under
-    ``ContinuousBatcher``; one final chunk under ``DynamicBatcher``) and
+    chunks as the scheduler emits them (one per decode iteration) and
     ends when the request resolves. ``weights_version`` tags the param
-    set that served the request (hot weight swap; under continuous
-    batching, the version of its final iteration) and ``replica`` which
+    set that served the request (hot weight swap: the version of its
+    final iteration) and ``replica`` which
     engine replica ran it (router). ``first_token_at`` is the
     ``perf_counter`` instant of the first streamed token (TTFT =
     ``first_token_at - enqueued_at``)."""
@@ -282,430 +246,6 @@ class _Request:
         self.prefix = prefix
 
 
-class _BatcherBase:
-    """Shared admission/lifecycle spine for both schedulers: request
-    validation, queueing, deadline expiry, dispatcher-thread health and
-    teardown. Subclasses implement ``_run_loop`` (the scheduling policy)
-    and dispatching."""
-
-    def __init__(self, engine, bucket_keys: Sequence[int],
-                 slots: Optional[int] = None,
-                 max_new_tokens: int = 32, sampling: Optional[dict] = None,
-                 pad_id: Optional[int] = None, start: bool = True,
-                 name: Optional[str] = None, watchdog=None):
-        if not (getattr(engine, "supports_decode", False)
-                or getattr(engine, "supports_paged", False)):
-            raise MXNetError(
-                f"{type(self).__name__} needs a decode-capable InferStep "
-                "(net with prefill/decode_step, or the paged protocol)")
-        self._engine = engine
-        self.bucket_keys = sorted(int(k) for k in bucket_keys)
-        if not self.bucket_keys:
-            raise MXNetError("bucket_keys must be non-empty")
-        self.slots = int(slots) if slots is not None else batcher_slots()
-        self.max_new = int(max_new_tokens)
-        # forced target-prefix budget; only ContinuousBatcher (paged
-        # pool + prefix trie) raises this above zero
-        self.max_prefix = 0
-        self._sampling = dict(sampling or {})
-        self._pad = int(pad_id) if pad_id is not None else engine._pad
-        self.name = name
-        self._watchdog = watchdog
-        self._queue: "queue.Queue[_Request]" = queue.Queue()
-        self._init_rolling()
-        self._stop = threading.Event()
-        self._thread = None
-        if start:
-            self.start()
-
-    # --------------------------------------------------- SLO telemetry
-    def _init_rolling(self):
-        """Rolling SLO windows (queue wait / TTFT) feeding the worker's
-        health report and the router's predicted-wait placement; written
-        by the scheduler thread, read by caller threads — every touch
-        holds ``_roll_lock`` (and nothing blocking runs under it)."""
-        self._roll_lock = threading.Lock()
-        self._recent_waits = collections.deque(maxlen=64)
-        self._recent_ttft = collections.deque(maxlen=64)
-
-    def _note_wait(self, ms: float):
-        with self._roll_lock:
-            self._recent_waits.append(ms)
-
-    def _note_ttft(self, ms: float):
-        with self._roll_lock:
-            self._recent_ttft.append(ms)
-
-    def rolling_wait_ms(self, min_samples: int = 8) -> Optional[float]:
-        """Rolling queue-wait p50 (ms) over recent completions, or None
-        below ``min_samples`` — the worker-reported signal behind both
-        admission control and SLO-aware router placement."""
-        with self._roll_lock:
-            waits = sorted(self._recent_waits)
-        if len(waits) < min_samples:
-            return None
-        return waits[len(waits) // 2]
-
-    def rolling_ttft_ms(self, min_samples: int = 4) -> Optional[float]:
-        """Rolling time-to-first-token p50 (ms), or None below
-        ``min_samples``."""
-        with self._roll_lock:
-            ttft = sorted(self._recent_ttft)
-        if len(ttft) < min_samples:
-            return None
-        return ttft[len(ttft) // 2]
-
-    def _label(self) -> str:
-        return f"{type(self).__name__}" + (f" {self.name!r}"
-                                           if self.name else "")
-
-    # ------------------------------------------------------------ lifecycle
-    def start(self):
-        if self._thread is not None and self._thread.is_alive():
-            return
-        self._stop.clear()
-        self._thread = threading.Thread(
-            target=self._run, name="mxtpu-batcher", daemon=True)
-        self._thread.start()
-
-    def stop(self, drain: bool = True, timeout: float = 30.0):
-        """Stop the dispatcher; with ``drain`` (default) outstanding
-        requests are dispatched first. Anything still queued when the
-        thread is down is FAILED (a stopped batcher must never hold an
-        unresolvable future)."""
-        if drain and self.healthy:
-            deadline = time.perf_counter() + timeout
-            while not self._drained() and time.perf_counter() < deadline:
-                time.sleep(0.005)
-        self._stop.set()
-        if self._thread is not None:
-            self._thread.join(timeout=timeout)
-            self._thread = None
-        self.cancel_pending()
-
-    def _drained(self) -> bool:
-        return self._queue.empty()
-
-    @property
-    def healthy(self) -> bool:
-        """True while the dispatcher thread is alive and accepting — the
-        router's per-replica liveness poll. Goes false on ``stop()`` and
-        when the thread died (a crash outside the dispatch try)."""
-        t = self._thread
-        return t is not None and t.is_alive() and not self._stop.is_set()
-
-    def cancel_pending(self, error: Optional[BaseException] = None) -> int:
-        """Drain the queue, failing every undispatched request's future
-        (default error: RuntimeError naming the batcher). The router uses
-        this when evicting an unhealthy replica — the failed futures are
-        its signal to resubmit those requests elsewhere. Returns how many
-        requests were cancelled."""
-        n = 0
-        while True:
-            try:
-                r = self._queue.get_nowait()
-            except queue.Empty:
-                return n
-            r.future._fail(error if error is not None else RuntimeError(
-                f"{self._label()} stopped with this request still queued"))
-            n += 1
-
-    def __enter__(self):
-        self.start()
-        return self
-
-    def __exit__(self, *exc):
-        self.stop()
-        return False
-
-    # ------------------------------------------------------------- requests
-    def _admission_check(self, fut) -> bool:
-        """Subclass hook: return False (after failing ``fut``) to reject
-        the request at submit time (backpressure)."""
-        return True
-
-    def submit(self, prompt_ids, max_new_tokens: Optional[int] = None,
-               deadline_ms: Optional[float] = None,
-               frames: Optional[dict] = None,
-               prefix_ids=None,
-               request_id: Optional[str] = None) -> GenerationResult:
-        """Enqueue one prompt (1-D int sequence). Returns a future whose
-        ``result()`` is the generated token list, trimmed at EOS and at
-        the request's ``max_new_tokens`` (<= the batcher's).
-
-        ``deadline_ms`` bounds the request's total latency from NOW: a
-        request still queued (or, under continuous batching, still
-        decoding) when its deadline passes is failed with
-        ``DeadlineExceeded`` instead of being served late.
-
-        ``frames`` carries prefilled KV from a prefill-role worker
-        (``serving.disagg``): ``ContinuousBatcher`` adopts them into its
-        pool at admission instead of re-running the prefill; any
-        adoption failure (and the ``DynamicBatcher`` fallback, which has
-        no paged pool) re-prefills from the prompt — the request is
-        served either way.
-
-        ``prefix_ids`` is target-side conversation history (tokens the
-        model already produced in earlier turns, re-sent by the client):
-        ``ContinuousBatcher`` forces them verbatim before sampling new
-        tokens and serves any part already in its prefix trie straight
-        from cached KV pages. Only new tokens are returned. Requires a
-        batcher built with ``max_prefix_tokens > 0``.
-
-        ``request_id`` tags the future (and its spans/phase breakdown)
-        with the fleet-wide trace id minted at the router; None is fine
-        for direct callers — phases still stamp, spans are just
-        unlinked.
-
-        Submitting to a stopped (or crashed) batcher fails the future
-        immediately with a RuntimeError — a request must never enqueue
-        behind a dispatcher that will not run again."""
-        prompt = _np.asarray(prompt_ids, dtype=_np.int32).reshape(-1)
-        if prompt.shape[0] > self.bucket_keys[-1]:
-            raise MXNetError(
-                f"prompt length {prompt.shape[0]} exceeds the largest "
-                f"bucket key {self.bucket_keys[-1]}")
-        max_new = self.max_new if max_new_tokens is None \
-            else int(max_new_tokens)
-        if max_new > self.max_new:
-            raise MXNetError(
-                f"request max_new_tokens {max_new} > batcher "
-                f"max_new_tokens {self.max_new}")
-        prefix = None
-        if prefix_ids is not None:
-            prefix = _np.asarray(prefix_ids, dtype=_np.int32).reshape(-1)
-            if prefix.shape[0] == 0:
-                prefix = None
-            elif prefix.shape[0] > self.max_prefix:
-                raise MXNetError(
-                    f"prefix length {prefix.shape[0]} > batcher "
-                    f"max_prefix_tokens {self.max_prefix}")
-        fut = GenerationResult()
-        fut.request_id = request_id
-        if not self.healthy:
-            fut._fail(RuntimeError(
-                f"{self._label()} is not accepting requests (stopped, or "
-                "its dispatcher thread died) — the request would never "
-                "resolve"))
-            return fut
-        if not self._admission_check(fut):
-            return fut
-        deadline = None if deadline_ms is None \
-            else time.perf_counter() + float(deadline_ms) / 1e3
-        self._queue.put(_Request(prompt, max_new, fut, deadline,
-                                 frames=frames, prefix=prefix))
-        return fut
-
-    def _expire(self, reqs):
-        """Fail (never dispatch) requests whose deadline passed while
-        they were queued. Runs BEFORE batch assembly, so expired rows
-        don't occupy slots and the occupancy/queue-wait telemetry of the
-        dispatched batch is unaffected."""
-        now = time.perf_counter()
-        live = []
-        for r in reqs:
-            if r.deadline is not None and now > r.deadline:
-                _tel.registry().counter("serve/deadline_exceeded").inc()
-                r.future._fail(DeadlineExceeded(
-                    f"request deadline passed after "
-                    f"{(now - r.future.enqueued_at) * 1e3:.0f} ms in "
-                    "queue — not dispatched"))
-            else:
-                live.append(r)
-        return live
-
-    def _bucket_for(self, max_len):
-        for k in self.bucket_keys:
-            if max_len <= k:
-                return k
-        raise MXNetError(
-            f"prompt length {max_len} > largest bucket key "
-            f"{self.bucket_keys[-1]}")
-
-    # ------------------------------------------------------------ dispatcher
-    def _run(self):
-        try:
-            self._run_loop()
-        except BaseException as e:
-            # the thread is dying (a crash outside the dispatch try, e.g.
-            # the `batcher.thread` fault point): fail whatever is queued
-            # so no future is left unresolvable, then let it die —
-            # `healthy` flips false and the router (if any) takes over
-            self._fail_inflight(RuntimeError(
-                f"{self._label()} dispatcher thread died"))
-            self.cancel_pending(RuntimeError(
-                f"{self._label()} dispatcher thread died"))
-            # injected deaths exit quietly (the crash is the test's
-            # point); real crashes re-raise for the interpreter's
-            # thread-exception hook
-            if not isinstance(e, _faults.FaultInjected):
-                raise
-
-    def _fail_inflight(self, error):
-        """Subclass hook: fail requests the scheduler already pulled off
-        the queue (slots, partial batches) when the thread dies."""
-
-    def _run_loop(self):  # pragma: no cover - abstract
-        raise NotImplementedError
-
-
-class DynamicBatcher(_BatcherBase):
-    """Admit concurrent generation requests into fixed (batch, bucket)
-    engine dispatches — the PR-5 whole-batch scheduler, kept as the
-    ``MXTPU_BATCHER=fixed`` fallback and the strict one-weight-version-
-    per-request path.
-
-    Parameters
-    ----------
-    engine : ``parallel.infer.InferStep`` over a decode-capable net.
-    bucket_keys : ascending prompt-length menu (the warmup contract —
-        ``engine.warmup([(slots, k) for k in bucket_keys], max_new)``
-        compiles every shape this batcher can emit).
-    slots : batch rows per dispatch (``MXTPU_BATCHER_SLOTS``).
-    timeout_ms : admission window (``MXTPU_BATCHER_TIMEOUT_MS``).
-    max_new_tokens : decode length of every dispatch (per-request
-        ``max_new_tokens`` may only be <= this; results are trimmed).
-    sampling : dict of ``decode_n`` sampling kwargs (method/top_k/
-        temperature/seed) shared by the batch.
-    warmup : drive the engine's prefill+decode programs for the whole
-        menu at construction (recommended for serving).
-    name : tag for telemetry and fault matching (``serving.faults``);
-        the router names each replica's batcher after the replica.
-    watchdog : optional ``telemetry.Watchdog`` notified after every
-        resolved dispatch — its ``heartbeat.json`` is the router's
-        liveness signal for this replica (a hung dispatch stops the
-        notifications and the heartbeat goes stale).
-    """
-
-    def __init__(self, engine, bucket_keys: Sequence[int],
-                 slots: Optional[int] = None,
-                 timeout_ms: Optional[float] = None,
-                 max_new_tokens: int = 32, sampling: Optional[dict] = None,
-                 pad_id: Optional[int] = None, warmup: bool = False,
-                 start: bool = True, name: Optional[str] = None,
-                 watchdog=None):
-        super().__init__(engine, bucket_keys, slots=slots,
-                         max_new_tokens=max_new_tokens, sampling=sampling,
-                         pad_id=pad_id, start=False, name=name,
-                         watchdog=watchdog)
-        self.timeout_s = (timeout_ms if timeout_ms is not None
-                          else batcher_timeout_ms()) / 1e3
-        if warmup:
-            engine.warmup([(self.slots, k) for k in self.bucket_keys],
-                          max_new_tokens=self.max_new, **self._sampling)
-        if start:
-            self.start()
-
-    def _run_loop(self):
-        while not self._stop.is_set():
-            # fault point: an unhandled crash of the dispatcher thread
-            # (NOT caught by the dispatch try below) — a dead replica
-            _faults.fire("batcher.thread", tag=self.name)
-            try:
-                first = self._queue.get(timeout=0.05)
-            except queue.Empty:
-                continue
-            reqs = [first]
-            deadline = time.perf_counter() + self.timeout_s
-            while len(reqs) < self.slots:
-                remaining = deadline - time.perf_counter()
-                if remaining <= 0:
-                    break
-                try:
-                    reqs.append(self._queue.get(timeout=remaining))
-                except queue.Empty:
-                    break
-            reqs = self._expire(reqs)
-            if not reqs:
-                continue
-            t0 = time.perf_counter()
-            try:
-                out = self._dispatch(reqs)
-            except Exception as e:  # noqa: BLE001 - fail the futures, not the thread
-                for r in reqs:
-                    r.future._fail(e)
-                continue
-            self._resolve(reqs, out, t0)
-
-    def _dispatch(self, reqs):
-        """Assemble one fixed (slots, bucket) batch and fire the engine.
-        Pure staging + dispatch — linted sync-free
-        (``tools/check_no_sync_in_step.py``): the host reads happen in
-        ``_resolve`` after the device work is in flight."""
-        _faults.fire("batcher.hang", tag=self.name)
-        _faults.fire("batcher.dispatch", tag=self.name)
-        bucket = self._bucket_for(max(r.prompt.shape[0] for r in reqs))
-        src = _np.full((self.slots, bucket), self._pad, _np.int32)
-        vl = _np.zeros((self.slots,), _np.int32)
-        for i, r in enumerate(reqs):
-            n = r.prompt.shape[0]
-            src[i, :n] = r.prompt
-            vl[i] = n
-        # the version THIS dispatch serves, captured with the dispatch:
-        # responses are tagged with it even if a hot swap flips the
-        # engine's live buffer before the results are read back
-        version = getattr(self._engine, "weights_version", None)
-        out = self._engine.decode_n(
-            src, vl, max_new_tokens=self.max_new, **self._sampling)
-        return out, version
-
-    def _resolve(self, reqs, out, t0):
-        """Per-request detach: trim each row at its EOS / its own
-        ``max_new_tokens`` and resolve its future. The host read here is
-        the sync point of the whole pipeline."""
-        (tokens_nd, lengths_nd), version = out
-        tokens = tokens_nd.asnumpy()
-        lengths = lengths_nd.asnumpy()
-        dispatch_ms = (time.perf_counter() - t0) * 1e3
-        now = time.perf_counter()
-        reg = _tel.registry()
-        emitted = 0
-        for i, r in enumerate(reqs):
-            n = min(int(lengths[i]), r.max_new)
-            r.future.queue_wait_ms = (now - r.future.enqueued_at) * 1e3 \
-                - dispatch_ms
-            reg.histogram("infer/queue_wait_ms").observe(
-                max(r.future.queue_wait_ms, 0.0))
-            self._note_wait(max(r.future.queue_wait_ms, 0.0))
-            emitted += n
-            r.future.weights_version = version
-            r.future.replica = self.name
-            r.future.phases = {
-                "queue_ms": max(r.future.queue_wait_ms, 0.0),
-                "decode_ms": dispatch_ms,
-            }
-            if _tracing.trace_enabled():
-                _tracing.span("trace.queue", _evus(r.future.enqueued_at),
-                              {"replica": self.name},
-                              request_id=r.future.request_id,
-                              end_us=_evus(t0))
-                _tracing.span("trace.decode", _evus(t0),
-                              {"replica": self.name, "tokens": n},
-                              request_id=r.future.request_id,
-                              end_us=_evus(now))
-            r.future._resolve(tokens[i, :n].tolist())
-            if r.future.first_token_at is not None:
-                ttft = (r.future.first_token_at
-                        - r.future.enqueued_at) * 1e3
-                reg.histogram("infer/ttft_ms").observe(ttft)
-                self._note_ttft(ttft)
-        wd = self._watchdog
-        if wd is not None:
-            wd.notify_step(seconds=dispatch_ms / 1e3)
-            wd.note_request(inflight=self._queue.qsize(),
-                            request_id=reqs[-1].future.request_id,
-                            completed=len(reqs))
-        reg.counter("infer/requests").inc(len(reqs))
-        reg.counter("infer/tokens").inc(emitted)
-        reg.gauge("infer/batch_occupancy").set(len(reqs) / self.slots)
-        reg.histogram("infer/prefill_ms").observe(dispatch_ms)
-        if emitted:
-            reg.histogram("infer/decode_ms_per_token").observe(
-                dispatch_ms / emitted)
-            reg.gauge("infer/tokens_per_sec").set(
-                emitted / (dispatch_ms / 1e3))
-
-
 class _Slot:
     """Host-side record of one OCCUPIED decode slot."""
 
@@ -737,8 +277,8 @@ class _Slot:
         return not self.finished and self.carry is not None
 
 
-class ContinuousBatcher(_BatcherBase):
-    """Iteration-level scheduler over a paged KV cache — the tentpole.
+class ContinuousBatcher:
+    """Iteration-level scheduler over a paged KV cache.
 
     Between every decode iteration the scheduler retires finished rows
     (EOS, per-request ``max_new_tokens``, deadline), returns their pages
@@ -794,6 +334,12 @@ class ContinuousBatcher(_BatcherBase):
     sampling : ``method``/``top_k``/``temperature`` shared by every
         iteration. NOTE the key schedule is per-iteration, so sampled
         runs are reproducible per batcher, not vs ``decode_n``.
+    name : tag for telemetry and fault matching (``serving.faults``);
+        the router names each replica's batcher after the replica.
+    watchdog : optional ``telemetry.Watchdog`` notified after every
+        iteration — its ``heartbeat.json`` is the router's liveness
+        signal for this replica (a hung dispatch stops the notifications
+        and the heartbeat goes stale).
     """
 
     def __init__(self, engine, bucket_keys: Sequence[int],
@@ -813,16 +359,34 @@ class ContinuousBatcher(_BatcherBase):
                  prefill_chunk: Optional[int] = None,
                  warmup: bool = False, start: bool = True,
                  name: Optional[str] = None, watchdog=None):
-        super().__init__(engine, bucket_keys, slots=slots,
-                         max_new_tokens=max_new_tokens, sampling=sampling,
-                         pad_id=pad_id, start=False, name=name,
-                         watchdog=watchdog)
         if not getattr(engine, "supports_paged", False):
             raise MXNetError(
-                "ContinuousBatcher needs a paged-protocol InferStep "
-                "(net with prefill_paged/decode_step_paged); use "
-                "DynamicBatcher (MXTPU_BATCHER=fixed) otherwise")
+                "ContinuousBatcher needs an InferStep whose net speaks the "
+                "paged protocol (init_paged_state and decode_step_paged, "
+                "with prefill_paged where a slot keeps encoder memory); "
+                f"{type(getattr(engine, '_net', engine)).__name__} does not")
+        self._engine = engine
+        self.bucket_keys = sorted(int(k) for k in bucket_keys)
+        if not self.bucket_keys:
+            raise MXNetError("bucket_keys must be non-empty")
+        self.slots = int(slots) if slots is not None else batcher_slots()
+        self.max_new = int(max_new_tokens)
+        self._sampling = dict(sampling or {})
         self._sampling.pop("seed", None)  # per-iteration key schedule
+        self._pad = int(pad_id) if pad_id is not None else engine._pad
+        self.name = name
+        self._watchdog = watchdog
+        self._queue: "queue.Queue[_Request]" = queue.Queue()
+        # set by ``submit`` and ``stop``: what the idle scheduler waits
+        # for. It takes nothing off the queue while it waits, so a request
+        # changes hands (queue, waiting line, slot) inside a pass alone
+        self._arrival = threading.Event()
+        # odd while a pass runs (written by the scheduler thread alone):
+        # ``_drained`` reads it before and after the rest
+        self._pass_seq = 0
+        self._init_rolling()
+        self._stop = threading.Event()
+        self._thread = None
         self.page_size = int(page_size) if page_size is not None \
             else _pages.page_size_default()
         self.max_prefix = int(max_prefix_tokens)
@@ -978,6 +542,246 @@ class ContinuousBatcher(_BatcherBase):
         if start:
             self.start()
 
+    # --------------------------------------------------- SLO telemetry
+    def _init_rolling(self):
+        """Rolling SLO windows (queue wait / TTFT) feeding the worker's
+        health report and the router's predicted-wait placement; written
+        by the scheduler thread, read by caller threads — every touch
+        holds ``_roll_lock`` (and nothing blocking runs under it)."""
+        self._roll_lock = threading.Lock()
+        self._recent_waits = collections.deque(maxlen=64)
+        self._recent_ttft = collections.deque(maxlen=64)
+
+    def _note_wait(self, ms: float):
+        with self._roll_lock:
+            self._recent_waits.append(ms)
+
+    def _note_ttft(self, ms: float):
+        with self._roll_lock:
+            self._recent_ttft.append(ms)
+
+    def rolling_wait_ms(self, min_samples: int = 8) -> Optional[float]:
+        """Rolling queue-wait p50 (ms) over recent completions, or None
+        below ``min_samples`` — the worker-reported signal behind both
+        admission control and SLO-aware router placement."""
+        with self._roll_lock:
+            waits = sorted(self._recent_waits)
+        if len(waits) < min_samples:
+            return None
+        return waits[len(waits) // 2]
+
+    def rolling_ttft_ms(self, min_samples: int = 4) -> Optional[float]:
+        """Rolling time-to-first-token p50 (ms), or None below
+        ``min_samples``."""
+        with self._roll_lock:
+            ttft = sorted(self._recent_ttft)
+        if len(ttft) < min_samples:
+            return None
+        return ttft[len(ttft) // 2]
+
+    def _label(self) -> str:
+        return f"{type(self).__name__}" + (f" {self.name!r}"
+                                           if self.name else "")
+
+    # ------------------------------------------------------------ lifecycle
+    def start(self):
+        if self._thread is not None and self._thread.is_alive():
+            return
+        self._stop.clear()
+        self._thread = threading.Thread(
+            target=self._run, name="mxtpu-batcher", daemon=True)
+        self._thread.start()
+
+    def stop(self, drain: bool = True, timeout: float = 30.0):
+        """Stop the scheduler; with ``drain`` (default) outstanding
+        requests are served first. Whatever is still queued, waiting or
+        in a slot once the thread is down is FAILED (a stopped batcher
+        must never hold an unresolvable future)."""
+        if drain and self.healthy:
+            deadline = time.perf_counter() + timeout
+            while not self._drained() and time.perf_counter() < deadline:
+                time.sleep(0.005)
+        self._stop.set()
+        self._arrival.set()
+        if self._thread is not None:
+            self._thread.join(timeout=timeout)
+            self._thread = None
+        self.cancel_pending()
+        self._fail_inflight(RuntimeError(
+            f"{self._label()} stopped with this request in flight"))
+
+    def _drained(self) -> bool:
+        """Nothing queued, waiting or in a slot, and nothing on its way
+        from one to the next. A request changes hands inside a pass alone
+        (an admission prefill holds it in neither ``_pending`` nor
+        ``_slots``), so while a pass runs, or where one began or ended
+        during this read, the answer is no."""
+        seq = self._pass_seq
+        return not seq & 1 and self._queue.empty() and not self._pending \
+            and not any(self._slots) and self._pass_seq == seq
+
+    @property
+    def healthy(self) -> bool:
+        """True while the dispatcher thread is alive and accepting — the
+        router's per-replica liveness poll. Goes false on ``stop()`` and
+        when the thread died (a crash outside the dispatch try)."""
+        t = self._thread
+        return t is not None and t.is_alive() and not self._stop.is_set()
+
+    def cancel_pending(self, error: Optional[BaseException] = None) -> int:
+        """Drain the queue, failing every undispatched request's future
+        (default error: RuntimeError naming the batcher). The router uses
+        this when evicting an unhealthy replica — the failed futures are
+        its signal to resubmit those requests elsewhere. Returns how many
+        requests were cancelled."""
+        n = 0
+        while True:
+            try:
+                r = self._queue.get_nowait()
+            except queue.Empty:
+                return n
+            r.future._fail(error if error is not None else RuntimeError(
+                f"{self._label()} stopped with this request still queued"))
+            n += 1
+
+    def __enter__(self):
+        self.start()
+        return self
+
+    def __exit__(self, *exc):
+        self.stop()
+        return False
+
+    # ------------------------------------------------------------- requests
+    def _admission_check(self, fut) -> bool:
+        """Reject-with-backpressure at submit: queue depth beyond
+        ``MXTPU_ADMIT_MAX_QUEUE``, or rolling queue-wait p50 beyond
+        ``MXTPU_ADMIT_MAX_WAIT_MS``, or free pages below the watermark
+        with nothing about to retire — the caller (router) reroutes."""
+        reason = None
+        if self._queue.qsize() + len(self._pending) >= self._admit_max_queue:
+            reason = (f"queue depth {self._queue.qsize()} >= "
+                      f"{self._admit_max_queue} (MXTPU_ADMIT_MAX_QUEUE)")
+        elif self._admit_max_wait_ms > 0:
+            p50 = self.rolling_wait_ms()
+            if p50 is not None and p50 > self._admit_max_wait_ms:
+                reason = (f"queue wait p50 {p50:.0f} ms > "
+                          f"{self._admit_max_wait_ms:.0f} ms "
+                          "(MXTPU_ADMIT_MAX_WAIT_MS)")
+        if reason is not None:
+            with self._stats_lock:
+                self.stats["rejected"] += 1
+            _tel.registry().counter("infer/rejected_backpressure").inc()
+            fut._fail(Backpressure(
+                f"{self._label()} rejected the request: {reason}"))
+            return False
+        return True
+
+    def submit(self, prompt_ids, max_new_tokens: Optional[int] = None,
+               deadline_ms: Optional[float] = None,
+               frames: Optional[dict] = None,
+               prefix_ids=None,
+               request_id: Optional[str] = None) -> GenerationResult:
+        """Enqueue one prompt (1-D int sequence). Returns a future whose
+        ``result()`` is the generated token list, trimmed at EOS and at
+        the request's ``max_new_tokens`` (<= the batcher's).
+
+        ``deadline_ms`` bounds the request's total latency from NOW: a
+        request still queued or still decoding when its deadline passes
+        is failed with ``DeadlineExceeded`` instead of being served late.
+
+        ``frames`` carries prefilled KV from a prefill-role worker
+        (``serving.disagg``): admission adopts them into the pool instead
+        of re-running the prefill; any adoption failure re-prefills from
+        the prompt — the request is served either way.
+
+        ``prefix_ids`` is target-side conversation history (tokens the
+        model already produced in earlier turns, re-sent by the client):
+        they are forced verbatim before new tokens are sampled, and any
+        part already in the prefix trie is served straight from cached KV
+        pages. Only new tokens are returned. Requires a batcher built
+        with ``max_prefix_tokens > 0``. Neither ``frames`` nor
+        ``prefix_ids`` is built for a net whose slots keep no encoder
+        memory.
+
+        ``request_id`` tags the future (and its spans/phase breakdown)
+        with the fleet-wide trace id minted at the router; None is fine
+        for direct callers — phases still stamp, spans are just
+        unlinked.
+
+        Submitting to a stopped (or crashed) batcher fails the future
+        immediately with a RuntimeError — a request must never enqueue
+        behind a dispatcher that will not run again."""
+        if not self._enc_mem and (frames is not None
+                                  or prefix_ids is not None):
+            raise MXNetError(
+                "handoff frames and forced prefixes are not built for a "
+                "net whose slots keep no encoder memory: both are written "
+                "against per-slot cross buffers (serving.disagg "
+                "pack_frames, ContinuousBatcher._adopt)")
+        prompt = _np.asarray(prompt_ids, dtype=_np.int32).reshape(-1)
+        if prompt.shape[0] > self.bucket_keys[-1]:
+            raise MXNetError(
+                f"prompt length {prompt.shape[0]} exceeds the largest "
+                f"bucket key {self.bucket_keys[-1]}")
+        max_new = self.max_new if max_new_tokens is None \
+            else int(max_new_tokens)
+        if max_new > self.max_new:
+            raise MXNetError(
+                f"request max_new_tokens {max_new} > batcher "
+                f"max_new_tokens {self.max_new}")
+        prefix = None
+        if prefix_ids is not None:
+            prefix = _np.asarray(prefix_ids, dtype=_np.int32).reshape(-1)
+            if prefix.shape[0] == 0:
+                prefix = None
+            elif prefix.shape[0] > self.max_prefix:
+                raise MXNetError(
+                    f"prefix length {prefix.shape[0]} > batcher "
+                    f"max_prefix_tokens {self.max_prefix}")
+        fut = GenerationResult()
+        fut.request_id = request_id
+        if not self.healthy:
+            fut._fail(RuntimeError(
+                f"{self._label()} is not accepting requests (stopped, or "
+                "its dispatcher thread died) — the request would never "
+                "resolve"))
+            return fut
+        if not self._admission_check(fut):
+            return fut
+        deadline = None if deadline_ms is None \
+            else time.perf_counter() + float(deadline_ms) / 1e3
+        self._queue.put(_Request(prompt, max_new, fut, deadline,
+                                 frames=frames, prefix=prefix))
+        self._arrival.set()
+        return fut
+
+    def _expire(self, reqs):
+        """Fail (never dispatch) requests whose deadline passed while
+        they were queued. Runs BEFORE admission, so expired rows don't
+        occupy slots and the occupancy/queue-wait telemetry of the
+        dispatched batch is unaffected."""
+        now = time.perf_counter()
+        live = []
+        for r in reqs:
+            if r.deadline is not None and now > r.deadline:
+                _tel.registry().counter("serve/deadline_exceeded").inc()
+                r.future._fail(DeadlineExceeded(
+                    f"request deadline passed after "
+                    f"{(now - r.future.enqueued_at) * 1e3:.0f} ms in "
+                    "queue — not dispatched"))
+            else:
+                live.append(r)
+        return live
+
+    def _bucket_for(self, max_len):
+        for k in self.bucket_keys:
+            if max_len <= k:
+                return k
+        raise MXNetError(
+            f"prompt length {max_len} > largest bucket key "
+            f"{self.bucket_keys[-1]}")
+
     # --------------------------------------------------------------- warmup
     def _warmup(self):
         """Compile every program the scheduler can dispatch — one
@@ -1093,36 +897,28 @@ class ContinuousBatcher(_BatcherBase):
             eng.compile_guard.signatures - before)
         eng.compile_guard.mark_steady()
 
-    # ---------------------------------------------------------- admission
-    def _admission_check(self, fut) -> bool:
-        """Reject-with-backpressure at submit: queue depth beyond
-        ``MXTPU_ADMIT_MAX_QUEUE``, or rolling queue-wait p50 beyond
-        ``MXTPU_ADMIT_MAX_WAIT_MS``, or free pages below the watermark
-        with nothing about to retire — the caller (router) reroutes."""
-        reason = None
-        if self._queue.qsize() + len(self._pending) >= self._admit_max_queue:
-            reason = (f"queue depth {self._queue.qsize()} >= "
-                      f"{self._admit_max_queue} (MXTPU_ADMIT_MAX_QUEUE)")
-        elif self._admit_max_wait_ms > 0:
-            p50 = self.rolling_wait_ms()
-            if p50 is not None and p50 > self._admit_max_wait_ms:
-                reason = (f"queue wait p50 {p50:.0f} ms > "
-                          f"{self._admit_max_wait_ms:.0f} ms "
-                          "(MXTPU_ADMIT_MAX_WAIT_MS)")
-        if reason is not None:
-            with self._stats_lock:
-                self.stats["rejected"] += 1
-            _tel.registry().counter("infer/rejected_backpressure").inc()
-            fut._fail(Backpressure(
-                f"{self._label()} rejected the request: {reason}"))
-            return False
-        return True
-
-    def _drained(self) -> bool:
-        return self._queue.empty() and not self._pending and \
-            not any(self._slots)
+    # ------------------------------------------------------------ scheduler
+    def _run(self):
+        try:
+            self._run_loop()
+        except BaseException as e:
+            # the thread is dying (a crash outside the dispatch try, e.g.
+            # the `batcher.thread` fault point): fail whatever is queued
+            # so no future is left unresolvable, then let it die —
+            # `healthy` flips false and the router (if any) takes over
+            self._fail_inflight(RuntimeError(
+                f"{self._label()} dispatcher thread died"))
+            self.cancel_pending(RuntimeError(
+                f"{self._label()} dispatcher thread died"))
+            # injected deaths exit quietly (the crash is the test's
+            # point); real crashes re-raise for the interpreter's
+            # thread-exception hook
+            if not isinstance(e, _faults.FaultInjected):
+                raise
 
     def _fail_inflight(self, error):
+        """Fail the requests the scheduler already took off the queue
+        (slots and the waiting line) and take every page back."""
         for i, s in enumerate(self._slots):
             if s is not None and not s.req.future.done():
                 s.req.future._fail(error)
@@ -1133,21 +929,14 @@ class ContinuousBatcher(_BatcherBase):
         self._pending.clear()
         self.pool.reset()
 
-    def stop(self, drain: bool = True, timeout: float = 30.0):
-        super().stop(drain=drain, timeout=timeout)
-        self._fail_inflight(RuntimeError(
-            f"{self._label()} stopped with this request in flight"))
-
-    # ------------------------------------------------------------ scheduler
     def _run_loop(self):
         while not self._stop.is_set():
             _faults.fire("batcher.thread", tag=self.name)
+            self._arrival.clear()
             if not self._step_once():
-                # idle: block briefly for an arrival
-                try:
-                    self._pending.append(self._queue.get(timeout=0.05))
-                except queue.Empty:
-                    continue
+                # idle: wait briefly for an arrival, and leave it in the
+                # queue for the next pass's intake
+                self._arrival.wait(0.05)
 
     def _step_once(self) -> bool:
         """One scheduler iteration: retire -> admit -> decode -> collect.
@@ -1155,9 +944,13 @@ class ContinuousBatcher(_BatcherBase):
         if self._drained():
             return False
         acc = self._pass
-        with _tel.phase("sched.step", acc, "step_s",
-                        {"iter": self._iter + 1}):
-            busy = self._pass_once()
+        self._pass_seq += 1
+        try:
+            with _tel.phase("sched.step", acc, "step_s",
+                            {"iter": self._iter + 1}):
+                busy = self._pass_once()
+        finally:
+            self._pass_seq += 1
         with self._stats_lock:
             for k, v in acc.items():
                 self.stats[k] = self.stats[k] + v
@@ -1799,20 +1592,6 @@ class ContinuousBatcher(_BatcherBase):
             reg.gauge("infer/pages_shared").set(self.pool.shared_pages)
         return n_admitted
 
-    def submit(self, prompt_ids, max_new_tokens: Optional[int] = None,
-               deadline_ms: Optional[float] = None,
-               frames: Optional[dict] = None, prefix_ids=None,
-               request_id: Optional[str] = None) -> GenerationResult:
-        if not self._enc_mem and (frames is not None
-                                  or prefix_ids is not None):
-            raise MXNetError(
-                "handoff frames and forced prefixes are not built for a "
-                "net whose slots keep no encoder memory: both are written "
-                "against per-slot cross buffers (serving.disagg "
-                "pack_frames, ContinuousBatcher._adopt)")
-        return super().submit(prompt_ids, max_new_tokens, deadline_ms,
-                              frames, prefix_ids, request_id)
-
     # ----------------------------------------- a prompt that lives in pages
     def _admit_prompts(self) -> int:
         """Admission for a net with no encoder memory. A waiting request
@@ -2112,8 +1891,8 @@ class ContinuousBatcher(_BatcherBase):
     def _poison(self, err):
         """A decode dispatch failed: the donated pool state is gone, so
         fail every in-flight request, rebuild the pools, and keep the
-        thread alive for fresh work (mirrors DynamicBatcher's
-        fail-the-futures-not-the-thread contract)."""
+        thread alive for fresh work (fail the futures, not the
+        thread)."""
         for i, s in enumerate(self._slots):
             if s is not None:
                 if not s.req.future.done():

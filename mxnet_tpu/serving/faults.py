@@ -12,8 +12,9 @@ contract, usable against a real serving process).
 Failure points wired in this package:
 
 ==================== ====================================================
-``batcher.dispatch``  raises inside ``DynamicBatcher._dispatch`` — the
-                      engine call fails, futures get the error, the
+``batcher.dispatch``  raises before a ``ContinuousBatcher`` dispatch
+                      (decode burst, admission prefill, prefix hits) —
+                      the engine call fails, futures get the error, the
                       dispatcher thread survives.
 ``batcher.thread``    raises at the top of the dispatcher loop, OUTSIDE
                       the dispatch try — the thread dies, simulating a

@@ -27,7 +27,7 @@ owns the request lifecycle end to end:
   re-prefilling from the prompt (``disagg/re_prefills``) — requests
   are never lost to a handoff.
 - **Health**: a replica is healthy while (a) its batcher's dispatcher
-  thread is alive (``DynamicBatcher.healthy``), (b) its watchdog
+  thread is alive (``ContinuousBatcher.healthy``), (b) its watchdog
   heartbeat — the PR-1 ``heartbeat.json``, written atomically — is fresh
   and not flagged ``stalled``/``hard_hang``, and (c) it has not been
   evicted. The health loop re-scores every ``health_interval_s``.
@@ -77,7 +77,7 @@ from ..telemetry.watchdog import read_heartbeat
 from . import faults as _faults
 from . import prefix as _prefix
 from . import tracing as _tracing
-from .batcher import Backpressure, DeadlineExceeded, DynamicBatcher, \
+from .batcher import Backpressure, ContinuousBatcher, DeadlineExceeded, \
     GenerationResult, _evus
 
 __all__ = ["Router", "Replica", "ReplicaUnavailable", "retry_max",
@@ -198,11 +198,11 @@ class Replica:
     """One engine+batcher unit behind the router.
 
     ``heartbeat_path`` points at a watchdog ``heartbeat.json`` (wire the
-    same ``Watchdog`` into the batcher via ``DynamicBatcher(...,
+    same ``Watchdog`` into the batcher via ``ContinuousBatcher(...,
     watchdog=...)`` so dispatches feed it). No path = liveness from the
     dispatcher thread alone."""
 
-    def __init__(self, name: str, batcher: DynamicBatcher,
+    def __init__(self, name: str, batcher: ContinuousBatcher,
                  heartbeat_path: Optional[str] = None,
                  heartbeat_stale_s: float = 10.0, role: str = "both"):
         self.name = str(name)

@@ -2,12 +2,11 @@
 
 The process half of the cross-process serving plane
 (``serving.transport``): ``python -m mxnet_tpu.serving.worker --dir D``
-builds a net, wraps it in an ``InferStep`` + the process-default batcher
-(``serving.make_batcher`` — ``ContinuousBatcher`` unless
-``MXTPU_BATCHER=fixed``), writes the PR-1 watchdog heartbeat into
-``--dir``, announces itself in ``worker.json`` (name/host/port/pid —
-written AFTER warmup, so its existence is the readiness signal), and
-serves the transport verbs until told to stop:
+builds a net, wraps it in an ``InferStep`` + the serving scheduler
+(``serving.make_batcher``: a ``ContinuousBatcher``), writes the PR-1
+watchdog heartbeat into ``--dir``, announces itself in ``worker.json``
+(name/host/port/pid — written AFTER warmup, so its existence is the
+readiness signal), and serves the transport verbs until told to stop:
 
 - **SIGTERM** (or the ``drain`` verb) drains gracefully: new submits
   are rejected with ``ReplicaUnavailable`` (the router replays them
@@ -149,7 +148,6 @@ class ServingWorker:
     def __init__(self, net, directory: str, name: str,
                  port: int = 0, max_len: int = 24,
                  bucket_keys=(8,), slots: int = 2, max_new: int = 4,
-                 batcher_kind: Optional[str] = None,
                  warmup: bool = True, heartbeat_s: float = 0.5,
                  ckpt_dir: Optional[str] = None,
                  drain_s: Optional[float] = None,
@@ -158,7 +156,6 @@ class ServingWorker:
         from ..parallel import InferStep
         from ..telemetry.watchdog import Watchdog
         from . import make_batcher
-        from .batcher import DynamicBatcher
 
         self.directory = directory
         os.makedirs(directory, exist_ok=True)
@@ -189,17 +186,10 @@ class ServingWorker:
         # a dedicated prefill worker never decodes: skip the batcher's
         # decode-program warmup and warm the prefill engine instead
         bat_warmup = warmup and self.role != "prefill"
-        if batcher_kind == "fixed":
-            self.batcher = DynamicBatcher(
-                self.engine, bucket_keys=tuple(bucket_keys), slots=slots,
-                max_new_tokens=max_new, warmup=bat_warmup, name=name,
-                watchdog=self.watchdog)
-        else:
-            self.batcher = make_batcher(
-                self.engine, tuple(bucket_keys), slots=slots,
-                max_new_tokens=max_new, warmup=bat_warmup, name=name,
-                watchdog=self.watchdog,
-                max_prefix_tokens=int(max_prefix))
+        self.batcher = make_batcher(
+            self.engine, tuple(bucket_keys), slots=slots,
+            max_new_tokens=max_new, warmup=bat_warmup, name=name,
+            watchdog=self.watchdog, max_prefix_tokens=int(max_prefix))
         self.prefiller = None
         if self.role == "prefill":
             self.prefiller = _disagg.PrefillEngine(
@@ -634,7 +624,7 @@ def spawn_worker(directory: str, name: Optional[str] = None,
                  net_factory: Optional[str] = None,
                  max_len: int = 24, bucket_keys=(8,), slots: int = 2,
                  max_new: int = 4, ckpt_dir: Optional[str] = None,
-                 batcher: Optional[str] = None, warmup: bool = True,
+                 warmup: bool = True,
                  heartbeat_s: float = 0.1,
                  extra_env: Optional[dict] = None,
                  python: Optional[str] = None,
@@ -660,8 +650,6 @@ def spawn_worker(directory: str, name: Optional[str] = None,
             cmd += [f"--{k.replace('_', '-')}", str(v)]
     if ckpt_dir:
         cmd += ["--ckpt-dir", ckpt_dir]
-    if batcher:
-        cmd += ["--batcher", batcher]
     if role:
         cmd += ["--role", role]
     if max_prefix:
@@ -710,9 +698,6 @@ def main(argv=None) -> int:
                     help="comma-separated prompt bucket menu")
     ap.add_argument("--slots", type=int, default=2)
     ap.add_argument("--max-new", type=int, default=4)
-    ap.add_argument("--batcher", default=None,
-                    choices=["continuous", "fixed"],
-                    help="override MXTPU_BATCHER for this worker")
     ap.add_argument("--role", default=None,
                     choices=["both", "prefill", "decode"],
                     help="disaggregated-fleet role (default MXTPU_ROLE "
@@ -753,7 +738,7 @@ def main(argv=None) -> int:
         net, directory, name, port=port, max_len=args.max_len,
         bucket_keys=tuple(int(k) for k in args.bucket_keys.split(",")),
         slots=args.slots, max_new=args.max_new,
-        batcher_kind=args.batcher, warmup=not args.no_warmup,
+        warmup=not args.no_warmup,
         heartbeat_s=args.heartbeat_s, ckpt_dir=args.ckpt_dir,
         drain_s=args.drain_s, role=args.role,
         max_prefix=args.max_prefix)

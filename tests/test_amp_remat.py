@@ -86,16 +86,25 @@ REMAT_POLICIES = [None, "nothing_saveable", "dots_saveable",
 
 
 def test_remat_policies_bit_identical_losses():
+    """Every remat policy gives the same losses as every other, to the
+    bit. Against the program with no remat they are equal to float32
+    rounding: two programs, one fusion (and so one reduction order) each.
+    Read on this JAX: 1 and 2 ulp on the first two losses, 0 on the
+    third."""
     batch = _tok_batch()
-    base = None
+    plain = remat = None
     for policy in REMAT_POLICIES:
         step = _tiny_transformer_step(remat=policy)
-        losses = [float(step(*batch).asscalar()) for _ in range(3)]
-        if base is None:
-            base = losses
+        losses = np.array([step(*batch).asscalar() for _ in range(3)],
+                          np.float32)
+        if policy is None:
+            plain = losses
+        elif remat is None:
+            remat = losses
+            np.testing.assert_array_max_ulp(losses, plain, maxulp=8)
         else:
-            assert losses == base, f"remat={policy} diverged: " \
-                f"{losses} vs {base}"
+            assert losses.tolist() == remat.tolist(), \
+                f"remat={policy} diverged: {losses} vs {remat}"
 
 
 def test_per_layer_remat_bit_identical_losses():

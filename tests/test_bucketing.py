@@ -164,14 +164,20 @@ class TestMaskedLossPaddingInvariant:
         for i, n in enumerate(lens):
             label[i, n:] = -1
         # pad with GARBAGE logits and -1 labels: the loss may not see any
-        # of it, bit for bit
+        # of it. The two shapes are two programs, one reduction order
+        # each, so the sums are equal to float32 rounding and not to the
+        # bit (read on this JAX: 1 ulp)
         logits_p = np.concatenate(
             [logits, rng.randn(B, S2 - S, V).astype("float32")], axis=1)
         label_p = np.concatenate(
             [label, np.full((B, S2 - S), -1, "int32")], axis=1)
         a = np.asarray(f(logits, label))
         b = np.asarray(f(logits_p, label_p))
-        assert a.tobytes() == b.tobytes()
+        np.testing.assert_array_max_ulp(a, b, maxulp=4)
+        # and the garbage is not seen at all: other garbage, the same bits
+        logits_q = np.concatenate(
+            [logits, rng.randn(B, S2 - S, V).astype("float32")], axis=1)
+        assert np.asarray(f(logits_q, label_p)).tobytes() == b.tobytes()
 
     def test_trainstep_losses_bit_identical_padded_vs_unpadded(self):
         """End to end through TrainStep: the same sentences fed at their

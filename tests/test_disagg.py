@@ -39,8 +39,7 @@ from mxnet_tpu.base import MXNetError
 from mxnet_tpu.gluon.model_zoo.transformer import TransformerModel
 from mxnet_tpu.parallel import InferStep
 from mxnet_tpu.serving import (Backpressure, ContinuousBatcher,
-                               DeadlineExceeded, DynamicBatcher,
-                               PrefillEngine, RemoteReplica, Replica,
+                               DeadlineExceeded, PrefillEngine, RemoteReplica, Replica,
                                ReplicaUnavailable, Router, RpcClient,
                                disagg, faults)
 from mxnet_tpu.serving.disagg import (HandoffStash, load_spilled,
@@ -87,8 +86,10 @@ def decode_batcher():
 
 @pytest.fixture(scope="module")
 def shared_engine():
+    """One engine under the router tests' batchers, its paged programs
+    warm: one batcher built warm on it, and stopped."""
     eng = InferStep(_make_net(0), max_len=24)
-    eng.warmup([(2, 8)], max_new_tokens=4)
+    _batcher(eng, warmup=True).stop()
     return eng
 
 
@@ -100,10 +101,9 @@ def _clean_faults():
 
 
 def _batcher(engine, **kw):
-    cfg = dict(bucket_keys=(8,), slots=2, timeout_ms=5.0,
-               max_new_tokens=4)
+    cfg = dict(bucket_keys=(8,), slots=2, max_new_tokens=4)
     cfg.update(kw)
-    return DynamicBatcher(engine, **cfg)
+    return ContinuousBatcher(engine, **cfg)
 
 
 # ----------------------------------------------------------------- frames
@@ -275,9 +275,10 @@ class TestAdoption:
 
     def test_dynamic_batcher_ignores_frames(self, shared_engine,
                                             prefill_engine):
-        """The fixed batcher has no paged pool: frames are dropped and
-        the request decodes from its prompt — served either way."""
-        bat = _batcher(shared_engine, name="fixed-frames")
+        """Frames handed to a batcher that was never warmed (the
+        fixture's, not the decode worker's): the request serves the
+        tokens its prompt alone gives, adopted or re-prefilled."""
+        bat = _batcher(shared_engine, name="cold-frames")
         rng = np.random.RandomState(10)
         p = _prompts(rng, 1)[0]
         try:
@@ -536,7 +537,7 @@ class TestElasticity:
         assert sc.step() is None       # decided "down" but nothing
         assert sc.actions == []        # retirable: no action recorded
         with sc._lock:
-            assert sc._last_action_at == 0.0  # cooldown refunded
+            assert sc._last_action_at is None  # cooldown refunded
 
     def test_env_knobs_configure_defaults(self, monkeypatch):
         FleetScaler = _launch_mod().FleetScaler

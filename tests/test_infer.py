@@ -31,10 +31,10 @@ from mxnet_tpu.gluon.model_zoo.bert import BERTEncoderForGeneration, \
 from mxnet_tpu.gluon.model_zoo.transformer import TransformerModel
 from mxnet_tpu.gluon.nn import MultiHeadAttention
 from mxnet_tpu.parallel import InferStep
-from mxnet_tpu.serving import DynamicBatcher
 
 # float32-resolution tolerance for incremental-vs-full logits parity
 ATOL = 5e-6
+PREFILL_ATOL = 2e-6  # under ten times the 3.6e-07 read (see the test)
 RTOL = 1e-5
 
 
@@ -207,8 +207,15 @@ def _teacher_forced_parity(net, prefix_len=3, Ls=7, Lt=9, atol=ATOL):
     full = net(src, tgt, vl).asnumpy()
     logits, state = net.prefill(src, tgt[:, :prefix_len],
                                 src_valid_length=vl, max_len=24)
-    # prefill runs the IDENTICAL program shape per position => bitwise
-    np.testing.assert_array_equal(logits.asnumpy(), full[:, prefix_len - 1])
+    # two program shapes (a prefix of prefix_len against the full Lt),
+    # one reduction order each: equal to float32 rounding, not to the bit.
+    # Read on this JAX: 3.6e-07 absolute (3 ulp of logits of order 1) in
+    # 110 of 122 elements, for both nets
+    got = logits.asnumpy()
+    np.testing.assert_allclose(got, full[:, prefix_len - 1], rtol=0,
+                               atol=PREFILL_ATOL, err_msg="prefill")
+    assert (got.argmax(-1) == full[:, prefix_len - 1].argmax(-1)).all(), \
+        "greedy token flipped at the prefill"
     for p in range(prefix_len, Lt):
         tok = nd.array(tgt.asnumpy()[:, p], dtype="int32")
         logits, state = net.decode_step(tok, jnp.int32(p), state)
@@ -560,108 +567,6 @@ class TestSpeculativeDecode:
         assert not eng.has_draft
         with pytest.raises(MXNetError, match="attach_draft"):
             eng.spec_pair()
-
-
-# ------------------------------------------------------- DynamicBatcher
-class TestDynamicBatcher:
-    def _batcher(self, tmodel, **kw):
-        eng = InferStep(tmodel, max_len=24)
-        cfg = dict(bucket_keys=(8, 12), slots=2, timeout_ms=40.0,
-                   max_new_tokens=4)
-        cfg.update(kw)
-        return DynamicBatcher(eng, **cfg), eng
-
-    def test_full_batch_matches_direct_dispatch(self, tmodel):
-        """Two submits filling the batch == ONE hand-assembled
-        (slots, bucket) decode_n dispatch, row for row."""
-        rng = np.random.RandomState(10)
-        bat, eng = self._batcher(tmodel, timeout_ms=2000.0)
-        prompts = [rng.randint(3, 61, (n,)).astype(np.int32)
-                   for n in (5, 7)]
-        try:
-            futs = [bat.submit(p) for p in prompts]
-            got = [f.result(timeout=60) for f in futs]
-        finally:
-            bat.stop()
-        src = np.zeros((2, 8), np.int32)
-        vl = np.zeros((2,), np.int32)
-        for i, p in enumerate(prompts):
-            src[i, :p.shape[0]] = p
-            vl[i] = p.shape[0]
-        toks, lengths = eng.decode_n(src, vl, max_new_tokens=4)
-        toks, lengths = toks.asnumpy(), lengths.asnumpy()
-        for i in range(2):
-            np.testing.assert_array_equal(np.asarray(got[i]),
-                                          toks[i, :int(lengths[i])])
-
-    def test_timeout_dispatch_occupancy_and_queue_wait(self, tmodel):
-        """A lone request dispatches after the admission window with the
-        empty slots padded out; occupancy/queue-wait telemetry lands."""
-        mx.telemetry.reset()
-        mx.telemetry.enable()
-        bat, _ = self._batcher(tmodel, slots=4, timeout_ms=30.0)
-        try:
-            fut = bat.submit([5, 6, 7])
-            out = fut.result(timeout=60)
-            assert isinstance(out, list) and len(out) <= 4
-            assert fut.queue_wait_ms is not None
-            rep = mx.telemetry.report()
-            assert rep["infer_batch_occupancy"] == 0.25
-            assert rep["infer_requests"] == 1
-            assert rep["infer_queue_wait_ms_p50"] is not None
-        finally:
-            bat.stop()
-            mx.telemetry.reset()
-
-    def test_per_request_max_new_trim(self, tmodel):
-        """A request's own max_new_tokens (< the batcher's) trims its
-        result even though the batch decodes the full length."""
-        bat, _ = self._batcher(tmodel, timeout_ms=5.0)
-        try:
-            fut = bat.submit([7, 8, 9, 10], max_new_tokens=2)
-            assert len(fut.result(timeout=60)) <= 2
-        finally:
-            bat.stop()
-
-    def test_request_validation(self, tmodel):
-        bat, _ = self._batcher(tmodel, start=False)
-        with pytest.raises(MXNetError):
-            bat.submit(np.zeros((13,), np.int32))  # > largest bucket
-        with pytest.raises(MXNetError):
-            bat.submit([3, 4], max_new_tokens=99)  # > batcher max_new
-        with pytest.raises(MXNetError):
-            DynamicBatcher(object(), bucket_keys=(8,))  # no decode protocol
-        with pytest.raises(MXNetError):
-            DynamicBatcher(bat._engine, bucket_keys=())
-
-    def test_dispatch_error_fails_futures_not_thread(self, tmodel):
-        """An engine-side error resolves the futures with the exception;
-        the dispatcher thread survives for the next batch."""
-        eng = InferStep(tmodel, max_len=8)  # too small for max_new=20
-        bat = DynamicBatcher(eng, bucket_keys=(4,), slots=2,
-                             timeout_ms=5.0, max_new_tokens=20)
-        try:
-            fut = bat.submit([3, 4])
-            with pytest.raises(MXNetError):
-                fut.result(timeout=60)
-            assert isinstance(fut.exception(), MXNetError)
-            assert bat._thread.is_alive()
-        finally:
-            bat.stop()
-
-    def test_warmed_batcher_zero_steady_recompiles(self, tmodel):
-        """warmup=True compiles the whole (slots, bucket) menu up front;
-        serving traffic across both buckets then never compiles."""
-        bat, eng = self._batcher(tmodel, timeout_ms=5.0, warmup=True)
-        assert eng.compile_guard.steady
-        rng = np.random.RandomState(11)
-        try:
-            for n in (5, 10, 8, 12):  # both buckets, repeated
-                fut = bat.submit(rng.randint(3, 61, (n,)).astype(np.int32))
-                fut.result(timeout=60)
-        finally:
-            bat.stop()
-        assert eng.compile_guard.steady_state_recompiles == 0
 
 
 # ---------------------------------------- a paged dispatch is one enqueue

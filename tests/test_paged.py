@@ -36,8 +36,8 @@ from mxnet_tpu.gluon.model_zoo.transformer import TransformerModel
 from mxnet_tpu.gluon.nn import MultiHeadAttention
 from mxnet_tpu.parallel import InferStep
 from mxnet_tpu.serving import (Backpressure, ContinuousBatcher,
-                               DeadlineExceeded, DynamicBatcher, PagePool,
-                               Replica, Router, faults, make_batcher)
+                               DeadlineExceeded, PagePool, Replica, Router,
+                               faults, make_batcher)
 from mxnet_tpu.serving import pages as pages_mod
 
 
@@ -169,15 +169,21 @@ class TestPagedParity:
         np.testing.assert_array_equal(before_k, np.asarray(kp2[1:]))
         assert np.abs(np.asarray(kp2[0])).sum() > 0  # trash took the write
 
-    def test_continuous_greedy_bitwise_vs_decode_n(self, tmodel):
+    @pytest.mark.parametrize("seed,vl,T", [
+        (3, (4, 7, 8), 6),   # more requests than slots: a slot is reused
+        (10, (5, 7), 4),     # two submits fill the batch: one dispatch
+    ], ids=["slot_reuse", "full_batch"])
+    def test_continuous_greedy_bitwise_vs_decode_n(self, tmodel, seed, vl,
+                                                   T):
         """End to end: every request's greedy tokens through the paged
-        scheduler == the PR-5 dense engine, per request (single-bucket
-        menu => identical program shapes => bitwise logits)."""
+        scheduler == ONE hand-assembled (batch, bucket) dispatch of the
+        dense engine, row for row (single-bucket menu => identical
+        program shapes => bitwise logits)."""
         eng = InferStep(tmodel, max_len=24)
-        rng = np.random.RandomState(3)
-        B, Ls, T = 3, 8, 6
+        rng = np.random.RandomState(seed)
+        B, Ls = len(vl), 8
         src = rng.randint(3, 61, (B, Ls)).astype(np.int32)
-        vl = np.array([4, 7, 8], np.int32)
+        vl = np.array(vl, np.int32)
         toks_d, lens_d = eng.decode_n(src, vl, max_new_tokens=T)
         toks_d, lens_d = toks_d.asnumpy(), lens_d.asnumpy()
         bat = ContinuousBatcher(eng, bucket_keys=(Ls,), slots=2,
@@ -350,6 +356,43 @@ class TestContinuousBatcher:
         with pytest.raises(MXNetError):
             ContinuousBatcher(InferStep(bert), bucket_keys=(8,))
 
+    def test_request_validation(self, tmodel):
+        bat, eng = self._batcher(tmodel, bucket_keys=(8, 12), warmup=False,
+                                 start=False)
+        with pytest.raises(MXNetError):
+            bat.submit(np.zeros((13,), np.int32))  # > largest bucket
+        with pytest.raises(MXNetError):
+            bat.submit([3, 4], max_new_tokens=99)  # > batcher max_new
+        with pytest.raises(MXNetError, match="paged protocol"):
+            ContinuousBatcher(object(), bucket_keys=(8,))
+        with pytest.raises(MXNetError):
+            ContinuousBatcher(eng, bucket_keys=())
+
+    def test_per_request_max_new_trim(self, tmodel):
+        """A request's own max_new_tokens (< the batcher's) trims its
+        result: the row retires at its own cap."""
+        bat, _ = self._batcher(tmodel)
+        try:
+            fut = bat.submit([7, 8, 9, 10], max_new_tokens=2)
+            assert len(fut.result(timeout=60)) <= 2
+        finally:
+            bat.stop()
+        assert bat.pool.free_pages == bat.pool.num_pages
+
+    def test_warmed_batcher_zero_steady_recompiles(self, tmodel):
+        """warmup=True compiles the whole (rows, bucket) menu up front;
+        serving traffic across both buckets then never compiles."""
+        bat, eng = self._batcher(tmodel, bucket_keys=(8, 12))
+        assert eng.compile_guard.steady
+        rng = np.random.RandomState(11)
+        try:
+            for n in (5, 10, 8, 12):  # both buckets, repeated
+                fut = bat.submit(rng.randint(3, 61, (n,)).astype(np.int32))
+                fut.result(timeout=60)
+        finally:
+            bat.stop()
+        assert eng.compile_guard.steady_state_recompiles == 0
+
     def test_pool_too_small_for_one_request_raises(self, tmodel):
         eng = InferStep(tmodel, max_len=24)
         with pytest.raises(MXNetError, match="pages"):
@@ -462,12 +505,15 @@ class TestContinuousBatcher:
         assert "not accepting" in str(fut.exception())
         assert bat.pool.free_pages == bat.pool.num_pages
 
-    def test_dispatch_error_fails_slots_not_thread(self, tmodel):
-        """An engine error mid-iteration fails the in-flight futures,
-        rebuilds the pools, and the scheduler keeps serving."""
+    @pytest.mark.parametrize("after", [1, 0],
+                             ids=["decode_burst", "admission_prefill"])
+    def test_dispatch_error_fails_slots_not_thread(self, tmodel, after):
+        """An engine error (mid-iteration, or in the admission prefill)
+        resolves the in-flight futures with the exception and rebuilds
+        the pools; the scheduler thread survives and keeps serving."""
         bat, _ = self._batcher(tmodel)
         try:
-            faults.inject("batcher.dispatch", times=1, after=1)
+            faults.inject("batcher.dispatch", times=1, after=after)
             fut = bat.submit([3, 4, 5], max_new_tokens=6)
             with pytest.raises(faults.FaultInjected):
                 fut.result(timeout=60)
@@ -513,24 +559,27 @@ class TestContinuousBatcher:
 
 # ------------------------------------------------------- API routing
 class TestRouting:
-    def test_make_batcher_default_and_fixed(self, tmodel, monkeypatch):
+    def test_make_batcher_default_and_refusals(self, tmodel):
+        """The default is ``ContinuousBatcher`` with every keyword handed
+        on; a net without the paged protocol is refused by name, and so
+        is a keyword the scheduler does not take (nothing is dropped)."""
         eng = InferStep(tmodel, max_len=24)
         bat = make_batcher(eng, bucket_keys=(8,), slots=2,
-                           max_new_tokens=4, start=False)
-        assert isinstance(bat, ContinuousBatcher)
-        monkeypatch.setenv("MXTPU_BATCHER", "fixed")
-        bat2 = make_batcher(eng, bucket_keys=(8,), slots=2,
-                            max_new_tokens=4, start=False)
-        assert type(bat2) is DynamicBatcher
+                           max_new_tokens=4, iter_tokens=3, start=False)
+        assert type(bat) is ContinuousBatcher and bat.iter_tokens == 3
+        with pytest.raises(MXNetError, match="paged protocol"):
+            make_batcher(object(), bucket_keys=(8,))
+        with pytest.raises(TypeError):
+            make_batcher(eng, bucket_keys=(8,), timeout_ms=5.0, start=False)
 
-    def test_generate_routes_through_continuous(self, tmodel, monkeypatch):
+    def test_generate_routes_through_continuous(self, tmodel):
         src = np.random.RandomState(2).randint(3, 61, (2, 7)) \
             .astype(np.int32)
         toks_c, lens_c = tmodel.generate(src, max_new_tokens=4, max_len=24)
         assert getattr(tmodel, "_batchers", None), \
             "greedy generate must route through the ContinuousBatcher"
-        monkeypatch.setenv("MXTPU_BATCHER", "fixed")
-        toks_d, lens_d = tmodel.generate(src, max_new_tokens=4, max_len=24)
+        toks_d, lens_d = InferStep(tmodel, max_len=24).generate(
+            src, max_new_tokens=4)
         np.testing.assert_array_equal(toks_c.asnumpy(), toks_d.asnumpy())
         np.testing.assert_array_equal(lens_c.asnumpy(), lens_d.asnumpy())
 
@@ -674,21 +723,3 @@ class TestPagedResilience:
         versions = {f.weights_version for f in futs}
         assert "v-next" in versions and len(versions) >= 2
         assert bat.pool.free_pages == bat.pool.num_pages
-
-
-# ------------------------------------------------------------ no regress
-def test_dynamic_batcher_still_fixed_path(tmodel):
-    """The fallback engine path survives the base-class refactor: same
-    construction surface, same whole-batch semantics."""
-    eng = InferStep(tmodel, max_len=24)
-    bat = DynamicBatcher(eng, bucket_keys=(8, 12), slots=2,
-                         timeout_ms=40.0, max_new_tokens=4)
-    try:
-        fut = bat.submit([7, 8, 9, 10], max_new_tokens=2)
-        out = fut.result(timeout=60)
-        assert len(out) <= 2
-        if out:
-            # streaming degenerates to one final chunk
-            assert list(fut.tokens_iter(timeout=10)) == [out]
-    finally:
-        bat.stop()

@@ -2,7 +2,7 @@
 
 Contracts under test (ISSUE 7 tentpole + ISSUE 10 cross-process plane):
 
-- a hot weight swap under sustained ``DynamicBatcher`` load loses ZERO
+- a hot weight swap under sustained ``ContinuousBatcher`` load loses ZERO
   requests, responses carry the ``weights_version`` their dispatch
   actually served, and post-swap greedy outputs are BIT-identical to a
   fresh engine built from the same checkpoint;
@@ -25,6 +25,7 @@ Contracts under test (ISSUE 7 tentpole + ISSUE 10 cross-process plane):
 
 import json
 import os
+import sys
 import threading
 import time
 
@@ -38,7 +39,7 @@ from mxnet_tpu.base import MXNetError
 from mxnet_tpu.gluon.model_zoo.transformer import TransformerModel
 from mxnet_tpu.parallel import InferStep
 from mxnet_tpu.serving import (Backpressure, CheckpointWatcher,
-                               DeadlineExceeded, DynamicBatcher,
+                               ContinuousBatcher, DeadlineExceeded,
                                RemoteReplica, Replica, ReplicaUnavailable,
                                Router, RpcClient, RpcServer,
                                TransportError, faults)
@@ -77,9 +78,11 @@ def net_b():
 @pytest.fixture(scope="module")
 def shared_engine(net_a):
     """One warmed engine reused by the batcher/router tests (router
-    replicas may share an engine — two batchers, one param set)."""
+    replicas may share an engine — two batchers, one param set). Warm are
+    the paged programs every ``_batcher`` over it dispatches: one batcher
+    built warm on the engine, and stopped."""
     eng = InferStep(net_a, max_len=24)
-    eng.warmup([(2, 8)], max_new_tokens=4)
+    _batcher(eng, warmup=True).stop()
     return eng
 
 
@@ -91,10 +94,9 @@ def _clean_faults():
 
 
 def _batcher(engine, **kw):
-    cfg = dict(bucket_keys=(8,), slots=2, timeout_ms=5.0,
-               max_new_tokens=4)
+    cfg = dict(bucket_keys=(8,), slots=2, max_new_tokens=4)
     cfg.update(kw)
-    return DynamicBatcher(engine, **cfg)
+    return ContinuousBatcher(engine, **cfg)
 
 
 def _prompts(rng, n, lo=3, hi=61, lmin=3, lmax=8):
@@ -249,35 +251,107 @@ class TestBatcherHealth:
 
     def test_stop_fails_undrained_queue(self, shared_engine):
         """stop(drain=False) with work still queued (here: stuck behind
-        a hung dispatch) fails those futures instead of leaking them."""
+        a hung dispatch) fails those futures instead of leaking them, and
+        the request whose slot the hung dispatch held with them: nothing
+        is left unresolved and every page is back in the pool."""
         faults.inject("batcher.hang", times=1, delay=0.3,
                       match="undrained")
         bat = _batcher(shared_engine, name="undrained")
-        blocker = bat.submit([9, 10])  # dispatched, hangs 300 ms
+        blocker = bat.submit([9, 10])  # in a slot; its burst hangs 300 ms
         time.sleep(0.05)
         queued = bat.submit([3, 4, 5])
         assert not queued.done()
         bat.stop(drain=False)
-        assert isinstance(blocker.result(timeout=60), list)
-        assert queued.done()
+        assert queued.done() and blocker.done()
         with pytest.raises(RuntimeError, match="queued"):
             queued.result(timeout=0)
+        with pytest.raises(RuntimeError, match="in flight"):
+            blocker.result(timeout=0)
+        assert bat.pool.free_pages == bat.pool.num_pages
 
     def test_thread_death_fails_queued_futures(self, shared_engine):
-        """A crashing dispatcher fails what it held queued — no future
-        is ever left unresolvable."""
+        """A crashing dispatcher fails what it held queued and what its
+        slots held — no future is ever left unresolvable."""
         faults.inject("batcher.hang", times=1, delay=0.3,
                       match="dying-replica")
         faults.inject("batcher.thread", times=1, after=1,
                       match="dying-replica")
-        bat = _batcher(shared_engine, name="dying-replica",
-                       timeout_ms=1.0)
-        fut = bat.submit([3, 4])  # dispatched, hangs 300 ms
+        bat = _batcher(shared_engine, name="dying-replica")
+        fut = bat.submit([3, 4])  # in a slot; its burst hangs 300 ms
         time.sleep(0.1)
         fut2 = bat.submit([5, 6])  # queued; the thread dies next pass
-        assert isinstance(fut.result(timeout=60), list)
-        with pytest.raises(RuntimeError):
-            fut2.result(timeout=60)
+        for f in (fut, fut2):
+            with pytest.raises(RuntimeError, match="thread died"):
+                f.result(timeout=60)
+        assert not bat.healthy
+
+    def test_drain_waits_for_a_request_inside_its_admission_prefill(
+            self, shared_engine):
+        """stop(drain=True) while ``_admit`` holds a request in neither
+        the waiting line nor a slot (here: a slow admission prefill) waits
+        for it: the request is served, not failed as in flight."""
+        faults.inject("batcher.dispatch", times=1, delay=0.3,
+                      match="drain-admit")
+        bat = _batcher(shared_engine, name="drain-admit")
+        fut = bat.submit([3, 4, 5])
+        deadline = time.perf_counter() + 10
+        while (not bat._queue.empty() or bat._pending) \
+                and time.perf_counter() < deadline:
+            time.sleep(0.001)
+        # taken off the line, not yet in a slot: the prefill holds it
+        assert not any(bat._slots) and not fut.done()
+        assert not bat._drained()
+        bat.stop(drain=True)
+        assert isinstance(fut.result(timeout=0), list)
+        assert bat.pool.free_pages == bat.pool.num_pages
+
+    def test_drained_is_never_true_over_an_unresolved_request(
+            self, shared_engine):
+        """Stress: submitters on more threads than cores against a reader
+        of ``_drained()``. Whenever it reads true, every request whose
+        ``submit`` had returned before the read is resolved: a request on
+        its way from the queue to a slot reads as not drained."""
+        # every dispatch 2 ms slow: an admission prefill holds its
+        # requests long enough for the reader to look
+        faults.inject("batcher.dispatch", times=None, delay=0.002,
+                      match="drain-stress")
+        bat = _batcher(shared_engine, name="drain-stress")
+        futs, stop, broken = [], threading.Event(), []
+
+        def submitter(seed):
+            rng = np.random.RandomState(seed)
+            while not stop.is_set():
+                futs.append(bat.submit(_prompts(rng, 1)[0]))
+                time.sleep(rng.uniform(0.02, 0.08))
+
+        def reader():
+            while not stop.is_set():
+                n = len(futs)
+                if bat._drained() and not all(f.done() for f in futs[:n]):
+                    broken.append(n)
+
+        threads = [threading.Thread(target=submitter, args=(i,),
+                                    daemon=True)
+                   for i in range((os.cpu_count() or 4) + 2)]
+        threads.append(threading.Thread(target=reader, daemon=True))
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for t in threads:
+                t.start()
+            time.sleep(1.5)
+            stop.set()
+            for t in threads:
+                t.join(timeout=30)
+            assert not any(t.is_alive() for t in threads)
+        finally:
+            sys.setswitchinterval(interval)
+            stop.set()
+            bat.stop()
+        assert futs and not broken, \
+            f"_drained() read true over unresolved requests at {broken[:5]}"
+        assert all(f.done() for f in futs)
+        assert bat.pool.free_pages == bat.pool.num_pages
 
 
 # ------------------------------------------------------------- deadlines
@@ -290,8 +364,7 @@ class TestDeadlines:
         mx.telemetry.reset()
         faults.inject("batcher.hang", times=1, delay=0.2,
                       match="dl-replica")
-        bat = _batcher(shared_engine, slots=2, timeout_ms=5.0,
-                       name="dl-replica")
+        bat = _batcher(shared_engine, slots=2, name="dl-replica")
         try:
             blocker = bat.submit([9, 10])  # dispatched, hangs 200 ms
             time.sleep(0.05)  # blocker is in its (hung) dispatch alone
@@ -394,23 +467,39 @@ class TestHotWeightSwap:
         assert eng.compile_guard.steady_state_recompiles == 0
 
     def test_swap_under_load_loses_nothing(self, net_b, tmp_path):
-        """Acceptance: a swap mid-stream resolves every future, tags the
-        responses with the version that served them, and never
-        recompiles."""
+        """Acceptance: a swap mid-stream resolves every future, tags
+        each response with the version of its FINAL iteration, and never
+        recompiles. Tags are monotonic in the order the requests retire
+        (not in the order they were submitted: a slot freed early goes to
+        a later request). No token is the end of sequence here, so every
+        request's final iteration is a decode burst, and the requests of
+        one retire pass carry one tag."""
         net = _make_net(7)
-        eng = InferStep(net, max_len=24)
-        eng.warmup([(2, 8)], max_new_tokens=4)
+        eng = InferStep(net, max_len=24, eos_id=-1)
         _save_params(str(tmp_path / "step_9"), net_b)
         watcher = CheckpointWatcher(eng, str(tmp_path), start=False)
-        bat = _batcher(eng, warmup=False)
+        bat = _batcher(eng, warmup=True)
         rng = np.random.RandomState(11)
-        futs = []
+        futs, retired = [], []
+
+        def note_retired():
+            retired.extend(f for f in futs
+                           if f.done() and f not in retired)
+
         try:
             for i, p in enumerate(_prompts(rng, 30)):
                 futs.append(bat.submit(p))
                 if i == 12:
+                    futs[0].result(timeout=120)  # v0 has served some
+                    note_retired()
                     assert watcher.poll_once() is not None
+                note_retired()
                 time.sleep(0.002)
+            deadline = time.perf_counter() + 120
+            while len(retired) < len(futs) \
+                    and time.perf_counter() < deadline:
+                note_retired()
+                time.sleep(0.001)
             results = [f.result(timeout=120) for f in futs]
         finally:
             bat.stop()
@@ -419,8 +508,9 @@ class TestHotWeightSwap:
         assert "v0" in versions and len(versions) == 2, versions
         # version tags are MONOTONIC: once the swap lands, no later
         # dispatch serves the old weights
+        assert len(retired) == len(futs)
         seen_new = False
-        for f in futs:
+        for f in retired:
             if f.weights_version != "v0":
                 seen_new = True
             else:
@@ -1278,8 +1368,8 @@ def test_chaos_smoke_swap_and_failover_end_to_end(tmp_path, monkeypatch,
 
     net = _make_net(21)
     eng = InferStep(net, max_len=24)
-    eng.warmup([(2, 8)], max_new_tokens=4)
-    reps = [Replica("r1", _batcher(eng, name="r1")),
+    # r1 warms the paged programs both replicas dispatch
+    reps = [Replica("r1", _batcher(eng, name="r1", warmup=True)),
             Replica("r2", _batcher(eng, name="r2"))]
     router = Router(reps, retry_backoff_s=0.01, health_interval_s=0.02)
     _save_params(str(tmp_path / "step_1"), net_b)
@@ -1291,6 +1381,7 @@ def test_chaos_smoke_swap_and_failover_end_to_end(tmp_path, monkeypatch,
         for i, p in enumerate(_prompts(rng, 24)):
             futs.append(router.submit(p))
             if i == 10:
+                futs[0].result(timeout=120)  # v0 has served some
                 assert watcher.poll_once() is not None
             time.sleep(0.002)
         results = [f.result(timeout=120) for f in futs]

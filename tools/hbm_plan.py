@@ -10,11 +10,11 @@ nothing is materialized and no step runs. The budget is the device HBM
 limit (or ``--hbm-bytes`` / ``MXTPU_HBM_BYTES`` on rigs without memory
 stats) shaved by ``MXTPU_HBM_HEADROOM``.
 
-The demo model is the bench transformer (size it with ``--units``/
-``--layers``/``--vocab``); ``--amp``/``--remat`` show how mixed
-precision and rematerialization move the fitting batch — the numbers
-``benchmarks/bench_transformer --amp --remat --auto-batch`` then turns
-into a throughput win.
+The demo model is a small ``TransformerModel`` (size it with
+``--units``/``--layers``/``--vocab``); ``--amp``/``--remat`` show how
+mixed precision and rematerialization move the fitting batch. What a
+fitting batch is worth in throughput is measured by a cell
+(``perf/run.py``), not here.
 
 Example (CPU rig, synthetic 2 GB budget)::
 

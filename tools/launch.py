@@ -284,7 +284,7 @@ class FleetScaler:
         self._hot = 0           # consecutive high-pressure samples
         self._cold = 0          # consecutive idle samples
         self._last_shed = None  # previous cumulative shed count
-        self._last_action_at = 0.0
+        self._last_action_at = None  # monotonic instant; None = never
         self.actions: list = []  # ("up"/"down", monotonic instant)
         self._stop_evt = threading.Event()
         self._thread = None
@@ -333,7 +333,8 @@ class FleetScaler:
         cold = occ <= self.low and shed_delta == 0 and not wait_hot
         self._hot = self._hot + 1 if hot else 0
         self._cold = self._cold + 1 if cold else 0
-        if now - self._last_action_at < self.cooldown_s:
+        if self._last_action_at is not None \
+                and now - self._last_action_at < self.cooldown_s:
             return None
         if self._hot >= self.sustain and size < self.max_workers:
             self._hot = 0
@@ -364,7 +365,7 @@ class FleetScaler:
                 with self._lock:
                     # nothing retirable: undo the action record, spend
                     # no cooldown
-                    self._last_action_at = 0.0
+                    self._last_action_at = None
                     self.actions.pop()
                 return None
             self._count("serve/scale_down", sample)
