@@ -30,9 +30,12 @@ One kernel, two entry points:
   of cached positions (learned sparse attention), for grouped-query
   heads: ``Hq`` query heads over ``Hkv`` key/value heads. A mask ``(B, C,
   L)`` says which cached positions each query reads; it rides the grid
-  in blocks beside the pages. Grid ``(B, query blocks, pages)``; pages
-  past the last one a query block can see are neither fetched nor
-  computed.
+  in blocks beside the pages. Grid ``(B, query blocks, key blocks)``: a
+  step takes a block of several pages (1,024 keys), relays it head-major
+  once, gives each key/value head one product with all the query rows
+  that read it and updates the softmax carry once; blocks past the last
+  one a query block can see are neither fetched nor computed. The pools'
+  layout in HBM is the serving stack's, as for the other two.
 
 All keep ``MXTPU_FLASH_INTERPRET`` (force/forbid/auto, shared with
 ``flash_attention.py``) and ship a dense jnp reference
@@ -48,6 +51,7 @@ which is slower than the dense path it replaces).
 from __future__ import annotations
 
 import functools
+import math
 import os as _os
 
 import jax
@@ -208,95 +212,158 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, pos, *,
 
 
 # ------------------------------------------------ selected window (GQA)
-def _selected_window_kernel(pt_ref, off_ref, q_ref, k_ref, v_ref, mask_ref,
-                            o_ref, m_ref, l_ref, acc_ref, *, page_size,
-                            sm_scale, tq, groups):
-    """Grid (B, query blocks, pages), pages sequential: one pool page per
-    step, online-softmax carry in VMEM scratch. The query block holds, for
-    each key/value head, its ``groups`` query heads' ``tq`` queries in
-    turn (rows ``g * tq + t``); the mask block ``(tq, page)`` is the same
-    for every head."""
-    b, i, p = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+# keys a grid step of the selected window takes. The softmax carry (a
+# cross-lane max a row, ``alpha``, the rescaled accumulator) is paid once
+# a block: on a v5e the last chunk of a 16k prompt takes 5.6 ms at 512
+# keys a step, 4.9 at 1,024 and 5.4 at 1,280 (PERF.md, PR 30)
+_WINDOW_KEYS = 1024
+_WINDOW_VMEM_LIMIT = 48 * 1024 * 1024
 
-    @pl.when(p == 0)
+
+def _selected_window_tiles(C, P, page_size):
+    """``(queries a block, pages a block)`` of the selected window, from
+    the shapes alone: 256 queries where they divide the chunk (each
+    key/value head then meets its ``G * 256`` query rows in one product),
+    and as many whole pages as make ``_WINDOW_KEYS`` keys."""
+    tq = next((t for t in (256, 128) if C % t == 0), C)
+    return tq, max(1, min(P, _WINDOW_KEYS // page_size))
+
+
+def _selected_window_vmem_bytes(tq, pages, page_size, Hkv, G, D, itemsize):
+    """VMEM a grid step of the selected window holds: the pipeline's two
+    buffers of every block, the scratch, and the block of scores with its
+    exponentials (float32) and their cast for the second product."""
+    rows, kb = G * tq, pages * page_size
+    blocks = 2 * (2 * Hkv * rows * D * itemsize       # queries, output
+                  + 2 * kb * Hkv * D * itemsize       # K and V pages
+                  + tq * kb)                          # the mask, int8
+    scratch = Hkv * rows * (2 * _LANES + D) * 4 + 2 * Hkv * kb * D * itemsize
+    live = rows * kb * (4 + 4 + itemsize) + tq * kb * 4
+    return blocks + scratch + live
+
+
+def _selected_window_kernel(pt_ref, off_ref, q_ref, *refs, page_size, pages,
+                            length, sm_scale, tq, groups):
+    """Grid (B, query blocks, key blocks), key blocks sequential: ``pages``
+    pool pages a step (each its own operand, so the pipeline copies them
+    through the page table), online-softmax carry in VMEM scratch. The
+    pages are relaid head-major ONCE a block; then every key/value head
+    meets the ``groups * tq`` query rows that read it (rows ``g * tq +
+    t``) in one product, and the carry is updated once. The mask block
+    ``(tq, keys)`` is the same for every head; past ``length`` (the last
+    block may be partial) it is never trusted."""
+    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
+    mask_ref, o_ref, m_ref, l_ref, acc_ref, kt_ref, vt_ref = refs[2 * pages:]
+    b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    kb = pages * page_size
+    lw = l_ref.shape[-1]
+
+    @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # the block's LAST query bounds what any of its queries can see
-    @pl.when(p * page_size <= off_ref[b] + (i + 1) * tq - 1)
+    @pl.when(j * kb <= off_ref[b] + (i + 1) * tq - 1)
     def _accumulate():
-        k = k_ref[0]                               # (ps, Hkv, D)
-        v = v_ref[0]
-        keep = mask_ref[0] != 0                    # (tq, ps)
+        def head_major(page_refs):     # (keys, Hkv, D) to (Hkv, keys, D)
+            return jnp.swapaxes(
+                jnp.concatenate([r[0] for r in page_refs], axis=0), 0, 1)
+
+        kt_ref[...] = head_major(k_refs)
+        vt_ref[...] = head_major(v_refs)
+        keep = mask_ref[0].astype(jnp.int32)       # (tq, keys)
+        if length % kb:
+            pos = j * kb + jax.lax.broadcasted_iota(jnp.int32, (tq, kb), 1)
+            keep = jnp.where(pos < length, keep, 0)
+        # -inf under a running max that starts finite: an unselected key
+        # weighs exp(-inf) = 0 whatever the row has seen, no second select
+        bias = jnp.where(keep != 0, 0.0, -jnp.inf).astype(jnp.float32)
         # a process-wide "highest" precision is not one Mosaic takes for
         # bfloat16 operands
-        prec = jax.lax.Precision.DEFAULT if k.dtype == jnp.bfloat16 else None
-        for g in range(groups):
-            rows = slice(g * tq, (g + 1) * tq)
-            s = jax.lax.dot_general(
-                q_ref[0, 0, :, rows, :], k, (((2,), (2,)), ((0,), (1,))),
-                preferred_element_type=jnp.float32,
-                precision=prec) * sm_scale         # (Hkv, tq, ps)
-            s = jnp.where(keep[None], s, _NEG_INF)
-            m_prev = m_ref[:, rows, :]             # (Hkv, tq, LANES)
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p_act = jnp.where(keep[None], jnp.exp(s - m_new[:, :, :1]), 0.0)
-            l_ref[:, rows, :] = alpha * l_ref[:, rows, :] \
-                + jnp.sum(p_act, axis=2, keepdims=True)
-            acc_ref[:, rows, :] = acc_ref[:, rows, :] * alpha[:, :, :1] \
-                + jax.lax.dot_general(
-                    p_act.astype(v.dtype), v,
-                    (((2,), (0,)), ((0,), (1,))),
-                    preferred_element_type=jnp.float32,
-                    precision=prec)                # (Hkv, tq, D)
-            m_ref[:, rows, :] = m_new
+        prec = (jax.lax.Precision.DEFAULT if kt_ref.dtype == jnp.bfloat16
+                else None)
 
-    @pl.when(p == pl.num_programs(2) - 1)
+        def head(h, carry):
+            s = jax.lax.dot_general(
+                q_ref[0, 0, h], kt_ref[h], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=prec)                    # (groups * tq, keys)
+            s = (s.reshape(groups, tq, kb) * sm_scale + bias[None]) \
+                .reshape(groups * tq, kb)
+            m_prev = m_ref[h]                      # (groups * tq, LANES)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p_act = jnp.exp(s - m_new[:, :1])
+            # the denominator stays a sum a lane until the last block:
+            # adding lane groups is elementwise, a sum a row is not
+            l_new = alpha[:, :lw] * l_ref[h]
+            for c in range(kb // lw):
+                l_new = l_new + p_act[:, c * lw:(c + 1) * lw]
+            l_ref[h] = l_new
+            acc_ref[h] = acc_ref[h] * alpha[:, :1] + jax.lax.dot_general(
+                p_act.astype(vt_ref.dtype), vt_ref[h],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=prec)                    # (groups * tq, D)
+            m_ref[h] = m_new
+            return carry
+
+        # a loop, not an unrolled body: 8 % slower on a v5e and a third
+        # of the compile time (PERF.md, PR 30)
+        jax.lax.fori_loop(0, kt_ref.shape[0], head, 0)
+
+    @pl.when(j == pl.num_programs(2) - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[:, :, :1], 1e-30)
+        l = jnp.maximum(jnp.sum(l_ref[...], axis=2, keepdims=True), 1e-30)
         o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("sm_scale", "tq"))
+@functools.partial(jax.jit, static_argnames=("sm_scale", "tq", "pages"))
 def _dsa_selected_window_impl(q, k_pool, v_pool, page_table, q_offset,
-                              mask, sm_scale, tq):
+                              mask, sm_scale, tq, pages):
     B, C, Hq, D = q.shape
     ps, Hkv = k_pool.shape[1], k_pool.shape[2]
-    P = page_table.shape[1]
+    P, L = page_table.shape[1], mask.shape[2]
     G, nq = Hq // Hkv, C // tq
+    kb, nkb = pages * ps, pl.cdiv(P, pages)
     # (B, nq, Hkv, G * tq, D): a block's rows are (query head of the
     # group, query) for each key/value head
     qb = q.reshape(B, nq, tq, Hkv, G, D).transpose(0, 1, 3, 4, 2, 5) \
         .reshape(B, nq, Hkv, G * tq, D)
 
-    def page(b, i, p, pt, off):     # an unseen page re-reads nothing
-        return jnp.minimum(p, jnp.minimum(
-            (off[b] + (i + 1) * tq - 1) // ps, P - 1))
+    def last_seen(b, i, off):       # the block's last query's position
+        return off[b] + (i + 1) * tq - 1
 
+    def page(t):                    # an unseen page re-reads nothing
+        return lambda b, i, j, pt, off: (pt[b, jnp.minimum(
+            j * pages + t, jnp.minimum(last_seen(b, i, off) // ps, P - 1))],
+            0, 0, 0)
+
+    def mask_block(b, i, j, pt, off):
+        return (b, i, jnp.minimum(
+            j, jnp.minimum(last_seen(b, i, off) // kb, nkb - 1)))
+
+    pool_specs = [pl.BlockSpec((1, ps, Hkv, D), page(t))
+                  for t in range(pages)]
+    rows = pl.BlockSpec((1, 1, Hkv, G * tq, D),
+                        lambda b, i, j, pt, off: (b, i, 0, 0, 0))
     kernel = functools.partial(_selected_window_kernel, page_size=ps,
-                               sm_scale=sm_scale, tq=tq, groups=G)
+                               pages=pages, length=L, sm_scale=sm_scale,
+                               tq=tq, groups=G)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, nq, P),
-        in_specs=[
-            pl.BlockSpec((1, 1, Hkv, G * tq, D),
-                         lambda b, i, p, pt, off: (b, i, 0, 0, 0)),
-            pl.BlockSpec((1, ps, Hkv, D), lambda b, i, p, pt, off:
-                         (pt[b, page(b, i, p, pt, off)], 0, 0, 0)),
-            pl.BlockSpec((1, ps, Hkv, D), lambda b, i, p, pt, off:
-                         (pt[b, page(b, i, p, pt, off)], 0, 0, 0)),
-            pl.BlockSpec((1, tq, ps), lambda b, i, p, pt, off:
-                         (b, i, page(b, i, p, pt, off))),
-        ],
-        out_specs=pl.BlockSpec((1, 1, Hkv, G * tq, D),
-                               lambda b, i, p, pt, off: (b, i, 0, 0, 0)),
+        grid=(B, nq, nkb),
+        in_specs=[rows] + pool_specs + pool_specs
+        + [pl.BlockSpec((1, tq, kb), mask_block)],
+        out_specs=rows,
         scratch_shapes=[
             pltpu.VMEM((Hkv, G * tq, _LANES), jnp.float32),
-            pltpu.VMEM((Hkv, G * tq, _LANES), jnp.float32),
+            pltpu.VMEM((Hkv, G * tq, math.gcd(kb, _LANES)), jnp.float32),
             pltpu.VMEM((Hkv, G * tq, D), jnp.float32),
+            pltpu.VMEM((Hkv, kb, D), k_pool.dtype),
+            pltpu.VMEM((Hkv, kb, D), v_pool.dtype),
         ],
     )
     out = pl.pallas_call(
@@ -305,11 +372,11 @@ def _dsa_selected_window_impl(q, k_pool, v_pool, page_table, q_offset,
         out_shape=jax.ShapeDtypeStruct((B, nq, Hkv, G * tq, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=48 * 1024 * 1024),
+            vmem_limit_bytes=_WINDOW_VMEM_LIMIT),
         interpret=_use_interpret(),
         name="dsa_selected_window",
     )(page_table.astype(jnp.int32), q_offset.astype(jnp.int32), qb,
-      k_pool, v_pool, mask.astype(jnp.int8))
+      *([k_pool] * pages), *([v_pool] * pages), mask.astype(jnp.int8))
     return out.reshape(B, nq, Hkv, G, tq, D).transpose(0, 1, 4, 2, 3, 5) \
         .reshape(B, C, Hq * D)
 
@@ -323,13 +390,14 @@ def paged_selected_window_attention(q, k_pool, v_pool, page_table,
     L)`` true where query ``c`` of row ``b`` reads cached position ``l``
     (the caller keeps it causal: nothing past ``q_offset[b] + c``).
     Returns ``(B, C, Hq * D)``."""
-    C = q.shape[1]
-    # queries a block: 256 reads a chunk's last 2,048 queries over 16k keys
-    # in 13.3 ms on a v5e where 128 takes 19.1 (PERF.md, PR 27)
-    tq = next((t for t in (256, 128) if C % t == 0), C)
+    # 256 queries and 8 pages of 128 a block read a chunk's last 2,048
+    # queries over 16k keys in 4.9 ms on a v5e; a page a step, relaid for
+    # every query head, took 13.2 (PERF.md, PR 30)
+    tq, pages = _selected_window_tiles(q.shape[1], page_table.shape[1],
+                                       k_pool.shape[1])
     return _dsa_selected_window_impl(q, k_pool, v_pool, page_table,
                                      q_offset, mask, sm_scale=sm_scale,
-                                     tq=tq)
+                                     tq=tq, pages=pages)
 
 
 def paged_selected_window_reference(q, k_pool, v_pool, page_table,

@@ -15,7 +15,9 @@ Two shapes, as the serving plane has them:
   scores and attention walk the cached keys in blocks and stop at the last
   block any query can see; the selection is a mask, and attention is a
   flash pass over the pages masked to it (a Pallas kernel on the TPU,
-  ``ops/pallas/paged_flash_attention.paged_selected_window_attention``).
+  ``ops/pallas/paged_flash_attention.paged_selected_window_attention``:
+  dense and masked, 256 queries by 1,024 keys a step, every key/value
+  head against all its query heads in one product).
 - DECODE: one query a row; the scores of the row's cached keys come
   through the page table, the ``topk`` positions are gathered from the
   pools BY TOKEN (not by page), and attention reads those alone.

@@ -330,32 +330,74 @@ def test_what_the_serving_plane_refuses_for_this_net(ref, net):
         bat.submit([3, 4], frames={"length": 1})
 
 
-@pytest.mark.parametrize("offset", [0, 70])
-def test_selected_window_kernel_against_its_reference(offset, monkeypatch):
+# what one case of the kernel's test varies; the rest is the first two
+# cases' (2 rows, 256 queries, 24 pages of 16, 2 key/value heads of 2
+# query heads, float32 pools, 40 % of the seen keys selected)
+_WINDOW = dict(B=2, C=256, Hkv=2, G=2, D=32, ps=16, P=24, dtype="float32",
+               density=0.4, atol=2e-5)
+WINDOW_CASES = {
+    # one block of 24 pages: everything the kernel had to get right before
+    # a block held more than a page
+    "offset0": dict(offset=0),
+    "offset70": dict(offset=70),
+    # 130 pages of 128 are sixteen blocks of 8 and one of 2, and the chunk
+    # sits in the last: it clamps its page indices past the table's end
+    # and is handed a mask block that ends past the row's length
+    "pages130-not-a-multiple": dict(offset=16_384, B=1, ps=128, P=130),
+    # the causal limit falls inside a page (128) and inside a block (1,024)
+    # and crosses the first block's end inside the chunk (1,000 + 23)
+    "limit-inside-a-block": dict(offset=1_000, ps=128, P=20),
+    # no query of either row sees past the first of three blocks
+    "trailing-blocks-unseen": dict(offset=0, ps=128, P=24),
+    # the published grouping: 4 key/value heads of 8 query heads
+    "hkv4-g8": dict(offset=1_000, B=1, C=128, Hkv=4, G=8, ps=128, P=11),
+    # the serving dtype: bfloat16 queries, pools and second product against
+    # the float32 reference of the same values (one bfloat16 ulp of an
+    # output near 1 is 0.0078)
+    "bfloat16-pools": dict(offset=1_000, ps=128, P=20, dtype="bfloat16",
+                           atol=2e-2),
+    # every query reads its own position and nothing else: whole blocks of
+    # -inf scores before the one finite score of a row
+    "single-selected-key": dict(offset=1_500, B=1, ps=128, P=20,
+                                density=0.0),
+}
+
+
+@pytest.mark.parametrize("case", list(WINDOW_CASES))
+def test_selected_window_kernel_against_its_reference(case, monkeypatch):
     """The Pallas window over a selected set (interpreted here), grouped
     query heads over fewer key/value heads, pools read through a shuffled
-    page table, against the dense jnp form."""
+    page table, against the dense jnp form and the jnp loop."""
     from mxnet_tpu.ops.pallas import paged_flash_attention as pfa
 
     monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    w = dict(_WINDOW, **WINDOW_CASES[case])
+    offset, B, C, Hkv, G, D, ps, P = (w[k] for k in (
+        "offset", "B", "C", "Hkv", "G", "D", "ps", "P"))
+    L, dtype = P * ps, jnp.dtype(w["dtype"])
+    tq, pages = pfa._selected_window_tiles(C, P, ps)
+    assert pages == min(P, 1024 // ps) and C % tq == 0
     rng = np.random.default_rng(offset)
-    B, C, Hq, Hkv, D, ps, P = 2, 256, 4, 2, 32, 16, 24
-    q = jnp.asarray(rng.normal(size=(B, C, Hq, D)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(1 + B * P, ps, Hkv, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(1 + B * P, ps, Hkv, D)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(B, C, Hkv * G, D)), dtype)
+    kp = jnp.asarray(rng.normal(size=(1 + B * P, ps, Hkv, D)), dtype)
+    vp = jnp.asarray(rng.normal(size=(1 + B * P, ps, Hkv, D)), dtype)
     table = jnp.asarray(1 + rng.permutation(B * P).reshape(B, P), jnp.int32)
-    off = jnp.asarray([offset, offset // 2], jnp.int32)
+    off = jnp.asarray([offset, offset // 2][:B], jnp.int32)
     q_pos = off[:, None] + jnp.arange(C)[None]
-    seen = jnp.arange(P * ps)[None, None, :] <= q_pos[:, :, None]
-    mask = seen & jnp.asarray(rng.random((B, C, P * ps)) < 0.4)
-    mask = mask | (jnp.arange(P * ps)[None, None, :] == q_pos[:, :, None])
+    seen = jnp.arange(L)[None, None, :] <= q_pos[:, :, None]
+    mask = seen & jnp.asarray(rng.random((B, C, L)) < w["density"])
+    mask = mask | (jnp.arange(L)[None, None, :] == q_pos[:, :, None])
     got = pfa.paged_selected_window_attention(q, kp, vp, table, off, mask,
                                               sm_scale=D ** -0.5)
-    want = pfa.paged_selected_window_reference(q, kp, vp, table, off, mask,
+    f32 = [x.astype(jnp.float32) for x in (q, kp, vp)]
+    want = pfa.paged_selected_window_reference(*f32, table, off, mask,
                                                sm_scale=D ** -0.5)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
-    loop = dsa.selected_window_attention(q, kp, vp, table, off, mask,
-                                         P * ps // 16, 16, D ** -0.5)
+    assert got.dtype == dtype and np.isfinite(np.asarray(want)).all()
+    np.testing.assert_allclose(np.asarray(got.astype(jnp.float32)),
+                               np.asarray(want), atol=w["atol"])
+    block = dsa.kv_block(L)
+    loop = dsa.selected_window_attention(*f32, table, off, mask,
+                                         L // block, block, D ** -0.5)
     np.testing.assert_allclose(np.asarray(loop), np.asarray(want), atol=2e-5)
 
 

@@ -171,16 +171,28 @@ def test_grouped_expert_product_compiles(for_chip, tokens, tile):
 def test_selected_window_attention_compiles(for_chip):
     """The window over a selected set at the published widths: a chunk of
     2,048 queries, 32 query heads over 4 key/value heads of 128, pages of
-    128 positions, 130 pages a row."""
+    128 positions, 130 pages a row: 256 queries and 8 pages (1,024 keys) a
+    grid step, whose blocks, scratch and scores stay under the limit the
+    kernel asks the compiler for (which refuses what does not fit)."""
     spec, compile_ = for_chip
     pfa = _mod("paged_flash_attention")
-    pool = spec((16 * 130 + 1, 128, 4, 128), "bfloat16")
-    compile_(
+    C, Hq, Hkv, D, page, P = 2048, 32, 4, 128, 128, 130
+    tq, pages = pfa._selected_window_tiles(C, P, page)
+    assert (tq, pages) == (256, 8)
+    need = pfa._selected_window_vmem_bytes(tq, pages, page, Hkv, Hq // Hkv,
+                                           D, itemsize=2)
+    assert 24 << 20 < need < pfa._WINDOW_VMEM_LIMIT <= 64 << 20
+    pool = spec((16 * P + 1, page, Hkv, D), "bfloat16")
+    compiled = compile_(
         lambda q, k, v, pt, off, m: pfa.paged_selected_window_attention(
-            q, k, v, pt, off, m, sm_scale=128 ** -0.5),
-        spec((1, 2048, 32, 128), "bfloat16"), pool, pool,
-        spec((1, 130), "int32"), spec((1,), "int32"),
-        spec((1, 2048, 130 * 128), "bool"))
+            q, k, v, pt, off, m, sm_scale=D ** -0.5),
+        spec((1, C, Hq, D), "bfloat16"), pool, pool,
+        spec((1, P), "int32"), spec((1,), "int32"),
+        spec((1, C, P * page), "bool"))
+    # one call, under the name the benchmark's reader keys on
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "%dsa_selected_window" in text
 
 
 @pytest.mark.parametrize("window", [1, 2, 4, 16])
