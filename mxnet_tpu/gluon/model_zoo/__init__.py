@@ -5,7 +5,9 @@ from . import vision  # noqa: F401
 from . import bert  # noqa: F401
 from . import transformer  # noqa: F401
 from . import keye  # noqa: F401
+from . import granite_hybrid  # noqa: F401
 from . import ssd  # noqa: F401
 from . import faster_rcnn  # noqa: F401
 
-__all__ = ["vision", "bert", "transformer", "keye", "ssd", "faster_rcnn"]
+__all__ = ["vision", "bert", "transformer", "keye", "granite_hybrid",
+           "ssd", "faster_rcnn"]

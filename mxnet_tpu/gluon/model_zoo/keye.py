@@ -64,6 +64,7 @@ class KeyeLM(HybridBlock):
     are stored ``(in, out)`` and experts ``(E, in, out)``."""
 
     # what a serving slot keeps: paged arrays a layer, no encoder memory
+    # (an instance adds ``counts``, whose lengths are its own)
     paged_slot_state = {"pools": ("k_pools", "v_pools", "ik_pools"),
                         "encoder_memory": False}
 
@@ -89,6 +90,10 @@ class KeyeLM(HybridBlock):
         self._theta, self._eps = float(rope_theta), float(rms_eps)
         self._sec = tuple(int(s) for s in mrope_section)
         self._isec = tuple(s // 2 for s in self._sec)
+        self.paged_slot_state = dict(type(self).paged_slot_state, counts=(
+            ("expert_tokens", num_layers * num_experts),
+            ("experts_touched", 1), ("expert_layers", 1), ("keys_seen", 1),
+            ("keys_selected", 1)))
         h, d = hidden_size, head_dim
         shapes = {"embed": (vocab_size, h), "norm": (h,),
                   "head": (h, vocab_size)}

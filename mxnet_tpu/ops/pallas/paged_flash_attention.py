@@ -92,12 +92,13 @@ def flash_paged_enabled() -> bool:
 
 def _window_kernel(pt_ref, off_ref, vl_ref, q_ref, k_ref, v_ref, o_ref,
                    m_ref, l_ref, acc_ref, *, page_size, sm_scale,
-                   window):
+                   window, shared_position=False):
     """Grid (B, pages_per_row), pages sequential per row: one pool page
     per step, online-softmax carry (m, l, acc) in VMEM scratch. An
     S-query window rides each row: query ``i`` sits at absolute position
     ``off + i`` and masks keys above it; queries ``>= vl`` are padding
-    and finalize to zero."""
+    and finalize to zero. With ``shared_position`` every query of the
+    window sits at ``off`` (the grouped query heads of ONE token)."""
     b = pl.program_id(0)
     p = pl.program_id(1)
     n_pages = pl.num_programs(1)
@@ -114,7 +115,7 @@ def _window_kernel(pt_ref, off_ref, vl_ref, q_ref, k_ref, v_ref, o_ref,
     # the window's LAST query (off + window - 1) bounds what any query
     # can see — pages wholly past it contribute nothing (page 0 is never
     # skipped, so l is never all-zero for a live row)
-    @pl.when(p * page_size <= off + window - 1)
+    @pl.when(p * page_size <= off + (0 if shared_position else window - 1))
     def _accumulate():
         q = q_ref[0].astype(jnp.float32)          # (H, S, D)
         k = k_ref[0].astype(jnp.float32)          # (ps, H, D)
@@ -125,8 +126,8 @@ def _window_kernel(pt_ref, off_ref, vl_ref, q_ref, k_ref, v_ref, o_ref,
         ) * sm_scale                               # (H, S, ps)
         key_abs = p * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, 1, page_size), 2)
-        q_abs = off + jax.lax.broadcasted_iota(
-            jnp.int32, (1, window, 1), 1)
+        q_abs = off if shared_position else \
+            off + jax.lax.broadcasted_iota(jnp.int32, (1, window, 1), 1)
         s = jnp.where(key_abs <= q_abs, s, _NEG_INF)
         m_prev = m_ref[...]                        # (H, S, LANES)
         l_prev = l_ref[...]
@@ -152,7 +153,8 @@ def _window_kernel(pt_ref, off_ref, vl_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def paged_window_attention(q, k_pool, v_pool, page_table, q_offset,
-                           window_vl=None, *, sm_scale):
+                           window_vl=None, *, sm_scale,
+                           shared_position=False):
     """S-token query window over a paged history, pools read in place.
 
     q ``(B, S, H, D)``; query ``i`` of row ``b`` sits at absolute
@@ -160,7 +162,9 @@ def paged_window_attention(q, k_pool, v_pool, page_table, q_offset,
     it (causal across the cached history AND within the window — the
     caller has already scattered the window's K/V into the pool).
     ``window_vl`` ``(B,)`` optionally marks queries ``>= window_vl[b]``
-    as padding (their outputs are zeroed). Returns ``(B, S, H, D)``."""
+    as padding (their outputs are zeroed). ``shared_position`` puts every
+    query of the window at ``q_offset[b]`` (``paged_decode_attention``'s
+    grouped query heads). Returns ``(B, S, H, D)``."""
     B, S, H, D = q.shape
     ps = k_pool.shape[1]
     P = page_table.shape[1]
@@ -168,7 +172,8 @@ def paged_window_attention(q, k_pool, v_pool, page_table, q_offset,
         window_vl = jnp.full((B,), S, jnp.int32)
     qt = jnp.swapaxes(q, 1, 2)                     # (B, H, S, D)
     kernel = functools.partial(_window_kernel, page_size=ps,
-                               sm_scale=sm_scale, window=S)
+                               sm_scale=sm_scale, window=S,
+                               shared_position=bool(shared_position))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, P),
@@ -202,13 +207,23 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, pos, *,
                            sm_scale):
     """Single-token paged attention, pools read in place.
 
-    q ``(B, H, D)``; pools ``(num_pages, page_size, H, D)``;
+    q ``(B, H, D)``; pools ``(num_pages, page_size, Hkv, D)``;
     ``page_table`` ``(B, P)`` int32; ``pos`` ``(B,)`` int32 — row ``b``
     attends keys at absolute positions ``<= pos[b]`` (the caller has
     already scattered position ``pos`` into the pool). Returns
-    ``(B, H, D)``."""
-    return paged_window_attention(q[:, None], k_pool, v_pool, page_table,
-                                  pos, sm_scale=sm_scale)[:, 0]
+    ``(B, H, D)``. Where ``H`` is ``G`` times ``Hkv`` (grouped-query
+    heads: query head ``i`` reads key/value head ``i // G``) the ``G``
+    heads of a group ride the kernel's window axis, all at ``pos``."""
+    B, H, D = q.shape
+    Hkv = k_pool.shape[2]
+    if H == Hkv:
+        return paged_window_attention(q[:, None], k_pool, v_pool,
+                                      page_table, pos,
+                                      sm_scale=sm_scale)[:, 0]
+    qg = jnp.swapaxes(q.reshape(B, Hkv, H // Hkv, D), 1, 2)
+    out = paged_window_attention(qg, k_pool, v_pool, page_table, pos,
+                                 sm_scale=sm_scale, shared_position=True)
+    return jnp.swapaxes(out, 1, 2).reshape(B, H, D)
 
 
 # ------------------------------------------------ selected window (GQA)

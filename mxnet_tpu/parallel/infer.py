@@ -236,16 +236,36 @@ class InferStep:
     @property
     def slot_state(self) -> dict:
         """What a serving slot keeps for this net, as the net declares it
-        (``paged_slot_state``): ``pools``, the names of the paged arrays a
-        layer under the one page table, and ``encoder_memory``, whether
-        the slot also holds static encoder memory (per-slot ``cross_k`` /
-        ``cross_v`` buffers and ``mem_vl``). A net that declares nothing
-        is an encoder-decoder: K and V pools, and encoder memory. The
-        batcher builds cross buffers, valid lengths and the cross-frame
-        store only where ``encoder_memory`` is true; without it the prompt
-        lives in the pages and enters in chunks."""
-        return getattr(self._net, "paged_slot_state", None) or \
-            {"pools": ("k_pools", "v_pools"), "encoder_memory": True}
+        (``paged_slot_state``), the three kinds of slot state in one
+        place:
+
+        - ``pools``: the names of the PAGED arrays (a tuple of arrays
+          under each name, one for each layer that keeps them; a net may
+          keep them for some of its layers only), all under the one page
+          table: they grow with the context, a page at a time.
+        - ``encoder_memory``: whether the slot also holds static ENCODER
+          memory (per-slot ``cross_k`` / ``cross_v`` buffers and
+          ``mem_vl``), of the largest bucket's width.
+        - ``slot_arrays``: the names of arrays indexed by SLOT (again a
+          tuple of arrays under each name, ``(slots, ...)``), of a fixed
+          size whatever the context: a state-space layer's recurrent
+          state and its convolution's tail. The net's chunk program
+          carries them from chunk to chunk of a prompt and starts from
+          zero where ``q_offset`` is 0, so a re-admitted slot needs no
+          reset; its decode step leaves the arrays of rows that are not
+          ``active`` as they are.
+
+        ``counts`` names the device-side counts a net adds to
+        ``state["counts"]``, ``(name, length)`` in order
+        (``_take_counts``). A net that declares nothing is an
+        encoder-decoder: K and V pools, encoder memory, no slot arrays,
+        no counts. The batcher builds cross buffers, valid lengths and
+        the cross-frame store only where ``encoder_memory`` is true;
+        without it the prompt lives in the pages and enters in
+        chunks."""
+        return {"pools": ("k_pools", "v_pools"), "encoder_memory": True,
+                "slot_arrays": (), "counts": (),
+                **(getattr(self._net, "paged_slot_state", None) or {})}
 
     def _need_encoder_memory(self, what: str):
         if not self.slot_state["encoder_memory"]:
@@ -256,12 +276,16 @@ class InferStep:
 
     def _state_sig(self, state):
         """The part of a paged state that names a compiled program: the
-        shape of the first paged array the net declares, and the first
-        cross buffer's where it keeps encoder memory."""
+        shape of the first paged array the net declares (of the first
+        layer that keeps one), the first cross buffer's where it keeps
+        encoder memory, and the shape of the first array under each name
+        of its slot arrays (their leading axis is the slot count, which
+        the pools do not show)."""
         decl = self.slot_state
         return (state[decl["pools"][0]][0].shape,
                 state["cross_k"][0].shape if decl["encoder_memory"]
-                else None)
+                else None,
+                *(state[name][0].shape for name in decl["slot_arrays"]))
 
     @property
     def weights_version(self) -> str:

@@ -327,7 +327,16 @@ class ContinuousBatcher:
         slots take their burst. Default: the largest bucket (one chunk).
         No ``cross`` buffers, ``mem_vl`` or cross-frame store are built
         for such a net; the prefix cache, forced prefixes, speculation
-        and handoff frames are refused for it by name.
+        and handoff frames are refused for it by name. What a slot keeps
+        is one of three kinds, all declared by the net and read in
+        ``InferStep.slot_state``: pages (they grow with the context),
+        encoder memory (the largest bucket's width), and arrays indexed
+        by slot of a fixed size whatever the context (a state-space
+        layer's recurrent state). All of it is allocated here, once, for
+        every slot: ``state_bytes`` reports pages, slot arrays and
+        encoder memory; nothing is allocated a request. Slot arrays need
+        no host call at admission or preemption: the chunk program
+        starts them from zero where a prompt's first chunk enters.
     warmup : compile the admission-prefill program per bucket plus the
         decode-iteration program at construction (inert rows — the pools
         only ever see trash-page writes).
@@ -445,6 +454,19 @@ class ContinuousBatcher:
                                     self.slots, self.pages_per_slot)
         self._state = engine.init_paged_state(
             self.slots, self.num_pages, self.page_size, self.mem_len)
+        # what was provisioned, by kind of slot state (bytes on the
+        # device): the pool's pages, slots x the fixed-size arrays, and
+        # the encoder memory
+        decl = engine.slot_state
+        self.state_bytes = {
+            kind: sum(int(a.nbytes) for n in names for a in self._state[n])
+            for kind, names in (
+                ("pages", decl["pools"]),
+                ("slot_arrays", decl["slot_arrays"]),
+                ("encoder_memory",
+                 ("cross_k", "cross_v") if self._enc_mem else ()))}
+        for kind, n in self.state_bytes.items():
+            _tel.registry().gauge("infer/state_bytes_" + kind).set(n)
         # the draft model decodes against its OWN pools but the SAME
         # page table — one allocator, two KV caches
         self._dstate = engine.init_draft_state(
@@ -520,19 +542,17 @@ class ContinuousBatcher:
                       # prompt tokens they wrote
                       "prefill_chunk_s": 0.0, "prompt_chunks": 0,
                       "prompt_tokens": 0}
-        counts = self._state.get("counts")
-        if counts is not None:
-            # device-side counts that rode the tokens' read-backs
-            # (``InferStep._take_counts``), by the program they came
-            # from: tokens routed to each expert of each layer; distinct
-            # experts touched and expert layers run; keys seen and keys
-            # selected by the sparse attention
-            for k in ("prefill", "decode"):
-                self.stats.update({
-                    k + "_expert_tokens": _np.zeros(
-                        (int(counts.shape[0]) - 4,), _np.int64),
-                    k + "_experts_touched": 0, k + "_expert_layers": 0,
-                    k + "_keys_seen": 0, k + "_keys_selected": 0})
+        # device-side counts that rode the tokens' read-backs
+        # (``InferStep._take_counts``), under the names the net declares
+        # (``slot_state["counts"]``: an expert layer's tokens an expert,
+        # a sparse attention's keys seen and selected, a state-space
+        # layer's scanned tokens...), by the program they came from
+        self._count_fields = tuple(
+            (str(k), int(n)) for k, n in engine.slot_state["counts"])
+        for k in ("prefill_", "decode_"):
+            self.stats.update({
+                k + name: _np.zeros((n,), _np.int64) if n > 1 else 0
+                for name, n in self._count_fields})
         # what one pass adds to ``stats`` (phase seconds, and the
         # iteration's counts): summed here by the scheduler thread alone
         # and published in ONE ``_stats_lock`` hold at the pass's end
@@ -1670,16 +1690,16 @@ class ContinuousBatcher:
 
     def _note_counts(self, program: str, counts) -> None:
         """Device-side counts that rode a read-back, into the pass's sums
-        (published with the phase seconds in the one lock hold): tokens an
-        expert a layer, then distinct experts touched, expert layers run,
-        keys seen, keys selected."""
-        n = self.stats[program + "_expert_tokens"].shape[0]
-        acc = self._pass
-        acc[program + "_expert_tokens"] = \
-            acc[program + "_expert_tokens"] + counts[:n].astype(_np.int64)
-        for k, v in zip(("_experts_touched", "_expert_layers", "_keys_seen",
-                         "_keys_selected"), counts[n:n + 4]):
-            acc[program + k] += int(v)
+        (published with the phase seconds in the one lock hold), in the
+        order and under the names the net declares them."""
+        acc, at = self._pass, 0
+        for name, n in self._count_fields:
+            key = program + "_" + name
+            if n > 1:
+                acc[key] = acc[key] + counts[at:at + n].astype(_np.int64)
+            else:
+                acc[key] += int(counts[at])
+            at += n
 
     def _activate(self, slot: int, r, first_tok: int, t0: float,
                   version, length: int, s=None) -> None:
@@ -1831,7 +1851,7 @@ class ContinuousBatcher:
         acc = self._pass
         with _tel.phase("sched.collect.readback", acc, "readback_s"):
             toks = buf.asnumpy()
-        if "decode_expert_tokens" in self.stats:
+        if self._count_fields:
             self._note_counts("decode", toks[:, self.iter_tokens:].ravel())
         iter_ms = (time.perf_counter() - t0) * 1e3
         with _tel.phase("sched.collect", acc, "collect_s"):
@@ -1906,6 +1926,18 @@ class ContinuousBatcher:
         if self._spec_on:
             self._dstate = self._engine.init_draft_state(
                 self.slots, self.num_pages, self.page_size, self.mem_len)
+
+    def slot_arrays(self) -> dict:
+        """The arrays indexed by slot as they stand, ``{name: (array a
+        layer, ...)}`` (empty for a net that declares none): what each
+        slot's last occupant left. For checks and debugging, on a STOPPED
+        batcher only: a running scheduler donates them to its next
+        dispatch."""
+        if self._thread is not None:
+            raise MXNetError("slot_arrays() reads a stopped batcher: a "
+                             "running scheduler donates the arrays")
+        return {name: tuple(self._state[name])
+                for name in self._engine.slot_state["slot_arrays"]}
 
     @property
     def sustained_occupancy(self) -> float:

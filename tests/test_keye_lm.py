@@ -279,8 +279,10 @@ def test_batcher_serves_the_references_greedy_tokens(ref, net):
     """Five requests through two slots: slots retire and refill while
     another slot's prompt is still entering in chunks."""
     eng = InferStep(net)
-    assert eng.slot_state == {"pools": ("k_pools", "v_pools", "ik_pools"),
-                              "encoder_memory": False}
+    assert eng.slot_state["pools"] == ("k_pools", "v_pools", "ik_pools")
+    assert eng.slot_state["encoder_memory"] is False
+    assert eng.slot_state["slot_arrays"] == ()
+    assert sum(n for _, n in eng.slot_state["counts"]) == net.counts_size
     bat = make_batcher(eng, [8, 32], slots=2, max_new_tokens=6,
                        page_size=PAGE, prefill_chunk=CHUNK, iter_tokens=2,
                        prefix_cache=False, warmup=True, name="keye")

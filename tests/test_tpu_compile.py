@@ -195,6 +195,35 @@ def test_selected_window_attention_compiles(for_chip):
     assert "%dsa_selected_window" in text
 
 
+def test_grouped_decode_attention_compiles(for_chip):
+    """granite-4.0-h-micro's decode attention: 64 rows, 32 query heads
+    over 8 key/value heads of 64 (the group of 4 rides the window axis,
+    every query at the row's position), 12 pages of 128 a row."""
+    spec, compile_ = for_chip
+    pfa = _mod("paged_flash_attention")
+    B, Hq, Hkv, D, page, P = 64, 32, 8, 64, 128, 12
+    pool = spec((B * P + 1, page, Hkv, D), "bfloat16")
+    compile_(
+        lambda q, k, v, pt, pos: pfa.paged_decode_attention(
+            q, k, v, pt, pos, sm_scale=1 / 64),
+        spec((B, Hq, D), "bfloat16"), pool, pool, spec((B, P), "int32"),
+        spec((B,), "int32"))
+
+
+def test_hybrid_chunk_attention_compiles(for_chip):
+    """The same model's chunk attention through the selected-window kernel
+    with a causal mask: a chunk of 512 queries, heads of 64."""
+    spec, compile_ = for_chip
+    pfa = _mod("paged_flash_attention")
+    C, Hq, Hkv, D, page, P = 512, 32, 8, 64, 128, 12
+    pool = spec((64 * P + 1, page, Hkv, D), "bfloat16")
+    compile_(
+        lambda q, k, v, pt, off, m: pfa.paged_selected_window_attention(
+            q, k, v, pt, off, m, sm_scale=1 / 64),
+        spec((1, C, Hq, D), "bfloat16"), pool, pool, spec((1, P), "int32"),
+        spec((1,), "int32"), spec((1, C, P * page), "bool"))
+
+
 @pytest.mark.parametrize("window", [1, 2, 4, 16])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_paged_window_attention_compiles(for_chip, dtype, window):
