@@ -390,8 +390,9 @@ class MultiHeadAttention(HybridBlock):
 
         if self._causal and _pfa.flash_paged_enabled():
             # Pallas decode kernel: the page table rides the grid as a
-            # scalar-prefetch operand and each step reads one pool page
-            # in place — the gather below never materializes
+            # scalar-prefetch operand and each step reads a block of the
+            # row's pool pages in place, none past the row's position —
+            # the gather below never materializes
             out = NDArray(_pfa.paged_decode_attention(
                 q.data[:, 0], k_pool, v_pool, page_table, pos,
                 sm_scale=self._sm_scale())[:, None])

@@ -7,24 +7,27 @@ dense attention over it — two full copies of every cached key/value per
 decoded token, plus an O(L) score row in HBM. These kernels close that
 gap (the FlashAttention/PagedAttention fusion, ROADMAP item 2): the page
 table rides the grid as a scalar-prefetch operand, each grid step DMAs
-one page directly out of the pool, and an online-softmax carry in VMEM
-scratch accumulates across the sequential page dimension — the gather
-never materializes and scores never leave VMEM.
+a block of pages directly out of the pool (each page its own operand; a
+page no query of the row sees is not fetched), and an online-softmax
+carry in VMEM scratch accumulates across the sequential block dimension —
+the gather never materializes and scores never leave VMEM.
 
 One kernel, two entry points:
 
 - ``paged_window_attention`` — an S-token query window per row, each
   query ``i`` at absolute position ``q_offset[b] + i`` (causal within
-  and across the window). Grid ``(B, pages_per_row)``; row ``b``'s step
-  ``p`` reads pool block ``page_table[b, p]``. This is the
-  q_offset-aware PREFILL variant: suffix-only prefix-cache replay and
-  speculative verification both score a short window against a long
+  and across the window). Grid ``(B, key blocks)``; page ``t`` of row
+  ``b``'s block ``j`` is pool block ``page_table[b, j * pages + t]``, as
+  many pages a block as ``_window_tiles`` gives for the shapes. A page
+  is taken as its ``(key, head)`` rows, the pool's own order, and the
+  rows of every head meet all of them in one product whose foreign
+  columns are masked: no relayout, one softmax carry a block. This is
+  the q_offset-aware PREFILL variant: suffix-only prefix-cache replay
+  and speculative verification both score a short window against a long
   paged history in one pass.
 - ``paged_decode_attention`` — single query token per row (the decode
-  hot path): the window kernel at ``S = 1`` with ``q_offset = pos``. A
-  separate 2-D ``(H, D)`` query kernel is not expressible for the chip:
-  Mosaic refuses a batched ``dot_general`` whose lhs has no free
-  dimension, so the query keeps its unit window axis.
+  hot path): the window kernel at ``S = 1`` with ``q_offset = pos``, or,
+  for grouped-query heads, with the group on the window axis.
 
 - ``paged_selected_window_attention`` — the window over a SELECTED set
   of cached positions (learned sparse attention), for grouped-query
@@ -90,66 +93,193 @@ def flash_paged_enabled() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _window_kernel(pt_ref, off_ref, vl_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, page_size, sm_scale,
-                   window, shared_position=False):
-    """Grid (B, pages_per_row), pages sequential per row: one pool page
-    per step, online-softmax carry (m, l, acc) in VMEM scratch. An
-    S-query window rides each row: query ``i`` sits at absolute position
-    ``off + i`` and masks keys above it; queries ``>= vl`` are padding
-    and finalize to zero. With ``shared_position`` every query of the
-    window sits at ``off`` (the grouped query heads of ONE token)."""
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-    n_pages = pl.num_programs(1)
+# The window kernel's two sizes, in bytes of one pool. A grid step takes
+# as many whole pages as make _WINDOW_STEP_BYTES, at most the row's, and
+# computes them in blocks of _WINDOW_BLOCK_BYTES, each under its own
+# ``pl.when``: a block past the row's position costs nothing, a longer
+# block computes more keys no query sees, a shorter one pays more softmax
+# carries. On a v5e (PERF.md, PR 32; kernel alone, microseconds a call):
+# granite's call (64 rows of 12 pages of 128 keys, positions about 600)
+# with a row in one step 336 / 313 / 308 at blocks of 128 / 256 / 512 KiB
+# and 375 to 465 with a row in two or three steps (the pipeline looks one
+# step ahead, so a row's first pages are waited for); transformer-big's
+# (128 rows of 16 pages of 16 keys) at positions 0 to 40 407 / 404 / 422 /
+# 456 at blocks of 64 / 128 / 256 / 512 KiB, every row full 762 / 581 /
+# 499 / 456. What is left there is the pipeline's own work on 2 x 16 page
+# operands a row, about 3 us, however they are spread over grid steps
+_WINDOW_STEP_BYTES = 2 * 1024 * 1024
+_WINDOW_BLOCK_BYTES = 128 * 1024
+_WINDOW_STEP_VMEM_LIMIT = 32 * 1024 * 1024
 
-    @pl.when(p == 0)
+
+def _window_tiles(P, page_size, Hkv, D, itemsize):
+    """``(pages a grid step, pages a block)`` of the window kernel, from
+    the shapes alone: granite's row of 12 pages of 128 keys is one step of
+    12 blocks of a page, transformer-big's row of 16 pages of 16 keys one
+    step of 4 blocks of 4; a row longer than ``_WINDOW_STEP_BYTES`` takes
+    several steps."""
+    page_bytes = page_size * Hkv * D * itemsize
+    pages = max(1, min(P, _WINDOW_STEP_BYTES // page_bytes))
+    return pages, max(1, min(pages, _WINDOW_BLOCK_BYTES // page_bytes))
+
+
+def _window_vmem_bytes(pages, block, page_size, Hkv, S, D, itemsize):
+    """VMEM a grid step of the window kernel holds: the pipeline's two
+    buffers of every operand (a row of ``D`` elements takes whole lanes),
+    the scratch, one block of pages joined, and its scores with their
+    exponentials (float32) and their cast for the second product."""
+    rows, cols = Hkv * S, block * page_size * Hkv
+    wide = -(-D // _LANES) * _LANES
+    operands = 2 * (2 * rows + 2 * pages * page_size * Hkv) * wide * itemsize
+    scratch = rows * (2 * _LANES + wide) * 4
+    live = 2 * cols * wide * itemsize + rows * cols * (4 + 4 + itemsize)
+    return operands + scratch + live
+
+
+def _window_kernel(pt_ref, off_ref, vl_ref, q_ref, *refs, page_size, pages,
+                   block, sm_scale, window, shared_position=False):
+    """Grid (B, steps), steps sequential per row: ``pages`` pool pages a
+    step (each its own operand, so the pipeline copies them through the
+    page table), taken in blocks of ``block`` pages; online-softmax carry
+    (m, l, acc) in VMEM scratch, updated once a block. A page arrives as
+    ``(page_size * Hkv, D)``, one row a (key, head), which is how the
+    pool lies in HBM: nothing is relaid. The ``Hkv * S`` query rows (head
+    ``r // S``, query ``r % S``) meet every row of the block in ONE
+    product, and a query row keeps the columns of its own head: what it
+    gives the others is masked like a key past its position, weighs
+    ``exp(-inf) = 0`` in the second product and so never reaches the
+    accumulator. Query ``i`` sits at absolute position ``off + i`` and
+    masks keys above it; queries ``>= vl`` are padding and finalize to
+    zero. With ``shared_position`` every query of the window sits at
+    ``off`` (the grouped query heads of ONE token)."""
+    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * pages:]
+    b, j = pl.program_id(0), pl.program_id(1)
+    rows = q_ref.shape[1]
+    Hkv = rows // window
+    lw = l_ref.shape[-1]
+
+    @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     off = off_ref[b]
-    vl = vl_ref[b]
-
-    # the window's LAST query (off + window - 1) bounds what any query
-    # can see — pages wholly past it contribute nothing (page 0 is never
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    q_abs = off if shared_position else off + row % window
+    # the window's LAST query bounds what any query can see: a block
+    # wholly past it contributes nothing (the row's first block is never
     # skipped, so l is never all-zero for a live row)
-    @pl.when(p * page_size <= off + (0 if shared_position else window - 1))
-    def _accumulate():
-        q = q_ref[0].astype(jnp.float32)          # (H, S, D)
-        k = k_ref[0].astype(jnp.float32)          # (ps, H, D)
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale                               # (H, S, ps)
-        key_abs = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, page_size), 2)
-        q_abs = off if shared_position else \
-            off + jax.lax.broadcasted_iota(jnp.int32, (1, window, 1), 1)
-        s = jnp.where(key_abs <= q_abs, s, _NEG_INF)
-        m_prev = m_ref[...]                        # (H, S, LANES)
-        l_prev = l_ref[...]
-        m_cur = jnp.max(s, axis=2, keepdims=True)  # (H, S, 1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p_act = jnp.exp(s - m_new[:, :, :1])       # (H, S, ps)
-        l_new = alpha * l_prev + jnp.sum(p_act, axis=2, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha[:, :, :1] + jax.lax.dot_general(
-            p_act, v, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-        )                                          # (H, S, D)
-        m_ref[...] = m_new
-        l_ref[...] = l_new
+    last = off + (0 if shared_position else window - 1)
 
-    @pl.when(p == n_pages - 1)
+    def accumulate(k_blk, v_blk, first_key):
+        def joined(page_refs):
+            if len(page_refs) == 1:
+                return page_refs[0][0]
+            return jnp.concatenate([r[0] for r in page_refs], axis=0)
+
+        k, v = joined(k_blk), joined(v_blk)        # (cols, D)
+        cols = k.shape[0]
+        # operands go to the MXU as the pools hold them; a process-wide
+        # "highest" precision is not one Mosaic takes for bfloat16
+        prec = (jax.lax.Precision.DEFAULT if k.dtype == jnp.bfloat16
+                else None)
+        s = jax.lax.dot_general(
+            q_ref[0].astype(k.dtype), k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=prec) * sm_scale             # (rows, cols)
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+        s = jnp.where(jnp.logical_and(col % Hkv == row // window,
+                                      first_key + col // Hkv <= q_abs),
+                      s, _NEG_INF)
+        m_prev = m_ref[...]                        # (rows, LANES)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p_act = jnp.exp(s - m_new[:, :1])
+        # the denominator stays a sum a lane until the last block:
+        # adding lane groups is elementwise, a sum a row is not
+        l_new = alpha[:, :lw] * l_ref[...]
+        for c in range(cols // lw):
+            l_new = l_new + p_act[:, c * lw:(c + 1) * lw]
+        l_ref[...] = l_new
+        acc_ref[...] = acc_ref[...] * alpha[:, :1] + jax.lax.dot_general(
+            p_act.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=prec)                        # (rows, D)
+        m_ref[...] = m_new
+
+    for lo in range(0, pages, block):
+        hi = min(pages, lo + block)
+        first_key = (j * pages + lo) * page_size
+        pl.when(first_key <= last)(functools.partial(
+            accumulate, k_refs[lo:hi], v_refs[lo:hi], first_key))
+
+    @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[:, :, :1], 1e-30)
-        out = acc_ref[...] / l                     # (H, S, D)
-        live = jax.lax.broadcasted_iota(
-            jnp.int32, (1, window, 1), 1) < vl
-        o_ref[0] = jnp.where(live, out, 0.0).astype(o_ref.dtype)
+        l = jnp.maximum(jnp.sum(l_ref[...], axis=1, keepdims=True), 1e-30)
+        o_ref[0] = jnp.where(row % window < vl_ref[b], acc_ref[...] / l,
+                             0.0).astype(o_ref.dtype)
+
+
+# jitted so that one trace serves every layer and every program of a
+# shape: the page operands' index maps are traced one by one, which cost
+# transformer-big's warm-up (25 programs of six layers) 4 s of ``setup_s``
+@functools.partial(jax.jit, static_argnames=(
+    "sm_scale", "shared_position", "pages", "block", "interpret"))
+def _paged_window_impl(q, k_pool, v_pool, page_table, q_offset, window_vl,
+                       sm_scale, shared_position, pages, block, interpret):
+    B, S, H, D = q.shape
+    N, ps = k_pool.shape[0], k_pool.shape[1]
+    P = page_table.shape[1]
+    # query rows (head, query); a page as its (key, head) rows: with 8 or
+    # 16 heads of bfloat16 the pool's tiles in HBM are these rows already
+    rows = jnp.swapaxes(q, 1, 2).reshape(B, H * S, D)
+    extra = 0 if shared_position else S - 1
+
+    def page(t):
+        # page t of step j, if the row's last query sees it; else the
+        # page this operand held the step before, so that nothing is
+        # copied, and in step 0 pool page 0 (masked whatever it holds),
+        # which the next short row then finds in place too
+        def index(b, j, pt, off, vl):
+            last = jnp.clip((off[b] + extra) // ps, 0, P - 1)
+            jj = jnp.minimum(j, jnp.maximum(last - t, 0) // pages)
+            return (jnp.where(t <= last, pt[b, jj * pages + t], 0), 0, 0)
+        return index
+
+    pool_specs = [pl.BlockSpec((1, ps * H, D), page(t))
+                  for t in range(pages)]
+    row_spec = pl.BlockSpec((1, H * S, D),
+                            lambda b, j, pt, off, vl: (b, 0, 0))
+    kernel = functools.partial(_window_kernel, page_size=ps, pages=pages,
+                               block=block, sm_scale=sm_scale, window=S,
+                               shared_position=shared_position)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, pl.cdiv(P, pages)),
+        in_specs=[row_spec] + pool_specs + pool_specs,
+        out_specs=row_spec,
+        scratch_shapes=[
+            pltpu.VMEM((H * S, _LANES), jnp.float32),
+            pltpu.VMEM((H * S, math.gcd(ps * H, _LANES)), jnp.float32),
+            pltpu.VMEM((H * S, D), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H * S, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_WINDOW_STEP_VMEM_LIMIT),
+        interpret=interpret,
+        name="paged_window",
+    )(page_table.astype(jnp.int32), q_offset.astype(jnp.int32),
+      window_vl.astype(jnp.int32), rows,
+      *([k_pool.reshape(N, ps * H, D)] * pages),
+      *([v_pool.reshape(N, ps * H, D)] * pages))
+    return jnp.swapaxes(out.reshape(B, H, S, D), 1, 2)   # (B, S, H, D)
 
 
 def paged_window_attention(q, k_pool, v_pool, page_table, q_offset,
@@ -166,41 +296,14 @@ def paged_window_attention(q, k_pool, v_pool, page_table, q_offset,
     query of the window at ``q_offset[b]`` (``paged_decode_attention``'s
     grouped query heads). Returns ``(B, S, H, D)``."""
     B, S, H, D = q.shape
-    ps = k_pool.shape[1]
-    P = page_table.shape[1]
     if window_vl is None:
         window_vl = jnp.full((B,), S, jnp.int32)
-    qt = jnp.swapaxes(q, 1, 2)                     # (B, H, S, D)
-    kernel = functools.partial(_window_kernel, page_size=ps,
-                               sm_scale=sm_scale, window=S,
-                               shared_position=bool(shared_position))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, P),
-        in_specs=[
-            pl.BlockSpec((1, H, S, D),
-                         lambda b, p, pt, off, vl: (b, 0, 0, 0)),
-            pl.BlockSpec((1, ps, H, D),
-                         lambda b, p, pt, off, vl: (pt[b, p], 0, 0, 0)),
-            pl.BlockSpec((1, ps, H, D),
-                         lambda b, p, pt, off, vl: (pt[b, p], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, H, S, D),
-                               lambda b, p, pt, off, vl: (b, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, S, _LANES), jnp.float32),
-            pltpu.VMEM((H, S, _LANES), jnp.float32),
-            pltpu.VMEM((H, S, D), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-        interpret=_use_interpret(),
-    )(page_table.astype(jnp.int32), q_offset.astype(jnp.int32),
-      window_vl.astype(jnp.int32), qt, k_pool, v_pool)
-    return jnp.swapaxes(out, 1, 2)                 # (B, S, H, D)
+    pages, block = _window_tiles(page_table.shape[1], k_pool.shape[1], H, D,
+                                 k_pool.dtype.itemsize)
+    return _paged_window_impl(
+        q, k_pool, v_pool, page_table, q_offset, window_vl,
+        sm_scale=float(sm_scale), shared_position=bool(shared_position),
+        pages=pages, block=block, interpret=_use_interpret())
 
 
 def paged_decode_attention(q, k_pool, v_pool, page_table, pos, *,

@@ -208,42 +208,167 @@ class TestFlashPagedKernel:
     CPU rig) against their dense references, and speculative decoding
     through the batcher against the dense engine."""
 
-    def _pools(self, rng, num_pages=5, ps=4, H=2, D=8):
-        kp = jnp.asarray(rng.randn(num_pages, ps, H, D).astype(np.float32))
-        vp = jnp.asarray(rng.randn(num_pages, ps, H, D).astype(np.float32))
-        return kp, vp
+    # (page, key/value heads, query heads a group, head size, pages a row,
+    #  pages a block or None for the kernel's own choice, dtype, positions)
+    DECODE_CASES = {
+        # the case this test had: pages of 4, two a row (table [[1, 2],
+        # [3, 4]] over a pool of 5), mid-page tails
+        "page4_two_pages": (4, 2, 1, 8, 2, None, "float32", [2, 6]),
+        # position 0, the last key of a page, the first of the next, the
+        # row's last position; two blocks of two pages
+        "page16_edges": (16, 4, 1, 16, 4, 2, "float32", [0, 15, 16, 63]),
+        # a row of 5 pages in blocks of 2: the last block is partial
+        "page16_grouped_partial_block":
+            (16, 2, 4, 16, 5, 2, "float32", [0, 31, 32, 79]),
+        "page16_one_page_a_block":
+            (16, 4, 1, 16, 3, 1, "float32", [5, 16, 47]),
+        # granite's widths: a row of 3 pages of 128 in one step of three
+        # blocks by the kernel's own rule, grouped heads
+        "page128_grouped_bf16":
+            (128, 8, 4, 64, 3, None, "bfloat16", [0, 127, 128, 383]),
+        "page128_f32": (128, 8, 1, 64, 3, None, "float32", [1, 200, 383]),
+    }
+    # (page, heads, head size, pages a row, pages a block, dtype, window,
+    #  offsets, real queries a row)
+    WINDOW_CASES = {
+        # the case this test had: a suffix-replay row, a padded query
+        "page4_offset_and_padding":
+            (4, 2, 8, 2, None, "float32", 3, [0, 5], [3, 2]),
+        # windows that start at 0, cross a page, cross a block, and end on
+        # the row's last position; padding in two rows
+        "page16_s4": (16, 4, 16, 4, 2, "float32", 4,
+                      [0, 13, 30, 60], [4, 2, 4, 1]),
+        "page16_s1": (16, 4, 16, 5, 2, "float32", 1, [0, 16, 79], [1, 1, 1]),
+        "page128_s4_bf16": (128, 8, 64, 3, None, "bfloat16", 4,
+                            [0, 125, 380], [4, 3, 4]),
+    }
+    # float32 pools: the tolerance these tests always had. bfloat16 pools:
+    # the output's own rounding (2 ** -8 of values up to about 2) and the
+    # probabilities' cast for the second product
+    TOL = {"float32": dict(rtol=1e-5, atol=1e-5),
+           "bfloat16": dict(rtol=2e-2, atol=2e-2)}
 
-    def test_decode_kernel_matches_reference(self):
+    def _paged(self, rng, B, ps, Hkv, D, P, dtype, block_pages, monkeypatch):
+        """Pools whose page 0 is the trash page, a table with each row's
+        own pages, and the kernel's block set to ``block_pages`` pages."""
         from mxnet_tpu.ops.pallas import paged_flash_attention as pfa
+        kp = jnp.asarray(rng.randn(B * P + 1, ps, Hkv, D), dtype)
+        vp = jnp.asarray(rng.randn(B * P + 1, ps, Hkv, D), dtype)
+        table = 1 + np.arange(B * P, dtype=np.int32).reshape(B, P)
+        if block_pages is not None:
+            # a grid step of two blocks, so that rows take several steps
+            # of several blocks each
+            page_bytes = ps * Hkv * D * jnp.dtype(dtype).itemsize
+            monkeypatch.setattr(pfa, "_WINDOW_BLOCK_BYTES",
+                                block_pages * page_bytes)
+            monkeypatch.setattr(pfa, "_WINDOW_STEP_BYTES",
+                                2 * block_pages * page_bytes)
+            assert pfa._window_tiles(
+                P, ps, Hkv, D, jnp.dtype(dtype).itemsize) == (
+                    min(P, 2 * block_pages), min(P, block_pages))
+        return pfa, kp, vp, table
+
+    @pytest.mark.parametrize("case", sorted(DECODE_CASES))
+    def test_decode_kernel_matches_reference(self, case, monkeypatch):
+        ps, Hkv, G, D, P, block, dtype, pos = self.DECODE_CASES[case]
         rng = np.random.RandomState(0)
-        kp, vp = self._pools(rng)
-        q = jnp.asarray(rng.randn(2, 2, 8).astype(np.float32))
-        table = jnp.asarray(np.array([[1, 2], [3, 4]], np.int32))
-        pos = jnp.asarray(np.array([2, 6], np.int32))  # mid-page tails
-        got = pfa.paged_decode_attention(q, kp, vp, table, pos,
-                                         sm_scale=8 ** -0.5)
-        want = pfa.paged_decode_reference(q, kp, vp, table, pos,
-                                          sm_scale=8 ** -0.5)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=1e-5, atol=1e-5)
+        B = len(pos)
+        pfa, kp, vp, table = self._paged(rng, B, ps, Hkv, D, P, dtype,
+                                         block, monkeypatch)
+        q = jnp.asarray(rng.randn(B, Hkv * G, D), dtype)
+        pos = jnp.asarray(np.array(pos, np.int32))
+        got = pfa.paged_decode_attention(q, kp, vp, jnp.asarray(table), pos,
+                                         sm_scale=D ** -0.5)
+        # query head i reads key/value head i // G: the reference takes
+        # one key/value head a query head
+        want = pfa.paged_decode_reference(
+            q, jnp.repeat(kp, G, axis=2), jnp.repeat(vp, G, axis=2),
+            jnp.asarray(table), pos, sm_scale=D ** -0.5)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   **self.TOL[dtype])
 
-    def test_window_kernel_matches_reference_offset_and_padding(self):
-        from mxnet_tpu.ops.pallas import paged_flash_attention as pfa
+    @pytest.mark.parametrize("case", sorted(WINDOW_CASES))
+    def test_window_kernel_matches_reference_offset_and_padding(
+            self, case, monkeypatch):
+        ps, H, D, P, block, dtype, S, off, vl = self.WINDOW_CASES[case]
         rng = np.random.RandomState(1)
-        kp, vp = self._pools(rng)
-        S = 3
-        q = jnp.asarray(rng.randn(2, S, 2, 8).astype(np.float32))
-        table = jnp.asarray(np.array([[1, 2], [3, 4]], np.int32))
-        off = jnp.asarray(np.array([0, 5], np.int32))  # suffix replay row
-        vl = jnp.asarray(np.array([3, 2], np.int32))   # row 1 pads query 2
-        got = pfa.paged_window_attention(q, kp, vp, table, off, vl,
-                                         sm_scale=8 ** -0.5)
-        want = pfa.paged_window_reference(q, kp, vp, table, off, vl,
-                                          sm_scale=8 ** -0.5)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=1e-5, atol=1e-5)
+        B = len(off)
+        pfa, kp, vp, table = self._paged(rng, B, ps, H, D, P, dtype, block,
+                                         monkeypatch)
+        q = jnp.asarray(rng.randn(B, S, H, D), dtype)
+        off = jnp.asarray(np.array(off, np.int32))
+        vl = jnp.asarray(np.array(vl, np.int32))
+        got = pfa.paged_window_attention(q, kp, vp, jnp.asarray(table), off,
+                                         vl, sm_scale=D ** -0.5)
+        want = pfa.paged_window_reference(q, kp, vp, jnp.asarray(table), off,
+                                          vl, sm_scale=D ** -0.5)
+        got = np.asarray(got, np.float32)
+        np.testing.assert_allclose(got, np.asarray(want, np.float32),
+                                   **self.TOL[dtype])
         # padded query rows finalize to exact zero in both
-        assert np.abs(np.asarray(got)[1, 2]).sum() == 0.0
+        for b in range(B):
+            assert np.abs(got[b, int(vl[b]):]).sum() == 0.0
+
+    @pytest.mark.parametrize("block_pages", [1, 2, 4])
+    def test_kernel_reads_no_page_past_the_position(self, block_pages,
+                                                    monkeypatch):
+        """A table's entries past a row's position may point anywhere (a
+        retired request's pages, the trash page): the kernel fetches none
+        of them, whole blocks or the tail of a live block, so what they
+        hold (NaN here) reaches no output; and an inactive row parked on
+        the trash page beside live rows moves none of them."""
+        rng = np.random.RandomState(3)
+        ps, H, D, P = 16, 4, 16, 4
+        pos = np.array([0, 15, 16, 40, 63, 7], np.int32)
+        B = len(pos)
+        pfa, kp, vp, table = self._paged(rng, B, ps, H, D, P, "float32",
+                                         block_pages, monkeypatch)
+        table[5] = 0                     # the inactive row: trash page
+        clean_k, clean_v = kp, vp
+        for b in range(B - 1):
+            for p in range(int(pos[b]) // ps + 1, P):
+                kp = kp.at[table[b, p]].set(np.nan)
+                vp = vp.at[table[b, p]].set(np.nan)
+        q = jnp.asarray(rng.randn(B, H, D).astype(np.float32))
+        args = (jnp.asarray(table), jnp.asarray(pos))
+        got = np.asarray(pfa.paged_decode_attention(q, kp, vp, *args,
+                                                    sm_scale=0.25))
+        want = np.asarray(pfa.paged_decode_reference(
+            q, clean_k, clean_v, *args, sm_scale=0.25))
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got[:5], want[:5], rtol=1e-5, atol=1e-5)
+
+    def test_window_tiling_is_a_function_of_the_shapes(self):
+        """``(pages a grid step, pages a block)``: granite's row of 12
+        pages of 128 keys (8 key/value heads of 64, bfloat16) is one step
+        of blocks of a page, transformer-big's 16 pages of 16 (16 heads of
+        64) one step of blocks of 4; never more than a row has; a 16k row
+        of granite's takes steps of 16 pages."""
+        from mxnet_tpu.ops.pallas import paged_flash_attention as pfa
+        assert pfa._window_tiles(12, 128, 8, 64, 2) == (12, 1)
+        assert pfa._window_tiles(16, 16, 16, 64, 2) == (16, 4)
+        assert pfa._window_tiles(128, 128, 8, 64, 2) == (16, 1)
+        assert pfa._window_tiles(2, 16, 8, 64, 4) == (2, 2)   # the smoke run
+        assert pfa._window_tiles(1, 128, 8, 64, 2) == (1, 1)  # a row of one
+        assert pfa._window_tiles(12, 128, 8, 64, 4) == (8, 1)  # float32
+        assert pfa._window_tiles(3, 4, 2, 8, 4) == (3, 3)     # these tests'
+
+    @pytest.mark.parametrize("cell,P,page,Hkv,S", [
+        ("granite decode", 12, 128, 8, 4),
+        ("transformer-big decode", 16, 16, 16, 1),
+        ("transformer-big widest warm-up window", 16, 16, 16, 16)])
+    def test_window_vmem_reckoning_is_under_the_limit_passed(
+            self, cell, P, page, Hkv, S):
+        """What a grid step holds at the published shapes is under the
+        limit the kernel hands the compiler (which refuses what does not
+        fit: tests/test_tpu_compile.py compiles the same shapes)."""
+        from mxnet_tpu.ops.pallas import paged_flash_attention as pfa
+        pages, block = pfa._window_tiles(P, page, Hkv, 64, 2)
+        need = pfa._window_vmem_bytes(pages, block, page, Hkv, S, 64,
+                                      itemsize=2)
+        assert 1 << 20 < need < pfa._WINDOW_STEP_VMEM_LIMIT
+        assert pfa._WINDOW_STEP_VMEM_LIMIT <= 64 << 20
 
     def test_forced_kernel_paged_step_matches_fallback(self, monkeypatch):
         """Layer level: ``paged_step`` with the kernel forced (interpret

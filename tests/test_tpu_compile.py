@@ -138,16 +138,34 @@ def test_layer_norm_under_a_mesh_takes_the_partitionable_form(
                 ).lower(x, g, g).compile()
 
 
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_paged_decode_attention_compiles(for_chip, dtype):
+# the paged window kernel's callers at the shapes they ship: the smoke
+# run's (chip_smoke.py) and transformer-big.translate-closed's (128 slots,
+# 16 pages of 16 a slot, 16 heads of 64, a pool of 1,153 pages; PERF.md
+# section 4)
+SMOKE = (SLOTS, PAGES_PER_SLOT, PAGE, HEADS, HEAD_DIM,
+         SLOTS * PAGES_PER_SLOT + 1)
+BIG = (128, 16, 16, 16, 64, 1153)
+
+
+def _named_once(compiled):
+    """One call, under the name the ledger's breakdown lists it by."""
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "%paged_window" in text
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    (SMOKE, "float32"), (SMOKE, "bfloat16"), (BIG, "bfloat16")])
+def test_paged_decode_attention_compiles(for_chip, shape, dtype):
     spec, compile_ = for_chip
     pfa = _mod("paged_flash_attention")
-    pool = spec((SLOTS * PAGES_PER_SLOT + 1, PAGE, HEADS, HEAD_DIM), dtype)
-    compile_(
+    B, P, page, H, D, pool_pages = shape
+    pool = spec((pool_pages, page, H, D), dtype)
+    _named_once(compile_(
         lambda q, k, v, pt, pos: pfa.paged_decode_attention(
-            q, k, v, pt, pos, sm_scale=HEAD_DIM ** -0.5),
-        spec((SLOTS, HEADS, HEAD_DIM), dtype), pool, pool,
-        spec((SLOTS, PAGES_PER_SLOT), "int32"), spec((SLOTS,), "int32"))
+            q, k, v, pt, pos, sm_scale=D ** -0.5),
+        spec((B, H, D), dtype), pool, pool,
+        spec((B, P), "int32"), spec((B,), "int32")))
 
 
 @pytest.mark.parametrize("tokens,tile", [(16, 16), (2048, 128)])
@@ -203,11 +221,27 @@ def test_grouped_decode_attention_compiles(for_chip):
     pfa = _mod("paged_flash_attention")
     B, Hq, Hkv, D, page, P = 64, 32, 8, 64, 128, 12
     pool = spec((B * P + 1, page, Hkv, D), "bfloat16")
-    compile_(
+    _named_once(compile_(
         lambda q, k, v, pt, pos: pfa.paged_decode_attention(
             q, k, v, pt, pos, sm_scale=1 / 64),
         spec((B, Hq, D), "bfloat16"), pool, pool, spec((B, P), "int32"),
-        spec((B,), "int32"))
+        spec((B,), "int32")))
+
+
+def test_long_row_decode_attention_compiles(for_chip):
+    """A row longer than a grid step takes: granite's widths at 128 pages
+    a row (16k positions) go in steps of 16 pages, the widest step the
+    tiling gives, under the limit the kernel hands the compiler."""
+    spec, compile_ = for_chip
+    pfa = _mod("paged_flash_attention")
+    B, Hq, Hkv, D, page, P = 8, 32, 8, 64, 128, 128
+    assert pfa._window_tiles(P, page, Hkv, D, 2) == (16, 1)
+    pool = spec((B * P + 1, page, Hkv, D), "bfloat16")
+    _named_once(compile_(
+        lambda q, k, v, pt, pos: pfa.paged_decode_attention(
+            q, k, v, pt, pos, sm_scale=1 / 64),
+        spec((B, Hq, D), "bfloat16"), pool, pool, spec((B, P), "int32"),
+        spec((B,), "int32")))
 
 
 def test_hybrid_chunk_attention_compiles(for_chip):
@@ -224,18 +258,24 @@ def test_hybrid_chunk_attention_compiles(for_chip):
         spec((1,), "int32"), spec((1, C, P * page), "bool"))
 
 
-@pytest.mark.parametrize("window", [1, 2, 4, 16])
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_paged_window_attention_compiles(for_chip, dtype, window):
+@pytest.mark.parametrize("shape,dtype,window", [
+    (SMOKE, dtype, window) for dtype in ("float32", "bfloat16")
+    for window in (1, 2, 4, 16)] + [
+    (SMOKE, "bfloat16", 3), (BIG, "bfloat16", 4), (BIG, "bfloat16", 16)])
+def test_paged_window_attention_compiles(for_chip, shape, dtype, window):
+    """Suffix replay and speculative verification windows: the smoke run's
+    shapes, a window that is no power of two, and transformer-big's
+    published shape at the windows its warm-up compiles."""
     spec, compile_ = for_chip
     pfa = _mod("paged_flash_attention")
-    pool = spec((SLOTS * PAGES_PER_SLOT + 1, PAGE, HEADS, HEAD_DIM), dtype)
-    rows = spec((SLOTS,), "int32")
-    compile_(
+    B, P, page, H, D, pool_pages = shape
+    pool = spec((pool_pages, page, H, D), dtype)
+    rows = spec((B,), "int32")
+    _named_once(compile_(
         lambda q, k, v, pt, off, vl: pfa.paged_window_attention(
-            q, k, v, pt, off, vl, sm_scale=HEAD_DIM ** -0.5),
-        spec((SLOTS, window, HEADS, HEAD_DIM), dtype), pool, pool,
-        spec((SLOTS, PAGES_PER_SLOT), "int32"), rows, rows)
+            q, k, v, pt, off, vl, sm_scale=D ** -0.5),
+        spec((B, window, H, D), dtype), pool, pool,
+        spec((B, P), "int32"), rows, rows))
 
 
 @pytest.mark.parametrize("backward", ["xla", "pallas"])
