@@ -13,6 +13,13 @@ product. In decode 16 rows touch about 82 of 128 experts a layer and only
 those 82 are read; a dense product over all experts with masking reads all
 128.
 
+The router scores by a softmax over all experts (Keye) or by a sigmoid
+with a selection bias that does not weigh and a scale (JoyAI; ``route``).
+A layer may hold a RUN of the router's experts, one chip's share of an
+expert-parallel layer (``moe_experts(held=)``): the router still ranks all
+its outputs, the pairs that fall on experts held elsewhere sort into a sink
+past the held ones, and the sink's tiles are left out of the product.
+
 ``moe_experts`` is the layer (router, grouping, product, combine);
 ``grouped_swiglu`` the product alone, a Pallas kernel on the TPU
 (``MXTPU_FLASH_INTERPRET`` as for every kernel of this package) with
@@ -51,13 +58,28 @@ def f_block(width: int) -> int:
     return width
 
 
-def route(u, router, k):
-    """``(experts (T, k) int32, weights (T, k) float32)``: softmax over all
-    experts in float32, the ``k`` largest, renormalised."""
+def route(u, router, k, scoring="softmax", bias=None, scale=1.0):
+    """``(experts (T, k) int32, weights (T, k) float32)`` of the ``k``
+    experts a token, over all of the router's outputs in float32.
+
+    ``scoring="softmax"``: the ``k`` largest softmax probabilities,
+    renormalised. ``scoring="sigmoid"``: ``s = sigmoid(logits)``; the ``k``
+    largest of ``s + bias`` are chosen (``bias (E,)`` selects and does not
+    weigh), and they weigh ``scale x s / sum of the chosen s``."""
     logits = jnp.dot(u, router, preferred_element_type=jnp.float32)
-    prob = jax.nn.softmax(logits, axis=-1)
-    top, idx = jax.lax.top_k(prob, k)
-    return idx.astype(jnp.int32), top / jnp.sum(top, -1, keepdims=True)
+    if scoring == "softmax":
+        top, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    elif scoring == "sigmoid":
+        score = jax.nn.sigmoid(logits)
+        pick = score if bias is None else score + bias.astype(jnp.float32)
+        idx = jax.lax.top_k(pick, k)[1]
+        top = jnp.take_along_axis(score, idx, axis=-1)
+    else:
+        raise ValueError(f"unknown router scoring {scoring!r}; "
+                         "use softmax/sigmoid")
+    weights = top / jnp.sum(top, -1, keepdims=True)
+    return idx.astype(jnp.int32), weights if scale == 1.0 \
+        else weights * scale
 
 
 def group_by_expert(experts, num_experts, tile):
@@ -190,23 +212,46 @@ def grouped_swiglu(x, tile_expert, n_tiles, w_gate, w_up, w_down, tile):
                                     w_down, tile=tile)
 
 
-def moe_experts(u, router, w_gate, w_up, w_down, k, valid=None):
+def moe_experts(u, router, w_gate, w_up, w_down, k, valid=None,
+                scoring="softmax", bias=None, scale=1.0, held=None):
     """The expert layer on tokens ``u (T, H)``: ``sum_{e in top-k} a_e
-    w_down[e] (silu(u w_gate[e]) * (u w_up[e]))`` with ``a`` the
-    renormalised router probabilities. ``valid (T,)`` marks padding tokens,
-    which are computed and not counted. Returns ``(out (T, H), counts (E,)
-    int32)``: tokens routed to each expert."""
+    w_down[e] (silu(u w_gate[e]) * (u w_up[e]))`` with ``a`` the router's
+    weights (``route``: softmax probabilities renormalised, or sigmoid
+    scores chosen with ``bias`` and scaled by ``scale``). ``valid (T,)``
+    marks padding tokens, which are computed and not counted.
+
+    ``held = (first, n)`` says WHICH experts the weights hold: ``w_gate``,
+    ``w_up``, ``w_down`` are experts ``first .. first + n - 1`` of the
+    router's ``E`` outputs (one chip's share of an expert-parallel layer).
+    The router still ranks all ``E``; a pair that falls on an expert held
+    elsewhere is neither computed nor added, and the result is this
+    share's part of the layer's sum.
+
+    Returns ``(out (T, H), counts)``: tokens routed to each expert, ``(E,)``
+    int32, over the router's whole width."""
     T, H = u.shape
     E = router.shape[1]
-    experts, weights = route(u, router, k)
-    tile = row_tile(T * k, E)
-    dest, src_token, tile_expert, n_tiles, counts = group_by_expert(
-        experts, E, tile)
+    experts, weights = route(u, router, k, scoring, bias, scale)
+    local, n = experts, E
+    if held is not None:
+        first, n = held
+        inside = jnp.logical_and(experts >= first, experts < first + n)
+        weights = jnp.where(inside, weights, 0.0)
+        local = jnp.where(inside, experts - first, n)   # a sink, sorted last
+    tile = row_tile(T * k, n)
+    dest, src_token, tile_expert, n_tiles, grouped = group_by_expert(
+        local, n + (held is not None), tile)
+    if held is not None:
+        # the sink's tiles lie last: they are left out of the product
+        n_tiles = n_tiles - (grouped[n] + tile - 1) // tile
+        tile_expert = jnp.minimum(tile_expert, jnp.minimum(
+            tile_expert[jnp.maximum(n_tiles[0] - 1, 0)], n - 1))
     y = grouped_swiglu(u[src_token], tile_expert, n_tiles, w_gate, w_up,
                        w_down, tile)
     picked = y[dest.reshape(T * k)].reshape(T, k, H).astype(jnp.float32)
     out = jnp.einsum("tkh,tk->th", picked, weights).astype(u.dtype)
-    if valid is not None:
-        counts = jnp.zeros((E,), jnp.int32).at[experts.reshape(T * k)].add(
-            jnp.repeat(valid.astype(jnp.int32), k))
+    if valid is None and held is None:
+        return out, grouped
+    counts = jnp.zeros((E,), jnp.int32).at[experts.reshape(T * k)].add(
+        1 if valid is None else jnp.repeat(valid.astype(jnp.int32), k))
     return out, counts

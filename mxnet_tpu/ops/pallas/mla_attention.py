@@ -1,0 +1,297 @@
+"""Latent attention kernels: Pallas kernels over a cache that holds ONE
+vector a token a layer, ``[c (rank); k_r (rope)]``, for every head.
+
+Multi-head latent attention keeps, for each cached position, the normed
+latent ``c`` from which every head's key and value are linear maps, and one
+rotary key ``k_r`` that all heads share. Two kernels, one for each way of
+reading that cache:
+
+- ``mla_latent_decode`` (``%mla_latent_decode``), the ABSORBED form: the
+  up-projections are folded into the query and the output, so all heads of
+  all query positions of a row are rows of ONE product against the latent
+  pages, read in place through the page table. A key is the ``rank +
+  rope`` numbers of a position and its value is the first ``rank`` of them:
+  one read of a page serves both products. The body is ``%paged_window``'s:
+  a grid step takes several pages (each its own operand), computes them in
+  blocks under ``pl.when``, and carries the online softmax in VMEM.
+- ``mla_prefill`` (``%mla_prefill``), the EXPANDED form for a chunk of
+  queries: keys ``[k_nope_h; k_r]`` and values per head, expanded once from
+  the latent by the caller (a third of the absorbed form's operations at a
+  chunk's widths). The score is the sum of two products, ``q_nope . k_nope``
+  and ``q_rope . k_r``, because the score's width (nope + rope) is not the
+  value's; the expanded keys and values are read as column blocks of the
+  ``(L, heads x (nope + v))`` array the expansion writes, no relayout.
+
+Queries come in scaled. Each has its dense ``jax.numpy`` form in
+``ops/mla.py``, which stands on the CPU and under a multi-device mesh.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import _use_interpret
+from .flash_attention import _NEG_INF
+
+__all__ = ["mla_latent_decode", "mla_prefill", "decode_tiles",
+           "prefill_tiles"]
+
+_LANES = 128
+# bytes of latent pages a grid step of the decode kernel takes, and keys a
+# block of its softmax carry (``%paged_window``'s sizes, PERF.md, PR 32: a
+# step of 2 MiB keeps the pipeline's two buffers small and a row of 16k
+# positions in eleven steps)
+_DECODE_STEP_BYTES = 2 * 1024 * 1024
+_DECODE_BLOCK_KEYS = 512
+_VMEM_LIMIT = 32 * 1024 * 1024
+
+
+def _prec(dtype):
+    # a process-wide "highest" matmul precision (the float32 parity tests
+    # set it) is not one Mosaic takes for bfloat16 operands
+    return jax.lax.Precision.DEFAULT if dtype == jnp.bfloat16 else None
+
+
+def _softmax_step(s, v, m_ref, l_ref, acc_ref):
+    """One block of the online softmax: scores ``s (rows, cols)`` float32
+    (masked already), values ``v (cols, D)``. The denominator stays a sum
+    a lane until the last block: adding lane groups is elementwise, a sum a
+    row is not."""
+    lw = l_ref.shape[-1]
+    m_prev = m_ref[...]                            # (rows, LANES)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new[:, :1])
+    l_new = alpha[:, :lw] * l_ref[...]
+    for c in range(s.shape[1] // lw):
+        l_new = l_new + p[:, c * lw:(c + 1) * lw]
+    l_ref[...] = l_new
+    acc_ref[...] = acc_ref[...] * alpha[:, :1] + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=_prec(v.dtype))
+    m_ref[...] = m_new
+
+
+def _init(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _nt(a, b):
+    """``a (m, d) . b (n, d)^T`` in float32."""
+    return jax.lax.dot_general(
+        a.astype(b.dtype), b, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=_prec(b.dtype))
+
+
+# ------------------------------------------------------- decode (absorbed)
+def decode_tiles(P, page_size, width, itemsize):
+    """``(pages a grid step, pages a block)`` of the decode kernel, from
+    the shapes alone: pages of 128 positions of 640 numbers in bfloat16 go
+    12 a step (2 MiB) in blocks of 4 (512 keys)."""
+    page_bytes = page_size * width * itemsize
+    pages = max(1, min(P, _DECODE_STEP_BYTES // page_bytes))
+    return pages, max(1, min(pages, _DECODE_BLOCK_KEYS // page_size))
+
+
+def _latent_decode_kernel(pt_ref, pos_ref, qc_ref, qr_ref, *refs, page_size,
+                          pages, block, heads, rank):
+    """Grid (B, steps), steps sequential per row. The ``S x heads`` query
+    rows (query ``r // heads``, at position ``pos + r // heads``) meet the
+    block's keys in one product: the latent part against the first ``rank``
+    columns of a page, the rotary part against the rest; the second product
+    takes the same first ``rank`` columns as values."""
+    page_refs = refs[:pages]
+    o_ref, m_ref, l_ref, acc_ref = refs[pages:]
+    b, j = pl.program_id(0), pl.program_id(1)
+    rows = qc_ref.shape[1]
+
+    pl.when(j == 0)(functools.partial(_init, m_ref, l_ref, acc_ref))
+
+    off = pos_ref[b]
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    q_abs = off + row // heads
+    last = off + rows // heads - 1      # what the row's last query sees
+
+    def accumulate(blk, first_key):
+        lat = blk[0][0] if len(blk) == 1 else \
+            jnp.concatenate([r[0] for r in blk], axis=0)   # (cols, W)
+        c, kr = lat[:, :rank], lat[:, rank:]
+        s = _nt(qc_ref[0], c) + _nt(qr_ref[0], kr)         # (rows, cols)
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, s.shape[1]), 1)
+        s = jnp.where(first_key + col <= q_abs, s, _NEG_INF)
+        _softmax_step(s, c, m_ref, l_ref, acc_ref)
+
+    for lo in range(0, pages, block):
+        hi = min(pages, lo + block)
+        first_key = (j * pages + lo) * page_size
+        pl.when(first_key <= last)(functools.partial(
+            accumulate, page_refs[lo:hi], first_key))
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        l = jnp.maximum(jnp.sum(l_ref[...], axis=1, keepdims=True), 1e-30)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "pages", "block",
+                                             "interpret"))
+def _mla_latent_decode_impl(qc, qr, pool, page_table, pos, rank, pages,
+                            block, interpret):
+    B, S, H, _ = qc.shape
+    N, ps, W = pool.shape
+    P = page_table.shape[1]
+    rows = S * H
+
+    def page(t):
+        # page t of step j, if the row's last query sees it; else the page
+        # this operand held the step before (nothing is copied), and in
+        # step 0 pool page 0, masked whatever it holds
+        def index(b, j, pt, off):
+            last = jnp.clip((off[b] + S - 1) // ps, 0, P - 1)
+            jj = jnp.minimum(j, jnp.maximum(last - t, 0) // pages)
+            at = jnp.minimum(jj * pages + t, P - 1)
+            return (jnp.where(t <= last, pt[b, at], 0), 0, 0)
+        return index
+
+    def row_spec(width):
+        return pl.BlockSpec((1, rows, width), lambda b, j, pt, off: (b, 0, 0))
+
+    kernel = functools.partial(_latent_decode_kernel, page_size=ps,
+                               pages=pages, block=block, heads=H, rank=rank)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, pl.cdiv(P, pages)),
+            in_specs=[row_spec(rank), row_spec(W - rank)]
+            + [pl.BlockSpec((1, ps, W), page(t)) for t in range(pages)],
+            out_specs=row_spec(rank),
+            scratch_shapes=[
+                pltpu.VMEM((rows, _LANES), jnp.float32),
+                pltpu.VMEM((rows, math.gcd(ps, _LANES)), jnp.float32),
+                pltpu.VMEM((rows, rank), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, rows, rank), qc.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name="mla_latent_decode",
+    )(page_table.astype(jnp.int32), pos.astype(jnp.int32),
+      qc.reshape(B, rows, rank), qr.reshape(B, rows, W - rank),
+      *([pool] * pages))
+    return out.reshape(B, S, H, rank)
+
+
+def mla_latent_decode(qc, qr, pool, page_table, pos):
+    """Absorbed latent attention of ``S`` query positions a row, the pool
+    read in place. ``qc (B, S, H, rank)`` (the query through the key
+    up-projection) and ``qr (B, S, H, rope)``, both scaled; ``pool
+    (num_pages, page, rank + rope)``; query ``i`` of row ``b`` sits at
+    ``pos[b] + i`` and reads the positions up to its own, which the caller
+    has written. Returns the weighted latents ``(B, S, H, rank)``."""
+    rank = qc.shape[-1]
+    pages, block = decode_tiles(page_table.shape[1], pool.shape[1],
+                                pool.shape[2], pool.dtype.itemsize)
+    return _mla_latent_decode_impl(qc, qr, pool, page_table, pos, rank=rank,
+                                   pages=pages, block=block,
+                                   interpret=_use_interpret())
+
+
+# ------------------------------------------------------ prefill (expanded)
+def prefill_tiles(C, L):
+    """``(queries, keys)`` a grid step of the prefill kernel: 1,024 queries
+    where they divide the chunk (the expanded keys are read once a query
+    block), 512 keys where they divide the expanded length."""
+    tq = next((t for t in (1024, 512, 256, 128) if C % t == 0), C)
+    tk = next((t for t in (512, 256, 128) if L % t == 0), L)
+    return tq, tk
+
+
+def _prefill_kernel(off_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
+                    m_ref, l_ref, acc_ref, *, tq, tk):
+    """Grid (R, heads, query blocks, key blocks), key blocks sequential. A
+    step is one head's ``tq`` queries against ``tk`` expanded keys; blocks
+    past the last one the query block sees are neither fetched nor
+    computed."""
+    r, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    off = off_ref[r]
+
+    pl.when(j == 0)(functools.partial(_init, m_ref, l_ref, acc_ref))
+
+    @pl.when(j * tk <= off + (i + 1) * tq - 1)
+    def _accumulate():
+        s = _nt(qn_ref[0, 0], kn_ref[0]) + _nt(qr_ref[0, 0], kr_ref[0])
+        q_pos = off + i * tq + jax.lax.broadcasted_iota(
+            jnp.int32, (tq, 1), 0)
+        k_pos = j * tk + jax.lax.broadcasted_iota(jnp.int32, (1, tk), 1)
+        s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
+        _softmax_step(s, v_ref[0], m_ref, l_ref, acc_ref)
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _finalize():
+        l = jnp.maximum(jnp.sum(l_ref[...], axis=1, keepdims=True), 1e-30)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("tq", "tk", "interpret"))
+def _mla_prefill_impl(qn, qr, kv, kr, q_offset, tq, tk, interpret):
+    R, H, C, D = qn.shape
+    L, rope = kr.shape[1], kr.shape[2]
+
+    def key_block(r, i, j, off):        # an unseen block re-reads nothing
+        return jnp.minimum(j, (off[r] + (i + 1) * tq - 1) // tk)
+
+    kernel = functools.partial(_prefill_kernel, tq=tq, tk=tk)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(R, H, C // tq, L // tk),
+            in_specs=[
+                pl.BlockSpec((1, 1, tq, D),
+                             lambda r, h, i, j, off: (r, h, i, 0)),
+                pl.BlockSpec((1, 1, tq, rope),
+                             lambda r, h, i, j, off: (r, h, i, 0)),
+                # head h's keys and values: column blocks 2h and 2h + 1
+                pl.BlockSpec((1, tk, D), lambda r, h, i, j, off: (
+                    r, key_block(r, i, j, off), 2 * h)),
+                pl.BlockSpec((1, tk, rope), lambda r, h, i, j, off: (
+                    r, key_block(r, i, j, off), 0)),
+                pl.BlockSpec((1, tk, D), lambda r, h, i, j, off: (
+                    r, key_block(r, i, j, off), 2 * h + 1))],
+            out_specs=pl.BlockSpec((1, tq, D),
+                                   lambda r, h, i, j, off: (r, i, h)),
+            scratch_shapes=[
+                pltpu.VMEM((tq, _LANES), jnp.float32),
+                pltpu.VMEM((tq, math.gcd(tk, _LANES)), jnp.float32),
+                pltpu.VMEM((tq, D), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((R, C, H * D), qn.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name="mla_prefill",
+    )(q_offset.astype(jnp.int32), qn, qr, kv, kr, kv)
+
+
+def mla_prefill(qn, qr, kv, kr, q_offset):
+    """Expanded latent attention of a chunk: ``qn (R, H, C, D)`` and ``qr
+    (R, H, C, rope)``, both scaled, query ``i`` of row ``r`` at
+    ``q_offset[r] + i``; ``kv (R, L, H x 2D)`` the expanded keys and values,
+    head ``h``'s keys in columns ``[2hD, 2hD + D)`` and its values in the
+    ``D`` after them (the value's width is the key's nope width); ``kr (R,
+    L, rope)`` the one rotary key a position. Causal: a query reads the
+    positions up to its own. ``L`` is a multiple of the key block and ``C``
+    of the query block (``prefill_tiles``). Returns ``(R, C, H x D)``."""
+    tq, tk = prefill_tiles(qn.shape[2], kr.shape[1])
+    return _mla_prefill_impl(qn, qr, kv, kr, q_offset, tq=tq, tk=tk,
+                             interpret=_use_interpret())

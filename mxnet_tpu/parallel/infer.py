@@ -242,7 +242,10 @@ class InferStep:
         - ``pools``: the names of the PAGED arrays (a tuple of arrays
           under each name, one for each layer that keeps them; a net may
           keep them for some of its layers only), all under the one page
-          table: they grow with the context, a page at a time.
+          table: they grow with the context, a page at a time. What a
+          page holds is the net's: K and V by head (the default), K, V
+          and an indexer's keys, or ONE latent vector a token with no
+          head axis (``latent_pools``).
         - ``encoder_memory``: whether the slot also holds static ENCODER
           memory (per-slot ``cross_k`` / ``cross_v`` buffers and
           ``mem_vl``), of the largest bucket's width.
@@ -255,16 +258,26 @@ class InferStep:
           reset; its decode step leaves the arrays of rows that are not
           ``active`` as they are.
 
+        ``step_tokens`` is the most tokens a decode step yields a row: 1,
+        or 2 for a net that drafts the token after next itself and
+        verifies it in the next step. Its ``decode_step_paged`` hands back
+        ``(logits (B, 2, vocab), draft (B,))``; ``decode_iter`` then
+        returns four columns a step, ``[token, second token, count,
+        draft]``, the COUNT A ROW beside the tokens it counts, and the
+        scheduler moves each row by its count.
+
         ``counts`` names the device-side counts a net adds to
         ``state["counts"]``, ``(name, length)`` in order
-        (``_take_counts``). A net that declares nothing is an
-        encoder-decoder: K and V pools, encoder memory, no slot arrays,
-        no counts. The batcher builds cross buffers, valid lengths and
+        (``_take_counts``); ``mtp_drafts`` and ``mtp_accepted``, where a
+        net names them, are added by the decode burst itself. A net that
+        declares nothing is an encoder-decoder: K and V pools, encoder
+        memory, no slot arrays, no counts, one token a step. The batcher
+        builds cross buffers, valid lengths and
         the cross-frame store only where ``encoder_memory`` is true;
         without it the prompt lives in the pages and enters in
         chunks."""
         return {"pools": ("k_pools", "v_pools"), "encoder_memory": True,
-                "slot_arrays": (), "counts": (),
+                "slot_arrays": (), "counts": (), "step_tokens": 1,
                 **(getattr(self._net, "paged_slot_state", None) or {})}
 
     def _need_encoder_memory(self, what: str):
@@ -597,6 +610,11 @@ class InferStep:
         if fn is not None:
             return fn
         net, eos, pad = self._net, self._eos, self._pad
+        if self.slot_state["step_tokens"] == 2:
+            fn = jax.jit(self._decode_two(steps, method, top_k),
+                         donate_argnums=(1,))
+            self._paged_fns[cfg] = fn
+            return fn
 
         def decode(values, state, page_tables, tokens, lengths, active,
                    seed, temperature):
@@ -629,6 +647,62 @@ class InferStep:
         fn = jax.jit(decode, donate_argnums=(1,))
         self._paged_fns[cfg] = fn
         return fn
+
+    def _decode_two(self, steps, method, top_k):
+        """The decode burst of a net whose step yields up to two tokens a
+        row (``slot_state["step_tokens"]`` 2). A step feeds the row's last
+        token and the net's own draft of the next at positions ``[p, p +
+        1]``; ``g0`` and ``g1`` are the tokens at the two. Where the draft
+        IS ``g0`` (greedy sampling alone: any other method keeps one token
+        a step) the row yields ``g0, g1`` and moves two positions, else
+        ``g0`` and one, and the next step overwrites what this one cached
+        at ``p + 1``. Every token handed back is the model's own at its
+        position whatever the draft. Four columns a step: ``[g0, g1 or
+        pad, count, draft]``."""
+        net, eos, pad = self._net, self._eos, self._pad
+        names = [n for n, _ in self.slot_state["counts"]]
+        at = [names.index(n) if n in names else None
+              for n in ("mtp_drafts", "mtp_accepted")]
+
+        def decode(values, state, page_tables, tokens, lengths, active,
+                   seed, temperature):
+            B = tokens.shape[0]
+            buf = jnp.full((B, steps, 4), pad, jnp.int32)
+
+            def body(j, c):
+                tok, pos, fin, st, k, bf = c
+                live = jnp.logical_not(fin)
+                with self._net_scope(values, jax.random.PRNGKey(0)):
+                    (logits, draft), st = net.decode_step_paged(
+                        NDArray(tok), pos, st, page_tables, live)
+                logits = logits.astype(jnp.float32)
+                k, sk = jax.random.split(k)
+                g0 = _sample_tokens(logits[:, 0], sk, method, top_k,
+                                    temperature)
+                g1 = jnp.argmax(logits[:, 1], axis=-1).astype(jnp.int32)
+                took = jnp.logical_and(live, jnp.logical_and(
+                    draft == g0, g0 != eos)) if method == "greedy" \
+                    else jnp.zeros_like(live)
+                count = live.astype(jnp.int32) + took
+                row = jnp.stack([jnp.where(live, g0, pad),
+                                 jnp.where(took, g1, pad), count, draft], 1)
+                bf = jax.lax.dynamic_update_slice(bf, row[:, None],
+                                                  (0, j, 0))
+                fin = jnp.logical_or(fin, jnp.logical_or(
+                    g0 == eos, jnp.logical_and(took, g1 == eos)))
+                if None not in at and method == "greedy":
+                    counts = st["counts"].at[at[0]].add(jnp.sum(live)) \
+                        .at[at[1]].add(jnp.sum(took))
+                    st = dict(st, counts=counts)
+                return jnp.where(took, g1, g0), pos + count, fin, st, k, bf
+
+            _, _, _, state, _, buf = jax.lax.fori_loop(
+                0, steps, body,
+                (tokens, lengths, jnp.logical_not(active), state,
+                 jax.random.PRNGKey(seed), buf))
+            return _take_counts(buf.reshape(B, steps * 4), state)
+
+        return decode
 
     @staticmethod
     def _paged_cfg(method, top_k, seed, steps=1):
@@ -754,7 +828,9 @@ class InferStep:
         ``seed`` is an integer operand, the key is made in the program
         (``prefill_paged`` says how a seed past int32 folds). Sync-free
         by lint — the scheduler's collect phase is the sync point.
-        Returns ``(tok_block (slots, steps) NDArray, new_state)``."""
+        Returns ``(tok_block (slots, steps) NDArray, new_state)``; for a
+        net whose step yields up to two tokens a row, four columns a step
+        (``_decode_two``)."""
         page_tables, tokens, lengths = self._operands(
             np.int32, page_tables, tokens, lengths)
         active, = self._operands(np.bool_, active)

@@ -128,12 +128,15 @@ class GenerationResult:
     final iteration) and ``replica`` which
     engine replica ran it (router). ``first_token_at`` is the
     ``perf_counter`` instant of the first streamed token (TTFT =
-    ``first_token_at - enqueued_at``)."""
+    ``first_token_at - enqueued_at``). ``drafts``, for a net that drafts
+    the token after next itself, lists ``(j, token)``: what its module
+    proposed for generated token ``j`` (kept only where it WAS token
+    ``j``; the tokens are the model's own either way)."""
 
     __slots__ = ("_event", "_tokens", "_error", "enqueued_at",
                  "queue_wait_ms", "weights_version", "replica",
                  "_cond", "_stream", "first_token_at",
-                 "request_id", "phases")
+                 "request_id", "phases", "drafts")
 
     def __init__(self):
         self._event = threading.Event()
@@ -153,6 +156,7 @@ class GenerationResult:
         # observed end-to-end latency exactly
         self.request_id = None
         self.phases = None
+        self.drafts = None
 
     def _stream_tokens(self, tokens):
         """Append newly emitted tokens to the live stream (scheduler
@@ -251,7 +255,7 @@ class _Slot:
 
     __slots__ = ("req", "carry", "length", "emitted", "finished",
                  "admitted_seq", "version", "active_at", "base",
-                 "entered", "admitted_at")
+                 "entered", "admitted_at", "drafts")
 
     def __init__(self, req, admitted_seq):
         self.req = req
@@ -271,6 +275,7 @@ class _Slot:
         # prompt is encoder memory. The slot decodes once all are in
         self.entered = None
         self.admitted_at = None
+        self.drafts = []         # (index of the generated token, draft)
 
     @property
     def decoding(self) -> bool:
@@ -295,8 +300,12 @@ class ContinuousBatcher:
     max_new_tokens : per-request generation cap (requests may ask less).
     page_size / num_pages : KV pool geometry (``MXTPU_PAGE_SIZE`` /
         ``MXTPU_PAGES``; default pool fully provisions every slot).
-    iter_tokens : decode tokens per iteration (``MXTPU_ITER_TOKENS``);
-        1 = pure Orca-style per-token scheduling.
+    iter_tokens : decode steps per iteration (``MXTPU_ITER_TOKENS``);
+        1 = pure Orca-style per-token scheduling. A step yields one token
+        a row, or up to ``InferStep.slot_state["step_tokens"]`` for a net
+        that drafts the next one itself: the count a row rides the token
+        block's read-back, and lengths, pages and ``max_new_tokens``
+        follow it.
     admit_free_pages / admit_max_queue / admit_max_wait_ms : backpressure
         thresholds (``MXTPU_ADMIT_*``): keep N pages free, bound the
         queue depth, reject while rolling queue-wait p50 breaches.
@@ -413,8 +422,13 @@ class ContinuousBatcher:
                                                 "greedy") == "greedy")
         self.spec_wide = bool(spec_wide)
         self.suffix_wide = bool(suffix_wide)
-        # the one seam: what a slot keeps is the net's to declare
+        # the one seam: what a slot keeps is the net's to declare, and
+        # how many tokens a step of its decode program may yield a row
         self._enc_mem = bool(engine.slot_state["encoder_memory"])
+        self._step_tokens = int(engine.slot_state["step_tokens"])
+        # columns of the token block a step: the token, or [token, second
+        # token, count, draft]
+        self._step_cols = 1 if self._step_tokens == 1 else 4
         if self._enc_mem:
             self.chunk = None
             cached = 1 + self.max_prefix + self.max_new \
@@ -1068,6 +1082,8 @@ class ContinuousBatcher:
                                        "tokens": len(s.emitted)},
                                       request_id=r.future.request_id,
                                       end_us=_evus(now))
+                if s.drafts:
+                    r.future.drafts = list(s.drafts)
                 r.future._resolve(list(s.emitted))
             with self._stats_lock:
                 self.stats["retired"] += 1
@@ -1754,13 +1770,16 @@ class ContinuousBatcher:
             # its allocation the device's surplus burst steps land in the
             # trash page, so the cap is safe. A speculative round writes
             # up to spec_k entries ahead and ACCEPTED entries must land
-            # in real pages, so the cap stretches by spec_k too.
+            # in real pages, so the cap stretches by spec_k too. A net
+            # whose step yields up to two tokens writes two positions a
+            # step; the one a refused draft leaves past the cap is never
+            # read.
             base = s.base
             if self._spec_on:
                 grow = self.spec_k + 1
                 cap = base + s.req.max_new + self.spec_k
             else:
-                grow = self.iter_tokens
+                grow = self.iter_tokens * self._step_tokens
                 cap = base + s.req.max_new
             upto = min(s.length + grow, cap)
             while not self.pool.ensure(i, upto):
@@ -1852,7 +1871,8 @@ class ContinuousBatcher:
         with _tel.phase("sched.collect.readback", acc, "readback_s"):
             toks = buf.asnumpy()
         if self._count_fields:
-            self._note_counts("decode", toks[:, self.iter_tokens:].ravel())
+            self._note_counts("decode", toks[
+                :, self.iter_tokens * self._step_cols:].ravel())
         iter_ms = (time.perf_counter() - t0) * 1e3
         with _tel.phase("sched.collect", acc, "collect_s"):
             reg = _tel.registry()
@@ -1872,11 +1892,17 @@ class ContinuousBatcher:
                     burst = int(toks[i, self.spec_k + 1])
                     reg.histogram("infer/spec_accept_len").observe(
                         max(burst - 1, 0))
+                elif self._step_tokens > 1:
+                    fresh = self._take_steps(s, toks[i], eos)
+                    burst = 0
                 else:
                     burst = self.iter_tokens
                 for j in range(burst):
                     tok = int(toks[i, j])
-                    s.length += 1  # this step cached the previous carry
+                    # this step cached the previous carry (a step of two
+                    # positions, ``_take_steps``: the carry, and its
+                    # draft where the draft was kept)
+                    s.length += 1
                     s.carry = tok
                     fresh.append(tok)
                     if tok == eos or len(s.emitted) + len(fresh) \
@@ -1908,6 +1934,32 @@ class ContinuousBatcher:
                 wd.notify_step(seconds=iter_ms / 1e3)
                 wd.note_request(inflight=len(live) + len(self._pending))
 
+    def _take_steps(self, s, row, eos):
+        """A row of the token block of a net whose step yields up to two
+        tokens: four columns a step, ``[token, second token, count,
+        draft]``. The row moves ``count`` positions a step (the step
+        cached its carry, and the draft too where the draft was the
+        token); ``max_new_tokens`` cuts a second token that would pass
+        it. Returns the tokens to stream."""
+        fresh, cols = [], self._step_cols
+        hist = _tel.registry().histogram("infer/mtp_accept_len")
+        for j in range(self.iter_tokens):
+            first, second, count, draft = (
+                int(t) for t in row[cols * j:cols * (j + 1)])
+            if count < 1:
+                break       # the burst stopped this row (an end token)
+            s.drafts.append((len(s.emitted) + len(fresh), draft))
+            hist.observe(count - 1)
+            for tok in (first, second)[:count]:
+                s.length += 1
+                s.carry = tok
+                fresh.append(tok)
+                if tok == eos or len(s.emitted) + len(fresh) \
+                        >= s.req.max_new:
+                    s.finished = True
+                    return fresh
+        return fresh
+
     def _poison(self, err):
         """A decode dispatch failed: the donated pool state is gone, so
         fail every in-flight request, rebuild the pools, and keep the
@@ -1938,6 +1990,16 @@ class ContinuousBatcher:
                              "running scheduler donates the arrays")
         return {name: tuple(self._state[name])
                 for name in self._engine.slot_state["slot_arrays"]}
+
+    def paged_state(self) -> dict:
+        """The device state as it stands (pools, slot arrays, counts): for
+        checks that drive the engine's programs by hand on what the
+        scheduler left, on a STOPPED batcher only. A program the caller
+        hands it to donates it: the batcher cannot serve afterwards."""
+        if self._thread is not None:
+            raise MXNetError("paged_state() reads a stopped batcher: a "
+                             "running scheduler donates the state")
+        return self._state
 
     @property
     def sustained_occupancy(self) -> float:

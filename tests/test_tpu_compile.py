@@ -357,3 +357,64 @@ def test_setup_leaves_cache_dir_to_jax_when_placed_from_outside(
     assert calls == ["jax_compilation_cache_dir"]
     assert compile_cache.cache_dir() == os.path.join(
         REPO_ROOT, ".mxtpu_cache", "xla")
+
+
+# ---- joyai-llm-flash's latent attention at the published widths (PR 33):
+# 32 heads, a latent of 512 with one rotary key of 64 a position in a row of
+# 640 (whole lanes), pages of 128 positions, 130 pages a row
+def _named(compiled, name, calls=1):
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == calls
+    assert name in text
+
+
+@pytest.mark.parametrize("rows,positions", [(40, 2), (1, 1)])
+def test_latent_decode_attention_compiles(for_chip, rows, positions):
+    """The absorbed decode kernel: 40 rows of two query positions of 32
+    heads (64 query rows) over latent pages of 640 numbers a position, 12
+    pages a grid step in blocks of 4; and one row of one position."""
+    spec, compile_ = for_chip
+    mla = _mod("mla_attention")
+    H, rank, rope, page, P = 32, 512, 128, 128, 130
+    assert mla.decode_tiles(P, page, rank + rope, 2) == (12, 4)
+    _named(compile_(
+        mla.mla_latent_decode,
+        spec((rows, positions, H, rank), "bfloat16"),
+        spec((rows, positions, H, rope), "bfloat16"),
+        spec((40 * P + 1, page, rank + rope), "bfloat16"),
+        spec((rows, P), "int32"), spec((rows,), "int32")),
+        "%mla_latent_decode")
+
+
+def test_latent_prefill_attention_compiles(for_chip):
+    """The expanded chunk kernel: 2,048 queries of 32 heads of 128 + 64
+    against 16,896 expanded positions (130 pages rounded up to whole key
+    blocks), 1,024 queries by 512 keys a grid step, a head's keys and
+    values read as column blocks of the expansion."""
+    spec, compile_ = for_chip
+    mla = _mod("mla_attention")
+    H, D, rope, C, L = 32, 128, 128, 2048, 16896
+    assert mla.prefill_tiles(C, L) == (1024, 512)
+    _named(compile_(
+        mla.mla_prefill,
+        spec((1, H, C, D), "bfloat16"), spec((1, H, C, rope), "bfloat16"),
+        spec((1, L, H * 2 * D), "bfloat16"), spec((1, L, rope), "bfloat16"),
+        spec((1,), "int32")), "%mla_prefill")
+
+
+def test_held_experts_product_compiles(for_chip):
+    """The grouped expert product over ONE CHIP'S SHARE, 16 experts held of
+    256: a decode step's 80 tokens (row tile 16) and a chunk's 2,048 (row
+    tile 128), the sink of the pairs held elsewhere sorted last."""
+    spec, compile_ = for_chip
+    gs = _mod("grouped_swiglu")
+    E, n, H, F, k = 256, 16, 2048, 768, 8
+    for tokens, tile in ((80, 16), (2048, 128)):
+        assert gs.row_tile(tokens * k, n) == tile
+        compile_(
+            lambda u, r, b, wg, wu, wd: gs.moe_experts(
+                u, r, wg, wu, wd, k, scoring="sigmoid", bias=b, scale=2.5,
+                held=(0, n))[0],
+            spec((tokens, H), "bfloat16"), spec((H, E), "bfloat16"),
+            spec((E,), "bfloat16"), spec((n, H, F), "bfloat16"),
+            spec((n, H, F), "bfloat16"), spec((n, F, H), "bfloat16"))
