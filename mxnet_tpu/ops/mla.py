@@ -17,8 +17,9 @@ Two orders of the same sums, as the serving plane has them:
 - DECODE, ABSORBED: ``q'_h = W^K_h^T q_nope_h`` is ``rank`` wide, every
   head of every query position is a row of one product against the latent
   pages, the weighted latents come back and ``W^V_h`` is applied after
-  (``decode_attention``; ``%mla_latent_decode`` on the TPU). A step reads
-  ``rank + rope`` numbers a cached position whatever the head count.
+  (``decode_attention``; ``%mla_latent_decode`` on the TPU, which copies
+  a row's live pages itself, a block behind the two products). A step
+  reads ``rank + rope`` numbers a cached position whatever the head count.
 
 The ``jax.numpy`` forms below stand on the CPU and under a multi-device
 mesh (``flash_paged_enabled``); softmax and scores are float32 everywhere.

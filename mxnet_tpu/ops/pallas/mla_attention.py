@@ -11,9 +11,13 @@ reading that cache:
   all query positions of a row are rows of ONE product against the latent
   pages, read in place through the page table. A key is the ``rank +
   rope`` numbers of a position and its value is the first ``rank`` of them:
-  one read of a page serves both products. The body is ``%paged_window``'s:
-  a grid step takes several pages (each its own operand), computes them in
-  blocks under ``pl.when``, and carries the online softmax in VMEM.
+  one read of a page serves both products. The kernel takes the pool as
+  ONE operand left in HBM and issues its own copies through the page table:
+  a grid step is a row, which walks its LIVE pages only, a block of pages
+  at a time into one half of a VMEM scratch while the other half is
+  computed; a row's last block starts the next live row's first, and each
+  page lands at its own row offset, so both products read the block in
+  place. The online softmax is carried in VMEM.
 - ``mla_prefill`` (``%mla_prefill``), the EXPANDED form for a chunk of
   queries: keys ``[k_nope_h; k_r]`` and values per head, expanded once from
   the latent by the caller (a third of the absorbed form's operations at a
@@ -43,12 +47,11 @@ __all__ = ["mla_latent_decode", "mla_prefill", "decode_tiles",
            "prefill_tiles"]
 
 _LANES = 128
-# bytes of latent pages a grid step of the decode kernel takes, and keys a
-# block of its softmax carry (``%paged_window``'s sizes, PERF.md, PR 32: a
-# step of 2 MiB keeps the pipeline's two buffers small and a row of 16k
-# positions in eleven steps)
-_DECODE_STEP_BYTES = 2 * 1024 * 1024
-_DECODE_BLOCK_KEYS = 512
+# keys a block of the decode kernel: one buffer of its scratch, one step of
+# its softmax carry (PERF.md, PR 34: of 2, 4, 8 and 16 pages of 128 a block
+# the call read 1.00, 0.72, 0.61 and 0.61 ms where its copies alone take
+# 0.58; under 8 the loop's own work on a block's copies shows)
+_DECODE_BLOCK_KEYS = 1024
 _VMEM_LIMIT = 32 * 1024 * 1024
 
 
@@ -92,101 +95,130 @@ def _nt(a, b):
 
 
 # ------------------------------------------------------- decode (absorbed)
-def decode_tiles(P, page_size, width, itemsize):
-    """``(pages a grid step, pages a block)`` of the decode kernel, from
-    the shapes alone: pages of 128 positions of 640 numbers in bfloat16 go
-    12 a step (2 MiB) in blocks of 4 (512 keys)."""
-    page_bytes = page_size * width * itemsize
-    pages = max(1, min(P, _DECODE_STEP_BYTES // page_bytes))
-    return pages, max(1, min(pages, _DECODE_BLOCK_KEYS // page_size))
+def decode_tiles(P, page_size):
+    """Pages a block of the decode kernel, from the shapes alone: 1,024
+    keys, so pages of 128 positions go 8 a block, and never more than a
+    row's table has. A block is what one buffer of the kernel's scratch
+    holds, one step of its softmax carry and the grain of its copies."""
+    return max(1, min(P, _DECODE_BLOCK_KEYS // page_size))
 
 
-def _latent_decode_kernel(pt_ref, pos_ref, qc_ref, qr_ref, *refs, page_size,
-                          pages, block, heads, rank):
-    """Grid (B, steps), steps sequential per row. The ``S x heads`` query
-    rows (query ``r // heads``, at position ``pos + r // heads``) meet the
-    block's keys in one product: the latent part against the first ``rank``
-    columns of a page, the rotary part against the rest; the second product
-    takes the same first ``rank`` columns as values."""
-    page_refs = refs[:pages]
-    o_ref, m_ref, l_ref, acc_ref = refs[pages:]
-    b, j = pl.program_id(0), pl.program_id(1)
+def _latent_decode_kernel(pt_ref, pos_ref, qc_ref, qr_ref, pool_ref, o_ref,
+                          buf_ref, sem_ref, slot_ref, m_ref, l_ref, acc_ref,
+                          *, block, heads, rank):
+    """Grid (B,), a row a step, sequential: the scratch, its semaphores and
+    the slot carry over from row to row. ``pool_ref`` is the whole pool in
+    HBM; block ``k`` of a row is its pages ``k x block ...``, each copied to
+    its own row offset of ``buf_ref[slot]``, so that both products read the
+    block in place. The ``S x heads`` query rows (query ``r // heads``, at
+    position ``pos + r // heads``) meet the block's keys in one product:
+    the latent part against the first ``rank`` columns, the rotary part
+    against the rest; the second product takes the same first ``rank``
+    columns as values."""
+    b = pl.program_id(0)
+    B, P = pt_ref.shape
+    ps = pool_ref.shape[1]
     rows = qc_ref.shape[1]
+    S = rows // heads
 
-    pl.when(j == 0)(functools.partial(_init, m_ref, l_ref, acc_ref))
+    def live_pages(r):
+        # pages the last query of row r sees: none for a row with nothing
+        # cached (a position below 0), never more than the table has
+        return jnp.clip((pos_ref[r] + S - 1) // ps + 1, 0, P)
 
+    def copies(r, k, slot, do):
+        # the live pages of block k of row r <-> buf_ref[slot]; what lies
+        # past them in the buffer is masked, and finite (see the first row)
+        n = live_pages(r) - k * block
+        for t in range(block):
+            @pl.when(t < n)
+            def _copy():
+                do(pltpu.make_async_copy(
+                    pool_ref.at[pt_ref[r, k * block + t]],
+                    buf_ref.at[slot, pl.ds(t * ps, ps)], sem_ref.at[slot]))
+
+    def start_first_block_after(r, slot):
+        # the next LIVE row's first block: it lands while this row's last
+        # block is computed, so no row waits for its first pages
+        nxt = jax.lax.while_loop(
+            lambda r: (r < B) & (live_pages(jnp.minimum(r, B - 1)) == 0),
+            lambda r: r + 1, r + 1)
+
+        @pl.when(nxt < B)
+        def _start():
+            copies(nxt, 0, slot, lambda c: c.start())
+
+    @pl.when(b == 0)
+    def _first_row():
+        # zeros, so that the masked tail of a partial block holds finite
+        # numbers for the value product from the first call on: later it
+        # holds pages a live row has read
+        buf_ref[...] = jnp.zeros_like(buf_ref)
+        slot_ref[0] = 0
+        start_first_block_after(-1, 0)
+
+    _init(m_ref, l_ref, acc_ref)
     off = pos_ref[b]
-    row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-    q_abs = off + row // heads
-    last = off + rows // heads - 1      # what the row's last query sees
+    q_abs = off + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // heads
+    blocks = pl.cdiv(live_pages(b), block)
 
-    def accumulate(blk, first_key):
-        lat = blk[0][0] if len(blk) == 1 else \
-            jnp.concatenate([r[0] for r in blk], axis=0)   # (cols, W)
-        c, kr = lat[:, :rank], lat[:, rank:]
+    def body(k, slot):
+        @pl.when(k + 1 < blocks)
+        def _next_block():
+            copies(b, k + 1, 1 - slot, lambda c: c.start())
+
+        @pl.when(k + 1 == blocks)
+        def _next_row():
+            start_first_block_after(b, 1 - slot)
+
+        copies(b, k, slot, lambda c: c.wait())
+        c, kr = buf_ref[slot, :, :rank], buf_ref[slot, :, rank:]
         s = _nt(qc_ref[0], c) + _nt(qr_ref[0], kr)         # (rows, cols)
         col = jax.lax.broadcasted_iota(jnp.int32, (1, s.shape[1]), 1)
-        s = jnp.where(first_key + col <= q_abs, s, _NEG_INF)
+        s = jnp.where(k * block * ps + col <= q_abs, s, _NEG_INF)
         _softmax_step(s, c, m_ref, l_ref, acc_ref)
+        return 1 - slot
 
-    for lo in range(0, pages, block):
-        hi = min(pages, lo + block)
-        first_key = (j * pages + lo) * page_size
-        pl.when(first_key <= last)(functools.partial(
-            accumulate, page_refs[lo:hi], first_key))
-
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _finalize():
-        l = jnp.maximum(jnp.sum(l_ref[...], axis=1, keepdims=True), 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+    slot_ref[0] = jax.lax.fori_loop(0, blocks, body, slot_ref[0])
+    l = jnp.maximum(jnp.sum(l_ref[...], axis=1, keepdims=True), 1e-30)
+    o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("rank", "pages", "block",
-                                             "interpret"))
-def _mla_latent_decode_impl(qc, qr, pool, page_table, pos, rank, pages,
-                            block, interpret):
+@functools.partial(jax.jit, static_argnames=("rank", "block", "interpret"))
+def _mla_latent_decode_impl(qc, qr, pool, page_table, pos, rank, block,
+                            interpret):
     B, S, H, _ = qc.shape
-    N, ps, W = pool.shape
-    P = page_table.shape[1]
+    _, ps, W = pool.shape
     rows = S * H
 
-    def page(t):
-        # page t of step j, if the row's last query sees it; else the page
-        # this operand held the step before (nothing is copied), and in
-        # step 0 pool page 0, masked whatever it holds
-        def index(b, j, pt, off):
-            last = jnp.clip((off[b] + S - 1) // ps, 0, P - 1)
-            jj = jnp.minimum(j, jnp.maximum(last - t, 0) // pages)
-            at = jnp.minimum(jj * pages + t, P - 1)
-            return (jnp.where(t <= last, pt[b, at], 0), 0, 0)
-        return index
-
     def row_spec(width):
-        return pl.BlockSpec((1, rows, width), lambda b, j, pt, off: (b, 0, 0))
+        return pl.BlockSpec((1, rows, width), lambda b, pt, off: (b, 0, 0))
 
-    kernel = functools.partial(_latent_decode_kernel, page_size=ps,
-                               pages=pages, block=block, heads=H, rank=rank)
+    kernel = functools.partial(_latent_decode_kernel, block=block, heads=H,
+                               rank=rank)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(B, pl.cdiv(P, pages)),
-            in_specs=[row_spec(rank), row_spec(W - rank)]
-            + [pl.BlockSpec((1, ps, W), page(t)) for t in range(pages)],
+            grid=(B,),
+            in_specs=[row_spec(rank), row_spec(W - rank),
+                      pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=row_spec(rank),
             scratch_shapes=[
+                pltpu.VMEM((2, block * ps, W), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
                 pltpu.VMEM((rows, _LANES), jnp.float32),
                 pltpu.VMEM((rows, math.gcd(ps, _LANES)), jnp.float32),
                 pltpu.VMEM((rows, rank), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((B, rows, rank), qc.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="mla_latent_decode",
     )(page_table.astype(jnp.int32), pos.astype(jnp.int32),
-      qc.reshape(B, rows, rank), qr.reshape(B, rows, W - rank),
-      *([pool] * pages))
+      qc.reshape(B, rows, rank), qr.reshape(B, rows, W - rank), pool)
     return out.reshape(B, S, H, rank)
 
 
@@ -197,12 +229,10 @@ def mla_latent_decode(qc, qr, pool, page_table, pos):
     (num_pages, page, rank + rope)``; query ``i`` of row ``b`` sits at
     ``pos[b] + i`` and reads the positions up to its own, which the caller
     has written. Returns the weighted latents ``(B, S, H, rank)``."""
-    rank = qc.shape[-1]
-    pages, block = decode_tiles(page_table.shape[1], pool.shape[1],
-                                pool.shape[2], pool.dtype.itemsize)
-    return _mla_latent_decode_impl(qc, qr, pool, page_table, pos, rank=rank,
-                                   pages=pages, block=block,
-                                   interpret=_use_interpret())
+    return _mla_latent_decode_impl(
+        qc, qr, pool, page_table, pos, rank=qc.shape[-1],
+        block=decode_tiles(page_table.shape[1], pool.shape[1]),
+        interpret=_use_interpret())
 
 
 # ------------------------------------------------------ prefill (expanded)

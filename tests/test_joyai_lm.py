@@ -323,6 +323,37 @@ def test_the_scheduler_follows_the_count_a_row(ref, driver, cls, rate):
     assert bat.state_bytes["encoder_memory"] == 0
 
 
+def test_a_burst_runs_the_decode_kernel_and_serves_the_same_tokens(
+        ref, net, monkeypatch):
+    """With the paged kernels routed to (``MXTPU_FLASH_PAGED=1``; here
+    interpreted) a burst's loop runs ``%mla_latent_decode``, its own copies
+    and semaphores under the loop's carry, a call a latent cache a step,
+    rows coming and going beside it: the tokens are the ``jax.numpy``
+    form's, which are the reference's."""
+    from mxnet_tpu.ops.pallas import mla_attention as kern
+
+    traced, real = [], kern.mla_latent_decode
+
+    def counted(qc, qr, pool, page_table, pos):
+        traced.append(pool.shape)
+        return real(qc, qr, pool, page_table, pos)
+
+    monkeypatch.setattr(kern, "mla_latent_decode", counted)
+    prompts = [tokens(n, 20 + n) for n in (5, 23, 9, 16, 3, 38)]
+    max_new = [5, 8, 2, 7, 1, 6]
+    monkeypatch.setenv("MXTPU_FLASH_PAGED", "0")
+    plain, *_ = _through_batcher(net, prompts, max_new)
+    assert not traced
+    monkeypatch.setenv("MXTPU_FLASH_PAGED", "1")
+    out, _, stats, _ = _through_batcher(net, prompts, max_new)
+    # the three blocks' caches and the module's, in every program traced
+    assert traced and len(traced) % 4 == 0
+    assert out == plain
+    for p, n, got in zip(prompts, max_new, out):
+        assert got == ref.greedy(SEED, TINY, p, n)
+    assert stats["decode_latent_keys"] > 0
+
+
 def test_sampling_other_than_greedy_serves_one_token_a_step(ref, driver):
     net = build(ref, driver, cls=_Oracle)
     eng = InferStep(net, eos_id=NO_END)

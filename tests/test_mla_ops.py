@@ -87,29 +87,61 @@ def test_rope_pairs_neighbours_and_keeps_the_dot_product_relative():
 
 
 # ---------------------------------------------- kernels, interpret mode
-@pytest.mark.parametrize("positions,pos", [
-    (2, [0, 9, 22]), (1, [3, 23, 11]), (2, [-1, 5, 21])])
-def test_latent_decode_kernel_matches_its_jnp_form(positions, pos):
-    """``%mla_latent_decode`` in interpret mode: several pages a grid step,
-    blocks skipped past the row's position, a row that starts before
-    position 0 (the module's first step), two query positions a row."""
+# (query positions a row, the rows' positions, the pool's dtype, whether the
+# pages no live row reads hold NaN and Inf)
+DECODE_CASES = {
+    "two_positions": (2, [0, 9, 22], "float32", False),
+    "one_position": (1, [3, 23, 11], "float32", False),
+    "before_position_0": (2, [-1, 5, 21], "float32", False),
+    # pos -2 with two positions: no block; -1: one block for the second
+    "nothing_cached_beside_live_rows": (2, [-2, -1, 9], "float32", False),
+    # 9 + 2 positions are three pages: the last block of 2 or 4 is partial,
+    # and what the kernel must not read would poison the value product
+    "partial_last_block_unused_pages_nan": (2, [9, 1, 17], "float32", True),
+    # the copy a row starts for "the next row" is the next LIVE row's
+    "live_dead_live": (2, [7, -5, 13, -9, -3, 20], "float32", True),
+    "every_page_of_the_table": (2, [P * PAGE - 2, 3, P * PAGE - 2],
+                                "float32", False),
+    "bfloat16": (2, [0, 9, 22, -4, 14], "bfloat16", False),
+}
+
+
+@pytest.mark.parametrize("block", [1, 2, 4, 6])
+@pytest.mark.parametrize("case", list(DECODE_CASES))
+def test_latent_decode_kernel_matches_its_jnp_form(case, block):
+    """``%mla_latent_decode`` in interpret mode, ``block`` pages a block:
+    the kernel's own copies walk a row's live pages alone, two buffers
+    deep; rows past their position, before position 0 and with nothing
+    cached; two query positions a row."""
+    positions, pos, dtype, poison = DECODE_CASES[case]
     rng = np.random.default_rng(5)
     B = len(pos)
     pool, tables = _cache(rng, B)
     qc = jnp.asarray(rng.standard_normal((B, positions, H, RANK))
-                     .astype(np.float32))
+                     .astype(np.float32), dtype)
     _, qr = _queries(rng, B, positions)
+    pool, qr = pool.astype(dtype), qr.astype(dtype)
     pos = jnp.asarray(pos, jnp.int32)
-    want = mla.decode_attention(qc, qr, pool, tables, pos)
-    for pages, block in ((2, 1), (4, 2), (6, 4)):
-        got = kern._mla_latent_decode_impl(
-            qc, qr, pool, tables, pos, rank=RANK, pages=pages, block=block,
-            interpret=True)
-        live = np.asarray(pos)[:, None] + np.arange(positions)[None] >= 0
-        np.testing.assert_allclose(np.asarray(got)[live],
-                                   np.asarray(want)[live], atol=2e-5)
-    assert kern.decode_tiles(130, 128, 640, 2) == (12, 4)
-    assert kern.decode_tiles(P, PAGE, RANK + ROPE, 4) == (P, P)
+    want = np.asarray(mla.decode_attention(qc, qr, pool, tables, pos),
+                      np.float32)
+    if poison:
+        pages = np.clip((np.asarray(pos) + positions - 1) // PAGE + 1, 0, P)
+        read = {int(t) for row, n in zip(np.asarray(tables), pages)
+                for t in row[:n]}
+        unread = [n for n in range(pool.shape[0]) if n not in read]
+        pool = pool.at[jnp.asarray(unread[::2])].set(jnp.nan) \
+                   .at[jnp.asarray(unread[1::2])].set(jnp.inf)
+    got = np.asarray(kern._mla_latent_decode_impl(
+        qc, qr, pool, tables, pos, rank=RANK, block=block, interpret=True),
+        np.float32)
+    assert np.isfinite(got).all()
+    live = np.asarray(pos)[:, None] + np.arange(positions)[None] >= 0
+    np.testing.assert_allclose(
+        got[live], want[live], atol=2e-2 if dtype == "bfloat16" else 2e-5)
+    # a row with nothing cached reads nothing and yields zeros
+    assert not got[np.asarray(pos) + positions - 1 < 0].any()
+    assert kern.decode_tiles(130, 128) == 8
+    assert kern.decode_tiles(P, PAGE) == P
 
 
 @pytest.mark.parametrize("offset", [0, 8, -1])

@@ -371,19 +371,29 @@ def _named(compiled, name, calls=1):
 @pytest.mark.parametrize("rows,positions", [(40, 2), (1, 1)])
 def test_latent_decode_attention_compiles(for_chip, rows, positions):
     """The absorbed decode kernel: 40 rows of two query positions of 32
-    heads (64 query rows) over latent pages of 640 numbers a position, 12
-    pages a grid step in blocks of 4; and one row of one position."""
+    heads (64 query rows) over latent pages of 640 numbers a position, the
+    pool ONE operand left in HBM and a block of 8 pages a copy, two buffers
+    deep; and one row of one position."""
     spec, compile_ = for_chip
     mla = _mod("mla_attention")
     H, rank, rope, page, P = 32, 512, 128, 128, 130
-    assert mla.decode_tiles(P, page, rank + rope, 2) == (12, 4)
-    _named(compile_(
+    block = mla.decode_tiles(P, page)
+    assert block == 8
+    # what the kernel asks of VMEM: two buffers of a block of the pool and
+    # the softmax carry (two float32 lane groups and the weighted latents
+    # for a row's query rows)
+    scratch = 2 * block * page * (rank + rope) * 2 \
+        + positions * H * (128 + 128 + rank) * 4
+    assert scratch < mla._VMEM_LIMIT
+    compiled = compile_(
         mla.mla_latent_decode,
         spec((rows, positions, H, rank), "bfloat16"),
         spec((rows, positions, H, rope), "bfloat16"),
         spec((40 * P + 1, page, rank + rope), "bfloat16"),
-        spec((rows, P), "int32"), spec((rows,), "int32")),
-        "%mla_latent_decode")
+        spec((rows, P), "int32"), spec((rows,), "int32"))
+    _named(compiled, "%mla_latent_decode")
+    # the pool goes to the kernel as it lies: nothing copies or relays it
+    assert "copy(%pool" not in compiled.as_text()
 
 
 def test_latent_prefill_attention_compiles(for_chip):
