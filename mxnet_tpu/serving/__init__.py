@@ -65,7 +65,9 @@ and replica failures:
   (``FleetTelemetry`` polls each worker's ``telemetry`` verb on
   ``MXTPU_SCRAPE_S``), and per-request SLO attribution
   (``GenerationResult.phases`` — queue/handoff/prefill/decode/retry
-  breakdown summing to the observed end-to-end latency).
+  breakdown summing to the observed end-to-end latency, computed from
+  the request's own timeline of stamped instants; ``seat``, ``service``
+  and ``deliver`` split the first token's latency further).
 
 Env knobs: ``MXTPU_PAGE_SIZE``/``MXTPU_PAGES`` (KV pool geometry),
 ``MXTPU_ITER_TOKENS`` (decode tokens per scheduler iteration),

@@ -20,6 +20,15 @@ Telemetry (``infer/`` family): ``queue_wait_ms``/``ttft_ms`` per request,
 ``admitted_per_iter`` per iteration, ``prefill_ms``/
 ``decode_ms_per_token``/``tokens_per_sec`` per dispatch,
 ``requests``/``tokens``/``rejected_backpressure``/``preempted`` counters.
+
+A request carries its own timeline (``GenerationResult``: instants on
+``time.perf_counter``, the clock ``telemetry.phase`` reads, each stamped
+where the thing happens), and everything said of a request is computed
+from it in one place: ``GenerationResult.phases``, the ``infer/``
+histograms above, the ``trace.*`` spans (at retire) and the window
+histograms ``stats["h_*"]`` (``telemetry.metrics.BucketBlock``), which
+hold the parts of a first token's latency and the scheduler's own passes
+at a resolution a 95th percentile can be read from.
 """
 
 from __future__ import annotations
@@ -35,19 +44,30 @@ import numpy as _np
 
 from ..base import MXNetError
 from .. import telemetry as _tel
+from ..telemetry import metrics as _metrics
 from . import faults as _faults
 from . import pages as _pages
 from . import prefix as _prefix
 from . import tracing as _tracing
 
 
-def _evus(t_pc: float) -> float:
-    """Event-clock µs for a ``perf_counter`` instant (telemetry events
-    share the ``perf_counter`` timebase, so the two clocks differ only
-    by the process's event-log origin)."""
-    return _tracing.clock_us() - (time.perf_counter() - t_pc) * 1e6
+# the parts of a first token's latency that follow one another (what
+# ``GenerationResult.phases`` gives under ``<part>_ms``), and the window
+# histograms in ``stats``: the four parts and their sum as the caller saw
+# it, a working pass, a decode burst (dispatch to tokens on the host) and
+# a prompt's chunk (dispatch to its token)
+TTFT_PARTS = ("queue", "seat", "service", "deliver")
+_PART_KEYS = tuple((f"h_{part}_ms", f"{part}_ms") for part in TTFT_PARTS)
+_REQUEST_HISTS = tuple(h for h, _ in _PART_KEYS) + ("h_ttft_ms",
+                                                     "h_chunk_ms")
+_PASS_HISTS = ("h_pass_ms", "h_burst_ms")
+HIST_KEYS = _REQUEST_HISTS + _PASS_HISTS
+# entries of ``phases`` that lie INSIDE others (``seat_ms`` and
+# ``service_ms`` split ``prefill_ms``, ``deliver_ms`` runs while the slot
+# decodes): whoever adds phases up to an end-to-end latency leaves them out
+PHASE_DETAIL = ("seat_ms", "service_ms", "deliver_ms")
 
-__all__ = ["ContinuousBatcher", "GenerationResult",
+__all__ = ["ContinuousBatcher", "GenerationResult", "PHASE_DETAIL",
            "DeadlineExceeded", "Backpressure", "batcher_slots",
            "iter_tokens_default", "spec_k_default", "spec_draft_enabled",
            "make_batcher"]
@@ -109,6 +129,15 @@ def spec_draft_enabled(default: bool = True) -> bool:
     return v not in ("0", "false", "off")
 
 
+def _one_request(rows):
+    """Args of an admission prefill's span: the request's identifier
+    where the dispatch carries one request (``rows``: tuples whose second
+    entry is the request), so that its prefill can be found on the
+    profiler's timeline; None for a batch."""
+    return {"request_id": rows[0][1].future.request_id} \
+        if len(rows) == 1 else None
+
+
 def make_batcher(engine, bucket_keys, **kwargs):
     """Build the serving scheduler over ``engine``: a
     ``ContinuousBatcher`` with every keyword argument handed on as it
@@ -126,17 +155,37 @@ class GenerationResult:
     ends when the request resolves. ``weights_version`` tags the param
     set that served the request (hot weight swap: the version of its
     final iteration) and ``replica`` which
-    engine replica ran it (router). ``first_token_at`` is the
-    ``perf_counter`` instant of the first streamed token (TTFT =
-    ``first_token_at - enqueued_at``). ``drafts``, for a net that drafts
+    engine replica ran it (router). ``drafts``, for a net that drafts
     the token after next itself, lists ``(j, token)``: what its module
     proposed for generated token ``j`` (kept only where it WAS token
-    ``j``; the tokens are the model's own either way)."""
+    ``j``; the tokens are the model's own either way).
+
+    **The timeline**: ``time.perf_counter`` instants, each stamped once,
+    where the thing happens. ``enqueued_at`` (``submit``),
+    ``admitted_at`` (the scheduler gave it a slot), ``first_chunk_at``
+    (its first prefill dispatch: the first chunk of a prompt that enters
+    its pages in chunks, else the admission prefill, where it equals
+    ``admitted_at``), ``active_at`` (its first token is on the host),
+    ``first_token_at`` (the first token is in the stream: TTFT is
+    ``first_token_at - enqueued_at``), ``first_read_at`` (the CALLER's
+    thread took the first chunk from ``tokens_iter()``, or ``result()``
+    returned), ``finished_at`` (retired). ``phases`` is computed from
+    them: ``queue_ms``, ``seat_ms`` (slot taken to first dispatch: the
+    wait for the chunk seat), ``service_ms`` (first dispatch to first
+    token: its chunks and the bursts between them), ``deliver_ms`` (first
+    token to the caller's read) add up to ``first_read_at - enqueued_at``;
+    ``prefill_ms`` is slot to first token (``seat_ms + service_ms``) and
+    ``decode_ms`` first token to retirement. After a preemption the
+    instants and parts are the LAST admission's (``first_token_at``
+    alone keeps the first stream's), counted from ``requeued_at``, and
+    ``preempt_ms`` is the time before it."""
 
     __slots__ = ("_event", "_tokens", "_error", "enqueued_at",
                  "queue_wait_ms", "weights_version", "replica",
                  "_cond", "_stream", "first_token_at",
-                 "request_id", "phases", "drafts")
+                 "request_id", "phases", "drafts", "admitted_at",
+                 "first_chunk_at", "active_at", "first_read_at",
+                 "finished_at", "requeued_at")
 
     def __init__(self):
         self._event = threading.Event()
@@ -149,36 +198,89 @@ class GenerationResult:
         self._cond = threading.Condition()
         self._stream = []
         self.first_token_at = None
+        self.admitted_at = self.first_chunk_at = self.active_at = None
+        self.first_read_at = self.finished_at = self.requeued_at = None
         # fleet tracing/SLO attribution: the request id minted at the
         # router (or adopted from the RPC trace context) and the
         # per-phase latency breakdown — every ``*_ms`` entry names a
-        # phase; the router adds ``other_ms`` so the sum equals the
-        # observed end-to-end latency exactly
+        # phase; the router adds ``other_ms`` so that those which follow
+        # one another (all but ``PHASE_DETAIL``) sum to the observed
+        # end-to-end latency exactly
         self.request_id = None
         self.phases = None
         self.drafts = None
 
-    def _stream_tokens(self, tokens):
+    def _stream_tokens(self, tokens, at=None):
         """Append newly emitted tokens to the live stream (scheduler
-        thread). First call stamps ``first_token_at`` (TTFT)."""
+        thread). First call stamps ``first_token_at`` (TTFT): ``at`` where
+        the scheduler has just read the clock for ``active_at``."""
         if not tokens:
             return
         with self._cond:
             if self.first_token_at is None:
-                self.first_token_at = time.perf_counter()
+                self.first_token_at = at if at is not None \
+                    else time.perf_counter()
             self._stream.extend(tokens)
             self._cond.notify_all()
 
-    def _stream_reset(self):
+    def _requeue(self, now):
         """Preemption (pool exhaustion): the request restarts from its
-        prompt, so the stream restarts too. ``result()`` is unaffected —
-        only live ``tokens_iter`` consumers observe the re-emission."""
+        prompt, so the stream restarts too, and the timeline from
+        ``requeued_at``. ``result()`` is unaffected — only live
+        ``tokens_iter`` consumers observe the re-emission."""
         with self._cond:
             self._stream = []
+            self.requeued_at = now
+            self.admitted_at = self.first_chunk_at = None
+            self.active_at = self.first_read_at = None
             self._cond.notify_all()
 
+    def parts_ms(self) -> dict:
+        """``phases`` as far as the timeline's stamps give them (the
+        class's docstring): the one place a request's intervals are
+        taken."""
+        since = self.enqueued_at if self.requeued_at is None \
+            else self.requeued_at
+        out = {"queue_ms": (self.admitted_at - since) * 1e3,
+               "seat_ms": (self.first_chunk_at - self.admitted_at) * 1e3,
+               "service_ms": (self.active_at - self.first_chunk_at) * 1e3,
+               "prefill_ms": (self.active_at - self.admitted_at) * 1e3}
+        if self.requeued_at is not None:
+            out["preempt_ms"] = (self.requeued_at - self.enqueued_at) * 1e3
+        if self.first_read_at is not None:
+            out["deliver_ms"] = (self.first_read_at - self.active_at) * 1e3
+        if self.finished_at is not None:
+            out["decode_ms"] = (self.finished_at - self.active_at) * 1e3
+        return out
+
+    def _set_phases(self, adopted=False):
+        """``phases`` anew from the timeline; a request whose prefill ran
+        elsewhere (a handoff) stays marked ``adopted``."""
+        parts = self.parts_ms()
+        if adopted or (self.phases and "adopted" in self.phases):
+            parts["adopted"] = True
+        self.phases = parts
+        return parts
+
+    def _note_read(self):
+        """The caller's thread takes its first chunk (under ``_cond``).
+        A request that was retired before its caller read (a caller of
+        ``result()`` alone) gets its ``deliver_ms`` here."""
+        if self.first_read_at is None:
+            self.first_read_at = time.perf_counter()
+            if self.finished_at is not None:
+                self._set_phases()
+
     def _resolve(self, tokens):
+        """Resolve the future. Where the scheduler's retire has stamped
+        ``finished_at``, ``phases`` is completed from the timeline in the
+        same hold of ``_cond`` under which a caller stamps its first
+        read, so one of the two writes ``deliver_ms``, whichever comes
+        second. Returns ``phases`` as this call left them."""
         with self._cond:
+            if self.finished_at is not None and self.active_at is not None:
+                self._set_phases()
+            phases = self.phases
             self._tokens = tokens
             if not self._stream and tokens:
                 if self.first_token_at is None:
@@ -186,6 +288,7 @@ class GenerationResult:
                 self._stream = list(tokens)
             self._event.set()
             self._cond.notify_all()
+        return phases
 
     def _fail(self, err):
         with self._cond:
@@ -204,6 +307,9 @@ class GenerationResult:
             raise TimeoutError("generation result not ready")
         if self._error is not None:
             raise self._error
+        if self.first_read_at is None:
+            with self._cond:
+                self._note_read()
         return self._tokens
 
     def tokens_iter(self, timeout: Optional[float] = None):
@@ -221,6 +327,8 @@ class GenerationResult:
                         raise TimeoutError("no token within timeout")
                 chunk = list(self._stream[i:])
                 done = self._event.is_set()
+                if chunk and self.first_read_at is None:
+                    self._note_read()
             if chunk:
                 i += len(chunk)
                 yield chunk
@@ -254,8 +362,7 @@ class _Slot:
     """Host-side record of one OCCUPIED decode slot."""
 
     __slots__ = ("req", "carry", "length", "emitted", "finished",
-                 "admitted_seq", "version", "active_at", "base",
-                 "entered", "admitted_at", "drafts")
+                 "admitted_seq", "version", "base", "entered", "drafts")
 
     def __init__(self, req, admitted_seq):
         self.req = req
@@ -265,7 +372,6 @@ class _Slot:
         self.finished = False
         self.admitted_seq = admitted_seq
         self.version = None
-        self.active_at = None    # perf_counter at activation (decode_ms)
         # cached positions before the first generated token: the prime
         # and the forced prefix (encoder-decoder), or the prompt itself
         # (a net with no encoder, whose prompt lives in the pages)
@@ -274,7 +380,6 @@ class _Slot:
         # prompt tokens written into the pages so far; None where the
         # prompt is encoder memory. The slot decodes once all are in
         self.entered = None
-        self.admitted_at = None
         self.drafts = []         # (index of the generated token, draft)
 
     @property
@@ -556,6 +661,22 @@ class ContinuousBatcher:
                       # prompt tokens they wrote
                       "prefill_chunk_s": 0.0, "prompt_chunks": 0,
                       "prompt_tokens": 0}
+        # window histograms (``HIST_KEYS``): int64 counts over fixed
+        # log-spaced edges (``telemetry.metrics.BucketBlock``), observed
+        # by the scheduler thread alone. What is observed of requests
+        # (``_observe((key, ms))``, an append) becomes a new block right
+        # after ``_pass_once`` has dispatched the burst, where the thread
+        # would only wait for the device; a pass's own two observations
+        # have a small block to themselves, made at its end. A pass
+        # publishes the rows of NEW blocks (``_rows``), so a
+        # ``dict(stats)`` is a sound snapshot and the difference of two is
+        # the histogram of what was observed between them
+        self._hist = _metrics.BucketBlock(_REQUEST_HISTS)
+        self._hist_pass = _metrics.BucketBlock(_PASS_HISTS)
+        self._observe = self._hist.observe
+        self._rows = {}
+        for block in (self._hist, self._hist_pass):
+            self.stats.update(block.rows())
         # device-side counts that rode the tokens' read-backs
         # (``InferStep._take_counts``), under the names the net declares
         # (``slot_state["counts"]``: an expert layer's tokens an expert,
@@ -985,10 +1106,20 @@ class ContinuousBatcher:
                 busy = self._pass_once()
         finally:
             self._pass_seq += 1
+        self._hist_pass.observe(("h_pass_ms", acc["step_s"] * 1e3))
+        if acc["iterations"]:
+            self._hist_pass.observe(
+                ("h_burst_ms", (acc["dispatch_s"] + acc["readback_s"]) * 1e3))
+        rows = self._rows
+        for block in (self._hist, self._hist_pass):
+            # (a pass that dispatched a burst has flushed the first)
+            rows.update(block.flush() or ())
         with self._stats_lock:
             for k, v in acc.items():
                 self.stats[k] = self.stats[k] + v
+            self.stats.update(rows)
         acc.clear()
+        rows.clear()
         return busy
 
     def _pass_once(self) -> bool:
@@ -1025,6 +1156,10 @@ class ContinuousBatcher:
             t0 = time.perf_counter()
             with _tel.phase("sched.dispatch", acc, "dispatch_s"):
                 out = self._dispatch(live)
+            # the device runs the burst: what this pass's retire and admit
+            # observed becomes a block here, where the thread would only
+            # wait (``_step_once`` publishes it with the pass's seconds)
+            self._rows.update(self._hist.flush() or ())
             self._collect(live, out, t0)
         except Exception as e:  # noqa: BLE001 - fail the slots, not the thread
             self._poison(e)
@@ -1042,6 +1177,7 @@ class ContinuousBatcher:
         between-dispatches safe point."""
         now = time.perf_counter()
         reg = _tel.registry()
+        traced = _tracing.trace_enabled()
         done = []
         for i, s in enumerate(self._slots):
             if s is None:
@@ -1069,22 +1205,23 @@ class ContinuousBatcher:
                 self._register_prefix(i, s)
             self.pool.release(i)
             self._slots[i] = None
-            if not r.future.done():
-                r.future.weights_version = s.version
-                r.future.replica = self.name
-                if s.active_at is not None:
-                    base = dict(r.future.phases or {})
-                    base["decode_ms"] = (now - s.active_at) * 1e3
-                    r.future.phases = base
-                    if _tracing.trace_enabled():
-                        _tracing.span("trace.decode", _evus(s.active_at),
-                                      {"replica": self.name,
-                                       "tokens": len(s.emitted)},
-                                      request_id=r.future.request_id,
-                                      end_us=_evus(now))
+            fut = r.future
+            if not fut.done():
+                fut.weights_version = s.version
+                fut.replica = self.name
                 if s.drafts:
-                    r.future.drafts = list(s.drafts)
-                r.future._resolve(list(s.emitted))
+                    fut.drafts = list(s.drafts)
+                fut.finished_at = now
+                parts = fut._resolve(list(s.emitted))
+                if parts and "deliver_ms" in parts:
+                    # the caller has taken its first chunk (one that has
+                    # not is skipped: ``_note_read`` completes ``phases``)
+                    self._observe(("h_deliver_ms", parts["deliver_ms"]))
+                    self._observe(("h_ttft_ms", parts["queue_ms"]
+                                   + parts["prefill_ms"]
+                                   + parts["deliver_ms"]))
+            if traced and fut.active_at is not None:
+                self._trace_request(s, now)
             with self._stats_lock:
                 self.stats["retired"] += 1
             reg.counter("infer/requests").inc()
@@ -1093,6 +1230,29 @@ class ContinuousBatcher:
             if wd is not None:
                 wd.note_request(request_id=r.future.request_id,
                                 completed=1)
+
+    def _trace_request(self, s, now) -> None:
+        """The request-level spans of a retiring slot, made from its
+        timeline in this one place: ``trace.queue | trace.seat |
+        trace.prefill (trace.adopt) | trace.decode`` follow one another
+        from the enqueue (or the preemption) to ``now``."""
+        fut = s.req.future
+        since = fut.enqueued_at if fut.requeued_at is None \
+            else fut.requeued_at
+        args = {"replica": self.name}
+        tokens = {"replica": self.name, "tokens": len(s.emitted)}
+        adopted = (fut.phases or {}).get("adopted")
+        spans = [("trace.queue", since, fut.admitted_at, args)]
+        if fut.first_chunk_at > fut.admitted_at:
+            spans.append(("trace.seat", fut.admitted_at,
+                          fut.first_chunk_at, args))
+        spans += [
+            ("trace.adopt" if adopted else "trace.prefill",
+             fut.first_chunk_at, fut.active_at, tokens if adopted else args),
+            ("trace.decode", fut.active_at, now, tokens)]
+        for name, t0, t1, a in spans:
+            _tracing.span(name, _tel.us_of(t0), a,
+                          request_id=fut.request_id, end_us=_tel.us_of(t1))
 
     def _adopt(self, slot: int, frames: dict) -> bool:
         """Adopt prefilled KV frames (``serving.disagg``) into ``slot``'s
@@ -1480,32 +1640,11 @@ class ContinuousBatcher:
                 s.carry = int(fr["carry"])
                 s.emitted = [int(t) for t in fr["emitted"]]
                 s.version = version
-                s.active_at = t_admit
                 self._slots[slot] = s
                 self._seed_from_frames(slot, r, fr)
-                r.future.queue_wait_ms = \
-                    (t_admit - r.future.enqueued_at) * 1e3
-                self._note_wait(max(r.future.queue_wait_ms, 0.0))
-                reg.histogram("infer/queue_wait_ms").observe(
-                    max(r.future.queue_wait_ms, 0.0))
-                r.future.phases = {
-                    "queue_ms": max(r.future.queue_wait_ms, 0.0),
-                    "prefill_ms": 0.0, "adopted": True}
-                if _tracing.trace_enabled():
-                    _tracing.span("trace.queue",
-                                  _evus(r.future.enqueued_at),
-                                  {"replica": self.name},
-                                  request_id=r.future.request_id,
-                                  end_us=_evus(t_admit))
-                    _tracing.span("trace.adopt", _evus(t_admit),
-                                  {"replica": self.name,
-                                   "tokens": len(s.emitted)},
-                                  request_id=r.future.request_id)
-                r.future._stream_tokens(list(s.emitted))
-                ttft = (r.future.first_token_at
-                        - r.future.enqueued_at) * 1e3
-                reg.histogram("infer/ttft_ms").observe(ttft)
-                self._note_ttft(ttft)
+                # no prefill ran here: ``service_ms`` is the adoption's
+                # own host work
+                self._first_token(s, t_admit, adopted=True)
                 if s.carry == self._engine._eos \
                         or len(s.emitted) >= r.max_new:
                     s.finished = True
@@ -1538,11 +1677,10 @@ class ContinuousBatcher:
                 slot_ids[i] = slot
                 first_pages[i] = self.pool.table[slot, 0]
                 active[i] = True
-            t0 = time.perf_counter()
             try:
                 _faults.fire("batcher.dispatch", tag=self.name)
                 with _tel.phase("sched.admit.prefill", self._pass,
-                                "prefill_s"):
+                                "prefill_s", _one_request(cold)) as ph:
                     tok0, self._state = self._engine.prefill_paged(
                         self._state, src, vl, slot_ids, first_pages,
                         active, seed=self._iter, **self._sampling)
@@ -1562,15 +1700,14 @@ class ContinuousBatcher:
                         r.future._fail(e)
                 self._poison(e)
                 return 0
-            prefill_ms = (time.perf_counter() - t0) * 1e3
-            reg.histogram("infer/prefill_ms").observe(prefill_ms)
+            reg.histogram("infer/prefill_ms").observe(ph.seconds * 1e3)
             for i, (slot, r) in enumerate(cold):
                 if r.prefix is not None:
                     # its first token comes from the suffix replay; the
                     # BOS-prime sample is overridden by the forced
                     # history
                     continue
-                self._activate(slot, r, int(tok0[i]), t0, version, 1)
+                self._activate(slot, r, int(tok0[i]), ph.t0, version, 1)
                 n_admitted += 1
         if suffix:
             srows = 1
@@ -1599,11 +1736,10 @@ class ContinuousBatcher:
                 tables[i] = self.pool.table[slot]
                 sids[i] = slot
                 act[i] = True
-            t1 = time.perf_counter()
             try:
                 _faults.fire("batcher.dispatch", tag=self.name)
                 with _tel.phase("sched.admit.prefill", self._pass,
-                                "prefill_s"):
+                                "prefill_s", _one_request(suffix)) as ph:
                     tokS, self._state = self._engine.prefill_suffix_paged(
                         self._state, toks, vl_s, q_off, tables, sids, act,
                         seed=self._iter, wide=self.suffix_wide,
@@ -1615,10 +1751,9 @@ class ContinuousBatcher:
                         r.future._fail(e)
                 self._poison(e)
                 return 0
-            reg.histogram("infer/prefill_ms").observe(
-                (time.perf_counter() - t1) * 1e3)
+            reg.histogram("infer/prefill_ms").observe(ph.seconds * 1e3)
             for i, (slot, r, target, start) in enumerate(plans):
-                self._activate(slot, r, int(tokS[i]), t1, version,
+                self._activate(slot, r, int(tokS[i]), ph.t0, version,
                                len(target))
                 n_admitted += 1
         with self._stats_lock:
@@ -1656,7 +1791,7 @@ class ContinuousBatcher:
             s = _Slot(r, self._seq)
             self._seq += 1
             s.base, s.entered, s.version = n, 0, version
-            s.admitted_at = time.perf_counter()
+            r.future.admitted_at = time.perf_counter()
             self._slots[slot] = s
             placed += 1
         reg.histogram("infer/admitted_per_iter").observe(placed)
@@ -1671,13 +1806,16 @@ class ContinuousBatcher:
         part = r.prompt[s.entered:s.entered + self.chunk]
         toks = _np.full((1, self.chunk), self._pad, _np.int32)
         toks[0, :len(part)] = part
-        t0 = time.perf_counter()
+        fut = r.future
         try:
             _faults.fire("batcher.dispatch", tag=self.name)
             with _tel.phase("sched.admit.prefill_chunk", self._pass,
                             "prefill_chunk_s",
                             {"slot": slot,
-                             "chunk": s.entered // self.chunk}):
+                             "chunk": s.entered // self.chunk,
+                             "request_id": fut.request_id}) as ph:
+                if fut.first_chunk_at is None:
+                    fut.first_chunk_at = ph.t0
                 out, self._state = self._engine.prefill_suffix_paged(
                     self._state, toks,
                     _np.full((1,), len(part), _np.int32),
@@ -1689,8 +1827,9 @@ class ContinuousBatcher:
         except Exception as e:  # noqa: BLE001 - fail futures, not thread
             self._poison(e)
             return 0
-        chunk_s = time.perf_counter() - t0
+        chunk_s = ph.seconds
         reg.histogram("infer/prefill_ms").observe(chunk_s * 1e3)
+        self._observe(("h_chunk_ms", chunk_s * 1e3))
         # a chunk is this net's admission prefill: it counts there too,
         # so that ``admit_s - prefill_s`` stays admit's own time
         self._pass["prefill_s"] += chunk_s
@@ -1699,8 +1838,7 @@ class ContinuousBatcher:
         self._note_counts("prefill", out[1:])
         s.entered += len(part)
         if s.entered >= s.base:
-            self._activate(slot, r, int(out[0]), s.admitted_at, version,
-                           s.base, s=s)
+            self._activate(slot, r, int(out[0]), None, version, s.base, s=s)
             self._pass["admitted"] += 1
         return placed + 1
 
@@ -1717,45 +1855,50 @@ class ContinuousBatcher:
                 acc[key] += int(counts[at])
             at += n
 
-    def _activate(self, slot: int, r, first_tok: int, t0: float,
-                  version, length: int, s=None) -> None:
+    def _activate(self, slot: int, r, first_tok: int, t0, version,
+                  length: int, s=None) -> None:
         """Install the freshly-prefilled request into its slot and
         stream its first sampled token (TTFT instant): shared by the
-        cold-prefill and suffix-replay admission paths, and by the last
+        cold-prefill and suffix-replay admission paths (``t0``: their
+        dispatch, where the request took its slot), and by the last
         chunk of a prompt that entered its pages in chunks (``s``, the
-        slot it has held since ``t0``)."""
-        reg = _tel.registry()
+        slot it has held since ``admitted_at``)."""
         if s is None:
             s = _Slot(r, self._seq)
             self._seq += 1
         s.length = length  # cached positions (prime + prefix, or prompt)
         s.carry = first_tok
         s.version = version
-        s.active_at = time.perf_counter()
         s.emitted.append(s.carry)
         self._slots[slot] = s
-        r.future.queue_wait_ms = (t0 - r.future.enqueued_at) * 1e3
-        self._note_wait(max(r.future.queue_wait_ms, 0.0))
-        reg.histogram("infer/queue_wait_ms").observe(
-            max(r.future.queue_wait_ms, 0.0))
-        r.future.phases = {
-            "queue_ms": max(r.future.queue_wait_ms, 0.0),
-            "prefill_ms": (s.active_at - t0) * 1e3}
-        if _tracing.trace_enabled():
-            _tracing.span("trace.queue", _evus(r.future.enqueued_at),
-                          {"replica": self.name},
-                          request_id=r.future.request_id,
-                          end_us=_evus(t0))
-            _tracing.span("trace.prefill", _evus(t0),
-                          {"replica": self.name},
-                          request_id=r.future.request_id,
-                          end_us=_evus(s.active_at))
-        r.future._stream_tokens([s.carry])
-        ttft = (r.future.first_token_at - r.future.enqueued_at) * 1e3
-        reg.histogram("infer/ttft_ms").observe(ttft)
-        self._note_ttft(ttft)
+        self._first_token(s, t0)
         if s.carry == self._engine._eos or len(s.emitted) >= r.max_new:
             s.finished = True
+
+    def _first_token(self, s, dispatched_at, adopted=False) -> None:
+        """A request's first token is on the host: stamp ``active_at``,
+        take everything that is said of its admission from the timeline
+        (``phases``, ``queue_wait_ms``, the rolling windows, the
+        ``infer/`` histograms, the window histograms of the three parts
+        that have ended) and stream what it has. ``dispatched_at`` is the
+        admission prefill's dispatch for a request that took its slot
+        there; a prompt that entered in chunks has both stamps already."""
+        fut = s.req.future
+        reg = _tel.registry()
+        if fut.admitted_at is None:
+            fut.admitted_at = fut.first_chunk_at = dispatched_at
+        fut.active_at = now = time.perf_counter()
+        parts = fut._set_phases(adopted)
+        fut.queue_wait_ms = wait = (fut.admitted_at
+                                    - fut.enqueued_at) * 1e3
+        self._note_wait(wait)
+        reg.histogram("infer/queue_wait_ms").observe(wait)
+        for hist, part in _PART_KEYS[:3]:     # the three that have ended
+            self._observe((hist, parts[part]))
+        fut._stream_tokens(list(s.emitted), at=now)
+        ttft = (fut.first_token_at - fut.enqueued_at) * 1e3
+        reg.histogram("infer/ttft_ms").observe(ttft)
+        self._note_ttft(ttft)
 
     def _ensure_capacity(self, live):
         """Grow page allocations so every live row can cache
@@ -1813,7 +1956,7 @@ class ContinuousBatcher:
         s = self._slots[slot]
         self.pool.release(slot)
         self._slots[slot] = None
-        s.req.future._stream_reset()
+        s.req.future._requeue(time.perf_counter())
         self._pending.appendleft(s.req)
         with self._stats_lock:
             self.stats["preempted"] += 1

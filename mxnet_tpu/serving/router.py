@@ -78,7 +78,7 @@ from . import faults as _faults
 from . import prefix as _prefix
 from . import tracing as _tracing
 from .batcher import Backpressure, ContinuousBatcher, DeadlineExceeded, \
-    GenerationResult, _evus
+    GenerationResult, PHASE_DETAIL
 
 __all__ = ["Router", "Replica", "ReplicaUnavailable", "retry_max",
            "restart_backoff_s", "shed_queue_depth", "shed_wait_ms",
@@ -888,8 +888,11 @@ class Router:
                     # by the worker (queue/prefill/decode), extended
                     # with router-side phases. ``other_ms`` is the
                     # residual and is deliberately UNCLAMPED so the
-                    # ``*_ms`` phases sum to the observed end-to-end
-                    # latency exactly, by construction.
+                    # ``*_ms`` phases that follow one another (all but
+                    # the batcher's ``PHASE_DETAIL``, which lie inside
+                    # ``prefill_ms`` and ``decode_ms``) sum to the
+                    # observed end-to-end latency exactly, by
+                    # construction.
                     tdone = time.perf_counter()
                     phases = dict(getattr(r.inner, "phases", None) or {})
                     if r.attempts > 1 and r.assigned_at is not None:
@@ -898,6 +901,7 @@ class Router:
                     e2e_ms = (tdone - r.created) * 1e3
                     named = sum(v for k, v in phases.items()
                                 if k.endswith("_ms")
+                                and k not in PHASE_DETAIL
                                 and isinstance(v, (int, float)))
                     phases["other_ms"] = e2e_ms - named
                     r.outer.phases = phases
@@ -908,13 +912,13 @@ class Router:
                             f"serve/slo_burn_{r.klass}").inc()
                     if _tracing.trace_enabled():
                         _tracing.span(
-                            "trace.request", _evus(r.created),
+                            "trace.request", _tel.us_of(r.created),
                             {"replica": r.inner.replica,
                              "klass": r.klass,
                              "attempts": r.attempts,
                              "e2e_ms": e2e_ms},
                             request_id=r.request_id,
-                            end_us=_evus(tdone))
+                            end_us=_tel.us_of(tdone))
                     r.outer._resolve(r.inner.result())
                     reg.counter("serve/completed").inc()
                     done.append(r)

@@ -48,7 +48,7 @@ from .watchdog import Watchdog
 
 __all__ = [
     "enable", "disable", "enabled", "span", "instant", "complete", "phase",
-    "clock_us", "registry", "report", "dump", "record_step",
+    "clock_us", "us_of", "registry", "report", "dump", "record_step",
     "start_watchdog", "stop_watchdog", "hbm_peak_bytes",
     "hbm_limit_bytes", "hbm_headroom_bytes", "device_memory_stats",
     "set_info", "run_info", "Registry", "Counter", "Gauge", "Histogram",
@@ -179,9 +179,13 @@ class phase:
       stream, so either trace can be laid over the other.
 
     With tracing off: two clock reads, one flag check, one float add.
+    The two reads are the interval's for everyone: ``t0`` is its start
+    (a ``perf_counter`` instant, the clock a request's timeline is
+    stamped on) and, once closed, ``seconds`` its length, so that nothing
+    beside a phase reads the clock again for the same interval.
     """
 
-    __slots__ = ("_name", "_acc", "_key", "_args", "_ann", "_t0")
+    __slots__ = ("_name", "_acc", "_key", "_args", "_ann", "t0", "seconds")
 
     def __init__(self, name: str, acc=None, key=None,
                  args: Optional[dict] = None):
@@ -191,11 +195,12 @@ class phase:
     def __enter__(self):
         self._ann = TraceAnnotation(self._name, **(self._args or {}))
         self._ann.__enter__()
-        self._t0 = time.perf_counter()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        t0, t1 = self._t0, time.perf_counter()
+        t0, t1 = self.t0, time.perf_counter()
+        self.seconds = t1 - t0
         self._ann.__exit__(*exc)
         if self._acc is not None:
             self._acc[self._key] += t1 - t0
@@ -211,6 +216,14 @@ def clock_us() -> float:
     timebase of every emitted event, exposed so the serving plane can
     answer clock-alignment probes (``ping``/``telemetry`` verbs)."""
     return _events_now_us()
+
+
+def us_of(t: float) -> float:
+    """A ``time.perf_counter`` instant on the process trace clock: the
+    events' timebase IS ``perf_counter`` less this module's origin, so a
+    span made later from stamped instants (a request's timeline) lies
+    where a ``phase`` of the same instants would."""
+    return (t - _EVENTS_T0) * 1e6
 
 
 # ------------------------------------------------------------------- steps

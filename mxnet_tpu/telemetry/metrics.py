@@ -14,8 +14,124 @@ import math
 import threading
 from typing import Dict, Optional
 
+import numpy as np
+
 __all__ = ["Counter", "Gauge", "Histogram", "Registry",
-           "merge_summaries"]
+           "merge_summaries", "BUCKET_CELLS", "BucketBlock", "bucket_of",
+           "bucket_percentile"]
+
+# ---------------------------------------------------- window histograms
+# A histogram whose two snapshots can be subtracted: an int64 array of
+# counts over FIXED log-spaced edges, so that ``later - earlier`` is the
+# histogram of what was observed between the two (the serving scheduler
+# keeps its request parts and its passes this way in ``stats``, and a
+# benchmark reads a window's own 95th percentile from the difference).
+# Cell 0 holds [0, LO); cell i of 1..N holds [LO * R**(i-1), LO * R**i)
+# with R = 2**(1/48), so an edge lies at most 1.46 % above the one below
+# and a percentile read back is within that of the exact one whatever
+# the sample (below LO: within LO); cell N+1 holds everything from HI
+# on; the last cell is the SUM of the observations in whole nanoseconds
+# (a mean, and the exact value where one observation was made).
+_PER_OCTAVE = 48
+_LO_LOG2, _HI_LOG2 = -6, 17          # 2**-6 ms (15.6 us) .. 2**17 ms (131 s)
+_N = (_HI_LOG2 - _LO_LOG2) * _PER_OCTAVE
+_LO, _HI = 2.0 ** _LO_LOG2, 2.0 ** _HI_LOG2
+BUCKET_CELLS = _N + 3
+
+
+def bucket_of(ms: float) -> int:
+    """The cell of an observation in milliseconds."""
+    if ms < _LO:
+        return 0
+    if ms >= _HI:
+        return _N + 1
+    return int((math.log2(ms) - _LO_LOG2) * _PER_OCTAVE) + 1
+
+
+class BucketBlock:
+    """The window histograms of one owner, a row of ``BUCKET_CELLS`` a key
+    in ONE int64 block, made for a hot path. ``observe`` is an append by
+    the owner's thread and no more; ``flush`` does the arithmetic and
+    turns what was observed since the last one into a NEW block, whose
+    rows it returns for the owner to publish (the owner calls it where
+    its thread would otherwise wait: the serving scheduler while the
+    device runs a burst). An earlier block is never written again, so
+    rows handed out before stay a sound snapshot."""
+
+    __slots__ = ("keys", "observe", "_base", "_block", "_seen")
+
+    def __init__(self, keys):
+        self.keys = tuple(keys)
+        self._base = {k: i * BUCKET_CELLS for i, k in enumerate(self.keys)}
+        self._block = np.zeros((len(self.keys), BUCKET_CELLS), np.int64)
+        self._seen = []         # (key, milliseconds), not yet bucketed
+        self.observe = self._seen.append   # observe((key, ms))
+
+    def rows(self) -> dict:
+        """``{key: its row of the block as it stands}``."""
+        return {k: self._block[i] for i, k in enumerate(self.keys)}
+
+    def flush(self):
+        """The rows of a new block that holds every observation so far;
+        None where nothing was observed since the last flush."""
+        if not self._seen:
+            return None
+        cells, sums, base = {}, {}, self._base   # flat cell -> count; ms
+        for key, ms in self._seen:
+            i = base[key] + bucket_of(ms)
+            cells[i] = cells.get(i, 0) + 1
+            sums[key] = sums.get(key, 0.0) + ms
+        self._seen.clear()
+        block = self._block.copy()
+        flat = block.reshape(-1)
+        # the cells are distinct, so one indexed add takes them all
+        flat[list(cells)] += list(cells.values())
+        for key, ms in sums.items():
+            flat[base[key] + BUCKET_CELLS - 1] += int(ms * 1e6)
+        self._block = block
+        return self.rows()
+
+
+def _edge(i: int) -> float:
+    """Lower edge of cell ``i`` (1..N+1) in milliseconds."""
+    return 2.0 ** (_LO_LOG2 + (i - 1) / _PER_OCTAVE)
+
+
+def bucket_percentile(counts, p: float) -> Optional[float]:
+    """The ``p``-th percentile (0..100) in milliseconds of a window
+    histogram, or of a difference of two, as
+    ``perf/harness/clock.percentile`` takes it: interpolated between the
+    two nearest ranks, each rank placed inside its cell as if the cell's
+    observations lay evenly across it. None where nothing was observed;
+    the observation itself where there was one; 0 where every
+    observation was 0; ``HI`` for a rank in the last cell (read it as
+    "at least")."""
+    cells = [int(c) for c in counts[:-1]]
+    n = sum(cells)
+    if n <= 0:
+        return None
+    total_ns = int(counts[-1])
+    if n == 1:
+        return total_ns / 1e6
+    rank = (p / 100.0) * (n - 1)
+    lo = int(rank)
+    hi = min(lo + 1, n - 1)
+
+    def at(j):
+        seen = 0
+        for i, c in enumerate(cells):
+            if j < seen + c:
+                if i == 0:
+                    return 0.0 if total_ns == 0 \
+                        else _LO * (j - seen + 0.5) / c
+                if i == _N + 1:
+                    return _HI
+                a = _edge(i)
+                return a + (_edge(i + 1) - a) * (j - seen + 0.5) / c
+            seen += c
+        return _HI
+
+    return at(lo) * (1 - (rank - lo)) + at(hi) * (rank - lo)
 
 
 def merge_summaries(summaries) -> dict:

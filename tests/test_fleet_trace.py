@@ -34,6 +34,7 @@ import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu.serving import RemoteReplica, Router, faults, tracing
+from mxnet_tpu.serving.batcher import PHASE_DETAIL
 from mxnet_tpu.serving.tracing import (FleetTelemetry, aggregate_snapshots,
                                        estimate_offset, replay_scrapes)
 from mxnet_tpu.serving.worker import spawn_worker
@@ -440,8 +441,14 @@ class TestFleetTraceE2E:
             for key in ("queue_ms", "prefill_ms", "decode_ms",
                         "handoff_ms", "other_ms"):
                 assert key in phases, (key, phases)
+            # the phases that follow one another; ``seat_ms`` and
+            # ``service_ms`` split ``prefill_ms`` and ``deliver_ms`` lies
+            # inside ``decode_ms`` (``batcher.PHASE_DETAIL``)
             total = sum(v for k, v in phases.items()
-                        if k.endswith("_ms") and isinstance(v, float))
+                        if k.endswith("_ms") and isinstance(v, float)
+                        and k not in PHASE_DETAIL)
+            assert phases["prefill_ms"] == pytest.approx(
+                phases["seat_ms"] + phases["service_ms"], abs=1e-6)
             events, _ = _merge_root(root, request_id=fut.request_id)
             req = [e for e in events if e["name"] == "trace.request"]
             assert len(req) == 1
