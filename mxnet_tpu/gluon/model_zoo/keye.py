@@ -221,10 +221,9 @@ class KeyeLM(HybridBlock):
                                  v.reshape((R * C,) + v.shape[2:]))
             ip = _dsa.write_rows(state["ik_pools"][i], rows,
                                  ki.reshape(R * C, self._di))
-            scores = _dsa.window_index_scores(
+            mask = _dsa.window_select(
                 qi, wi, _dsa.gather_row_pages(ip, page_tables), q_pos,
-                n_blocks, block)
-            mask = _dsa.select_mask(scores, q_pos, self._topk)
+                n_blocks, block, self._topk)
             attn = _dsa.selected_window_attention(
                 q, kp, vp, page_tables, q_pos[:, 0], mask, n_blocks, block,
                 1.0 / math.sqrt(self._d))

@@ -13,14 +13,30 @@ Two shapes, as the serving plane has them:
 - the WINDOW (a prefill chunk): ``C`` queries a row at ``q_offset + i``
   over the row's pages, which already hold the chunk's own keys. Index
   scores and attention walk the cached keys in blocks and stop at the last
-  block any query can see; the selection is a mask, and attention is a
-  flash pass over the pages masked to it (a Pallas kernel on the TPU,
-  ``ops/pallas/paged_flash_attention.paged_selected_window_attention``:
-  dense and masked, 256 queries by 1,024 keys a step, every key/value
-  head against all its query heads in one product).
+  block any query can see; the selection is a mask (``window_select``),
+  and attention is a flash pass over the pages masked to it
+  (``selected_window_attention``).
 - DECODE: one query a row; the scores of the row's cached keys come
   through the page table, the ``topk`` positions are gathered from the
   pools BY TOKEN (not by page), and attention reads those alone.
+
+Which form runs where. Where the paged kernels are on
+(``flash_paged_enabled``: a TPU, no multi-device mesh) the window is two
+Pallas kernels a layer: ``ops/pallas/index_select.dsa_index_select`` (128
+queries a grid step keep their index scores in VMEM from the products to
+the ``topk``-th largest, found a bit a pass, and settle the ties at the
+cut; what leaves the chip is the selection, and nothing is scored for a
+query block that stands below ``topk``) and
+``ops/pallas/paged_flash_attention.paged_selected_window_attention``
+(dense and masked, 256 queries by 1,024 keys a step, every key/value head
+against all its query heads in one product). On the CPU, under a mesh, or
+where a window does not divide into the kernels' blocks, the same
+functions are ``jax.numpy``: ``window_index_scores`` (a loop over key
+blocks into one ``(R, C, L)`` float32 array), ``select_mask`` (a radix
+select, two bits a pass over that array: ``lax.top_k`` is a full sort on
+the TPU) and a flash loop over gathered keys; they are the kernels'
+references. The decode step is ``jax.numpy`` everywhere (its
+``lax.top_k`` over one query a row is what is left).
 
 Everything accumulates in float32 (scores, softmax); the pools and the
 queries keep their own dtype.
@@ -138,6 +154,29 @@ def select_mask(scores, q_pos, topk):
         crowded,
         lambda: seen & (above | (tie & (jnp.cumsum(tie, -1) <= room))),
         lambda: seen & (above | tie))
+
+
+def window_select(qi, wi, ki_all, q_pos, n_blocks, block, topk):
+    """The selected set of each window query as a mask ``(R, C, L)``:
+    ``select_mask`` of ``window_index_scores``. Where the paged kernels are
+    on (``flash_paged_enabled``: a TPU, no multi-device mesh) and the
+    window divides into its blocks, ONE Pallas kernel
+    (``ops/pallas/index_select.dsa_index_select``) keeps a query block's
+    scores on the chip from the products to the k-th largest, settles
+    the ties at the cut and hands back the set; else the two ``jax.numpy`` functions above, which are
+    the CPU's and a mesh's form and the kernel's reference. The queries of
+    a row sit at consecutive positions from ``q_pos[:, 0]``."""
+    from .pallas import index_select as _ixs
+    from .pallas import paged_flash_attention as _pfa
+
+    C, L = q_pos.shape[1], ki_all.shape[1]
+    if L <= topk or not _pfa.flash_paged_enabled() \
+            or _ixs.index_select_tiles(C, L) is None:
+        return select_mask(
+            window_index_scores(qi, wi, ki_all, q_pos, n_blocks, block),
+            q_pos, topk)
+    return _ixs.dsa_index_select(qi, wi, ki_all, q_pos[:, 0],
+                                 n_blocks * block, topk) != 0
 
 
 def selected_window_attention(q, k_pool, v_pool, page_tables, q_offset,

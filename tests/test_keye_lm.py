@@ -92,6 +92,22 @@ def test_full_forward_logits(ref, net, length):
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
+def test_full_forward_through_the_chips_kernels(ref, net, monkeypatch):
+    """256 tokens in one window of 128-position pages, the paged kernels
+    forced on: index scores and selection in the Pallas indexer, attention
+    in the selected-window kernel (both interpreted here), against the
+    reference's logits. ``topk`` 8 falls inside the first query block."""
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    monkeypatch.setenv("MXTPU_FLASH_PAGED", "force")
+    toks = tokens(256, 9)
+    x = jnp.asarray(toks[None], jnp.int32)
+    assert "dsa_index_select" in str(jax.make_jaxpr(
+        lambda t: net.hybrid_forward(None, t).data)(x))
+    got = net(nd.array(toks[None], dtype="int32")).asnumpy()[0]
+    want = np.asarray(ref.forward(SEED, TINY, toks))
+    np.testing.assert_allclose(got, want, atol=5e-5)
+
+
 def test_mrope_with_unequal_components(ref, net):
     toks = tokens(12, 3)
     rng = np.random.default_rng(4)
@@ -259,7 +275,8 @@ def test_kernel_name_the_benchmark_keys_on():
     """The trace names a Mosaic call after its ``pallas_call(name=...)``
     (``%moe_grouped_swiglu.<n>``, ``%dsa_selected_window.<n>``: read on
     the chip in PR 27); ``perf/layer_metrics/moe_*`` and ``dsa_*`` key on
-    those names (``perf/harness/lm_counts.py``)."""
+    those names (``perf/harness/lm_counts.py``;
+    ``dsa_select_time_share`` on ``%dsa_index_select.<n>``)."""
     import inspect
 
     from mxnet_tpu.ops.pallas import paged_flash_attention as pfa
@@ -272,6 +289,16 @@ def test_kernel_name_the_benchmark_keys_on():
     import re
     assert re.search(lm_counts.MOE_KERNEL, "%moe_grouped_swiglu.6 = bf16[")
     assert re.search(lm_counts.DSA_KERNEL, "%dsa_selected_window.11 = bf")
+    # the indexer's kernel (PR 37) and the reader that keeps its own pattern
+    from mxnet_tpu.ops.pallas import index_select as ixs
+
+    assert 'name="dsa_index_select"' in inspect.getsource(
+        ixs._dsa_index_select_impl.__wrapped__)
+    reader = load_module(os.path.join(
+        REPO, "perf", "layer_metrics", "dsa_select_time_share.py"))
+    assert re.search(reader.KERNEL, "%dsa_index_select.3 = s8[1,2048,16640]")
+    assert re.search(reader.KERNEL, "%dsa_index_select = s8[")
+    assert not re.search(reader.KERNEL, "%dsa_selected_window.11 = bf")
 
 
 # ------------------------------------------------- through the batcher
@@ -401,6 +428,105 @@ def test_selected_window_kernel_against_its_reference(case, monkeypatch):
     loop = dsa.selected_window_attention(*f32, table, off, mask,
                                          L // block, block, D ** -0.5)
     np.testing.assert_allclose(np.asarray(loop), np.asarray(want), atol=2e-5)
+
+
+# what one case of the index-select kernel's test varies; the rest is the
+# first case's (1 row, 256 queries of 4 heads of 16 over 1,024 cached
+# positions in key blocks of 256, topk 128, float32). "exact" draws small
+# dyadic numbers, so every product and sum is exact in float32 whatever
+# its order, many scores are equal and the rule for ties decides the set
+_SELECT = dict(R=1, C=256, J=4, Di=16, L=1024, topk=128, dtype="float32",
+               draw="exact", walked=None, poison=False)
+SELECT_CASES = {
+    # nothing is scored or counted: every query stands below topk
+    "offset0-chunk-within-topk": dict(offsets=[0], topk=256),
+    # topk falls inside the second query block: its first queries select
+    # every seen position, the rest the topk best
+    "topk-inside-a-query-block": dict(offsets=[0], topk=200),
+    # two rows at their own offsets, one block of each past all the keys
+    # the other sees
+    "two-rows": dict(R=2, offsets=[100, 700], topk=300),
+    # a prompt's last chunk: 90 live queries, the caller walked two blocks
+    # of 128 positions, the padding queries see -inf past them; the cached
+    # length is five key blocks of 256 and three of them are dead
+    "partial-last-chunk": dict(offsets=[150], L=1280, walked=2),
+    # whatever lies past the last seen position must not count: NaN and
+    # Inf keys there (pages of another life)
+    "dead-blocks-of-nan-and-inf": dict(offsets=[300], L=1536, poison=True),
+    # a zero query (every score equal: the cut is all ties, the lower
+    # positions win) and odd queries whose heads all weigh nothing
+    "a-row-of-equal-scores": dict(offsets=[200], draw="zero"),
+    # columns of -inf that every query sees: the caller walked three
+    # blocks of 128 and the queries stand past them
+    "all-inf-columns": dict(offsets=[600], J=16, draw="normal", walked=3),
+    # the serving dtype; a cached length that takes key blocks of 128
+    "bfloat16-operands": dict(offsets=[300], dtype="bfloat16", L=1152,
+                              J=16, draw="normal"),
+    "float32-normal": dict(offsets=[700], J=16, draw="normal"),
+}
+# the cases in which no query has more ties at the cut than room for them
+# (the others take the kernel's second search, over positions)
+_NEVER_CROWDED = ("offset0-chunk-within-topk", "all-inf-columns",
+                  "bfloat16-operands", "float32-normal")
+
+
+@pytest.mark.parametrize("case", list(SELECT_CASES))
+def test_index_select_kernel_against_its_jnp_form(case, monkeypatch):
+    """The Pallas indexer (interpreted here) against ``select_mask`` of
+    ``window_index_scores``: the same sets, bit for bit, through
+    ``window_select`` with the paged kernels forced on."""
+    from mxnet_tpu.ops.pallas import index_select as ixs
+
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    monkeypatch.setenv("MXTPU_FLASH_PAGED", "force")
+    w = dict(_SELECT, **SELECT_CASES[case])
+    R, C, J, Di, L, topk = (w[k] for k in ("R", "C", "J", "Di", "L", "topk"))
+    dtype, block = jnp.dtype(w["dtype"]), 128
+    assert ixs.index_select_tiles(C, L) == (128, 256 if L % 256 == 0 else 128)
+    rng = np.random.default_rng(len(case))
+    if w["draw"] == "normal":
+        qi, ki, wi = (rng.normal(size=s) for s in (
+            (R, C, J, Di), (R, L, Di), (R, C, J)))
+    else:
+        qi = rng.integers(-4, 5, size=(R, C, J, Di)) / 2.0
+        ki = rng.integers(-4, 5, size=(R, L, Di)) / 2.0
+        wi = rng.integers(-4, 5, size=(R, C, J)) / 4.0
+        if w["draw"] == "zero":
+            qi[:, 7] = 0.0
+            wi[:, 1::2] = 0.0
+    off = jnp.asarray(w["offsets"], jnp.int32)
+    q_pos = off[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
+    last = int(q_pos.max())
+    n_blocks = w["walked"] or min(last // block + 1, L // block)
+    if w["poison"]:
+        ki[:, last + 1:] = np.tile([np.nan, np.inf, -np.inf, 1e30],
+                                   Di // 4)
+    qi, ki = jnp.asarray(qi, dtype), jnp.asarray(ki, dtype)
+    wi = jnp.asarray(wi, jnp.float32)
+    want = dsa.select_mask(
+        dsa.window_index_scores(qi, wi, ki, q_pos, n_blocks, block),
+        q_pos, topk)
+    got = dsa.window_select(qi, wi, ki, q_pos, n_blocks, block, topk)
+    assert got.dtype == jnp.bool_
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # the kernel ran (the jnp form makes no such call), and a live query
+    # selects what the rule says: all it sees, or topk of it
+    assert "dsa_index_select" in str(jax.make_jaxpr(
+        lambda: dsa.window_select(qi, wi, ki, q_pos, n_blocks, block,
+                                  topk))())
+    live = np.asarray(q_pos) < n_blocks * block
+    picked = np.asarray(want).sum(-1)
+    assert (picked[live] == np.minimum(np.asarray(q_pos)[live] + 1,
+                                       topk)).all()
+    # which cases make the kernel settle ties by position (a query with
+    # more ties at its cut than room for them), by the jnp form's own test
+    keys = dsa._ordered_bits(
+        dsa.window_index_scores(qi, wi, ki, q_pos, n_blocks, block))
+    kth = dsa.kth_largest_bits(keys, topk)
+    seen = jnp.arange(L)[None, None, :] <= q_pos[:, :, None]
+    crowded = bool(jnp.any(
+        jnp.sum((keys == kth) & seen, -1) > topk - jnp.sum(keys > kth, -1)))
+    assert crowded == (case not in _NEVER_CROWDED)
 
 
 def test_bfloat16_weights_and_caches_serve(ref):

@@ -213,6 +213,53 @@ def test_selected_window_attention_compiles(for_chip):
     assert "%dsa_selected_window" in text
 
 
+def test_index_select_compiles(for_chip, monkeypatch):
+    """The window's indexer at the published widths (PR 37): a chunk of
+    2,048 queries of 16 index heads of 64 over 130 pages of 128 positions,
+    ``topk`` 2,048. ONE Mosaic call a layer under the name the benchmark's
+    reader keys on, 128 queries a grid step in key blocks of 256, its
+    blocks and scratch under the limit it asks the compiler for; and the
+    window's selection holds no loop over the ``(2048, 16640)`` scores any
+    more (the radix select's sixteen trips and the score blocks' loop are
+    the ``jax.numpy`` form's)."""
+    import re
+
+    from mxnet_tpu.ops import sparse_attention as dsa
+
+    spec, compile_ = for_chip
+    ixs = _mod("index_select")
+    monkeypatch.setenv("MXTPU_FLASH_PAGED", "force")
+    C, J, Di, L, topk = 2048, 16, 64, 16640, 2048
+    assert ixs.index_select_tiles(C, L) == (128, 256)
+    need = ixs.index_select_vmem_bytes(128, 256, L, J, Di, itemsize=2)
+    assert 16 << 20 < need < ixs._VMEM_LIMIT <= 64 << 20
+    block = dsa.kv_block(L, 512)
+
+    def window(qi, wi, ki, off, last):
+        q_pos = off[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
+        n_blocks = jnp.minimum(last // block + 1, L // block)
+        return dsa.window_select(qi, wi, ki, q_pos, n_blocks, block, topk)
+
+    def loops_over_the_scores(text):
+        return [ln for ln in text.splitlines()
+                if re.search(r"= .* while\(", ln) and "2048,16640" in ln]
+
+    args = (spec((1, C, J, Di), "bfloat16"), spec((1, C, J), "float32"),
+            spec((1, L, Di), "bfloat16"), spec((1,), "int32"),
+            spec((), "int32"))
+    text = compile_(window, *args).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "%dsa_index_select" in text
+    assert not loops_over_the_scores(text)
+    # with the paged kernels off (the CPU's and a mesh's form) the same
+    # function is the two jax.numpy loops over the scores (another
+    # callable: a trace is cached by the function, not by the environment)
+    monkeypatch.setenv("MXTPU_FLASH_PAGED", "0")
+    plain = jax.jit(lambda *a: window(*a)).lower(*args).compile().as_text()
+    assert "tpu_custom_call" not in plain
+    assert len(loops_over_the_scores(plain)) >= 2
+
+
 def test_grouped_decode_attention_compiles(for_chip):
     """granite-4.0-h-micro's decode attention: 64 rows, 32 query heads
     over 8 key/value heads of 64 (the group of 4 rides the window axis,
