@@ -245,7 +245,12 @@ class InferStep:
           table: they grow with the context, a page at a time. What a
           page holds is the net's: K and V by head (the default), K, V
           and an indexer's keys, or ONE latent vector a token with no
-          head axis (``latent_pools``).
+          head axis (``latent_pools``). A pool is ``(num_pages, page,
+          ...)``, or ``(planes, num_pages, page, ...)`` for a net that
+          runs one stack of weights several times a token and keeps a
+          K/V PLANE for every pass (``model_zoo/ouro.py``): a page id
+          then names that page in every plane, page 0 is every plane's
+          trash page, and the provisioned bytes count the plane axis.
         - ``encoder_memory``: whether the slot also holds static ENCODER
           memory (per-slot ``cross_k`` / ``cross_v`` buffers and
           ``mem_vl``), of the largest bucket's width.
@@ -290,7 +295,9 @@ class InferStep:
     def _state_sig(self, state):
         """The part of a paged state that names a compiled program: the
         shape of the first paged array the net declares (of the first
-        layer that keeps one), the first cross buffer's where it keeps
+        layer that keeps one; its plane axis too, where the pool has one:
+        as many passes are as much a part of the program as the page
+        size), the first cross buffer's where it keeps
         encoder memory, and the shape of the first array under each name
         of its slot arrays (their leading axis is the slot count, which
         the pools do not show)."""

@@ -447,10 +447,12 @@ class ContinuousBatcher:
         encoder memory (the largest bucket's width), and arrays indexed
         by slot of a fixed size whatever the context (a state-space
         layer's recurrent state). All of it is allocated here, once, for
-        every slot: ``state_bytes`` reports pages, slot arrays and
-        encoder memory; nothing is allocated a request. Slot arrays need
-        no host call at admission or preemption: the chunk program
-        starts them from zero where a prompt's first chunk enters.
+        every slot: ``state_bytes`` reports pages (every plane of a pool
+        that keeps a K/V plane for each pass of a looped stack), slot
+        arrays and encoder memory; nothing is allocated a request. Slot
+        arrays need no host call at admission or preemption: the chunk
+        program starts them from zero where a prompt's first chunk
+        enters.
     warmup : compile the admission-prefill program per bucket plus the
         decode-iteration program at construction (inert rows — the pools
         only ever see trash-page writes).
@@ -574,8 +576,8 @@ class ContinuousBatcher:
         self._state = engine.init_paged_state(
             self.slots, self.num_pages, self.page_size, self.mem_len)
         # what was provisioned, by kind of slot state (bytes on the
-        # device): the pool's pages, slots x the fixed-size arrays, and
-        # the encoder memory
+        # device): the pool's pages (whole arrays, so a pool's plane axis
+        # counts), slots x the fixed-size arrays, and the encoder memory
         decl = engine.slot_state
         self.state_bytes = {
             kind: sum(int(a.nbytes) for n in names for a in self._state[n])

@@ -339,6 +339,114 @@ class TestFlashPagedKernel:
         assert np.isfinite(got).all()
         np.testing.assert_allclose(got[:5], want[:5], rtol=1e-5, atol=1e-5)
 
+    # a pool that keeps FOUR planes under one page table, ``(4, num_pages,
+    # page, H, D)``, is read by the kernels as they are: flattened over
+    # planes and pages, through the table plus ``t * num_pages``.
+    # (page, key/value heads, query heads a group, head size, pages a row,
+    #  pages a block, dtype, positions)
+    PLANE_CASES = {
+        "page4_two_pages": (4, 2, 1, 8, 2, None, "float32", [2, 6]),
+        "page16_grouped_partial_block":
+            (16, 2, 4, 16, 5, 2, "float32", [0, 31, 32, 79]),
+        # the looped model's widths: 16 heads of 128, a row of 4 pages
+        "page128_h16_bf16": (128, 16, 1, 128, 4, None, "bfloat16",
+                             [0, 127, 300]),
+    }
+
+    def _planes(self, rng, planes, B, ps, Hkv, D, P, dtype, block,
+                monkeypatch):
+        pfa, _, _, table = self._paged(rng, B, ps, Hkv, D, P, dtype, block,
+                                       monkeypatch)
+        shape = (planes, B * P + 1, ps, Hkv, D)
+        return pfa, jnp.asarray(rng.randn(*shape), dtype), \
+            jnp.asarray(rng.randn(*shape), dtype), jnp.asarray(table)
+
+    @staticmethod
+    def _every_plane(call, kp, vp, table, like):
+        """``call(k pages, v pages, table of plane t)`` for a TRACED ``t``
+        inside a ``fori_loop``, stacked over the four planes."""
+        N = kp.shape[1]
+
+        @jax.jit
+        def run(kp, vp):
+            def body(t, out):
+                assert isinstance(t, jax.core.Tracer)
+                return out.at[t].set(call(
+                    kp.reshape((-1,) + kp.shape[2:]),
+                    vp.reshape((-1,) + vp.shape[2:]), table + t * N))
+            return jax.lax.fori_loop(
+                0, 4, body, jnp.zeros((4,) + like.shape, like.dtype))
+        return np.asarray(run(kp, vp), np.float32)
+
+    @pytest.mark.parametrize("case", sorted(PLANE_CASES))
+    def test_decode_kernel_reads_a_plane_through_a_moved_table(
+            self, case, monkeypatch):
+        ps, Hkv, G, D, P, block, dtype, pos = self.PLANE_CASES[case]
+        rng = np.random.RandomState(5)
+        B = len(pos)
+        pfa, kp, vp, table = self._planes(rng, 4, B, ps, Hkv, D, P, dtype,
+                                          block, monkeypatch)
+        q = jnp.asarray(rng.randn(B, Hkv * G, D), dtype)
+        pos = jnp.asarray(np.array(pos, np.int32))
+        got = self._every_plane(
+            lambda k, v, pt: pfa.paged_decode_attention(
+                q, k, v, pt, pos, sm_scale=D ** -0.5), kp, vp, table, q)
+        for t in range(4):
+            want = pfa.paged_decode_reference(
+                q, jnp.repeat(kp[t], G, axis=2), jnp.repeat(vp[t], G, axis=2),
+                table, pos, sm_scale=D ** -0.5)
+            np.testing.assert_allclose(got[t], np.asarray(want, np.float32),
+                                       **self.TOL[dtype])
+        assert np.abs(got[0] - got[3]).max() > 0.05
+
+    @pytest.mark.parametrize("case", ["page4_offset_and_padding",
+                                      "page16_s4", "page128_s4_bf16"])
+    def test_window_kernel_reads_a_plane_through_a_moved_table(
+            self, case, monkeypatch):
+        ps, H, D, P, block, dtype, S, off, vl = self.WINDOW_CASES[case]
+        rng = np.random.RandomState(6)
+        B = len(off)
+        pfa, kp, vp, table = self._planes(rng, 4, B, ps, H, D, P, dtype,
+                                          block, monkeypatch)
+        q = jnp.asarray(rng.randn(B, S, H, D), dtype)
+        off = jnp.asarray(np.array(off, np.int32))
+        vl = jnp.asarray(np.array(vl, np.int32))
+        got = self._every_plane(
+            lambda k, v, pt: pfa.paged_window_attention(
+                q, k, v, pt, off, vl, sm_scale=D ** -0.5), kp, vp, table, q)
+        for t in range(4):
+            want = pfa.paged_window_reference(q, kp[t], vp[t], table, off,
+                                              vl, sm_scale=D ** -0.5)
+            np.testing.assert_allclose(got[t], np.asarray(want, np.float32),
+                                       **self.TOL[dtype])
+        assert np.abs(got[1] - got[2]).max() > 0.05
+
+    def test_selected_window_reads_a_plane_through_a_moved_table(
+            self, monkeypatch):
+        """The chunk's kernel over a selected set and its ``jax.numpy``
+        form, on plane ``t`` of a pool of four planes."""
+        from mxnet_tpu.ops import sparse_attention as dsa
+        rng = np.random.RandomState(7)
+        ps, H, D, P, C = 8, 4, 16, 4, 16
+        pfa, kp, vp, table = self._planes(rng, 4, 2, ps, H, D, P, "float32",
+                                          None, monkeypatch)
+        q = jnp.asarray(rng.randn(2, C, H, D).astype(np.float32))
+        off = jnp.asarray(np.array([0, 13], np.int32))
+        q_pos = off[:, None] + jnp.arange(C)[None, :]
+        mask = jnp.arange(P * ps)[None, None, :] <= q_pos[:, :, None]
+        like = jnp.zeros((2, C, H * D), jnp.float32)
+        got = self._every_plane(
+            lambda k, v, pt: pfa.paged_selected_window_attention(
+                q, k, v, pt, off, mask, sm_scale=0.25), kp, vp, table, like)
+        loop = self._every_plane(
+            lambda k, v, pt: dsa.selected_window_attention(
+                q, k, v, pt, off, mask, 2, 16, 0.25), kp, vp, table, like)
+        for t in range(4):
+            want = pfa.paged_selected_window_reference(
+                q, kp[t], vp[t], table, off, mask, sm_scale=0.25)
+            np.testing.assert_allclose(got[t], want, rtol=1e-5, atol=1e-5)
+            np.testing.assert_allclose(loop[t], want, rtol=1e-5, atol=1e-5)
+
     def test_window_tiling_is_a_function_of_the_shapes(self):
         """``(pages a grid step, pages a block)``: granite's row of 12
         pages of 128 keys (8 key/value heads of 64, bfloat16) is one step

@@ -305,6 +305,48 @@ def test_hybrid_chunk_attention_compiles(for_chip):
         spec((1,), "int32"), spec((1, C, P * page), "bool"))
 
 
+@pytest.mark.parametrize("which", ["step", "chunk"])
+def test_attention_over_a_pool_of_planes_compiles(for_chip, which):
+    """Ouro-2.6B's two attention calls over pools with a plane a pass
+    ``(4, 41, 128, 16, 128)``, read flattened over planes and pages
+    through the page table moved by a ``fori_loop``'s carried index: the
+    decode step's 10 rows of 16 heads of 128 over 4 pages a row, and a
+    chunk of 256 queries through the selected-window kernel with a causal
+    mask. No whole-pool copy stands before either (a page is read where
+    it lies)."""
+    spec, compile_ = for_chip
+    pfa = _mod("paged_flash_attention")
+    T, B, H, D, page, P = 4, 10, 16, 128, 128, 4
+    N = B * P + 1
+    pool = spec((T, N, page, H, D), "bfloat16")
+
+    def pages(p):
+        return p.reshape((-1,) + p.shape[2:])
+
+    def step(q, k, v, pt, pos):
+        def body(t, acc):
+            return acc + pfa.paged_decode_attention(
+                q, pages(k), pages(v), pt + t * N, pos, sm_scale=D ** -0.5)
+        return jax.lax.fori_loop(0, T, body, jnp.zeros_like(q))
+
+    def chunk(q, k, v, pt, off, m):
+        def body(t, acc):
+            return acc + pfa.paged_selected_window_attention(
+                q, pages(k), pages(v), pt + t * N, off, m,
+                sm_scale=D ** -0.5)
+        return jax.lax.fori_loop(
+            0, T, body, jnp.zeros(q.shape[:2] + (H * D,), q.dtype))
+
+    if which == "step":
+        compiled = compile_(step, spec((B, H, D), "bfloat16"), pool, pool,
+                            spec((B, P), "int32"), spec((B,), "int32"))
+    else:
+        compiled = compile_(chunk, spec((1, 256, H, D), "bfloat16"), pool,
+                            pool, spec((1, P), "int32"), spec((1,), "int32"),
+                            spec((1, 256, P * page), "bool"))
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
 @pytest.mark.parametrize("shape,dtype,window", [
     (SMOKE, dtype, window) for dtype in ("float32", "bfloat16")
     for window in (1, 2, 4, 16)] + [
