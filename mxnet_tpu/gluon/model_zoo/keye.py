@@ -265,9 +265,11 @@ class KeyeLM(HybridBlock):
     def decode_step_paged(self, tokens, pos, state, page_tables, active):
         """One paged decode step over the SLOT batch: ``tokens (B,)`` at
         per-row positions ``pos (B,)``. The indexer scans the row's cached
-        keys through the page table, selects, and attention gathers the
-        selected positions from the pages. Inactive rows write to the trash
-        page and their logits are garbage."""
+        keys through the page table, selects, and attention reads the
+        selected positions from the pages (``ops/sparse_attention.
+        selected_decode``: on the chip a mask and the live pages in place,
+        else positions gathered by token). Inactive rows write to the trash
+        page, if anywhere, and their logits are garbage."""
         tok = (tokens.data if isinstance(tokens, NDArray)
                else jnp.asarray(tokens)).astype(jnp.int32)
         pos = jnp.asarray(pos, jnp.int32)
@@ -286,18 +288,14 @@ class KeyeLM(HybridBlock):
             q, k, v, qi, ki, wi = self._project(i, x, pos3)
             kp = _dsa.write_rows(state["k_pools"][i], rows, k)
             vp = _dsa.write_rows(state["v_pools"][i], rows, v)
-            ip = _dsa.write_rows(state["ik_pools"][i], rows, ki)
-            picked, valid = _dsa.decode_select(qi, wi, ip, page_tables, pos,
-                                               self._topk)
-            attn = _dsa.selected_decode_attention(
-                q, kp, vp, page_tables, picked, valid,
-                1.0 / math.sqrt(self._d))
+            attn, ip, selected = _dsa.selected_decode(
+                q, qi, wi, ki, kp, vp, state["ik_pools"][i], page_tables,
+                rows, pos, active, self._topk, 1.0 / math.sqrt(self._d))
             h = x + jnp.dot(attn, self._w(f"l{i}_wo"))
             x, per_expert = self._experts(i, h, active)
             counts = self._count(
                 counts, i, per_expert,
-                jnp.sum(jnp.where(active, pos + 1, 0)),
-                jnp.sum(jnp.logical_and(valid, active[:, None])))
+                jnp.sum(jnp.where(active, pos + 1, 0)), selected)
             k_pools.append(kp), v_pools.append(vp), ik_pools.append(ip)
         return self._logits(x), {
             "k_pools": tuple(k_pools), "v_pools": tuple(v_pools),

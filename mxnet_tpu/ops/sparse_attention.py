@@ -16,9 +16,11 @@ Two shapes, as the serving plane has them:
   block any query can see; the selection is a mask (``window_select``),
   and attention is a flash pass over the pages masked to it
   (``selected_window_attention``).
-- DECODE: one query a row; the scores of the row's cached keys come
-  through the page table, the ``topk`` positions are gathered from the
-  pools BY TOKEN (not by page), and attention reads those alone.
+- DECODE: one query a row over the row's cached positions
+  (``selected_decode``). On the chip the selection is a mask here too and
+  attention walks the row's live pages in place under it; in ``jax.numpy``
+  the ``topk`` positions are gathered from the pools BY TOKEN (not by
+  page), and attention reads those alone.
 
 Which form runs where. Where the paged kernels are on
 (``flash_paged_enabled``: a TPU, no multi-device mesh) the window is two
@@ -35,8 +37,24 @@ functions are ``jax.numpy``: ``window_index_scores`` (a loop over key
 blocks into one ``(R, C, L)`` float32 array), ``select_mask`` (a radix
 select, two bits a pass over that array: ``lax.top_k`` is a full sort on
 the TPU) and a flash loop over gathered keys; they are the kernels'
-references. The decode step is ``jax.numpy`` everywhere (its
-``lax.top_k`` over one query a row is what is left).
+references. The decode step takes the chunk's form at one query a row, two
+kernels a layer of ``ops/pallas/dsa_decode.py`` (PR 39), where the paged
+kernels are on and a row can hold more than ``topk`` positions:
+``dsa_decode_select`` (a row a grid step writes its own indexer key into
+its page, scores its LIVE pages through the page table, keeps the 16,640
+scores in VMEM to the ``topk``-th largest and hands back one int8 a
+position) and ``dsa_decode_window`` (a page of K and V as its (key, head)
+rows, the row's query heads against all of them in one product a page,
+foreign heads and unselected keys masked; both kernels issue their own
+copies, two buffers deep, and an inactive row costs nothing; 0.121 and
+0.403 ms a call in keye's cell on a v5e where the forms below took 0.36
+and 1.03: PERF.md, PR 39; the selected window's kernel at one query, 8
+query rows a head, relays every block head-major and read 1.26 ms alone
+against this form's 0.36, so it was not kept). Else it is
+``write_rows`` + ``decode_select`` (a gathered copy of the row's indexer
+keys, one product, ``lax.top_k``: a sort of every row on the TPU) +
+``selected_decode_attention`` (two gathers by token, a dense softmax): the
+CPU's and a mesh's form, and the kernels' references.
 
 Everything accumulates in float32 (scores, softmax); the pools and the
 queries keep their own dtype.
@@ -268,3 +286,42 @@ def selected_decode_attention(q, k_pool, v_pool, page_tables, positions,
     out = jnp.einsum("bngs,bsnd->bngd", p.astype(vs.dtype), vs,
                      preferred_element_type=jnp.float32)
     return out.reshape(B, Hq * D).astype(q.dtype)
+
+
+def selected_decode(q, qi, wi, ki, k_pool, v_pool, ik_pool, page_tables,
+                    rows, pos, active, topk, sm_scale):
+    """The decode step's attention of one layer: one query a row, ``q (B,
+    Hq, D)`` with indexer query ``qi (B, J, Di)`` and head weights ``wi (B,
+    J)`` at ``pos (B,)``, selects among the row's cached positions and
+    attends over the selected set. ``k_pool`` and ``v_pool`` hold the
+    row's own position already; its indexer key ``ki (B, Di)`` is written
+    here, at ``rows`` of the flattened pool (``write_rows``). Returns
+    ``(attention (B, Hq * D), ik_pool, keys the active rows selected)``.
+
+    Where the paged kernels are on (``flash_paged_enabled``: a TPU, no
+    multi-device mesh) and a row can hold more than ``topk`` positions, the
+    selection never becomes positions: ``dsa_decode_select`` writes the
+    row's key into its page, keeps the row's scores on the chip to the
+    ``topk``-th largest and hands back a mask, and ``dsa_decode_window``
+    walks the row's live pages in place under it (no sort, no gathered
+    copy of the indexer keys, no gather by token; an inactive row writes
+    nothing, not even to the trash page).
+    Else ``write_rows``, ``decode_select`` and ``selected_decode_attention``,
+    the CPU's and a mesh's form and the kernels' references."""
+    from .pallas import dsa_decode as _dec
+    from .pallas import paged_flash_attention as _pfa
+
+    L = page_tables.shape[1] * ik_pool.shape[1]
+    if L > topk and _pfa.flash_paged_enabled():
+        # an inactive row stands below 0: it reads nothing, selects nothing
+        at = jnp.where(active, pos, -1)
+        mask, ik_pool = _dec.dsa_decode_select(qi, wi, ki, ik_pool,
+                                               page_tables, at, topk)
+        attn = _dec.dsa_decode_window(q, k_pool, v_pool, page_tables, at,
+                                      mask, sm_scale=sm_scale)
+        return attn, ik_pool, jnp.sum(mask, dtype=jnp.int32)
+    ik_pool = write_rows(ik_pool, rows, ki)
+    picked, valid = decode_select(qi, wi, ik_pool, page_tables, pos, topk)
+    attn = selected_decode_attention(q, k_pool, v_pool, page_tables, picked,
+                                     valid, sm_scale)
+    return attn, ik_pool, jnp.sum(jnp.logical_and(valid, active[:, None]))

@@ -155,8 +155,17 @@ def _serve_by_hand(net, prompt, n_new, slots=2, slot=1):
     return served, counts
 
 
+@pytest.mark.parametrize("kernels", [False, True], ids=["jnp", "kernels"])
 @pytest.mark.parametrize("length", [3, 8, 9, 21])   # the last chunk ragged
-def test_chunked_prefill_and_decode_follow_the_reference(ref, net, length):
+def test_chunked_prefill_and_decode_follow_the_reference(ref, net, length,
+                                                         kernels,
+                                                         monkeypatch):
+    """``kernels``: the paged kernels forced on (interpreted here), so the
+    whole decode step runs through ``dsa_decode_select`` and
+    ``dsa_decode_window`` (every cached length here is past ``topk``)."""
+    if kernels:
+        monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+        monkeypatch.setenv("MXTPU_FLASH_PAGED", "force")
     prompt, n_new = tokens(length, 10 + length), 6
     served, counts = _serve_by_hand(net, prompt, n_new)
     seq = np.concatenate([prompt, served[:-1]])
@@ -299,6 +308,26 @@ def test_kernel_name_the_benchmark_keys_on():
     assert re.search(reader.KERNEL, "%dsa_index_select.3 = s8[1,2048,16640]")
     assert re.search(reader.KERNEL, "%dsa_index_select = s8[")
     assert not re.search(reader.KERNEL, "%dsa_selected_window.11 = bf")
+    # the decode step's two kernels (PR 39) have names of their own: a
+    # reader of the chunk's kernels divides a CHUNK call's work by its mean
+    # event, and must not meet a decode call under its name
+    from mxnet_tpu.ops.pallas import dsa_decode as dec
+
+    decode = load_module(os.path.join(
+        REPO, "perf", "layer_metrics", "dsa_decode_time_share.py"))
+    for impl, name, event in (
+            (dec._dsa_decode_select_impl, "dsa_decode_select",
+             "%dsa_decode_select.90 = (s8[16,136,128]{2,1,0:T(8,128)(4,1)"),
+            (dec._dsa_decode_window_impl, "dsa_decode_window",
+             "%dsa_decode_window.93 = bf16[16,32,128]{2,1,0:T(8,128)(2,1)")):
+        assert f'name="{name}"' in inspect.getsource(impl.__wrapped__)
+        assert re.search(decode.KERNEL, event)
+        assert re.search(decode.KERNEL, f"%{name} = bf16[")
+        for chunk in (lm_counts.DSA_KERNEL, reader.KERNEL,
+                      lm_counts.MOE_KERNEL):
+            assert not re.search(chunk, event)
+    for event in ("%dsa_selected_window.11 = bf", "%dsa_index_select.3 = s8"):
+        assert not re.search(decode.KERNEL, event)
 
 
 # ------------------------------------------------- through the batcher
@@ -527,6 +556,221 @@ def test_index_select_kernel_against_its_jnp_form(case, monkeypatch):
     crowded = bool(jnp.any(
         jnp.sum((keys == kth) & seen, -1) > topk - jnp.sum(keys > kth, -1)))
     assert crowded == (case not in _NEVER_CROWDED)
+
+
+# what one case of the decode selection's test varies; the rest is the
+# first case's (2 rows of 4 heads of 16 over 10 pages of 128, topk 128,
+# float32, "exact" as above: dyadic numbers, so sums are exact, scores tie
+# and the rule for ties decides the set). A position below 0 is an
+# inactive row
+_DECODE = dict(J=4, Di=16, ps=128, P=10, topk=128, dtype="float32",
+               draw="exact", poison=False)
+DECODE_SELECT_CASES = {
+    # nothing is scored: every row stands below topk
+    "under-topk": dict(pos=[100, 50]),
+    # pos + 1 == topk selects all it sees; one more selects topk of them
+    "at-and-over-topk": dict(pos=[127, 128, 200]),
+    # the second block of pages is partial (10 pages in blocks of 8), one
+    # row fills its table, the other walks one block
+    "rows-at-different-positions": dict(pos=[1279, 300]),
+    "an-inactive-row-between": dict(pos=[700, -1, 400]),
+    "every-row-inactive": dict(pos=[-1, -1]),
+    # the last live page holds 105 positions; what follows in it is stale
+    "a-ragged-last-page": dict(pos=[1000, 232]),
+    # a zero query and a row whose heads all weigh nothing: every score is
+    # equal, the cut is all ties and the lower positions win
+    "every-score-ties": dict(pos=[700, 1000], draw="zero"),
+    # whatever lies past a row's position must not count: NaN and Inf keys
+    # there, in the live page and in the dead ones
+    "dead-pages-of-nan-and-inf": dict(pos=[700, 300], poison=True),
+    "float32-normal": dict(pos=[900, 1100], J=16, Di=64, draw="normal"),
+    # the serving dtype
+    "bfloat16-operands": dict(pos=[900, 1100], J=16, Di=64, draw="normal",
+                              dtype="bfloat16"),
+    # 20 pages: three blocks, the last of four pages
+    "three-blocks": dict(pos=[2500, 1023], P=20, topk=512),
+    # the tiny preset's pages: a block is the whole table
+    "pages-of-4": dict(pos=[26, 12, 8], J=2, Di=8, ps=4, P=7, topk=8),
+}
+# the cases in which no row has more ties at the cut than room for them
+# (the others take the kernel's second search, over positions)
+_DECODE_NEVER_CROWDED = ("under-topk", "every-row-inactive",
+                         "float32-normal", "bfloat16-operands")
+
+
+def _decode_select_inputs(case):
+    w = dict(_DECODE, **DECODE_SELECT_CASES[case])
+    J, Di, ps, P = (w[k] for k in ("J", "Di", "ps", "P"))
+    pos = np.asarray(w["pos"], np.int32)
+    B, dtype = len(pos), jnp.dtype(w["dtype"])
+    rng = np.random.default_rng(len(case))
+    if w["draw"] == "normal":
+        qi, pool, wi, own = (rng.normal(size=s) for s in (
+            (B, J, Di), (1 + B * P, ps, Di), (B, J), (B, Di)))
+    else:
+        # a few dozen distinct scores over hundreds of positions: every
+        # cut falls among equal scores
+        qi = rng.integers(-1, 2, size=(B, J, Di)) / 1.0
+        pool = rng.integers(-1, 2, size=(1 + B * P, ps, Di)) / 1.0
+        wi = rng.integers(-1, 3, size=(B, J)) / 2.0
+        own = rng.integers(-1, 2, size=(B, Di)) / 1.0
+        if w["draw"] == "zero":
+            qi[0], wi[1] = 0.0, 0.0
+    table = 1 + rng.permutation(B * P).reshape(B, P)
+    if w["poison"]:
+        dead = np.arange(P * ps)[None] > pos[:, None]
+        for b in range(B):
+            flat = pool[table[b]].reshape(P * ps, Di)
+            flat[dead[b]] = np.tile([np.nan, np.inf, -np.inf, 1e30], Di // 4)
+            pool[table[b]] = flat.reshape(P, ps, Di)
+    at = np.maximum(pos, 0)
+    rows = np.where(pos >= 0, table[np.arange(B), at // ps] * ps + at % ps,
+                    at % ps)                    # an inactive row: the trash
+    return (w, jnp.asarray(qi, dtype), jnp.asarray(wi, jnp.float32),
+            jnp.asarray(own, dtype), jnp.asarray(pool, dtype),
+            jnp.asarray(table, jnp.int32), jnp.asarray(rows, jnp.int32), pos)
+
+
+@pytest.mark.parametrize("case", list(DECODE_SELECT_CASES))
+def test_decode_select_kernel_against_its_jnp_form(case, monkeypatch):
+    """The decode step's selection kernel (interpreted here) against
+    ``write_rows`` + ``decode_select``: the same SET row for row, ties to
+    the lower position, the row's own key in its page and nothing else of
+    the pool touched."""
+    from mxnet_tpu.ops.pallas import dsa_decode as dec
+
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    w, qi, wi, own, pool, table, rows, pos = _decode_select_inputs(case)
+    B, (ps, P, topk) = len(pos), (w[k] for k in ("ps", "P", "topk"))
+    L, live = P * ps, pos >= 0
+    written = dsa.write_rows(pool, rows, own)
+    picked, valid = dsa.decode_select(qi, wi, written, table,
+                                      jnp.asarray(np.maximum(pos, 0)), topk)
+    want = np.zeros((B, L), bool)
+    for b in np.nonzero(live)[0]:
+        want[b, np.asarray(picked)[b][np.asarray(valid)[b]]] = True
+    code, got_pool = dec.dsa_decode_select(qi, wi, own, pool, table,
+                                           jnp.asarray(pos), topk)
+    assert code.dtype == jnp.int8 and code.shape[1] % dec.decode_tiles(
+        P, ps) == 0 and not np.asarray(code)[:, P:].any()
+    got = np.asarray(code)[:, :P].reshape(B, L) != 0
+    np.testing.assert_array_equal(got, want)
+    assert (got.sum(-1) == np.where(live, np.minimum(pos + 1, topk), 0)).all()
+    # the pool: the live rows' keys written, an inactive row's not even to
+    # the trash page (page 0 is the reference's to scribble on)
+    np.testing.assert_array_equal(
+        np.asarray(got_pool.astype(jnp.float32))[1:],
+        np.asarray(written.astype(jnp.float32))[1:])
+    # which cases make the kernel settle ties by position, by the jnp
+    # form's own arithmetic
+    ki = dsa.gather_row_pages(written, table).astype(jnp.float32)
+    hit = jax.nn.relu(jnp.einsum("bjd,bsd->bjs", qi.astype(jnp.float32), ki))
+    scores = jnp.einsum("bjs,bj->bs", hit, wi)
+    seen = jnp.arange(L)[None] <= pos[:, None]
+    keys = dsa._ordered_bits(jnp.where(seen, scores, -jnp.inf))
+    kth = dsa.kth_largest_bits(keys, topk)
+    crowded = bool(jnp.any(
+        (pos >= topk) & (jnp.sum((keys == kth) & seen, -1)
+                         > topk - jnp.sum(keys > kth, -1))))
+    assert crowded == (case not in _DECODE_NEVER_CROWDED)
+
+
+# what one case of the decode attention's test varies; the rest is the
+# first case's (2 rows, 2 key/value heads of 2 query heads of 32, 24 pages
+# of 16 in one block, float32, 40 % of the seen keys selected)
+_DECODE_WINDOW = dict(Hkv=2, G=2, D=32, ps=16, P=24, dtype="float32",
+                      density=0.4, atol=2e-5)
+DECODE_WINDOW_CASES = {
+    "two-rows": dict(pos=[300, 100]),
+    # the published grouping and page: 4 key/value heads of 8 query heads,
+    # 11 pages of 128 in blocks of 8, the limit inside a page
+    "hkv4-g8": dict(pos=[1300, 500], Hkv=4, G=8, D=128, ps=128, P=11),
+    "an-inactive-row-between": dict(pos=[700, -1, 90], ps=128, P=10),
+    # the serving dtype against the float32 reference of the same values
+    "bfloat16-pools": dict(pos=[2500, 1023], Hkv=4, G=8, D=128, ps=128,
+                           P=20, dtype="bfloat16", atol=2e-2),
+    # a row reads its own position and nothing else: whole pages and
+    # blocks of -inf scores before the one finite score
+    "single-selected-key": dict(pos=[1500, 1023], ps=128, P=20,
+                                density=0.0),
+    "pages-of-4": dict(pos=[26, 12, 0], D=16, ps=4, P=7),
+}
+
+
+@pytest.mark.parametrize("case", list(DECODE_WINDOW_CASES))
+def test_decode_window_kernel_against_its_reference(case, monkeypatch):
+    """The decode step's attention kernel (interpreted here) under a mask,
+    pools read through a shuffled page table, against
+    ``selected_decode_attention`` over the same set as positions."""
+    from mxnet_tpu.ops.pallas import dsa_decode as dec
+
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    w = dict(_DECODE_WINDOW, **DECODE_WINDOW_CASES[case])
+    Hkv, G, D, ps, P = (w[k] for k in ("Hkv", "G", "D", "ps", "P"))
+    pos = np.asarray(w["pos"], np.int32)
+    B, L, dtype = len(pos), P * ps, jnp.dtype(w["dtype"])
+    rng = np.random.default_rng(len(case))
+    q = jnp.asarray(rng.normal(size=(B, Hkv * G, D)), dtype)
+    kp = jnp.asarray(rng.normal(size=(1 + B * P, ps, Hkv, D)), dtype)
+    vp = jnp.asarray(rng.normal(size=(1 + B * P, ps, Hkv, D)), dtype)
+    table = jnp.asarray(1 + rng.permutation(B * P).reshape(B, P), jnp.int32)
+    at = np.arange(L)[None]
+    mask = (at <= pos[:, None]) & (rng.random((B, L)) < w["density"])
+    mask |= at == pos[:, None]
+    pages = -(-P // dec.decode_tiles(P, ps)) * dec.decode_tiles(P, ps)
+    code = np.zeros((B, pages, ps), np.int8)
+    code[:, :P] = mask.reshape(B, P, ps)
+    got = dec.dsa_decode_window(q, kp, vp, table, jnp.asarray(pos),
+                                jnp.asarray(code), sm_scale=D ** -0.5)
+    assert got.dtype == dtype and got.shape == (B, Hkv * G * D)
+    K = int(mask.sum(-1).max())
+    picked, valid = np.zeros((B, K), np.int32), np.zeros((B, K), bool)
+    for b in range(B):
+        idx = np.nonzero(mask[b])[0]
+        picked[b, :len(idx)], valid[b, :len(idx)] = idx, True
+    want = dsa.selected_decode_attention(
+        *(x.astype(jnp.float32) for x in (q, kp, vp)), table,
+        jnp.asarray(picked), jnp.asarray(valid), D ** -0.5)
+    got, want, live = np.asarray(got.astype(jnp.float32)), \
+        np.asarray(want), pos >= 0
+    assert np.isfinite(want[live]).all()
+    np.testing.assert_allclose(got[live], want[live], atol=w["atol"])
+    assert not got[~live].any()         # an inactive row reads nothing
+
+
+def test_decode_counts_and_pools_whichever_form_runs(monkeypatch):
+    """``selected_decode`` with the paged kernels forced on and off: the
+    same attention, the same indexer pool and the same INTEGER of keys the
+    active rows selected (``decode_keys_selected``), an inactive row
+    counting nothing in either."""
+    w, qi, wi, own, pool, table, rows, pos = _decode_select_inputs(
+        "an-inactive-row-between")
+    B, ps, P, topk = len(pos), w["ps"], w["P"], w["topk"]
+    rng = np.random.default_rng(3)
+    q = jnp.asarray(rng.normal(size=(B, 4, 32)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(1 + B * P, ps, 2, 32)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(1 + B * P, ps, 2, 32)), jnp.float32)
+    active = jnp.asarray(pos >= 0)
+    at = jnp.asarray(np.maximum(pos, 0))
+
+    def step(mode):
+        monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+        monkeypatch.setenv("MXTPU_FLASH_PAGED", mode)
+        text = str(jax.make_jaxpr(lambda: dsa.selected_decode(
+            q, qi, wi, own, kp, vp, pool, table, rows, at, active, topk,
+            32 ** -0.5))())
+        assert ("dsa_decode_select" in text and "dsa_decode_window" in text) \
+            == (mode == "force")
+        return dsa.selected_decode(q, qi, wi, own, kp, vp, pool, table,
+                                   rows, at, active, topk, 32 ** -0.5)
+
+    attn, ip, n = step("force")
+    attn0, ip0, n0 = step("0")
+    assert int(n) == int(n0) == int(np.minimum(pos + 1, topk)[pos >= 0].sum())
+    live = pos >= 0
+    np.testing.assert_allclose(np.asarray(attn)[live],
+                               np.asarray(attn0)[live], atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(ip)[1:], np.asarray(ip0)[1:])
 
 
 def test_bfloat16_weights_and_caches_serve(ref):

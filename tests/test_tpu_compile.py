@@ -260,6 +260,61 @@ def test_index_select_compiles(for_chip, monkeypatch):
     assert len(loops_over_the_scores(plain)) >= 2
 
 
+def test_decode_step_selection_and_attention_compile(for_chip, monkeypatch):
+    """keye's decode step at the published widths (PR 39): 16 rows, 16
+    index heads of 64, 32 query heads over 4 key/value heads of 128, 130
+    pages of 128 positions, ``topk`` 2,048, bfloat16. The attention part of
+    a layer is exactly TWO Mosaic calls under the names the benchmark's
+    reader keys on, their blocks and scratch far under the limit they ask
+    the compiler for, the indexer pool handed over as a view (no copy of
+    it), and the text holds no sort and no gather. With the paged kernels
+    off (the CPU's and a mesh's form) the same function sorts 16 x 16,640
+    scores, gathers by token and makes no custom call."""
+    from mxnet_tpu.ops import sparse_attention as dsa
+
+    spec, compile_ = for_chip
+    dec = _mod("dsa_decode")
+    monkeypatch.setenv("MXTPU_FLASH_PAGED", "force")
+    B, J, Di, Hq, Hkv, D, page, P, topk = 16, 16, 64, 32, 4, 128, 128, 130, \
+        2048
+    block = dec.decode_tiles(P, page)
+    pages = -(-P // block) * block
+    assert (block, pages) == (8, 136)
+    # two buffers of a block of pages, the page that takes the row's key,
+    # the row's keys; two buffers of a block of K and of V, the selection a
+    # (key, head) column twice (the pipeline's), the softmax carry
+    select = 2 * Di * block * page * 2 + Di * page * 2 + pages * page * 4
+    window = 2 * 2 * block * page * Hkv * D * 2 \
+        + 2 * pages * page * Hkv * 4 + Hq * (128 + 128 + D) * 4
+    assert select < 1 << 20 and 4 << 20 < window < 6 << 20 \
+        and dec._VMEM_LIMIT <= 32 << 20
+    kv = spec((B * P + 1, page, Hkv, D), "bfloat16")
+
+    def step(q, qi, wi, ki, kp, vp, ip, pt, rows, pos, active):
+        return dsa.selected_decode(q, qi, wi, ki, kp, vp, ip, pt, rows, pos,
+                                   active, topk, D ** -0.5)
+
+    args = (spec((B, Hq, D), "bfloat16"), spec((B, J, Di), "bfloat16"),
+            spec((B, J), "float32"), spec((B, Di), "bfloat16"), kv, kv,
+            spec((B * P + 1, page, Di), "bfloat16"), spec((B, P), "int32"),
+            spec((B,), "int32"), spec((B,), "int32"), spec((B,), "bool"))
+    text = compile_(step, *args).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "%dsa_decode_select" in text and "%dsa_decode_window" in text
+    assert " sort(" not in text and " gather(" not in text
+    # no pool is copied or relaid on its way to a kernel
+    assert not [ln for ln in text.splitlines() if " copy(" in ln
+                and f"[{B * P + 1}," in ln.split(" copy(")[0]]
+    # another callable: a trace is cached by the function, not by the
+    # environment
+    monkeypatch.setenv("MXTPU_FLASH_PAGED", "0")
+    plain = jax.jit(lambda *a: step(*a)).lower(*args).compile().as_text()
+    assert "tpu_custom_call" not in plain
+    assert [ln for ln in plain.splitlines()
+            if " sort(" in ln and f"[{B},{P * page}]" in ln]
+    assert " gather(" in plain
+
+
 def test_grouped_decode_attention_compiles(for_chip):
     """granite-4.0-h-micro's decode attention: 64 rows, 32 query heads
     over 8 key/value heads of 64 (the group of 4 rides the window axis,
