@@ -8,8 +8,9 @@ from . import keye  # noqa: F401
 from . import granite_hybrid  # noqa: F401
 from . import joyai  # noqa: F401
 from . import ouro  # noqa: F401
+from . import zaya  # noqa: F401
 from . import ssd  # noqa: F401
 from . import faster_rcnn  # noqa: F401
 
 __all__ = ["vision", "bert", "transformer", "keye", "granite_hybrid", "joyai",
-           "ouro", "ssd", "faster_rcnn"]
+           "ouro", "zaya", "ssd", "faster_rcnn"]

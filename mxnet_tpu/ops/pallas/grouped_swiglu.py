@@ -20,8 +20,24 @@ expert-parallel layer (``moe_experts(held=)``): the router still ranks all
 its outputs, the pairs that fall on experts held elsewhere sort into a sink
 past the held ones, and the sink's tiles are left out of the product.
 
-``moe_experts`` is the layer (router, grouping, product, combine);
-``grouped_swiglu`` the product alone, a Pallas kernel on the TPU
+ROUTING and DISPATCH are apart (PR 40). ``route`` is the routing of a
+layer whose router is ONE matrix; ``dispatch_experts`` is everything after
+it (grouping, product, combine) for ``(experts (T, k), weights (T, k))``
+however they were made; ``moe_experts`` is ``route`` then
+``dispatch_experts``, to the letter what it was before the split (Keye's
+and JoyAI's programs trace to the jaxprs they traced to). A net whose
+router is its own calls the dispatch alone: ZAYA routes by a small MLP
+whose input runs from layer to layer, to ONE expert a token, weighed by its
+probability as it stands (``route`` renormalises the chosen weights, which
+at one expert a token would make every weight 1.0). Its experts have the
+model's own width (2048 x 2048 x 3, 16 of them): ``f_block`` gives that
+width 512 columns a grid step, three weight blocks of 2 MB two buffers
+deep, 12.6 MB of the 48 MB of VMEM the kernel asks for where Keye's 768
+takes 384 and 9.4; a decode step's 64 pairs on 16 experts walk tiles of 16
+rows a quarter full, each expert's 25 MB read once whatever its tile
+holds.
+
+``grouped_swiglu`` is the product alone, a Pallas kernel on the TPU
 (``MXTPU_FLASH_INTERPRET`` as for every kernel of this package) with
 ``grouped_swiglu_reference``, its jnp form, where a compiled kernel cannot
 be partitioned (``_partitionable``) and as the tolerance tests' oracle.
@@ -38,7 +54,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import _partitionable, _use_interpret
 
-__all__ = ["moe_experts", "route", "group_by_expert", "grouped_swiglu",
+__all__ = ["moe_experts", "dispatch_experts", "route", "group_by_expert",
+           "grouped_swiglu",
            "grouped_swiglu_reference", "row_tile", "f_block"]
 
 
@@ -212,26 +229,31 @@ def grouped_swiglu(x, tile_expert, n_tiles, w_gate, w_up, w_down, tile):
                                     w_down, tile=tile)
 
 
-def moe_experts(u, router, w_gate, w_up, w_down, k, valid=None,
-                scoring="softmax", bias=None, scale=1.0, held=None):
-    """The expert layer on tokens ``u (T, H)``: ``sum_{e in top-k} a_e
-    w_down[e] (silu(u w_gate[e]) * (u w_up[e]))`` with ``a`` the router's
-    weights (``route``: softmax probabilities renormalised, or sigmoid
-    scores chosen with ``bias`` and scaled by ``scale``). ``valid (T,)``
-    marks padding tokens, which are computed and not counted.
+def dispatch_experts(u, experts, weights, w_gate, w_up, w_down, valid=None,
+                     held=None, num_experts=None):
+    """The expert layer AFTER its routing: tokens ``u (T, H)``, each with
+    ``k`` chosen ``experts (T, k)`` int32 and the ``weights (T, k)``
+    float32 they are combined by, are grouped by expert, taken through the
+    grouped product and summed: ``sum_j weights[t, j] w_down[e] (silu(u
+    w_gate[e]) * (u w_up[e]))``, ``e = experts[t, j]``. Whoever routes
+    decides what a weight is: ``moe_experts`` hands over ``route``'s
+    (renormalised over the chosen), a net with a router of its own its own
+    (ZAYA: one expert a token, weighed by its probability as it stands).
+    ``valid (T,)`` marks padding tokens, which are computed and not
+    counted.
 
     ``held = (first, n)`` says WHICH experts the weights hold: ``w_gate``,
     ``w_up``, ``w_down`` are experts ``first .. first + n - 1`` of the
-    router's ``E`` outputs (one chip's share of an expert-parallel layer).
-    The router still ranks all ``E``; a pair that falls on an expert held
-    elsewhere is neither computed nor added, and the result is this
-    share's part of the layer's sum.
+    ``num_experts`` the router ranks (one chip's share of an
+    expert-parallel layer; without ``held`` the weights hold them all). A
+    pair that falls on an expert held elsewhere is neither computed nor
+    added, and the result is this share's part of the layer's sum.
 
-    Returns ``(out (T, H), counts)``: tokens routed to each expert, ``(E,)``
-    int32, over the router's whole width."""
+    Returns ``(out (T, H), counts)``: tokens routed to each expert,
+    ``(num_experts,)`` int32."""
     T, H = u.shape
-    E = router.shape[1]
-    experts, weights = route(u, router, k, scoring, bias, scale)
+    k = experts.shape[1]
+    E = w_gate.shape[0] if num_experts is None else num_experts
     local, n = experts, E
     if held is not None:
         first, n = held
@@ -255,3 +277,18 @@ def moe_experts(u, router, w_gate, w_up, w_down, k, valid=None,
     counts = jnp.zeros((E,), jnp.int32).at[experts.reshape(T * k)].add(
         1 if valid is None else jnp.repeat(valid.astype(jnp.int32), k))
     return out, counts
+
+
+def moe_experts(u, router, w_gate, w_up, w_down, k, valid=None,
+                scoring="softmax", bias=None, scale=1.0, held=None):
+    """The expert layer on tokens ``u (T, H)`` with ONE matrix for a
+    router: ``route`` (softmax probabilities renormalised, or sigmoid
+    scores chosen with ``bias`` and scaled by ``scale``), then
+    ``dispatch_experts`` over all of the router's outputs. ``valid`` and
+    ``held`` are the dispatch's.
+
+    Returns ``(out (T, H), counts)``: tokens routed to each expert, ``(E,)``
+    int32, over the router's whole width."""
+    experts, weights = route(u, router, k, scoring, bias, scale)
+    return dispatch_experts(u, experts, weights, w_gate, w_up, w_down,
+                            valid, held, router.shape[1])

@@ -29,6 +29,12 @@ One kernel, two entry points:
   hot path): the window kernel at ``S = 1`` with ``q_offset = pos``, or,
   for grouped-query heads, with the group on the window axis.
 
+Both take the pool as ``(num_pages, page_size, Hkv, D)`` or, declared the
+way the kernel reads it, ``(num_pages, page_size x Hkv, D)`` with
+``kv_heads=`` saying ``Hkv`` (``_page_size``; ZAYA's two heads of 128, PR
+40), and both take grouped query heads: a window of ``S`` positions by
+``G`` heads a key/value head rides the kernel's window axis whole.
+
 - ``paged_selected_window_attention`` — the window over a SELECTED set
   of cached positions (learned sparse attention), for grouped-query
   heads: ``Hq`` query heads over ``Hkv`` key/value heads. A mask ``(B, C,
@@ -137,7 +143,7 @@ def _window_vmem_bytes(pages, block, page_size, Hkv, S, D, itemsize):
 
 
 def _window_kernel(pt_ref, off_ref, vl_ref, q_ref, *refs, page_size, pages,
-                   block, sm_scale, window, shared_position=False):
+                   block, sm_scale, window, shared_position=False, group=1):
     """Grid (B, steps), steps sequential per row: ``pages`` pool pages a
     step (each its own operand, so the pipeline copies them through the
     page table), taken in blocks of ``block`` pages; online-softmax carry
@@ -151,13 +157,17 @@ def _window_kernel(pt_ref, off_ref, vl_ref, q_ref, *refs, page_size, pages,
     accumulator. Query ``i`` sits at absolute position ``off + i`` and
     masks keys above it; queries ``>= vl`` are padding and finalize to
     zero. With ``shared_position`` every query of the window sits at
-    ``off`` (the grouped query heads of ONE token)."""
+    ``off`` (the grouped query heads of ONE token); with ``group`` the
+    window is that many grouped query heads by ``window // group``
+    positions (rows ``g * positions + i`` of a key/value head), query
+    ``i`` of each at ``off + i``."""
     k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
     o_ref, m_ref, l_ref, acc_ref = refs[2 * pages:]
     b, j = pl.program_id(0), pl.program_id(1)
     rows = q_ref.shape[1]
     Hkv = rows // window
     lw = l_ref.shape[-1]
+    span = window // group          # positions the window holds
 
     @pl.when(j == 0)
     def _init():
@@ -167,11 +177,11 @@ def _window_kernel(pt_ref, off_ref, vl_ref, q_ref, *refs, page_size, pages,
 
     off = off_ref[b]
     row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-    q_abs = off if shared_position else off + row % window
+    q_abs = off if shared_position else off + row % span
     # the window's LAST query bounds what any query can see: a block
     # wholly past it contributes nothing (the row's first block is never
     # skipped, so l is never all-zero for a live row)
-    last = off + (0 if shared_position else window - 1)
+    last = off + (0 if shared_position else span - 1)
 
     def accumulate(k_blk, v_blk, first_key):
         def joined(page_refs):
@@ -218,7 +228,7 @@ def _window_kernel(pt_ref, off_ref, vl_ref, q_ref, *refs, page_size, pages,
     @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
         l = jnp.maximum(jnp.sum(l_ref[...], axis=1, keepdims=True), 1e-30)
-        o_ref[0] = jnp.where(row % window < vl_ref[b], acc_ref[...] / l,
+        o_ref[0] = jnp.where(row % span < vl_ref[b], acc_ref[...] / l,
                              0.0).astype(o_ref.dtype)
 
 
@@ -226,16 +236,17 @@ def _window_kernel(pt_ref, off_ref, vl_ref, q_ref, *refs, page_size, pages,
 # shape: the page operands' index maps are traced one by one, which cost
 # transformer-big's warm-up (25 programs of six layers) 4 s of ``setup_s``
 @functools.partial(jax.jit, static_argnames=(
-    "sm_scale", "shared_position", "pages", "block", "interpret"))
+    "sm_scale", "shared_position", "pages", "block", "interpret", "group"))
 def _paged_window_impl(q, k_pool, v_pool, page_table, q_offset, window_vl,
-                       sm_scale, shared_position, pages, block, interpret):
+                       sm_scale, shared_position, pages, block, interpret,
+                       group=1):
     B, S, H, D = q.shape
-    N, ps = k_pool.shape[0], k_pool.shape[1]
+    N, ps = k_pool.shape[0], _page_size(k_pool, H)
     P = page_table.shape[1]
     # query rows (head, query); a page as its (key, head) rows: with 8 or
     # 16 heads of bfloat16 the pool's tiles in HBM are these rows already
     rows = jnp.swapaxes(q, 1, 2).reshape(B, H * S, D)
-    extra = 0 if shared_position else S - 1
+    extra = 0 if shared_position else S // group - 1
 
     def page(t):
         # page t of step j, if the row's last query sees it; else the
@@ -254,7 +265,7 @@ def _paged_window_impl(q, k_pool, v_pool, page_table, q_offset, window_vl,
                             lambda b, j, pt, off, vl: (b, 0, 0))
     kernel = functools.partial(_window_kernel, page_size=ps, pages=pages,
                                block=block, sm_scale=sm_scale, window=S,
-                               shared_position=shared_position)
+                               shared_position=shared_position, group=group)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, pl.cdiv(P, pages)),
@@ -282,9 +293,19 @@ def _paged_window_impl(q, k_pool, v_pool, page_table, q_offset, window_vl,
     return jnp.swapaxes(out.reshape(B, H, S, D), 1, 2)   # (B, S, H, D)
 
 
+def _page_size(pool, heads):
+    """Positions a page of ``pool`` holds: its second axis, or, where the
+    pool is declared as the kernel reads it, ``(num_pages, page x heads,
+    D)`` (a page's (key, head) rows on ONE axis: two heads of 128 are then
+    whole ``(16, 128)`` tiles on the chip, where an axis of 2 heads would
+    be padded to 16 rows, eight times the bytes), that axis over the
+    heads."""
+    return pool.shape[1] if pool.ndim == 4 else pool.shape[1] // heads
+
+
 def paged_window_attention(q, k_pool, v_pool, page_table, q_offset,
                            window_vl=None, *, sm_scale,
-                           shared_position=False):
+                           shared_position=False, kv_heads=None):
     """S-token query window over a paged history, pools read in place.
 
     q ``(B, S, H, D)``; query ``i`` of row ``b`` sits at absolute
@@ -294,31 +315,51 @@ def paged_window_attention(q, k_pool, v_pool, page_table, q_offset,
     ``window_vl`` ``(B,)`` optionally marks queries ``>= window_vl[b]``
     as padding (their outputs are zeroed). ``shared_position`` puts every
     query of the window at ``q_offset[b]`` (``paged_decode_attention``'s
-    grouped query heads). Returns ``(B, S, H, D)``."""
+    grouped query heads). Pools are ``(num_pages, page, Hkv, D)`` or
+    ``(num_pages, page x Hkv, D)`` (``_page_size``); ``kv_heads`` says
+    ``Hkv`` where ``H`` is ``G`` times it (grouped-query heads: query
+    head ``i`` reads key/value head ``i // G``): the ``G`` heads of a
+    group then ride the kernel's window axis beside the ``S`` positions,
+    one softmax carry for the ``G x S`` rows of a key/value head. Returns
+    ``(B, S, H, D)``."""
     B, S, H, D = q.shape
+    Hkv = H if kv_heads is None else int(kv_heads)
+    G = H // Hkv
     if window_vl is None:
         window_vl = jnp.full((B,), S, jnp.int32)
-    pages, block = _window_tiles(page_table.shape[1], k_pool.shape[1], H, D,
-                                 k_pool.dtype.itemsize)
-    return _paged_window_impl(
+    pages, block = _window_tiles(
+        page_table.shape[1], _page_size(k_pool, Hkv), Hkv, D,
+        k_pool.dtype.itemsize)
+    if G > 1:
+        # (B, G x S, Hkv, D): window row g * S + i is head g of its group
+        # at position i
+        q = jnp.transpose(q.reshape(B, S, Hkv, G, D),
+                          (0, 3, 1, 2, 4)).reshape(B, G * S, Hkv, D)
+    out = _paged_window_impl(
         q, k_pool, v_pool, page_table, q_offset, window_vl,
         sm_scale=float(sm_scale), shared_position=bool(shared_position),
-        pages=pages, block=block, interpret=_use_interpret())
+        pages=pages, block=block, interpret=_use_interpret(), group=G)
+    if G > 1:
+        out = jnp.transpose(out.reshape(B, G, S, Hkv, D),
+                            (0, 2, 3, 1, 4)).reshape(B, S, H, D)
+    return out
 
 
 def paged_decode_attention(q, k_pool, v_pool, page_table, pos, *,
-                           sm_scale):
+                           sm_scale, kv_heads=None):
     """Single-token paged attention, pools read in place.
 
-    q ``(B, H, D)``; pools ``(num_pages, page_size, Hkv, D)``;
-    ``page_table`` ``(B, P)`` int32; ``pos`` ``(B,)`` int32 — row ``b``
-    attends keys at absolute positions ``<= pos[b]`` (the caller has
-    already scattered position ``pos`` into the pool). Returns
-    ``(B, H, D)``. Where ``H`` is ``G`` times ``Hkv`` (grouped-query
-    heads: query head ``i`` reads key/value head ``i // G``) the ``G``
-    heads of a group ride the kernel's window axis, all at ``pos``."""
+    q ``(B, H, D)``; pools ``(num_pages, page_size, Hkv, D)``, or
+    ``(num_pages, page_size x Hkv, D)`` with ``kv_heads`` saying ``Hkv``
+    (``_page_size``); ``page_table`` ``(B, P)`` int32; ``pos`` ``(B,)``
+    int32 — row ``b`` attends keys at absolute positions ``<= pos[b]``
+    (the caller has already scattered position ``pos`` into the pool).
+    Returns ``(B, H, D)``. Where ``H`` is ``G`` times ``Hkv``
+    (grouped-query heads: query head ``i`` reads key/value head ``i //
+    G``) the ``G`` heads of a group ride the kernel's window axis, all at
+    ``pos``."""
     B, H, D = q.shape
-    Hkv = k_pool.shape[2]
+    Hkv = k_pool.shape[2] if kv_heads is None else int(kv_heads)
     if H == Hkv:
         return paged_window_attention(q[:, None], k_pool, v_pool,
                                       page_table, pos,

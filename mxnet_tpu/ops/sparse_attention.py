@@ -94,7 +94,16 @@ def token_rows(page_tables, positions, page_size):
 
 def write_rows(pool, rows, values):
     """``pool`` with ``values (N, ...)`` written at flattened rows ``rows
-    (N,)`` (rows of the trash page for what must not land)."""
+    (N,)`` (rows of the trash page for what must not land). A pool
+    declared ``(num_pages, page x heads, D)``, a page's (key, head) rows
+    on one axis as the paged window kernel reads them, takes ``values (N,
+    heads, D)``, as many axes as its own: position ``r``'s heads are rows
+    ``r x heads`` onward."""
+    if values.ndim == pool.ndim:
+        heads = values.shape[1]
+        rows = (rows[:, None] * heads
+                + jnp.arange(heads, dtype=rows.dtype)).reshape(-1)
+        values = values.reshape((-1,) + values.shape[2:])
     flat = pool.reshape((-1,) + pool.shape[2:])
     return flat.at[rows].set(values.astype(pool.dtype)).reshape(pool.shape)
 

@@ -246,7 +246,10 @@ class InferStep:
           page holds is the net's: K and V by head (the default), K, V
           and an indexer's keys, or ONE latent vector a token with no
           head axis (``latent_pools``). A pool is ``(num_pages, page,
-          ...)``, or ``(planes, num_pages, page, ...)`` for a net that
+          ...)``, or ``(num_pages, page x heads, D)`` where few wide heads
+          would be padded on an axis of their own (two heads of 128: the
+          chip tiles the last two axes by ``(16, 128)``; nothing here
+          reads a pool's shape past its first axis but the net), or ``(planes, num_pages, page, ...)`` for a net that
           runs one stack of weights several times a token and keeps a
           K/V PLANE for every pass (``model_zoo/ouro.py``): a page id
           then names that page in every plane, page 0 is every plane's
@@ -261,7 +264,13 @@ class InferStep:
           carries them from chunk to chunk of a prompt and starts from
           zero where ``q_offset`` is 0, so a re-admitted slot needs no
           reset; its decode step leaves the arrays of rows that are not
-          ``active`` as they are.
+          ``active`` as they are. A net may keep pools AND slot arrays in
+          the SAME layer (``model_zoo/zaya.py``: K/V pages beside the
+          tail of a convolution over the last positions and a shifted
+          value, in every layer): what a page then holds is a function of
+          the slot's tail too, so the chunk program has to carry the tail
+          over chunk boundaries at any offset and leave the one of the
+          row's last REAL token.
 
         ``step_tokens`` is the most tokens a decode step yields a row: 1,
         or 2 for a net that drafts the token after next itself and

@@ -795,3 +795,137 @@ def test_bfloat16_weights_and_caches_serve(ref):
     assert len(gaps) == 10 and np.isfinite(gaps).all()
     assert gaps.mean() < 0.3
     assert bat.pool.free_pages == bat.pool.num_pages
+
+
+# --------------------------- the seam between routing and dispatch (PR 40)
+def _moe_experts_before_the_split(u, router, w_gate, w_up, w_down, k, valid=None,
+                scoring="softmax", bias=None, scale=1.0, held=None):
+    """The expert layer on tokens ``u (T, H)``: ``sum_{e in top-k} a_e
+    w_down[e] (silu(u w_gate[e]) * (u w_up[e]))`` with ``a`` the router's
+    weights (``route``: softmax probabilities renormalised, or sigmoid
+    scores chosen with ``bias`` and scaled by ``scale``). ``valid (T,)``
+    marks padding tokens, which are computed and not counted.
+
+    ``held = (first, n)`` says WHICH experts the weights hold: ``w_gate``,
+    ``w_up``, ``w_down`` are experts ``first .. first + n - 1`` of the
+    router's ``E`` outputs (one chip's share of an expert-parallel layer).
+    The router still ranks all ``E``; a pair that falls on an expert held
+    elsewhere is neither computed nor added, and the result is this
+    share's part of the layer's sum.
+
+    Returns ``(out (T, H), counts)``: tokens routed to each expert, ``(E,)``
+    int32, over the router's whole width."""
+    T, H = u.shape
+    E = router.shape[1]
+    experts, weights = moe.route(u, router, k, scoring, bias, scale)
+    local, n = experts, E
+    if held is not None:
+        first, n = held
+        inside = jnp.logical_and(experts >= first, experts < first + n)
+        weights = jnp.where(inside, weights, 0.0)
+        local = jnp.where(inside, experts - first, n)   # a sink, sorted last
+    tile = moe.row_tile(T * k, n)
+    dest, src_token, tile_expert, n_tiles, grouped = moe.group_by_expert(
+        local, n + (held is not None), tile)
+    if held is not None:
+        # the sink's tiles lie last: they are left out of the product
+        n_tiles = n_tiles - (grouped[n] + tile - 1) // tile
+        tile_expert = jnp.minimum(tile_expert, jnp.minimum(
+            tile_expert[jnp.maximum(n_tiles[0] - 1, 0)], n - 1))
+    y = moe.grouped_swiglu(u[src_token], tile_expert, n_tiles, w_gate, w_up,
+                       w_down, tile)
+    picked = y[dest.reshape(T * k)].reshape(T, k, H).astype(jnp.float32)
+    out = jnp.einsum("tkh,tk->th", picked, weights).astype(u.dtype)
+    if valid is None and held is None:
+        return out, grouped
+    counts = jnp.zeros((E,), jnp.int32).at[experts.reshape(T * k)].add(
+        1 if valid is None else jnp.repeat(valid.astype(jnp.int32), k))
+    return out, counts
+
+
+SEAM_CALLS = {
+    # (tokens, router outputs, held experts or None, k, keywords)
+    "keye-chunk": (2048, 128, None, 8, {}),
+    "keye-decode": (16, 128, None, 8, {}),
+    "joyai-chunk": (2048, 256, (0, 16), 8,
+                    dict(scoring="sigmoid", scale=2.5)),
+    "joyai-decode": (80, 256, (0, 16), 8,
+                     dict(scoring="sigmoid", scale=2.5)),
+}
+
+
+@pytest.mark.parametrize("valid", [False, True])
+@pytest.mark.parametrize("call", sorted(SEAM_CALLS))
+def test_moe_experts_is_route_then_dispatch_to_the_letter(call, valid):
+    """``moe_experts`` since the split (``route`` + ``dispatch_experts``)
+    traces to the jaxpr it traced to before, for Keye's and JoyAI's calls
+    at the published widths (chunk and decode shapes, all experts held and
+    a share of them, padding marked and not): their programs do not
+    move."""
+    import hashlib
+
+    tokens_, E, held, k, kw = SEAM_CALLS[call]
+    n, H, F = (held[1] if held else E), 2048, 768
+
+    def shape(*s, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dtype)
+
+    args = (shape(tokens_, H), shape(H, E), shape(n, H, F), shape(n, H, F),
+            shape(n, F, H), shape(E), shape(tokens_, dtype=jnp.bool_))
+
+    def digest(fn):
+        def layer(u, router, wg, wu, wd, bias, ok):
+            more = dict(kw, held=held) if held else dict(kw)
+            if "scoring" in kw:
+                more["bias"] = bias
+            return fn(u, router, wg, wu, wd, k, ok if valid else None,
+                      **more)
+        text = str(jax.make_jaxpr(layer)(*args))
+        return hashlib.sha256(text.encode()).hexdigest(), len(text)
+
+    now, before = digest(moe.moe_experts), \
+        digest(_moe_experts_before_the_split)
+    assert now == before and now[1] > 2000
+
+
+@pytest.mark.parametrize("tokens_,tile", [(64, 16), (1024, 128)])
+def test_dispatch_of_one_full_width_expert_a_token(tokens_, tile,
+                                                   monkeypatch):
+    """ZAYA's expert layer: 16 experts of width 2048 (four column steps of
+    512), ONE a token, weighed by the weight it is given and not by 1.0;
+    some experts get no token and are never read. The kernel (interpreted
+    here) against ``grouped_swiglu_reference`` and against each token's
+    own expert, plainly."""
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    rng = np.random.default_rng(tokens_)
+    E, H, F = 16, 128, 2048
+    assert moe.row_tile(tokens_, E) == tile and moe.f_block(F) == 512
+    u = jnp.asarray(rng.normal(size=(tokens_, H)), jnp.float32)
+    wg = jnp.asarray(rng.normal(size=(E, H, F)) / 11, jnp.float32)
+    wu = jnp.asarray(rng.normal(size=(E, H, F)) / 11, jnp.float32)
+    wd = jnp.asarray(rng.normal(size=(E, F, H)) / 45, jnp.float32)
+    # experts 3, 7 and 15 get no token; expert 0 gets a third of them
+    live = np.asarray([e for e in range(E) if e not in (3, 7, 15)])
+    experts = np.where(rng.random(tokens_) < 0.33, 0,
+                       live[rng.integers(0, len(live), tokens_)])
+    experts = jnp.asarray(experts[:, None], jnp.int32)
+    weights = jnp.asarray(rng.uniform(0.1, 0.9, (tokens_, 1)), jnp.float32)
+    valid = jnp.asarray(rng.random(tokens_) < 0.8)
+    got, grouped = moe.dispatch_experts(u, experts, weights, wg, wu, wd)
+    _, counted = moe.dispatch_experts(u, experts, weights, wg, wu, wd, valid)
+    monkeypatch.setattr(moe, "grouped_swiglu", moe.grouped_swiglu_reference)
+    want, _ = moe.dispatch_experts(u, experts, weights, wg, wu, wd)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
+    by_expert = np.bincount(np.asarray(experts[:, 0]), minlength=E)
+    np.testing.assert_array_equal(np.asarray(grouped), by_expert)
+    assert not by_expert[[3, 7, 15]].any()
+    np.testing.assert_array_equal(
+        np.asarray(counted),
+        np.bincount(np.asarray(experts[:, 0]), np.asarray(valid),
+                    minlength=E).astype(np.int32))
+    for t in (0, 5, tokens_ - 1):
+        e = int(experts[t, 0])
+        plain = float(weights[t, 0]) * (
+            (jax.nn.silu(u[t] @ wg[e]) * (u[t] @ wu[e])) @ wd[e])
+        np.testing.assert_allclose(np.asarray(got[t]), np.asarray(plain),
+                                   atol=1e-4)

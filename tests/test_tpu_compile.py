@@ -572,3 +572,63 @@ def test_held_experts_product_compiles(for_chip):
             spec((tokens, H), "bfloat16"), spec((H, E), "bfloat16"),
             spec((E,), "bfloat16"), spec((n, H, F), "bfloat16"),
             spec((n, H, F), "bfloat16"), spec((n, F, H), "bfloat16"))
+
+
+@pytest.mark.parametrize("tokens,tile", [(64, 16), (1024, 128)])
+def test_top1_full_width_expert_product_compiles(for_chip, tokens, tile):
+    """ZAYA1-8B's expert layer: 16 experts of the model's own width (hidden
+    2048, expert width 2048), ONE a token: a decode step's 64 rows (tiles
+    of 16, a quarter full) and a chunk's 1,024 (tiles of 128). The width
+    takes columns 512 a grid step: three weight blocks of 2 MB, two
+    buffers each, beside the row tile and its accumulator, inside the 48
+    MB the kernel asks for."""
+    spec, compile_ = for_chip
+    gs = _mod("grouped_swiglu")
+    E, H, F = 16, 2048, 2048
+    assert gs.row_tile(tokens, E) == tile and gs.f_block(F) == 512
+    assert 3 * 2 * H * gs.f_block(F) * 2 + tile * H * (2 * 2 * 2 + 4) \
+        < 48 * 1024 * 1024
+    compile_(
+        lambda u, e, a, wg, wu, wd: gs.dispatch_experts(
+            u, e, a, wg, wu, wd)[0],
+        spec((tokens, H), "bfloat16"), spec((tokens, 1), "int32"),
+        spec((tokens, 1), "float32"), spec((E, H, F), "bfloat16"),
+        spec((E, H, F), "bfloat16"), spec((E, F, H), "bfloat16"))
+
+
+@pytest.mark.parametrize("which", ["step", "chunk"])
+def test_attention_over_a_pool_of_key_head_rows_compiles(for_chip, which):
+    """ZAYA1-8B's attention: 8 query heads over 2 key/value heads of 128,
+    the pools declared ``(num_pages, 128 x 2, 128)`` (a page's (key, head)
+    rows on one axis: whole ``(16, 128)`` tiles, where ``(num_pages, 128,
+    2, 128)`` pads two heads to sixteen rows). The decode step's 64 rows of
+    32 pages, the group of 4 on the window axis; and a chunk of 1,024
+    positions as 8 rows of the grid, 4 heads x 128 positions on the window
+    axis each; a row's pages in one grid step, inside the VMEM the kernel
+    asks for."""
+    spec, compile_ = for_chip
+    pfa = _mod("paged_flash_attention")
+    B, Hq, Hkv, D, page, P = 64, 8, 2, 128, 128, 32
+    pool = spec((B * P + 1, page * Hkv, D), "bfloat16")
+    assert pfa._page_size(pool, Hkv) == page
+    pages, block = pfa._window_tiles(P, page, Hkv, D, 2)
+    assert (pages, block) == (32, 2)
+    if which == "step":
+        assert pfa._window_vmem_bytes(pages, block, page, Hkv, Hq // Hkv,
+                                      D, 2) < pfa._WINDOW_STEP_VMEM_LIMIT
+        _named_once(compile_(
+            lambda q, k, v, pt, pos: pfa.paged_decode_attention(
+                q, k, v, pt, pos, sm_scale=0.088, kv_heads=Hkv),
+            spec((B, Hq, D), "bfloat16"), pool, pool, spec((B, P), "int32"),
+            spec((B,), "int32")))
+    else:
+        C, tq = 1024, 128
+        assert pfa._window_vmem_bytes(pages, block, page, Hkv,
+                                      Hq // Hkv * tq, D, 2) \
+            < pfa._WINDOW_STEP_VMEM_LIMIT
+        _named_once(compile_(
+            lambda q, k, v, pt, off, vl: pfa.paged_window_attention(
+                q, k, v, pt, off, vl, sm_scale=0.088, kv_heads=Hkv),
+            spec((C // tq, tq, Hq, D), "bfloat16"), pool, pool,
+            spec((C // tq, P), "int32"), spec((C // tq,), "int32"),
+            spec((C // tq,), "int32")))
