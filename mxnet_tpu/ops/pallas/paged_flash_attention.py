@@ -6,34 +6,43 @@ table into a materialized ``(B, P*page_size, H, D)`` view and then runs
 dense attention over it — two full copies of every cached key/value per
 decoded token, plus an O(L) score row in HBM. These kernels close that
 gap (the FlashAttention/PagedAttention fusion, ROADMAP item 2): the page
-table rides the grid as a scalar-prefetch operand, each grid step DMAs
-a block of pages directly out of the pool (each page its own operand; a
-page no query of the row sees is not fetched), and an online-softmax
-carry in VMEM scratch accumulates across the sequential block dimension —
-the gather never materializes and scores never leave VMEM.
+table rides the grid as a scalar-prefetch operand, a row's pages are
+copied directly out of the pool (a page no query of the row sees is not
+fetched), and an online-softmax carry in VMEM scratch accumulates across
+the row's blocks — the gather never materializes and scores never leave
+VMEM.
 
-One kernel, two entry points:
+Two kernels, two entry points:
 
 - ``paged_window_attention`` — an S-token query window per row, each
   query ``i`` at absolute position ``q_offset[b] + i`` (causal within
-  and across the window). Grid ``(B, key blocks)``; page ``t`` of row
-  ``b``'s block ``j`` is pool block ``page_table[b, j * pages + t]``, as
-  many pages a block as ``_window_tiles`` gives for the shapes. A page
-  is taken as its ``(key, head)`` rows, the pool's own order, and the
-  rows of every head meet all of them in one product whose foreign
-  columns are masked: no relayout, one softmax carry a block. This is
-  the q_offset-aware PREFILL variant: suffix-only prefix-cache replay
-  and speculative verification both score a short window against a long
-  paged history in one pass.
+  and across the window), through ``_window_kernel``. Grid ``(B, key
+  blocks)``; page ``t`` of row ``b``'s block ``j`` is pool block
+  ``page_table[b, j * pages + t]``, each page an operand of its own that
+  the pipeline copies, as many pages a block as ``_window_tiles`` gives
+  for the shapes. A page is taken as its ``(key, head)`` rows, the pool's
+  own order, and the rows of every head meet all of them in one product
+  whose foreign columns are masked: no relayout, one softmax carry a
+  block. This is the q_offset-aware PREFILL variant: suffix-only
+  prefix-cache replay, a prompt's chunks and speculative verification all
+  score a window against a long paged history in one pass.
 - ``paged_decode_attention`` — single query token per row (the decode
-  hot path): the window kernel at ``S = 1`` with ``q_offset = pos``, or,
-  for grouped-query heads, with the group on the window axis.
+  hot path), or the grouped query heads of one token. Heads of whole
+  lanes (``D`` a multiple of 128: zaya, ouro) go through
+  ``_decode_kernel`` (PR 41), the form ``mla_attention.py`` and
+  ``dsa_decode.py`` have: grid ``(B,)``, the pools left whole in HBM, a
+  row's LIVE pages walked in blocks by the kernel's own copies, two
+  buffers deep (``dsa_decode._walk_live_pages``), the same product and
+  carry. Mosaic copies no page out of a pool whose rows are 64 wide, so
+  heads of 64 (granite, transformer-big) stay on ``_window_kernel`` at
+  ``S = 1`` with ``q_offset = pos``, the group on the window axis: their
+  programs are what they were.
 
 Both take the pool as ``(num_pages, page_size, Hkv, D)`` or, declared the
-way the kernel reads it, ``(num_pages, page_size x Hkv, D)`` with
+way the kernels read it, ``(num_pages, page_size x Hkv, D)`` with
 ``kv_heads=`` saying ``Hkv`` (``_page_size``; ZAYA's two heads of 128, PR
 40), and both take grouped query heads: a window of ``S`` positions by
-``G`` heads a key/value head rides the kernel's window axis whole.
+``G`` heads a key/value head rides the window kernel's window axis whole.
 
 - ``paged_selected_window_attention`` — the window over a SELECTED set
   of cached positions (learned sparse attention), for grouped-query
@@ -69,7 +78,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import _partitionable, _use_interpret
+from .dsa_decode import _walk_live_pages
 from .flash_attention import _NEG_INF
+from .mla_attention import _init, _prec, _softmax_step
 
 __all__ = ["paged_decode_attention", "paged_window_attention",
            "paged_decode_reference", "paged_window_reference",
@@ -112,7 +123,13 @@ def flash_paged_enabled() -> bool:
 # (128 rows of 16 pages of 16 keys) at positions 0 to 40 407 / 404 / 422 /
 # 456 at blocks of 64 / 128 / 256 / 512 KiB, every row full 762 / 581 /
 # 499 / 456. What is left there is the pipeline's own work on 2 x 16 page
-# operands a row, about 3 us, however they are spread over grid steps
+# operands a row, about 3 us, however they are spread over grid steps (7.5
+# us a row at zaya's 2 x 32: PR 40). Since PR 41 this form serves the
+# windows of ``S > 1`` (every cell's chunks, admission prefill, speculative
+# verification) and the decode step at heads of 64, granite's and
+# transformer-big's, whose pages Mosaic cannot copy out of a pool left in
+# HBM; the decode step at heads of 128 walks its live pages
+# (``_decode_kernel``, below, with its own numbers)
 _WINDOW_STEP_BYTES = 2 * 1024 * 1024
 _WINDOW_BLOCK_BYTES = 128 * 1024
 _WINDOW_STEP_VMEM_LIMIT = 32 * 1024 * 1024
@@ -345,6 +362,165 @@ def paged_window_attention(q, k_pool, v_pool, page_table, q_offset,
     return out
 
 
+# ------------------------------------------------- decode (one position)
+# The decode kernel's sizes. A block is one buffer of its scratch and the
+# grain of its copies: as many whole pages as hold _DECODE_BLOCK_KEYS keys
+# or _DECODE_BLOCK_BYTES of one pool, whichever is fewer, and never more
+# than a row's table has. A step is what one softmax carry takes (one
+# product with the keys, one carry, one product with the values): the pages
+# of a block that make _DECODE_STEP_BYTES of one pool. On a v5e (PERF.md,
+# PR 41; the kernel alone, 30 calls in one program, microseconds a call):
+# zaya's call (64 rows at 1,265 positions, 87 MB of live pages of 64 KiB,
+# 107 at 819 GB/s) with blocks of 8 pages in steps of 1 / 2 / 4 / 8 pages
+# 339 / 229 / 185 / 172, blocks of 16 in steps of 4 / 8 189 / 179, blocks
+# of 4 in one step 184, where the pipeline's form (``_window_kernel``,
+# 2 x 32 page operands a row) takes 502; ouro's (10 rows at 213 positions,
+# 24 MB of pages of 512 KiB, 29 at the peak) 58 to 62 at blocks of 1, 2 or
+# 4 pages in steps of 1 or 2 against 70
+_DECODE_BLOCK_KEYS = 1024
+_DECODE_BLOCK_BYTES = 1024 * 1024
+_DECODE_STEP_BYTES = 512 * 1024
+_DECODE_VMEM_LIMIT = 32 * 1024 * 1024
+
+
+def _decode_tiles(P, page_size, Hkv, D, itemsize):
+    """``(pages a block, pages a softmax step)`` of the decode kernel, from
+    the shapes alone: zaya's pages of 64 KiB (128 keys of 2 heads of 128)
+    go 8 a block in one step, ouro's of 512 KiB (16 heads) 2 in steps of
+    1."""
+    page_bytes = page_size * Hkv * D * itemsize
+    block = max(1, min(P, _DECODE_BLOCK_KEYS // page_size,
+                       _DECODE_BLOCK_BYTES // page_bytes))
+    return block, math.gcd(block, max(1, _DECODE_STEP_BYTES // page_bytes))
+
+
+def _decode_kernel(pt_ref, pos_ref, q_ref, k_pool_ref, v_pool_ref, o_ref,
+                   k_buf, v_buf, sem_ref, slot_ref, m_ref, l_ref, acc_ref, *,
+                   block, step, group, sm_scale):
+    """Grid (B,), a row a step, sequential: the scratch, its semaphores and
+    the slot carry over from row to row. ``q_ref (1, H, D)``, query head
+    ``r`` reading key/value head ``r // group``, all at ``pos``; a pool
+    ``(num_pages, page x Hkv, D)`` whole in HBM, a page as its (key, head)
+    rows; ``k_buf``, ``v_buf (2, block x page x Hkv, D)``. A row's live
+    pages (``pos // page + 1``; none below 0: the row hands back zeros) are
+    walked in blocks, each page one copy for K and one for V to its row
+    offset of a buffer, a dead page neither copied nor computed. The
+    arithmetic is ``_window_kernel``'s: the ``H`` query rows meet every row
+    of a step's pages in ONE product, foreign-head columns and keys past
+    ``pos`` masked, scores and the (m, l, acc) carry in float32."""
+    b = pl.program_id(0)
+    P = pt_ref.shape[1]
+    rows = q_ref.shape[1]
+    cols = k_pool_ref.shape[1]               # (key, head) rows a page
+    Hkv = rows // group
+    ps = cols // Hkv
+    pos = pos_ref[b]
+
+    def live_pages(r):
+        return jnp.clip(pos_ref[r] // ps + 1, 0, P)
+
+    def page_copies(page, slot, t):
+        return [pltpu.make_async_copy(
+            pool.at[page], buf.at[slot, pl.ds(t * cols, cols)],
+            sem_ref.at[slot]) for pool, buf in ((k_pool_ref, k_buf),
+                                                (v_pool_ref, v_buf))]
+
+    width = step * cols
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+    own = col % Hkv == \
+        jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0) // group
+    key = col // Hkv                         # a column's key in its step
+
+    def attend(k, slot):
+        n = live_pages(b) - k * block        # pages of this block that live
+        for t in range(0, block, step):
+            @pl.when(t < n)
+            def _step():
+                # the step's pages past the row's last hold what an earlier
+                # row left there: masked keys weigh 0, and 0 x NaN is NaN
+                for u in range(t + 1, t + step):
+                    @pl.when(u >= n)
+                    def _dead():
+                        v_buf[slot, pl.ds(u * cols, cols), :] = jnp.zeros(
+                            (cols, v_buf.shape[2]), v_buf.dtype)
+                kt = k_buf[slot, pl.ds(t * cols, width), :]
+                s = jax.lax.dot_general(
+                    q_ref[0].astype(kt.dtype), kt, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                    precision=_prec(kt.dtype)) * sm_scale    # (rows, width)
+                seen = key <= pos - (k * block + t) * ps
+                s = jnp.where(jnp.logical_and(own, seen), s, _NEG_INF)
+                _softmax_step(s, v_buf[slot, pl.ds(t * cols, width), :],
+                              m_ref, l_ref, acc_ref)
+
+    _init(m_ref, l_ref, acc_ref)
+    _walk_live_pages(pt_ref, slot_ref, live_pages, block, page_copies,
+                     attend)
+    l = jnp.maximum(jnp.sum(l_ref[...], axis=1, keepdims=True), 1e-30)
+    o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "sm_scale", "kv_heads", "block", "step", "interpret"))
+def _paged_decode_impl(q, k_pool, v_pool, page_table, pos, sm_scale,
+                       kv_heads, block, step, interpret):
+    B, H, D = q.shape
+    N, cols = k_pool.shape[0], _page_size(k_pool, kv_heads) * kv_heads
+    kernel = functools.partial(_decode_kernel, block=block, step=step,
+                               group=H // kv_heads, sm_scale=sm_scale)
+    row = pl.BlockSpec((1, H, D), lambda b, pt, at: (b, 0, 0))
+    pool = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[row, pool, pool],
+            out_specs=row,
+            scratch_shapes=[
+                pltpu.VMEM((2, block * cols, D), k_pool.dtype),
+                pltpu.VMEM((2, block * cols, D), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((H, _LANES), jnp.float32),
+                pltpu.VMEM((H, math.gcd(cols, _LANES)), jnp.float32),
+                pltpu.VMEM((H, D), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_DECODE_VMEM_LIMIT),
+        interpret=interpret,
+        name="paged_window",
+    )(page_table.astype(jnp.int32), pos.astype(jnp.int32), q,
+      k_pool.reshape(N, cols, D), v_pool.reshape(N, cols, D))
+
+
+def _decode_walk(q, k_pool, v_pool, page_table, pos, sm_scale, Hkv):
+    """``paged_decode_attention`` through ``_decode_kernel``."""
+    block, step = _decode_tiles(
+        page_table.shape[1], _page_size(k_pool, Hkv), Hkv, q.shape[2],
+        k_pool.dtype.itemsize)
+    return _paged_decode_impl(
+        q, k_pool, v_pool, page_table, pos, sm_scale=float(sm_scale),
+        kv_heads=Hkv, block=block, step=step, interpret=_use_interpret())
+
+
+def _decode_window(q, k_pool, v_pool, page_table, pos, sm_scale, Hkv):
+    """``paged_decode_attention`` through ``_window_kernel``: the window
+    at ``S = 1`` with ``q_offset = pos``, or, for grouped-query heads, the
+    group on the window axis, every query at ``pos``."""
+    B, H, D = q.shape
+    if H == Hkv:
+        return paged_window_attention(q[:, None], k_pool, v_pool,
+                                      page_table, pos, sm_scale=sm_scale,
+                                      kv_heads=Hkv)[:, 0]
+    qg = jnp.swapaxes(q.reshape(B, Hkv, H // Hkv, D), 1, 2)
+    out = paged_window_attention(qg, k_pool, v_pool, page_table, pos,
+                                 sm_scale=sm_scale, shared_position=True,
+                                 kv_heads=Hkv)
+    return jnp.swapaxes(out, 1, 2).reshape(B, H, D)
+
+
 def paged_decode_attention(q, k_pool, v_pool, page_table, pos, *,
                            sm_scale, kv_heads=None):
     """Single-token paged attention, pools read in place.
@@ -355,19 +531,17 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, pos, *,
     int32 — row ``b`` attends keys at absolute positions ``<= pos[b]``
     (the caller has already scattered position ``pos`` into the pool).
     Returns ``(B, H, D)``. Where ``H`` is ``G`` times ``Hkv``
-    (grouped-query heads: query head ``i`` reads key/value head ``i //
-    G``) the ``G`` heads of a group ride the kernel's window axis, all at
-    ``pos``."""
-    B, H, D = q.shape
+    (grouped-query heads) query head ``i`` reads key/value head ``i //
+    G``.
+
+    Heads of whole lanes (``D`` a multiple of 128) walk the row's live
+    pages with the kernel's own copies (``_decode_kernel``); Mosaic copies
+    no page out of a pool whose rows are narrower ("slice shape along
+    dimension 2 must be aligned to tiling (128), but is 64"), so heads of
+    64 keep the pipeline's page operands (``_window_kernel``)."""
     Hkv = k_pool.shape[2] if kv_heads is None else int(kv_heads)
-    if H == Hkv:
-        return paged_window_attention(q[:, None], k_pool, v_pool,
-                                      page_table, pos,
-                                      sm_scale=sm_scale)[:, 0]
-    qg = jnp.swapaxes(q.reshape(B, Hkv, H // Hkv, D), 1, 2)
-    out = paged_window_attention(qg, k_pool, v_pool, page_table, pos,
-                                 sm_scale=sm_scale, shared_position=True)
-    return jnp.swapaxes(out, 1, 2).reshape(B, H, D)
+    form = _decode_window if q.shape[2] % _LANES else _decode_walk
+    return form(q, k_pool, v_pool, page_table, pos, sm_scale, Hkv)
 
 
 # ------------------------------------------------ selected window (GQA)

@@ -20,6 +20,7 @@ Contracts under test:
   requests.
 """
 
+import math
 import os
 import time
 
@@ -227,7 +228,33 @@ class TestFlashPagedKernel:
         "page128_grouped_bf16":
             (128, 8, 4, 64, 3, None, "bfloat16", [0, 127, 128, 383]),
         "page128_f32": (128, 8, 1, 64, 3, None, "float32", [1, 200, 383]),
+        # the shipped widths. zaya: 2 key/value heads x 4 of 128, the pool
+        # declared (pages, 256, 128) (``FLAT_POOLS``); 10 pages a row, so
+        # the walk's second block of 8 is partial
+        "zaya_page128_flat_pool": (128, 2, 4, 128, 10, None, "bfloat16",
+                                   [0, 127, 128, 600, 1279]),
+        # ouro: 16 heads of 128, 4 pages a row in blocks of 2
+        "ouro_page128_h16": (128, 16, 1, 128, 4, None, "bfloat16",
+                             [0, 127, 128, 300, 511]),
+        # transformer-big: pages of 16, 16 heads of 64, positions 0 to 40
+        # of 16 pages
+        "big_page16_h16_d64": (16, 16, 1, 64, 16, None, "bfloat16",
+                               [0, 15, 16, 31, 40]),
+        # the walk's edges: 7 pages a row in blocks of 4 and softmax steps
+        # of 2 (``WALK_STEPS``): live pages that end in a block's first
+        # step, at its end, mid-block, and on the row's last page
+        "page16_live_pages_end_mid_block":
+            (16, 2, 2, 16, 7, 4, "float32", [3, 31, 32, 47, 70, 111]),
+        "page16_one_row": (16, 4, 1, 16, 5, 2, "float32", [40]),
     }
+    # cases whose pools are declared as the kernels read them, ``(pages,
+    # page x Hkv, D)`` with ``kv_heads=`` saying ``Hkv``
+    FLAT_POOLS = {"zaya_page128_flat_pool"}
+    # pages a softmax step of the walk where a case sets its block; the
+    # others take one step a block
+    WALK_STEPS = {"page16_live_pages_end_mid_block": 2,
+                  "page16_grouped_partial_block": 1}
+    FORMS = ("walk", "pipeline")
     # (page, heads, head size, pages a row, pages a block, dtype, window,
     #  offsets, real queries a row)
     WINDOW_CASES = {
@@ -248,9 +275,18 @@ class TestFlashPagedKernel:
     TOL = {"float32": dict(rtol=1e-5, atol=1e-5),
            "bfloat16": dict(rtol=2e-2, atol=2e-2)}
 
-    def _paged(self, rng, B, ps, Hkv, D, P, dtype, block_pages, monkeypatch):
+    @staticmethod
+    def _decode(pfa, form):
+        """``paged_decode_attention`` in one of its two forms, whatever
+        the entry point would pick for the head size."""
+        return {"walk": pfa._decode_walk,
+                "pipeline": pfa._decode_window}[form]
+
+    def _paged(self, rng, B, ps, Hkv, D, P, dtype, block_pages, monkeypatch,
+               step_pages=None):
         """Pools whose page 0 is the trash page, a table with each row's
-        own pages, and the kernel's block set to ``block_pages`` pages."""
+        own pages, and the kernels' blocks set to ``block_pages`` pages
+        (the walk's softmax step to ``step_pages``, a block by default)."""
         from mxnet_tpu.ops.pallas import paged_flash_attention as pfa
         kp = jnp.asarray(rng.randn(B * P + 1, ps, Hkv, D), dtype)
         vp = jnp.asarray(rng.randn(B * P + 1, ps, Hkv, D), dtype)
@@ -266,19 +302,31 @@ class TestFlashPagedKernel:
             assert pfa._window_tiles(
                 P, ps, Hkv, D, jnp.dtype(dtype).itemsize) == (
                     min(P, 2 * block_pages), min(P, block_pages))
+            step_pages = step_pages or block_pages
+            monkeypatch.setattr(pfa, "_DECODE_BLOCK_KEYS", block_pages * ps)
+            monkeypatch.setattr(pfa, "_DECODE_STEP_BYTES",
+                                step_pages * page_bytes)
+            assert pfa._decode_tiles(
+                P, ps, Hkv, D, jnp.dtype(dtype).itemsize) == (
+                    min(P, block_pages),
+                    math.gcd(min(P, block_pages), step_pages))
         return pfa, kp, vp, table
 
+    @pytest.mark.parametrize("form", FORMS)
     @pytest.mark.parametrize("case", sorted(DECODE_CASES))
-    def test_decode_kernel_matches_reference(self, case, monkeypatch):
+    def test_decode_kernel_matches_reference(self, case, form, monkeypatch):
         ps, Hkv, G, D, P, block, dtype, pos = self.DECODE_CASES[case]
         rng = np.random.RandomState(0)
         B = len(pos)
         pfa, kp, vp, table = self._paged(rng, B, ps, Hkv, D, P, dtype,
-                                         block, monkeypatch)
+                                         block, monkeypatch,
+                                         self.WALK_STEPS.get(case))
         q = jnp.asarray(rng.randn(B, Hkv * G, D), dtype)
         pos = jnp.asarray(np.array(pos, np.int32))
-        got = pfa.paged_decode_attention(q, kp, vp, jnp.asarray(table), pos,
-                                         sm_scale=D ** -0.5)
+        flat = (lambda p: p.reshape(p.shape[0], ps * Hkv, D)) \
+            if case in self.FLAT_POOLS else (lambda p: p)
+        got = self._decode(pfa, form)(q, flat(kp), flat(vp),
+                                      jnp.asarray(table), pos, D ** -0.5, Hkv)
         # query head i reads key/value head i // G: the reference takes
         # one key/value head a query head
         want = pfa.paged_decode_reference(
@@ -287,6 +335,80 @@ class TestFlashPagedKernel:
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32),
                                    **self.TOL[dtype])
+
+    @pytest.mark.parametrize("D,form", [(128, "walk"), (256, "walk"),
+                                        (64, "pipeline"), (16, "pipeline")])
+    def test_entry_point_picks_the_form_by_the_head_size(self, D, form,
+                                                         monkeypatch):
+        """Heads of whole lanes walk the live pages with the kernel's own
+        copies; Mosaic copies no page out of a pool whose rows are
+        narrower, so those keep the pipeline's page operands. The choice
+        reads the query's last axis and nothing else."""
+        from mxnet_tpu.ops.pallas import paged_flash_attention as pfa
+        took = []
+        for name in ("walk", "window"):
+            monkeypatch.setattr(
+                pfa, f"_decode_{name}",
+                lambda q, *a, _n=name: (took.append(_n), q)[1])
+        q = jnp.zeros((2, 4, D), jnp.bfloat16)
+        pool = jnp.zeros((5, 8, 2, D), jnp.bfloat16)
+        pfa.paged_decode_attention(q, pool, pool, jnp.zeros((2, 2), jnp.int32),
+                                   jnp.zeros((2,), jnp.int32), sm_scale=1.0)
+        assert took == [{"walk": "walk", "pipeline": "window"}[form]]
+
+    # rows of the batch: a position, or None for a row that is not live
+    # at all (position -1, its table on the trash page). Between them
+    # rows parked on the trash page at a position of their own, as the
+    # burst leaves an inactive row
+    WALK_ROWS = {
+        # blocks of 4 pages in steps of 2: a dead row first, so that the
+        # first live row starts the walk; two dead rows in a row; a dead
+        # row last
+        "dead_rows_between_live_ones":
+            (4, 2, [None, 5, "trash:40", None, 63, 16, "trash:0", 111, None]),
+        "one_live_row_among_dead_ones": (4, 4, [None, "trash:70", 100, None]),
+        "a_page_a_block": (1, 1, [0, None, 17, "trash:3", 111]),
+        "no_live_row": (2, 2, [None, None]),
+    }
+
+    @pytest.mark.parametrize("case", sorted(WALK_ROWS))
+    def test_walk_skips_dead_rows_and_reads_no_dead_page(self, case,
+                                                         monkeypatch):
+        """The pool's untouched pages AND the trash page hold NaN and inf:
+        a row below position 0 copies nothing and hands back zeros, a row
+        parked on the trash page reads it alone, and neither a dead page
+        nor what a block's dead tail still holds from an earlier row (the
+        trash page's NaN among it) reaches a live row's output."""
+        block, step, rows = self.WALK_ROWS[case]
+        rng = np.random.RandomState(11)
+        ps, Hkv, G, D, P = 16, 2, 2, 16, 7
+        B = len(rows)
+        pfa, kp, vp, table = self._paged(rng, B, ps, Hkv, D, P, "float32",
+                                         block, monkeypatch, step)
+        pos = np.zeros(B, np.int32)
+        live = np.zeros(B, bool)
+        for b, row in enumerate(rows):
+            if row is None:
+                pos[b], table[b] = -1, 0
+            elif isinstance(row, str):
+                pos[b], table[b] = int(row.split(":")[1]), 0
+            else:
+                pos[b], live[b] = row, True
+        clean_k, clean_v = kp, vp
+        bad = jnp.asarray(np.resize([np.nan, np.inf, -np.inf],
+                                    kp.shape[1:]), kp.dtype)
+        for page in {0} | {int(table[b, p]) for b in range(B)
+                           for p in range(pos[b] // ps + 1, P)}:
+            kp, vp = kp.at[page].set(bad), vp.at[page].set(bad)
+        q = jnp.asarray(rng.randn(B, Hkv * G, D).astype(np.float32))
+        args = (jnp.asarray(table), jnp.asarray(pos))
+        got = np.asarray(pfa._decode_walk(q, kp, vp, *args, 0.25, Hkv))
+        want = np.asarray(pfa.paged_decode_reference(
+            q, jnp.repeat(clean_k, G, axis=2), jnp.repeat(clean_v, G, axis=2),
+            args[0], jnp.maximum(args[1], 0), sm_scale=0.25))
+        np.testing.assert_allclose(got[live], want[live], rtol=1e-5,
+                                   atol=1e-5)
+        assert np.abs(got[pos < 0]).sum() == 0.0
 
     @pytest.mark.parametrize("case", sorted(WINDOW_CASES))
     def test_window_kernel_matches_reference_offset_and_padding(
@@ -310,8 +432,9 @@ class TestFlashPagedKernel:
         for b in range(B):
             assert np.abs(got[b, int(vl[b]):]).sum() == 0.0
 
+    @pytest.mark.parametrize("form", FORMS)
     @pytest.mark.parametrize("block_pages", [1, 2, 4])
-    def test_kernel_reads_no_page_past_the_position(self, block_pages,
+    def test_kernel_reads_no_page_past_the_position(self, block_pages, form,
                                                     monkeypatch):
         """A table's entries past a row's position may point anywhere (a
         retired request's pages, the trash page): the kernel fetches none
@@ -332,8 +455,7 @@ class TestFlashPagedKernel:
                 vp = vp.at[table[b, p]].set(np.nan)
         q = jnp.asarray(rng.randn(B, H, D).astype(np.float32))
         args = (jnp.asarray(table), jnp.asarray(pos))
-        got = np.asarray(pfa.paged_decode_attention(q, kp, vp, *args,
-                                                    sm_scale=0.25))
+        got = np.asarray(self._decode(pfa, form)(q, kp, vp, *args, 0.25, H))
         want = np.asarray(pfa.paged_decode_reference(
             q, clean_k, clean_v, *args, sm_scale=0.25))
         assert np.isfinite(got).all()
@@ -378,9 +500,10 @@ class TestFlashPagedKernel:
                 0, 4, body, jnp.zeros((4,) + like.shape, like.dtype))
         return np.asarray(run(kp, vp), np.float32)
 
+    @pytest.mark.parametrize("form", FORMS)
     @pytest.mark.parametrize("case", sorted(PLANE_CASES))
     def test_decode_kernel_reads_a_plane_through_a_moved_table(
-            self, case, monkeypatch):
+            self, case, form, monkeypatch):
         ps, Hkv, G, D, P, block, dtype, pos = self.PLANE_CASES[case]
         rng = np.random.RandomState(5)
         B = len(pos)
@@ -389,8 +512,8 @@ class TestFlashPagedKernel:
         q = jnp.asarray(rng.randn(B, Hkv * G, D), dtype)
         pos = jnp.asarray(np.array(pos, np.int32))
         got = self._every_plane(
-            lambda k, v, pt: pfa.paged_decode_attention(
-                q, k, v, pt, pos, sm_scale=D ** -0.5), kp, vp, table, q)
+            lambda k, v, pt: self._decode(pfa, form)(
+                q, k, v, pt, pos, D ** -0.5, Hkv), kp, vp, table, q)
         for t in range(4):
             want = pfa.paged_decode_reference(
                 q, jnp.repeat(kp[t], G, axis=2), jnp.repeat(vp[t], G, axis=2),

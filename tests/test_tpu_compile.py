@@ -15,7 +15,9 @@ touch it, and never while a module is imported (no top-level call, no
 
 import importlib
 import json
+import math
 import os
+import re
 import subprocess
 import sys
 
@@ -145,27 +147,72 @@ def test_layer_norm_under_a_mesh_takes_the_partitionable_form(
 SMOKE = (SLOTS, PAGES_PER_SLOT, PAGE, HEADS, HEAD_DIM,
          SLOTS * PAGES_PER_SLOT + 1)
 BIG = (128, 16, 16, 16, 64, 1153)
+# the decode call of the three other cells that make it, as (rows, pages a
+# row, query heads, key/value heads, head size, the pool's shape): zaya's
+# pools are declared as the kernel reads them, ouro's ride flattened over
+# their four planes
+ZAYA = (64, 32, 8, 2, 128, (64 * 32 + 1, 128 * 2, 128))
+OURO = (10, 4, 16, 16, 128, (4 * 41, 128, 16, 128))
+GRANITE = (64, 12, 32, 8, 64, (64 * 12 + 1, 128, 8, 64))
 
 
 def _named_once(compiled):
-    """One call, under the name the ledger's breakdown lists it by."""
+    """One call, under the name the ledger's breakdown lists it by; its
+    operands' shapes."""
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert "%paged_window" in text
+    call = next(ln for ln in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in ln)
+    return re.findall(r"\w+\[[\d,]*\]", call.split(
+        "operand_layout_constraints={")[1].split("frontend_attributes")[0])
+
+
+def _decode_call(for_chip, B, P, Hq, Hkv, D, pool_shape, dtype):
+    spec, compile_ = for_chip
+    pfa = _mod("paged_flash_attention")
+    pool = spec(pool_shape, dtype)
+    return _named_once(compile_(
+        lambda q, k, v, pt, pos: pfa.paged_decode_attention(
+            q, k, v, pt, pos, sm_scale=D ** -0.5, kv_heads=Hkv),
+        spec((B, Hq, D), dtype), pool, pool,
+        spec((B, P), "int32"), spec((B,), "int32")))
 
 
 @pytest.mark.parametrize("shape,dtype", [
     (SMOKE, "float32"), (SMOKE, "bfloat16"), (BIG, "bfloat16")])
 def test_paged_decode_attention_compiles(for_chip, shape, dtype):
-    spec, compile_ = for_chip
+    """Heads of 64: the pipeline's form, a row a grid step whose operands
+    are the row's pages one by one (Mosaic copies no page of ``(page x
+    heads, 64)`` out of a pool left in HBM: "slice shape along dimension 2
+    must be aligned to tiling (128), but is 64")."""
     pfa = _mod("paged_flash_attention")
     B, P, page, H, D, pool_pages = shape
-    pool = spec((pool_pages, page, H, D), dtype)
-    _named_once(compile_(
-        lambda q, k, v, pt, pos: pfa.paged_decode_attention(
-            q, k, v, pt, pos, sm_scale=D ** -0.5),
-        spec((B, H, D), dtype), pool, pool,
-        spec((B, P), "int32"), spec((B,), "int32")))
+    operands = _decode_call(for_chip, B, P, H, H, D,
+                            (pool_pages, page, H, D), dtype)
+    pages = pfa._window_tiles(P, page, H, D, jnp.dtype(dtype).itemsize)[0]
+    assert len(operands) == 4 + 2 * pages
+
+
+@pytest.mark.parametrize("cell", ["zaya", "ouro"])
+def test_paged_decode_attention_walks_whole_pools(for_chip, cell):
+    """Heads of 128 (PR 41): ONE call named ``%paged_window`` whose
+    operands are the page table, the positions, the queries and the two
+    pools WHOLE, left in HBM: no operand a page. The block and the softmax
+    step come from the shapes alone, and two buffers of a block of K and
+    of V stay far under the limit the kernel asks the compiler for."""
+    pfa = _mod("paged_flash_attention")
+    B, P, Hq, Hkv, D, pool_shape = {"zaya": ZAYA, "ouro": OURO}[cell]
+    page = 128
+    block, step = pfa._decode_tiles(P, page, Hkv, D, 2)
+    assert (block, step) == {"zaya": (8, 8), "ouro": (2, 1)}[cell]
+    assert 2 * 2 * block * page * Hkv * D * 2 <= 4 << 20 \
+        and pfa._DECODE_VMEM_LIMIT <= 32 << 20
+    operands = _decode_call(for_chip, B, P, Hq, Hkv, D, pool_shape,
+                            "bfloat16")
+    assert len(operands) == 5
+    assert operands[3:] == \
+        [f"bf16[{pool_shape[0]},{page * Hkv},{D}]"] * 2, operands
 
 
 @pytest.mark.parametrize("tokens,tile", [(16, 16), (2048, 128)])
@@ -222,8 +269,6 @@ def test_index_select_compiles(for_chip, monkeypatch):
     window's selection holds no loop over the ``(2048, 16640)`` scores any
     more (the radix select's sixteen trips and the score blocks' loop are
     the ``jax.numpy`` form's)."""
-    import re
-
     from mxnet_tpu.ops import sparse_attention as dsa
 
     spec, compile_ = for_chip
@@ -318,16 +363,12 @@ def test_decode_step_selection_and_attention_compile(for_chip, monkeypatch):
 def test_grouped_decode_attention_compiles(for_chip):
     """granite-4.0-h-micro's decode attention: 64 rows, 32 query heads
     over 8 key/value heads of 64 (the group of 4 rides the window axis,
-    every query at the row's position), 12 pages of 128 a row."""
-    spec, compile_ = for_chip
-    pfa = _mod("paged_flash_attention")
-    B, Hq, Hkv, D, page, P = 64, 32, 8, 64, 128, 12
-    pool = spec((B * P + 1, page, Hkv, D), "bfloat16")
-    _named_once(compile_(
-        lambda q, k, v, pt, pos: pfa.paged_decode_attention(
-            q, k, v, pt, pos, sm_scale=1 / 64),
-        spec((B, Hq, D), "bfloat16"), pool, pool, spec((B, P), "int32"),
-        spec((B,), "int32")))
+    every query at the row's position), 12 pages of 128 a row: heads of
+    64 keep the pipeline's form, a row's 12 pages of K and of V as
+    operands of one grid step."""
+    B, P, Hq, Hkv, D, pool_shape = GRANITE
+    assert len(_decode_call(for_chip, B, P, Hq, Hkv, D, pool_shape,
+                            "bfloat16")) == 4 + 2 * P
 
 
 def test_long_row_decode_attention_compiles(for_chip):
@@ -602,33 +643,111 @@ def test_attention_over_a_pool_of_key_head_rows_compiles(for_chip, which):
     the pools declared ``(num_pages, 128 x 2, 128)`` (a page's (key, head)
     rows on one axis: whole ``(16, 128)`` tiles, where ``(num_pages, 128,
     2, 128)`` pads two heads to sixteen rows). The decode step's 64 rows of
-    32 pages, the group of 4 on the window axis; and a chunk of 1,024
-    positions as 8 rows of the grid, 4 heads x 128 positions on the window
-    axis each; a row's pages in one grid step, inside the VMEM the kernel
+    32 pages walk their live pages, 8 a block (``test_paged_decode_
+    attention_walks_whole_pools``); a chunk of 1,024 positions goes as 8
+    rows of the window kernel's grid, 4 heads x 128 positions on the window
+    axis each, a row's pages in one grid step, inside the VMEM the kernel
     asks for."""
     spec, compile_ = for_chip
     pfa = _mod("paged_flash_attention")
-    B, Hq, Hkv, D, page, P = 64, 8, 2, 128, 128, 32
-    pool = spec((B * P + 1, page * Hkv, D), "bfloat16")
+    B, P, Hq, Hkv, D, pool_shape = ZAYA
+    page = pool_shape[1] // Hkv
+    pool = spec(pool_shape, "bfloat16")
     assert pfa._page_size(pool, Hkv) == page
-    pages, block = pfa._window_tiles(P, page, Hkv, D, 2)
-    assert (pages, block) == (32, 2)
     if which == "step":
-        assert pfa._window_vmem_bytes(pages, block, page, Hkv, Hq // Hkv,
-                                      D, 2) < pfa._WINDOW_STEP_VMEM_LIMIT
-        _named_once(compile_(
-            lambda q, k, v, pt, pos: pfa.paged_decode_attention(
-                q, k, v, pt, pos, sm_scale=0.088, kv_heads=Hkv),
-            spec((B, Hq, D), "bfloat16"), pool, pool, spec((B, P), "int32"),
-            spec((B,), "int32")))
+        assert _decode_call(for_chip, *ZAYA, "bfloat16")[3:] == \
+            ["bf16[2049,256,128]"] * 2
     else:
         C, tq = 1024, 128
+        pages, block = pfa._window_tiles(P, page, Hkv, D, 2)
+        assert (pages, block) == (32, 2)
         assert pfa._window_vmem_bytes(pages, block, page, Hkv,
                                       Hq // Hkv * tq, D, 2) \
             < pfa._WINDOW_STEP_VMEM_LIMIT
-        _named_once(compile_(
+        assert len(_named_once(compile_(
             lambda q, k, v, pt, off, vl: pfa.paged_window_attention(
                 q, k, v, pt, off, vl, sm_scale=0.088, kv_heads=Hkv),
             spec((C // tq, tq, Hq, D), "bfloat16"), pool, pool,
             spec((C // tq, P), "int32"), spec((C // tq,), "int32"),
-            spec((C // tq,), "int32")))
+            spec((C // tq,), "int32")))) == 4 + 2 * pages
+
+
+# ---- the decode bursts of the three cells whose step calls
+# ``paged_decode_attention``, at the configurations' own widths, slots and
+# pages and a few layers (the whole depths, compiled in a scratch script:
+# PERF.md section 6, PR 41)
+BURSTS = {
+    # cell: (layers kept, temporaries the parent's burst of the WHOLE depth
+    # reads in bytes (PERF.md sections 4 and 7 (u)), copies of a pool's
+    # shape the parent's burst makes a layer that has pools)
+    "zaya1-8b": (2, 0.047e9, 0),
+    "ouro-2.6b": (2, 0.07e9, 0),
+    # granite's pools of heads of 64 are copied to the kernel's layout
+    # before the loop and back after it (ROADMAP S3): not this PR's, and
+    # not to grow. Six layers hold ONE attention layer
+    "granite-4.0-h-micro": (6, 1.6e9, 4),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(BURSTS))
+def test_decode_burst_copies_no_pool(one_chip, monkeypatch, cell):
+    """The burst program (``iter_tokens`` decode steps in one ``while``)
+    compiled for the described chip from the cell's own configuration,
+    zeros for weights: no pool is copied whole on its way to
+    ``%paged_window`` (one such copy a layer is 268 MB in zaya, which holds
+    14.92 of 16.9 GB), and the temporaries stay under what the parent's
+    burst reads at the whole depth."""
+    from mxnet_tpu import nd
+    from mxnet_tpu.parallel import InferStep
+
+    monkeypatch.syspath_prepend(REPO_ROOT)
+    from perf.harness.loader import load_module
+
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "0")
+    monkeypatch.setenv("MXTPU_FLASH_PAGED", "force")
+    layers, parent_temporaries, parent_copies = BURSTS[cell]
+    perf = os.path.join(REPO_ROOT, "perf")
+    with open(os.path.join(perf, "configs", cell + ".json")) as f:
+        cfg = json.load(f)
+    cfg["num_hidden_layers"] = layers
+    if "layer_types" in cfg:
+        cfg["layer_types"] = cfg["layer_types"][:layers]
+    driver = load_module(os.path.join(perf, "drivers",
+                                      cfg["driver"] + ".py"))
+    ref = load_module(os.path.join(perf, "reference", cell + ".py"))
+    mod, cls = cfg["program"]["model"].split(":")
+    net = getattr(importlib.import_module(mod), cls)(
+        **driver._model_kwargs(cfg))
+    net.collect_params().setattr("grad_req", "null")
+    params, dtype = net._collect_params_with_prefix(), \
+        cfg["precision"]["weights"]
+    for name, shape in ref.tensor_specs(cfg).items():
+        params[name].set_data(nd.NDArray(jnp.zeros(shape, dtype)))
+    eng = InferStep(net, amp=dtype, eos_id=-1)
+    srv = cfg["serving"]
+    slots, page = srv["slots"], srv["page_size"]
+    P = -(-(max(srv["prompt_buckets"]) + srv["max_new_tokens"]) // page)
+
+    def placed(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    state = jax.eval_shape(
+        lambda: eng.init_paged_state(slots, slots * P, page, 0))
+    rows = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    compiled = eng._get_decode_iter_fn(srv["iter_tokens"], "greedy", 0).lower(
+        *jax.tree_util.tree_map(placed, (
+            eng._values, state,
+            jax.ShapeDtypeStruct((slots, P), jnp.int32), rows, rows,
+            jax.ShapeDtypeStruct((slots,), jnp.bool_),
+            jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.float32)))).compile()
+    text = compiled.as_text()
+    with_pools = len(state["k_pools"])       # layers that keep K/V pools
+    assert text.count("%paged_window") >= with_pools
+    # whatever view of a pool is copied, it has the pool's size
+    sizes = {p.size for p in state["k_pools"]}
+    copies = [ln for ln in text.splitlines() if " copy(" in ln and sizes & {
+        math.prod(map(int, dims.split(","))) for dims in re.findall(
+            r"\[([\d,]+)\]", ln.split(" copy(")[0])}]
+    assert len(copies) <= parent_copies * with_pools, copies[:3]
+    assert compiled.memory_analysis().temp_size_in_bytes < parent_temporaries

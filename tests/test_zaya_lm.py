@@ -248,6 +248,31 @@ def test_chunks_then_decode_follow_the_reference_at_every_position(
         calls * LAYERS * EXPERTS
 
 
+def test_heads_of_128_decode_through_the_walk(ref, driver, monkeypatch):
+    """At the published head size the decode step's call is the kernel
+    that walks a row's live pages with its own copies (interpreted here;
+    ``paged_decode_attention`` picks it by ``D % 128``): pools declared
+    ``(pages, page x 2, 128)``, the group of two on the query rows. The
+    served tokens are the reference's at every position."""
+    from mxnet_tpu.ops.pallas import paged_flash_attention as pfa
+
+    monkeypatch.setitem(TINY, "head_dim", 128)
+    monkeypatch.setenv("MXTPU_FLASH_PAGED", "force")
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    walked, walk = [], pfa._decode_walk
+    monkeypatch.setattr(pfa, "_decode_walk", lambda q, k_pool, *a: (
+        walked.append(k_pool.shape[1:]), walk(q, k_pool, *a))[1])
+    prompt, n_new = tokens(9, 19), 6
+    served, counts, _ = _serve_by_hand(build(ref, driver), prompt, n_new)
+    assert set(walked) == {(PAGE * KV, 128)}
+    seq = np.concatenate([prompt, served[:-1]])
+    logits = np.asarray(ref.forward(
+        SEED, TINY, seq, want=len(prompt) - 1 + np.arange(n_new)))
+    assert served == [int(t) for t in logits.argmax(-1)]
+    assert ref.served_token_gaps(SEED, TINY, prompt, served).max() < 1e-5
+    assert counts[ATTN_KEYS] == len(seq) * (len(seq) + 1) // 2
+
+
 # ------------------------------- a page is a function of three positions
 def _first_pages(state, n):
     """The first ``n`` positions of slot 1's first-layer K and V, ``(n,
