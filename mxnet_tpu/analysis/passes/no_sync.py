@@ -45,13 +45,19 @@ FAST_PATH_FUNCS = ("__call__", "_dispatch")
 # the root registration and its store dispatch, each request's trie
 # insert): a new root's cross frames stay on the device, and retiring a
 # request reads nothing back. _apply_prefix_hits is the one batched
-# adoption dispatch of an admission group.
-SCHEDULER_FUNCS = ("_dispatch", "_step_once", "_retire", "_register_roots",
+# adoption dispatch of an admission group. The burst dispatched AHEAD of
+# the last one's read-back is linted as _dispatch is, with the rule that
+# allows it (_may_run_ahead: state the scheduler holds, nothing read from
+# the device), and so is the engine's next_carry, the program that makes
+# its operands on the device: a sync in any of the three would put the
+# host's turn back between two bursts.
+SCHEDULER_FUNCS = ("_dispatch", "_may_run_ahead", "_dispatch_ahead",
+                   "_step_once", "_retire", "_register_roots",
                    "_store_rows", "_register_prefix", "_apply_prefix_hits")
 TARGETS = (
     (STEP_PY, "TrainStep", FAST_PATH_FUNCS),
     (INFER_PY, "InferStep", ("__call__", "_dispatch", "decode_n",
-                             "decode_iter", "prefill_paged",
+                             "decode_iter", "next_carry", "prefill_paged",
                              "prefill_suffix_paged", "spec_draft",
                              "spec_verify")),
     (BATCHER_PY, "ContinuousBatcher", SCHEDULER_FUNCS),
@@ -62,7 +68,7 @@ TARGETS = (
 # around its own compiled calls. (decode_n and __call__ stage their
 # operands on the device by design and stay under the first set alone.)
 DISPATCH_TARGETS = (
-    (INFER_PY, "InferStep", ("decode_iter", "prefill_paged",
+    (INFER_PY, "InferStep", ("decode_iter", "next_carry", "prefill_paged",
                              "prefill_suffix_paged", "spec_draft",
                              "spec_verify")),
     (BATCHER_PY, "ContinuousBatcher", SCHEDULER_FUNCS),

@@ -865,6 +865,53 @@ class InferStep:
                             active, seed, temp)
         return NDArray(buf), new_state
 
+    def _get_next_carry_fn(self, steps):
+        cfg = ("next_carry", steps)
+        fn = self._paged_fns.get(cfg)
+        if fn is not None:
+            return fn
+        two = self.slot_state["step_tokens"] == 2
+
+        def carry(block, lengths):
+            if not two:
+                return block[:, steps - 1], lengths + steps
+            # four columns a step, [token, second token, count, draft]: a
+            # row moves ``count`` positions a step and its last token is
+            # the last one a count reached (``_decode_two``)
+            tok, moved = block[:, 0], jnp.zeros_like(lengths)
+            for j in range(steps):
+                first, second, count = (block[:, 4 * j + c]
+                                        for c in range(3))
+                tok = jnp.where(count > 1, second,
+                                jnp.where(count > 0, first, tok))
+                moved = moved + count
+            return tok, lengths + moved
+
+        fn = jax.jit(carry)
+        self._paged_fns[cfg] = fn
+        return fn
+
+    def next_carry(self, block, lengths, steps=1):
+        """The next burst's ``tokens`` and ``lengths`` from the token block
+        the last burst handed back (``decode_iter``'s, counts' columns and
+        all) and the lengths that burst was given, by the arithmetic the
+        scheduler's collect phase does on the host: the last column and
+        ``lengths + steps``, or, for a net whose step yields up to two
+        tokens, the last token a ``count`` reached and ``lengths`` plus the
+        counts' sum. A program of its own beside the burst, so that a
+        scheduler may dispatch the burst after this one before it has read
+        this one; the block stays the caller's to read (nothing is
+        donated). A row that ended inside the block gets a token and a
+        length nobody may use. One enqueue, sync-free by lint. Returns
+        ``(tokens, lengths)`` as device arrays."""
+        block, lengths = self._operands(np.int32, block, lengths)
+        steps = max(steps, 1)
+        sig = ("next_carry", steps, block.shape)
+        self.compile_guard.observe(
+            sig, lambda: f"next_carry({steps}) "
+            + _cc.aval_summary((block, lengths)))
+        return self._get_next_carry_fn(steps)(block, lengths)
+
     # ---------------------------------------------------- speculative decode
     # Speculative decoding (ISSUE 14): a small DRAFT engine proposes k
     # greedy tokens per slot (one decode_iter dispatch of its own), then

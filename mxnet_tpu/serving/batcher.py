@@ -387,6 +387,25 @@ class _Slot:
         return not self.finished and self.carry is not None
 
 
+class _Burst:
+    """A decode burst dispatched and not yet read: the rows it ran for AS
+    THEY STOOD at the dispatch (slot index and slot record), the token
+    block on the device, the weights' version it ran on, the lengths it
+    was given (the host's array, or the device's for a burst dispatched
+    ahead), the instant of the dispatch, and a speculative round's draft
+    milliseconds."""
+
+    __slots__ = ("rows", "buf", "version", "lengths", "t0", "draft_ms")
+
+    def __init__(self, rows, buf, version, lengths, t0, draft_ms=None):
+        self.rows = rows
+        self.buf = buf
+        self.version = version
+        self.lengths = lengths
+        self.t0 = t0
+        self.draft_ms = draft_ms
+
+
 class ContinuousBatcher:
     """Iteration-level scheduler over a paged KV cache.
 
@@ -395,6 +414,17 @@ class ContinuousBatcher:
     to the pool, and admits queued requests into the vacated slots via a
     jitted prefill-into-pages dispatch — the decode batch stays full
     under load without a single retrace.
+
+    While EVERY slot decodes the scheduler keeps one burst queued behind
+    the burst in flight: the next burst takes its tokens and lengths from
+    the device (``InferStep.next_carry``) and is dispatched BEFORE the last
+    one is read, so reading, streaming, retiring and taking in run under
+    device work. Only where nothing could be seated whatever arrives
+    (``_may_run_ahead``): no slot free or entering, no row within a burst
+    of its ``max_new_tokens``, the pages of a second burst granted without
+    a fight, speculation off. Wherever that does not hold a pass is
+    dispatch, then read, as ever, and a first token waits behind no burst
+    it would not have waited behind.
 
     Parameters
     ----------
@@ -621,6 +651,14 @@ class ContinuousBatcher:
         self._pending = collections.deque()
         self._seq = 0
         self._iter = 0
+        # the burst dispatched ahead and not yet read (``_Burst``), or
+        # None: written by the scheduler thread alone, between two passes
+        # it is the one thing the device still runs for this batcher
+        self._flight = None
+        # the end of the last read-back: a burst dispatched ahead began on
+        # the device no earlier than this
+        self._read_at = 0.0
+        self._all_active = _np.ones((self.slots,), bool)
         # stats + the rolling-wait window are written by the scheduler
         # thread AND by submit-side admission control (caller threads);
         # every touch goes through this lock — an unsynchronized
@@ -630,6 +668,10 @@ class ContinuousBatcher:
         self.stats = {"iterations": 0, "occupancy_sum": 0.0,
                       "admitted": 0, "retired": 0, "preempted": 0,
                       "rejected": 0, "tokens": 0,
+                      # bursts dispatched before the burst before them
+                      # was read (over ``iterations``: the share of passes
+                      # whose host turn ran under device work)
+                      "bursts_ahead": 0,
                       # disaggregated serving: KV handoffs adopted into
                       # this pool / handoffs that fell back to a local
                       # re-prefill (serving.disagg)
@@ -768,14 +810,16 @@ class ContinuousBatcher:
             f"{self._label()} stopped with this request in flight"))
 
     def _drained(self) -> bool:
-        """Nothing queued, waiting or in a slot, and nothing on its way
-        from one to the next. A request changes hands inside a pass alone
-        (an admission prefill holds it in neither ``_pending`` nor
-        ``_slots``), so while a pass runs, or where one began or ended
-        during this read, the answer is no."""
+        """Nothing queued, waiting or in a slot, no burst dispatched
+        ahead and unread, and nothing on its way from one to the next. A
+        request changes hands inside a pass alone (an admission prefill
+        holds it in neither ``_pending`` nor ``_slots``), so while a pass
+        runs, or where one began or ended during this read, the answer is
+        no."""
         seq = self._pass_seq
         return not seq & 1 and self._queue.empty() and not self._pending \
-            and not any(self._slots) and self._pass_seq == seq
+            and not any(self._slots) and self._flight is None \
+            and self._pass_seq == seq
 
     @property
     def healthy(self) -> bool:
@@ -963,7 +1007,9 @@ class ContinuousBatcher:
                 self._state, self.pool.table, zeros, zeros,
                 _np.zeros((self.slots,), bool), steps=self.iter_tokens,
                 **self._sampling)
-            jax.block_until_ready((tok.data, buf.data))
+            # (and the program that carries one burst into the next)
+            jax.block_until_ready((tok.data, buf.data, eng.next_carry(
+                buf, zeros, steps=self.iter_tokens)))
             reg.counter("compile/warmup_compiles").inc(
                 eng.compile_guard.signatures - before)
             eng.compile_guard.mark_steady()
@@ -998,6 +1044,11 @@ class ContinuousBatcher:
             _np.zeros((self.slots,), bool), steps=self.iter_tokens,
             **self._sampling)
         jax.block_until_ready(buf.data)
+        if not self._spec_on:
+            # the program that carries one burst into the next; a batcher
+            # that speculates dispatches nothing ahead and has none
+            jax.block_until_ready(eng.next_carry(
+                buf, zeros, steps=self.iter_tokens))
         if self._spec_on:
             # one inert speculative round compiles BOTH spec programs
             # (draft k-token proposal + target k+1 verification)
@@ -1072,10 +1123,16 @@ class ContinuousBatcher:
             # thread-exception hook
             if not isinstance(e, _faults.FaultInjected):
                 raise
+        finally:
+            # a burst dispatched ahead is left to the device and never
+            # read: whoever stops the thread fails its rows and takes
+            # their pages back (``_fail_inflight``)
+            self._flight = None
 
     def _fail_inflight(self, error):
         """Fail the requests the scheduler already took off the queue
-        (slots and the waiting line) and take every page back."""
+        (slots and the waiting line) and take every page back (a burst
+        dispatched ahead went with the scheduler's thread: ``_run``)."""
         for i, s in enumerate(self._slots):
             if s is not None and not s.req.future.done():
                 s.req.future._fail(error)
@@ -1096,8 +1153,10 @@ class ContinuousBatcher:
                 self._arrival.wait(0.05)
 
     def _step_once(self) -> bool:
-        """One scheduler iteration: retire -> admit -> decode -> collect.
-        Returns False when there was nothing to do (idle)."""
+        """One scheduler iteration: retire -> admit -> decode -> collect;
+        while every slot decodes, the NEXT burst is dispatched between
+        this one's dispatch and its collect (``_pass_once``). Returns
+        False when there was nothing to do (idle)."""
         if self._drained():
             return False
         acc = self._pass
@@ -1147,22 +1206,30 @@ class ContinuousBatcher:
                 self._retire()
             with _tel.phase("sched.admit", acc, "admit_s"):
                 admitted = self._admit()
-            live = self._live()
-            if not live:
-                return admitted > 0
-            with _tel.phase("sched.capacity", acc, "capacity_s"):
-                self._ensure_capacity(live)
-            live = self._live()
-            if not live:
-                return True
-            t0 = time.perf_counter()
-            with _tel.phase("sched.dispatch", acc, "dispatch_s"):
-                out = self._dispatch(live)
+            # a burst the pass before dispatched ahead is this pass's own:
+            # nothing more is dispatched for it (read after admit: a
+            # poisoned admission has dropped it)
+            flight, self._flight = self._flight, None
+            if flight is None:
+                live = self._live()
+                if not live:
+                    return admitted > 0
+                with _tel.phase("sched.capacity", acc, "capacity_s"):
+                    self._ensure_capacity(live)
+                live = self._live()
+                if not live:
+                    return True
+                with _tel.phase("sched.dispatch", acc, "dispatch_s"):
+                    flight = self._dispatch(live)
+            if self._may_run_ahead(flight):
+                # (a span of its own name; its seconds are a dispatch's)
+                with _tel.phase("sched.dispatch.ahead", acc, "dispatch_s"):
+                    self._flight = self._dispatch_ahead(flight)
             # the device runs the burst: what this pass's retire and admit
             # observed becomes a block here, where the thread would only
             # wait (``_step_once`` publishes it with the pass's seconds)
             self._rows.update(self._hist.flush() or ())
-            self._collect(live, out, t0)
+            self._collect(flight)
         except Exception as e:  # noqa: BLE001 - fail the slots, not the thread
             self._poison(e)
         return True
@@ -1975,13 +2042,14 @@ class ContinuousBatcher:
         target verification dispatch scoring all k+1 positions; both
         engines' weights come from one coherent ``spec_pair()`` snapshot
         so a concurrent hot swap can never mix draft/target versions."""
+        t0 = time.perf_counter()
         _faults.fire("batcher.hang", tag=self.name)
         _faults.fire("batcher.dispatch", tag=self.name)
         tokens = _np.zeros((self.slots,), _np.int32)
         lengths = _np.zeros((self.slots,), _np.int32)
         active = _np.zeros((self.slots,), bool)
-        for i in live:
-            s = self._slots[i]
+        rows = [(i, self._slots[i]) for i in live]
+        for i, s in rows:
             tokens[i] = s.carry
             lengths[i] = s.length
             active[i] = True
@@ -1996,37 +2064,91 @@ class ContinuousBatcher:
             buf, self._state = self._engine.spec_verify(
                 self._state, self.pool.table, dbuf, tokens, lengths,
                 active, pair=pair, wide=self.spec_wide)
-            return buf, pair[2], draft_ms
+            return _Burst(rows, buf, pair[2], lengths, t0, draft_ms)
         version = getattr(self._engine, "weights_version", None)
         buf, self._state = self._engine.decode_iter(
             self._state, self.pool.table, tokens, lengths, active,
             steps=self.iter_tokens, seed=self._iter, **self._sampling)
-        return buf, version
+        return _Burst(rows, buf, version, lengths, t0)
 
-    def _collect(self, live, out, t0):
-        """Read back the iteration's token block — the scheduler's ONE
-        sync point — then stream, account lengths, and mark retirements
-        for the next iteration's safe point."""
-        if self._spec_on:
-            buf, version, draft_ms = out
-        else:
-            buf, version = out
-            draft_ms = None
+    def _may_run_ahead(self, flight) -> bool:
+        """Whether the burst after ``flight`` may be dispatched before
+        ``flight`` is read: only where the read-back could change nothing
+        the next burst is made of, and nothing could be seated whatever
+        arrives in the meantime. Every slot holds the decoding row
+        ``flight`` runs for (none free, none with a prompt still
+        entering, none retired or preempted since); no row can reach its
+        ``max_new_tokens`` within ``flight``, reckoned at the most tokens
+        a step can yield; speculation is off; and the pool grants every
+        row the pages of a SECOND burst as it stands, with no eviction
+        and no preemption (where it does not, the pass that follows
+        fights for pages as ever). All of it from state the scheduler
+        holds: nothing is read from the device."""
+        if self._spec_on or len(flight.rows) < self.slots:
+            return False
+        most = self.iter_tokens * self._step_tokens
+        for i, s in flight.rows:
+            if self._slots[i] is not s or s.finished \
+                    or len(s.emitted) + most >= s.req.max_new:
+                return False
+        for i, s in flight.rows:
+            # ``s.length`` is the row's before ``flight``
+            if not self.pool.ensure(i, min(s.length + 2 * most,
+                                           s.base + s.req.max_new)):
+                return False
+        return True
+
+    def _dispatch_ahead(self, flight):
+        """The burst after ``flight`` for the same rows, dispatched before
+        ``flight`` is read: its tokens and lengths come from ``flight``'s
+        token block on the device (``InferStep.next_carry``), so the
+        device goes from one burst to the next while the host reads,
+        streams, retires and takes in. Staging and two enqueues, sync-free
+        by lint like ``_dispatch``; the caller has asked
+        ``_may_run_ahead``."""
+        t0 = time.perf_counter()
+        _faults.fire("batcher.hang", tag=self.name)
+        _faults.fire("batcher.dispatch", tag=self.name)
+        tokens, lengths = self._engine.next_carry(
+            flight.buf, flight.lengths, steps=self.iter_tokens)
+        self._iter += 1
+        version = getattr(self._engine, "weights_version", None)
+        buf, self._state = self._engine.decode_iter(
+            self._state, self.pool.table, tokens, lengths, self._all_active,
+            steps=self.iter_tokens, seed=self._iter, **self._sampling)
+        self._pass["bursts_ahead"] += 1
+        _tel.registry().counter("infer/bursts_ahead").inc()
+        return _Burst(flight.rows, buf, version, lengths, t0)
+
+    def _collect(self, flight):
+        """Read back a burst's token block — the scheduler's ONE sync
+        point — then stream, account lengths, and mark retirements for
+        the next iteration's safe point. What is read is what was
+        dispatched: the rows are ``flight``'s, and a row that has ended
+        or left its slot since (an end token or a deadline inside the
+        burst before, while this one was already queued) ran this burst
+        for nothing: its part is dropped."""
+        version, draft_ms = flight.version, flight.draft_ms
+        live = [(i, s) for i, s in flight.rows
+                if self._slots[i] is s and not s.finished]
         acc = self._pass
         with _tel.phase("sched.collect.readback", acc, "readback_s"):
-            toks = buf.asnumpy()
+            toks = flight.buf.asnumpy()
         if self._count_fields:
             self._note_counts("decode", toks[
                 :, self.iter_tokens * self._step_cols:].ravel())
-        iter_ms = (time.perf_counter() - t0) * 1e3
+        # a burst dispatched ahead began when the one before it ended on
+        # the device, which the host saw no later than its read-back
+        now = time.perf_counter()
+        iter_ms = (now - max(flight.t0, self._read_at)) * 1e3
+        self._read_at = now
         with _tel.phase("sched.collect", acc, "collect_s"):
             reg = _tel.registry()
             emitted_total = 0
             eos = self._engine._eos
             if draft_ms is not None:
                 reg.histogram("infer/spec_draft_ms").observe(draft_ms)
-            for i in live:
-                s = self._slots[i]
+            for i, s in live:
                 fresh = []
                 if self._spec_on:
                     # row layout: [t_0..t_k, count]; count = accepted
@@ -2110,6 +2232,7 @@ class ContinuousBatcher:
         fail every in-flight request, rebuild the pools, and keep the
         thread alive for fresh work (fail the futures, not the
         thread)."""
+        self._flight = None  # it ran on the state that is gone
         for i, s in enumerate(self._slots):
             if s is not None:
                 if not s.req.future.done():

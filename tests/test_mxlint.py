@@ -225,6 +225,8 @@ class TestNoSync:
         assert "prefill_paged" in covered[("infer.py", "InferStep")]
         cont = covered[("batcher.py", "ContinuousBatcher")]
         assert "_dispatch" in cont
+        assert {"_may_run_ahead", "_dispatch_ahead"} <= cont
+        assert "next_carry" in covered[("infer.py", "InferStep")]
         assert "_step_once" in cont  # the scheduler loop body
         # the retire path: a new root's frames never come to the host
         assert {"_retire", "_register_roots", "_store_rows",
@@ -302,11 +304,50 @@ class TestNoSync:
         for path, cls, funcs in no_sync.DISPATCH_TARGETS:
             assert set(funcs) <= covered[(path, cls)]
         paged = dict(((p, c), f) for p, c, f in no_sync.DISPATCH_TARGETS)
-        assert {"decode_iter", "prefill_paged", "prefill_suffix_paged",
-                "spec_draft", "spec_verify"} == \
+        assert {"decode_iter", "next_carry", "prefill_paged",
+                "prefill_suffix_paged", "spec_draft", "spec_verify"} == \
             set(paged[(no_sync.INFER_PY, "InferStep")])
-        assert {"_store_rows", "_apply_prefix_hits", "_dispatch"} <= \
+        assert {"_store_rows", "_apply_prefix_hits", "_dispatch",
+                "_may_run_ahead", "_dispatch_ahead"} <= \
             set(paged[(no_sync.BATCHER_PY, "ContinuousBatcher")])
+
+    @pytest.mark.parametrize("cls,func,line,rule", [
+        # the burst dispatched ahead may not read the burst before it ...
+        ("ContinuousBatcher", "_dispatch_ahead",
+         "toks = flight.buf.asnumpy()", ".asnumpy()"),
+        # ... nor make its operands by an enqueue of its own
+        ("ContinuousBatcher", "_dispatch_ahead",
+         "tokens = jnp.asarray(flight.lengths)", "jnp.asarray"),
+        # the rule is reckoned from state the scheduler holds
+        ("ContinuousBatcher", "_may_run_ahead",
+         "done = int(flight.buf[0, 0])", "int(...)"),
+        # and the program that carries a burst into the next pulls
+        # nothing to the host
+        ("InferStep", "next_carry",
+         "block = np.asarray(block)", "np.asarray"),
+        ("InferStep", "next_carry",
+         "lengths = jnp.int32(lengths)", "jnp.int32"),
+    ])
+    def test_lint_covers_the_burst_dispatched_ahead(self, tmp_path, cls,
+                                                    func, line, rule):
+        """``_dispatch_ahead``, ``_may_run_ahead`` and ``next_carry`` are
+        linted targets under both rule sets, as ``_dispatch`` is."""
+        path = {"InferStep": no_sync.INFER_PY,
+                "ContinuousBatcher": no_sync.BATCHER_PY}[cls]
+        for targets in (no_sync.TARGETS, no_sync.DISPATCH_TARGETS):
+            assert func in dict(((p, c), f) for p, c, f in targets)[
+                (path, cls)]
+        bad = tmp_path / "ahead_bad.py"
+        bad.write_text(
+            "import numpy as np\n"
+            "import jax.numpy as jnp\n"
+            f"class {cls}:\n"
+            f"    def {func}(self, flight, block=None, lengths=None):\n"
+            f"        {line}\n"
+            "        return self._fn(flight)\n")
+        violations = no_sync.find_violations(str(bad), cls, (func,),
+                                             (func,))
+        assert len(violations) == 1 and rule in violations[0][1]
 
     def test_clean_dispatch_passes_both_rule_sets(self, tmp_path):
         good = tmp_path / "infer_clean.py"
