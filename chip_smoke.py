@@ -273,8 +273,8 @@ def phase_serve(size, on_tpu, seed):
     import mxnet_tpu as mx
     from mxnet_tpu import nd
     from mxnet_tpu.gluon.model_zoo import transformer as zoo
+    from mxnet_tpu.ops import paged as paged_ops
     from mxnet_tpu.ops.pallas import _use_interpret
-    from mxnet_tpu.ops.pallas import paged_flash_attention as pfa
     from mxnet_tpu.parallel import InferStep
     from mxnet_tpu.serving import make_batcher
 
@@ -286,7 +286,7 @@ def phase_serve(size, on_tpu, seed):
     net._probe_shapes(nd.zeros((2, 8), dtype="int32"),
                       nd.zeros((2, 8), dtype="int32"))
     eng = InferStep(net, max_len=BUCKETS[-1] + MAX_PREFIX + MAX_NEW + 8)
-    kernels_on = pfa.flash_paged_enabled()
+    kernels_on = paged_ops.kernels_on()
     if on_tpu:
         check(kernels_on and not _use_interpret(),
               "the default gates left the paged kernels off or interpreted "

@@ -1,7 +1,7 @@
 """amp-purity pass: mixed precision must stay pure end to end.
 
-Port of ``tools/check_amp_purity.py`` (PR 4) onto the pass framework —
-same two checks, same assertions:
+The two checks of PR 4's AMP purity lint, on the pass framework
+(``python tools/mxlint.py --passes amp-purity``):
 
 1. **jaxpr — no fp32 master feeds a low-precision dot.** Walks the real
    ``TrainStep(amp='bfloat16')`` program (shared ``ProgramIndex`` build)

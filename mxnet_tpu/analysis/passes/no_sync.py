@@ -1,12 +1,12 @@
 """no-sync pass: the jitted hot paths must never block on the device.
 
-Port of ``tools/check_no_sync_in_step.py`` (PR 2/5/8) onto the pass
-framework — same rule sets, same targets, same assertions. Any host
+The rule sets and targets of the sync lint of PRs 2, 5 and 8, on the pass
+framework (``python tools/mxlint.py --passes no-sync``). Any host
 synchronization (``.asnumpy()``, ``float(loss)``, ``np.asarray`` on a
 device array, ``block_until_ready``, ``time.sleep``) inside a dispatch
 path silently serializes the pipeline against the device; this walks the
 AST of the listed (file, class, methods) targets and flags blocking
-calls. The tool remains as a thin CLI shim importing from here.
+calls.
 
 The serving targets carry a second rule set (``DISPATCH_TARGETS``): a
 dispatch on the scheduler's pass is exactly one enqueue, so no *eager

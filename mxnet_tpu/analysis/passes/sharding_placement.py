@@ -1,7 +1,7 @@
 """sharding-placement pass: declared shardings must actually hold.
 
-Port of ``tools/check_sharding.py`` (PR 6) onto the pass framework —
-same three checks, same assertions. GSPMD fails soft: an array placed
+The three checks of PR 6's sharding lint, on the pass framework
+(``python tools/mxlint.py --passes sharding-placement``). GSPMD fails soft: an array placed
 with the wrong (or no) sharding still computes — XLA inserts resharding
 copies and the "FSDP" run silently trains fully replicated, OOMing at
 exactly the scale sharding was meant to unlock.

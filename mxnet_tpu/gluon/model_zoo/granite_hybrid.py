@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from ... import initializer as _init
 from ...base import MXNetError
 from ...ndarray import NDArray
+from ...ops import paged as _paged
 from ...ops import sparse_attention as _dsa
 from ...ops import ssm as _ssm
 from ..block import HybridBlock
@@ -254,12 +255,12 @@ class GraniteHybridLM(HybridBlock):
         slots = state["ssm"][0].shape[0]
         page = state["k_pools"][0].shape[1]
         L = page_tables.shape[1] * page
-        block = _dsa.kv_block(L, self._kv_chunk)
+        block = _paged.kv_block(L, self._kv_chunk)
         live = jnp.logical_and(active[:, None],
                                jnp.arange(C)[None, :] < token_vl[:, None])
         real = jnp.where(active, token_vl, 0)
         # padding queries write to the trash page
-        rows = jnp.where(live, _dsa.token_rows(
+        rows = jnp.where(live, _paged.token_rows(
             page_tables, jnp.minimum(q_pos, L - 1), page),
             q_pos % page).reshape(R * C)
         last = jnp.max(jnp.where(live, q_pos, 0))
@@ -277,9 +278,9 @@ class GraniteHybridLM(HybridBlock):
             if kind == "attention":
                 with jax.named_scope("attention"):
                     q, k, v = self._attn_in(i, x)
-                    k_pools[j] = _dsa.write_rows(
+                    k_pools[j] = _paged.write_rows(
                         k_pools[j], rows, k.reshape((R * C,) + k.shape[2:]))
-                    v_pools[j] = _dsa.write_rows(
+                    v_pools[j] = _paged.write_rows(
                         v_pools[j], rows, v.reshape((R * C,) + v.shape[2:]))
                     attn = _dsa.selected_window_attention(
                         q, k_pools[j], v_pools[j], page_tables, q_pos[:, 0],
@@ -342,23 +343,17 @@ class GraniteHybridLM(HybridBlock):
         is not ``active`` writes its K/V to the trash page and keeps its
         recurrent state and its convolution tail bit for bit; its logits
         are garbage."""
-        from ...ops.pallas import paged_flash_attention as _pfa
-
         tok = (tokens.data if isinstance(tokens, NDArray)
                else jnp.asarray(tokens)).astype(jnp.int32)
         pos = jnp.asarray(pos, jnp.int32)
         active = jnp.asarray(active, jnp.bool_)
         page_tables = jnp.asarray(page_tables, jnp.int32)
-        B = tok.shape[0]
         page = state["k_pools"][0].shape[1]
         L = page_tables.shape[1] * page
         pos = jnp.minimum(pos, L - 1)
-        rows = jnp.where(active, _dsa.token_rows(
+        rows = jnp.where(active, _paged.token_rows(
             page_tables, pos[:, None], page)[:, 0], pos % page)
         step = active.astype(jnp.int32)
-        kernel = _pfa.flash_paged_enabled()
-        # off the TPU a row gathers every cached position and masks
-        every = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
         x = self._embed(tok)
         k_pools, v_pools = list(state["k_pools"]), list(state["v_pools"])
         ssm, conv = list(state["ssm"]), list(state["conv"])
@@ -367,17 +362,11 @@ class GraniteHybridLM(HybridBlock):
             if kind == "attention":
                 with jax.named_scope("attention"):
                     q, k, v = self._attn_in(i, x)
-                    k_pools[j] = _dsa.write_rows(k_pools[j], rows, k)
-                    v_pools[j] = _dsa.write_rows(v_pools[j], rows, v)
-                    if kernel:
-                        attn = _pfa.paged_decode_attention(
-                            q, k_pools[j], v_pools[j], page_tables, pos,
-                            sm_scale=self._attn_scale) \
-                            .reshape(B, self._nq * self._d)
-                    else:
-                        attn = _dsa.selected_decode_attention(
-                            q, k_pools[j], v_pools[j], page_tables, every,
-                            every <= pos[:, None], self._attn_scale)
+                    k_pools[j] = _paged.write_rows(k_pools[j], rows, k)
+                    v_pools[j] = _paged.write_rows(v_pools[j], rows, v)
+                    attn = _paged.decode_attention(
+                        q, k_pools[j], v_pools[j], page_tables, pos,
+                        self._attn_scale)
                     y = jnp.dot(attn, self._w(f"l{i}_wo"))
             else:
                 z, xbc, dt = self._mamba_in(i, x)

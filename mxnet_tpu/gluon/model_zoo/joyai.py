@@ -57,7 +57,7 @@ from ... import initializer as _init
 from ...base import MXNetError
 from ...ndarray import NDArray
 from ...ops import mla as _mla
-from ...ops import sparse_attention as _dsa
+from ...ops import paged as _paged
 from ...ops.pallas import grouped_swiglu as _moe
 from ..block import HybridBlock
 from .keye import rms_norm
@@ -282,7 +282,7 @@ class JoyAILM(HybridBlock):
         q_pos = q_offset[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
         u = rms_norm(x, self._w(p + "attn_norm"), self._eps)
         qn, qr = self._queries(p, u, q_pos)
-        pool = _dsa.write_rows(pool, rows, self._latent(p, u, q_pos)
+        pool = _paged.write_rows(pool, rows, self._latent(p, u, q_pos)
                                .reshape(R * C, -1))
         attn, buf = _mla.window_attention(
             qn, qr, pool, self._w(p + "wkv_b"), page_tables, q_offset,
@@ -300,7 +300,7 @@ class JoyAILM(HybridBlock):
         L = page_tables.shape[1] * page
         ok = jnp.logical_and(live, jnp.logical_and(pos >= 0, pos < L))
         at = jnp.clip(pos, 0, L - 1)
-        return jnp.where(ok, _dsa.token_rows(page_tables, at, page),
+        return jnp.where(ok, _paged.token_rows(page_tables, at, page),
                          at % page)
 
     def _window(self, tok, q_pos, token_vl, state, page_tables, slot_ids,
@@ -397,7 +397,7 @@ class JoyAILM(HybridBlock):
         q_pos = pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
         u = rms_norm(x, self._w(p + "attn_norm"), self._eps)
         qn, qr = self._queries(p, u, q_pos)
-        pool = _dsa.write_rows(
+        pool = _paged.write_rows(
             pool, self._rows(page_tables, q_pos, live, page).reshape(B * S),
             self._latent(p, u, q_pos).reshape(B * S, -1))
         w = self._up_proj(p)

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from ... import initializer as _init
 from ...base import MXNetError
 from ...ndarray import NDArray
+from ...ops import paged as _paged
 from ...ops import sparse_attention as _dsa
 from ...ops.pallas import grouped_swiglu as _moe
 from ..block import HybridBlock
@@ -201,11 +202,11 @@ class KeyeLM(HybridBlock):
         R, C = tok.shape
         page = state["k_pools"][0].shape[1]
         L = page_tables.shape[1] * page
-        block = _dsa.kv_block(L, self._kv_chunk)
+        block = _paged.kv_block(L, self._kv_chunk)
         live = jnp.logical_and(active[:, None],
                                jnp.arange(C)[None, :] < token_vl[:, None])
         # padding queries write to the trash page
-        rows = jnp.where(live, _dsa.token_rows(
+        rows = jnp.where(live, _paged.token_rows(
             page_tables, jnp.minimum(q_pos, L - 1), page),
             q_pos % page).reshape(R * C)
         last = jnp.max(jnp.where(live, q_pos, 0))
@@ -215,14 +216,14 @@ class KeyeLM(HybridBlock):
         k_pools, v_pools, ik_pools = [], [], []
         for i in range(self._n):
             q, k, v, qi, ki, wi = self._project(i, x, pos3)
-            kp = _dsa.write_rows(state["k_pools"][i], rows,
+            kp = _paged.write_rows(state["k_pools"][i], rows,
                                  k.reshape((R * C,) + k.shape[2:]))
-            vp = _dsa.write_rows(state["v_pools"][i], rows,
+            vp = _paged.write_rows(state["v_pools"][i], rows,
                                  v.reshape((R * C,) + v.shape[2:]))
-            ip = _dsa.write_rows(state["ik_pools"][i], rows,
+            ip = _paged.write_rows(state["ik_pools"][i], rows,
                                  ki.reshape(R * C, self._di))
             mask = _dsa.window_select(
-                qi, wi, _dsa.gather_row_pages(ip, page_tables), q_pos,
+                qi, wi, _paged.gather_row_pages(ip, page_tables), q_pos,
                 n_blocks, block, self._topk)
             attn = _dsa.selected_window_attention(
                 q, kp, vp, page_tables, q_pos[:, 0], mask, n_blocks, block,
@@ -278,7 +279,7 @@ class KeyeLM(HybridBlock):
         page = state["k_pools"][0].shape[1]
         L = page_tables.shape[1] * page
         pos = jnp.minimum(pos, L - 1)
-        rows = jnp.where(active, _dsa.token_rows(
+        rows = jnp.where(active, _paged.token_rows(
             page_tables, pos[:, None], page)[:, 0], pos % page)
         pos3 = jnp.broadcast_to(pos[:, None], pos.shape + (3,))
         x = jnp.take(self._w("embed"), tok, axis=0)
@@ -286,8 +287,8 @@ class KeyeLM(HybridBlock):
         k_pools, v_pools, ik_pools = [], [], []
         for i in range(self._n):
             q, k, v, qi, ki, wi = self._project(i, x, pos3)
-            kp = _dsa.write_rows(state["k_pools"][i], rows, k)
-            vp = _dsa.write_rows(state["v_pools"][i], rows, v)
+            kp = _paged.write_rows(state["k_pools"][i], rows, k)
+            vp = _paged.write_rows(state["v_pools"][i], rows, v)
             attn, ip, selected = _dsa.selected_decode(
                 q, qi, wi, ki, kp, vp, state["ik_pools"][i], page_tables,
                 rows, pos, active, self._topk, 1.0 / math.sqrt(self._d))

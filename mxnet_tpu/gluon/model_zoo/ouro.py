@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from ... import initializer as _init
 from ...base import MXNetError
 from ...ndarray import NDArray
+from ...ops import paged as _paged
 from ...ops import sparse_attention as _dsa
 from ..block import HybridBlock
 from .keye import rms_norm
@@ -164,7 +165,7 @@ class OuroLM(HybridBlock):
         precision the cache is kept in."""
         if self._cache_dtype is not None:
             x = x.astype(self._cache_dtype)
-        return _dsa.write_rows(pool, rows, x)
+        return _paged.write_rows(pool, rows, x)
 
     def _plane_start(self, t, num_pages):
         """The first page of plane ``t`` in a pool flattened over planes
@@ -278,11 +279,11 @@ class OuroLM(HybridBlock):
         R, C = tok.shape
         page = state["k_pools"][0].shape[2]
         L = page_tables.shape[1] * page
-        block = _dsa.kv_block(L)
+        block = _paged.kv_block(L)
         live = jnp.logical_and(active[:, None],
                                jnp.arange(C)[None, :] < token_vl[:, None])
         # padding queries write to the trash page
-        rows = jnp.where(live, _dsa.token_rows(
+        rows = jnp.where(live, _paged.token_rows(
             page_tables, jnp.minimum(q_pos, L - 1), page),
             q_pos % page).reshape(R * C)
         last = jnp.max(jnp.where(live, q_pos, 0))
@@ -336,36 +337,23 @@ class OuroLM(HybridBlock):
         per-row positions ``pos (B,)``; row ``b`` IS slot ``b``. A row that
         is not ``active`` writes its K/V to the trash page of each plane;
         its logits are garbage."""
-        from ...ops.pallas import paged_flash_attention as _pfa
-
         tok = (tokens.data if isinstance(tokens, NDArray)
                else jnp.asarray(tokens)).astype(jnp.int32)
         pos = jnp.asarray(pos, jnp.int32)
         active = jnp.asarray(active, jnp.bool_)
         page_tables = jnp.asarray(page_tables, jnp.int32)
-        B = tok.shape[0]
         page = state["k_pools"][0].shape[2]
         L = page_tables.shape[1] * page
         pos = jnp.minimum(pos, L - 1)
-        rows = jnp.where(active, _dsa.token_rows(
+        rows = jnp.where(active, _paged.token_rows(
             page_tables, pos[:, None], page)[:, 0], pos % page)
-        kernel = _pfa.flash_paged_enabled()
-        # off the TPU a row gathers every cached position and masks
-        every = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
         cos, sin = self._angles(pos)
 
         def attend(q, k, v, k_pool, v_pool, tables, rows):
             k_pool = self._cached(k_pool, rows, k)
             v_pool = self._cached(v_pool, rows, v)
-            if kernel:
-                out = _pfa.paged_decode_attention(
-                    q, k_pool, v_pool, tables, pos,
-                    sm_scale=self._sm).reshape(B, self._nh * self._d)
-            else:
-                out = _dsa.selected_decode_attention(
-                    q, k_pool, v_pool, tables, every,
-                    every <= pos[:, None], self._sm)
-            return out, k_pool, v_pool
+            return _paged.decode_attention(
+                q, k_pool, v_pool, tables, pos, self._sm), k_pool, v_pool
 
         x, k_pools, v_pools, mass = self._loop(
             jnp.take(self._w("embed"), tok, axis=0), state, page_tables,

@@ -56,7 +56,7 @@ import jax.numpy as jnp
 from ... import initializer as _init
 from ...base import MXNetError
 from ...ndarray import NDArray
-from ...ops import sparse_attention as _dsa
+from ...ops import paged as _paged
 from ...ops.pallas import grouped_swiglu as _moe
 from ..block import HybridBlock
 from .keye import rms_norm
@@ -64,7 +64,6 @@ from .keye import rms_norm
 __all__ = ["ZayaLM"]
 
 F32 = jnp.float32
-QUERY_BLOCK = 128   # window positions a call of the paged kernel takes
 
 
 def rope_partial(x, cos, sin):
@@ -244,12 +243,7 @@ class ZayaLM(HybridBlock):
         precision the cache is kept in."""
         if self._cache_dtype is not None:
             x = x.astype(self._cache_dtype)
-        return _dsa.write_rows(pool, rows, x)
-
-    def _by_head(self, pool):
-        """The pool as ``(num_pages, page, Hkv, D)``, for the
-        ``jax.numpy`` forms of attention (off the chip a free view)."""
-        return pool.reshape(pool.shape[0], -1, self._nkv, self._d)
+        return _paged.write_rows(pool, rows, x)
 
     def _merge(self, i, sub, x, out):
         p = f"l{i}_{sub}_res_"
@@ -330,27 +324,6 @@ class ZayaLM(HybridBlock):
                 (sum(n for _, n in self.paged_slot_state["counts"]),),
                 jnp.int32)}
 
-    def _window_attention(self, q, k_pool, v_pool, page_tables, q_offset,
-                          real):
-        """The chunk's attention through the paged window kernel, the
-        pools read in place: the chunk in blocks of ``QUERY_BLOCK``
-        positions, each a row of the kernel's grid with the row's page
-        table and its own offset, the four query heads of a key/value
-        head on the window axis beside the positions."""
-        from ...ops.pallas import paged_flash_attention as _pfa
-
-        R, C = q.shape[:2]
-        tq = math.gcd(C, QUERY_BLOCK)
-        first = jnp.arange(C // tq, dtype=jnp.int32) * tq
-        vl = jnp.clip(real[:, None] - first, 0, tq).reshape(-1)
-        # a block of padding alone reads one page
-        off = jnp.where(vl > 0, (q_offset[:, None] + first).reshape(-1), 0)
-        out = _pfa.paged_window_attention(
-            q.reshape((R * (C // tq), tq) + q.shape[2:]), k_pool, v_pool,
-            jnp.repeat(page_tables, C // tq, axis=0), off, vl,
-            sm_scale=self._sm, kv_heads=self._nkv)
-        return out.reshape(R, C, self._nq * self._d)
-
     def _window(self, tok, q_pos, token_vl, state, page_tables, slot_ids,
                 active):
         """The window forward: ``tok (R, C)`` at positions ``q_pos (R, C)``,
@@ -359,8 +332,6 @@ class ZayaLM(HybridBlock):
         ``slot_ids[r]``'s tail and value half (zero where the row starts
         at position 0) and writes them back as they stand after the row's
         last real token. Returns ``(x (R, C, H), new_state)``."""
-        from ...ops.pallas import paged_flash_attention as _pfa
-
         R, C = tok.shape
         slots = state["tail"][0].shape[0]
         page = state["k_pools"][0].shape[1] // self._nkv
@@ -369,15 +340,9 @@ class ZayaLM(HybridBlock):
                                jnp.arange(C)[None, :] < token_vl[:, None])
         real = jnp.where(active, token_vl, 0)
         # padding queries write to the trash page
-        rows = jnp.where(live, _dsa.token_rows(
+        rows = jnp.where(live, _paged.token_rows(
             page_tables, jnp.minimum(q_pos, L - 1), page),
             q_pos % page).reshape(R * C)
-        kernel = _pfa.flash_paged_enabled()
-        if not kernel:      # the jax.numpy form walks blocks of keys
-            block = _dsa.kv_block(L, self._kv_chunk)
-            last = jnp.max(jnp.where(live, q_pos, 0))
-            n_blocks = jnp.minimum(last // block + 1, L // block)
-            causal = jnp.arange(L)[None, None, :] <= q_pos[:, :, None]
         # an inert row reads slot 0 and writes nowhere; a row without a
         # real token keeps what its slot holds
         read = jnp.clip(slot_ids, 0, slots - 1)
@@ -411,15 +376,10 @@ class ZayaLM(HybridBlock):
                     k_pools[i], rows, k.reshape((R * C,) + k.shape[2:]))
                 v_pools[i] = self._cached(
                     v_pools[i], rows, v.reshape((R * C,) + v.shape[2:]))
-                if kernel:
-                    heads = self._window_attention(
-                        q, k_pools[i], v_pools[i], page_tables, q_pos[:, 0],
-                        real)
-                else:
-                    heads = _dsa.selected_window_attention(
-                        q, self._by_head(k_pools[i]),
-                        self._by_head(v_pools[i]), page_tables, q_pos[:, 0],
-                        causal, n_blocks, block, self._sm)
+                heads = _paged.window_attention(
+                    q, k_pools[i], v_pools[i], page_tables, q_pos[:, 0],
+                    real, self._sm, kv_heads=self._nkv,
+                    kv_chunk=self._kv_chunk)
                 out = jnp.dot(heads, self._w(f"l{i}_wo"))
             x = self._merge(i, "attn", x, out)
             y, r, tokens, read_experts = self._experts(
@@ -463,8 +423,6 @@ class ZayaLM(HybridBlock):
         per-row positions ``pos (B,)``; row ``b`` IS slot ``b``. A row that
         is not ``active`` writes its K/V to the trash page and keeps its
         tail and its value half bit for bit; its logits are garbage."""
-        from ...ops.pallas import paged_flash_attention as _pfa
-
         tok = (tokens.data if isinstance(tokens, NDArray)
                else jnp.asarray(tokens)).astype(jnp.int32)
         pos = jnp.asarray(pos, jnp.int32)
@@ -474,11 +432,8 @@ class ZayaLM(HybridBlock):
         page = state["k_pools"][0].shape[1] // self._nkv
         L = page_tables.shape[1] * page
         pos = jnp.minimum(pos, L - 1)
-        rows = jnp.where(active, _dsa.token_rows(
+        rows = jnp.where(active, _paged.token_rows(
             page_tables, pos[:, None], page)[:, 0], pos % page)
-        kernel = _pfa.flash_paged_enabled()
-        # off the TPU a row gathers every cached position and masks
-        every = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
         cos, sin = self._angles(pos[:, None])
         x = jnp.take(self._w("embed"), tok, axis=0)
         r = jnp.zeros((B, self._rh), F32)
@@ -499,16 +454,9 @@ class ZayaLM(HybridBlock):
             with jax.named_scope("attention"):
                 k_pools[i] = self._cached(k_pools[i], rows, k[:, 0])
                 v_pools[i] = self._cached(v_pools[i], rows, v[:, 0])
-                if kernel:
-                    heads = _pfa.paged_decode_attention(
-                        q[:, 0], k_pools[i], v_pools[i], page_tables, pos,
-                        sm_scale=self._sm, kv_heads=self._nkv) \
-                        .reshape(B, self._nq * self._d)
-                else:
-                    heads = _dsa.selected_decode_attention(
-                        q[:, 0], self._by_head(k_pools[i]),
-                        self._by_head(v_pools[i]), page_tables, every,
-                        every <= pos[:, None], self._sm)
+                heads = _paged.decode_attention(
+                    q[:, 0], k_pools[i], v_pools[i], page_tables, pos,
+                    self._sm, kv_heads=self._nkv)
                 out = jnp.dot(heads, self._w(f"l{i}_wo"))
             x = self._merge(i, "attn", x, out)
             x, r, tokens, read_experts = self._experts(i, x, r, active)

@@ -355,7 +355,7 @@ class MultiHeadAttention(HybridBlock):
         their garbage never lands in another request's pages.
 
         The new token's K/V scatter to ``(page, pos % page_size)``; then
-        attention routes by ``paged_flash_attention.flash_paged_enabled()``:
+        attention routes by ``ops/paged.kernels_on()``:
         the Pallas decode kernel walks the page table inside its grid and
         reads the pools IN PLACE (no gather), while the fallback gathers
         the ``(B, P*page_size, H, D)`` view and runs the dense path —
@@ -386,9 +386,10 @@ class MultiHeadAttention(HybridBlock):
         off = jnp.where(active, pos % page_size, 0)
         k_pool = k_pool.at[page, off].set(k_t)
         v_pool = v_pool.at[page, off].set(v_t)
+        from ...ops import paged
         from ...ops.pallas import paged_flash_attention as _pfa
 
-        if self._causal and _pfa.flash_paged_enabled():
+        if self._causal and paged.kernels_on():
             # Pallas decode kernel: the page table rides the grid as a
             # scalar-prefetch operand and each step reads a block of the
             # row's pool pages in place, none past the row's position —
@@ -424,10 +425,11 @@ class MultiHeadAttention(HybridBlock):
         to the trash page and their outputs are zeroed under the kernel
         path (garbage-but-ignored under the dense fallback — callers
         only read rows ``< window_vl``). Routing matches ``paged_step``:
-        Pallas window kernel when ``flash_paged_enabled()``, dense
+        Pallas window kernel when ``paged.kernels_on()``, dense
         gather otherwise. Returns ``(out, k_pool, v_pool)``."""
         from ... import ndarray as F
         from ...ndarray.ndarray import NDArray
+        from ...ops import paged
         from ...ops.pallas import paged_flash_attention as _pfa
 
         if not self._self_attention:
@@ -454,7 +456,7 @@ class MultiHeadAttention(HybridBlock):
         off = jnp.where(live, abs_pos % page_size, 0)
         k_pool = k_pool.at[page, off].set(k_t)
         v_pool = v_pool.at[page, off].set(v_t)
-        if self._causal and _pfa.flash_paged_enabled():
+        if self._causal and paged.kernels_on():
             out = NDArray(_pfa.paged_window_attention(
                 q.data, k_pool, v_pool, page_table, pos, window_vl,
                 sm_scale=self._sm_scale()))

@@ -156,7 +156,6 @@ _ENV_KEYED_OPS = {
     # (MXTPU_FLASH_BWD is NOT here: it binds at import; the runtime
     # switch is set_flash_backward(), which clears jax caches itself)
     "_contrib_flash_attention": ("MXTPU_ATTN_DENSE_MAX",),
-    "BatchNorm": ("MXTPU_FUSED_BN",),
     "linear_cross_entropy": ("MXTPU_CE_DENSE_MAX_BYTES",),
 }
 

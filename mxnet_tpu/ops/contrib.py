@@ -480,7 +480,7 @@ def _dense_attention(q, k, v, valid_length, causal, sm_scale,
     makes the backward cast ds down BEFORE the dq/dk matmuls, so under
     AMP every dot stays low-precision — a `preferred_element_type=f32`
     score dot would leak an f32 cotangent into bf16 matmuls
-    (tools/check_amp_purity.py flags exactly that)."""
+    (the amp-purity pass of tools/mxlint.py flags exactly that)."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * sm_scale
     p = _masked_softmax_probs(s, valid_length, causal, q_offset)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(q.dtype), v)

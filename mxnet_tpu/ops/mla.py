@@ -22,7 +22,7 @@ Two orders of the same sums, as the serving plane has them:
   reads ``rank + rope`` numbers a cached position whatever the head count.
 
 The ``jax.numpy`` forms below stand on the CPU and under a multi-device
-mesh (``flash_paged_enabled``); softmax and scores are float32 everywhere.
+mesh (``paged.kernels_on()``); softmax and scores are float32 everywhere.
 """
 
 from __future__ import annotations
@@ -30,7 +30,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from . import sparse_attention as _dsa
+from . import paged
 
 NEG = -1e30
 EXPAND_KEYS = 512       # cached positions a step of the expansion's loop
@@ -48,12 +48,6 @@ def rope_interleaved(x, pos, theta):
     a, b = xf[..., 0], xf[..., 1]
     return jnp.stack([a * cos - b * sin, b * cos + a * sin], -1) \
         .reshape(x.shape).astype(x.dtype)
-
-
-def _kernels():
-    from .pallas import paged_flash_attention as _pfa
-
-    return _pfa.flash_paged_enabled()
 
 
 # ------------------------------------------------------------------ window
@@ -89,7 +83,7 @@ def window_attention(qn, qr, pool, wkvb, page_tables, q_offset, last,
     D), buf)``."""
     R, C, H, D = qn.shape
     rank = wkvb.shape[0]
-    lat = _dsa.gather_row_pages(pool, page_tables)          # (R, L, W)
+    lat = paged.gather_row_pages(pool, page_tables)          # (R, L, W)
     if buf is not None:
         from .pallas import mla_attention as _k
 
@@ -118,7 +112,7 @@ def expansion_buffer(rows, cached, width, chunk, dtype):
     cached latents into, or None where the ``jax.numpy`` form stands (the
     CPU, a multi-device mesh, a chunk the kernel's blocks do not divide):
     ``(rows, cached rounded up to whole key blocks, width)``."""
-    if not _kernels() or chunk % 128:
+    if not paged.kernels_on() or chunk % 128:
         return None
     padded = -(-cached // EXPAND_KEYS) * EXPAND_KEYS
     return jnp.zeros((rows, padded, width), dtype)
@@ -130,12 +124,12 @@ def decode_attention(qc, qr, pool, page_tables, pos):
     rank)``, ``qr (B, S, H, rope)`` (scaled), query ``i`` of row ``b`` at
     ``pos[b] + i`` over the row's cached latents up to its own position.
     Returns the weighted latents ``(B, S, H, rank)``."""
-    if _kernels():
+    if paged.kernels_on():
         from .pallas import mla_attention as _k
 
         return _k.mla_latent_decode(qc, qr, pool, page_tables, pos)
     rank = qc.shape[-1]
-    lat = _dsa.gather_row_pages(pool, page_tables)          # (B, L, W)
+    lat = paged.gather_row_pages(pool, page_tables)          # (B, L, W)
     c, kr = lat[..., :rank], lat[..., rank:]
     s = jnp.einsum("bshc,blc->bshl", qc, c,
                    preferred_element_type=jnp.float32) \

@@ -325,63 +325,32 @@ def _bn_train(data, gamma, beta, eps, axis):
     return _bn_train_fwd_rule(data, gamma, beta, eps, axis)[0]
 
 
-def _bn_mode() -> str:
-    """MXTPU_FUSED_BN: '1' shifted one-pass jnp (default), 'pallas' the
-    Pallas kernels (channel-last only), '0' round-3 two-pass jnp. Read
-    per call."""
-    import os
-
-    return os.environ.get("MXTPU_FUSED_BN", "1").lower()
-
-
-def _bn_fused_ok(data, axis):
-    from .pallas import batch_norm as _pbn
-
-    return _bn_mode() == "pallas" and _pbn.supports(data, axis)
-
-
 def _bn_stats(data, axis):
+    """Per-channel ``(mean, var)`` in float32, with the reduced axes and
+    the broadcast shape. SHIFTED one-pass statistics: subtract a
+    per-channel sample s (one element of the channel) before the
+    sum/sumsq — XLA's multi-output fusion computes both reductions in a
+    single read of x (measured round 3: 26.86 vs 29.28 ms on the
+    ResNet-50 step against two passes). The raw one-pass E[x^2]-E[x]^2
+    form was REVERTED in round 3: it cancels catastrophically whenever
+    |mean| >> std. With the shift, E[x-s] is ~std-sized (s sits within a
+    few std of the mean with overwhelming probability), so E[(x-s)^2] -
+    E[x-s]^2 only cancels O(1) bits — safe in f32 for any channel
+    distribution."""
     red = tuple(i for i in range(data.ndim) if i != (axis % data.ndim))
     bshape = [1] * data.ndim
     bshape[axis] = data.shape[axis]
-    if _bn_fused_ok(data, axis):
-        # Pallas one-read stats (channel-last layers only; opt-in — on
-        # the v5e trace the jnp form below compiles to the same single
-        # pass WITHOUT the layout copies Pallas operands force)
-        from .pallas import batch_norm as _pbn
-
-        C = data.shape[-1]
-        mean, var = _pbn.bn_stats(data.reshape(-1, C))
-        return mean, var, red, bshape
-    mode = _bn_mode()
-    if mode != "0":
-        # SHIFTED one-pass statistics, f32 accumulators: subtract a
-        # per-channel sample s (one element of the channel) before the
-        # sum/sumsq — XLA's multi-output fusion computes both reductions
-        # in a single read of x (measured round 3: 26.86 vs 29.28 ms on
-        # the ResNet-50 step). The raw one-pass E[x^2]-E[x]^2 form was
-        # REVERTED in round 3: it cancels catastrophically whenever
-        # |mean| >> std. With the shift, E[x-s] is ~std-sized (s sits
-        # within a few std of the mean with overwhelming probability),
-        # so E[(x-s)^2] - E[x-s]^2 only cancels O(1) bits — safe in f32
-        # for any channel distribution.
-        n = 1
-        for i in red:
-            n *= data.shape[i]
-        idx = tuple(slice(None) if i == (axis % data.ndim) else 0
-                    for i in range(data.ndim))
-        s = jax.lax.stop_gradient(data[idx]).astype(jnp.float32)
-        xs = data.astype(jnp.float32) - s.reshape(bshape)
-        s1 = jnp.sum(xs, axis=red)
-        s2 = jnp.sum(jnp.square(xs), axis=red)
-        mean = s + s1 / n
-        var = s2 / n - jnp.square(s1 / n)
-        return mean, var, red, bshape
-    # two-pass statistics, f32 accumulators, nothing materialized;
-    # one READ of the activation more than the shifted form above
-    mean = jnp.mean(data, axis=red, dtype=jnp.float32)
-    cdiff = data.astype(jnp.float32) - mean.reshape(bshape)
-    var = jnp.mean(jnp.square(cdiff), axis=red)
+    n = 1
+    for i in red:
+        n *= data.shape[i]
+    idx = tuple(slice(None) if i == (axis % data.ndim) else 0
+                for i in range(data.ndim))
+    s = jax.lax.stop_gradient(data[idx]).astype(jnp.float32)
+    xs = data.astype(jnp.float32) - s.reshape(bshape)
+    s1 = jnp.sum(xs, axis=red)
+    s2 = jnp.sum(jnp.square(xs), axis=red)
+    mean = s + s1 / n
+    var = s2 / n - jnp.square(s1 / n)
     return mean, var, red, bshape
 
 
@@ -406,10 +375,8 @@ def _bn_train_fwd_rule(data, gamma, beta, eps, axis):
 def _bn_train_bwd_rule(eps, axis, res, cts):
     """Closed-form fused BN backward (the hand-derived 2-pass kernel the
     reference wrote in CUDA): one fused pass for the two reductions
-    (sum dy, sum dy*xhat — through the Pallas ``bn_bwd_reduce`` kernel
-    when the layout supports it, guaranteeing the single joint read of
-    (x, dy) rather than hoping XLA's multi-output fusion merges them),
-    one jnp pass for dx that XLA fuses with neighbors. XLA's autodiff of
+    (sum dy, sum dy*xhat), one jnp pass for dx that XLA fuses with
+    neighbors. XLA's autodiff of
     the forward chain emits ~6 reduction/elementwise passes instead.
 
     Cotangents for the mean/var outputs are ignored: they are
@@ -426,15 +393,8 @@ def _bn_train_bwd_rule(eps, axis, res, cts):
     dyf = dy.astype(jnp.float32)
     xhat = (data.astype(jnp.float32) - mean.reshape(bshape)) \
         * inv.reshape(bshape)
-    if _bn_fused_ok(data, axis):
-        from .pallas import batch_norm as _pbn
-
-        C = data.shape[-1]
-        sum_dy, sum_dy_xhat = _pbn.bn_bwd_reduce(
-            data.reshape(-1, C), dy.reshape(-1, C), mean, inv)
-    else:
-        sum_dy = jnp.sum(dyf, axis=red)
-        sum_dy_xhat = jnp.sum(dyf * xhat, axis=red)
+    sum_dy = jnp.sum(dyf, axis=red)
+    sum_dy_xhat = jnp.sum(dyf * xhat, axis=red)
     gscale = (gamma.astype(jnp.float32) * inv).reshape(bshape)
     dx = gscale * (
         dyf - (sum_dy / n).reshape(bshape)

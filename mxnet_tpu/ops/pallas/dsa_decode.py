@@ -10,7 +10,7 @@ row's 16,640 indexer keys are gathered into a copy, ``lax.top_k`` sorts 16 x
 are gathered from the pools by token. Here both kernels take a ROW a grid
 step and issue their own copies through the page table, a block of pages
 into one half of a VMEM scratch while the other half is computed
-(``mla_attention.py``'s form): a row walks its live pages only, an inactive
+(``page_walk.walk_live_pages``): a row walks its live pages only, an inactive
 row (a position below 0) none, and a row's last block starts the next live
 row's first.
 
@@ -48,14 +48,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import _use_interpret
-from .index_select import _MIN, _ordered_key
-from .mla_attention import _init, _prec, _softmax_step
+from .index_select import MIN_KEY, ordered_key
+from .page_walk import (LANES, decode_tiles, init_carry, prec, softmax_step,
+                        walk_live_pages)
 
-__all__ = ["dsa_decode_select", "dsa_decode_window", "decode_tiles"]
+__all__ = ["dsa_decode_select", "dsa_decode_window"]
 
-# keys a block of either kernel: one buffer of its scratch and the grain of
-# its copies (``mla_attention.py``'s, measured there: PERF.md, PR 34)
-_BLOCK_KEYS = 1024
 # keys a step of the attention's online softmax (one product with the keys,
 # one carry, one product with the values). On a v5e (PERF.md, PR 39; the
 # kernel alone, 13 live rows at 9.1k positions): 0.487 ms a call at 128 (a
@@ -63,66 +61,6 @@ _BLOCK_KEYS = 1024
 # 0.357 at 256 / 512 / 1,024, where the copies alone take 0.353
 _STEP_KEYS = 512
 _VMEM_LIMIT = 32 * 1024 * 1024
-
-
-def decode_tiles(P, page_size):
-    """Pages a block of the decode kernels, from the shapes alone: 1,024
-    keys, so pages of 128 positions go 8 a block (one whole tile of the
-    selection's scratch), and never more than a row's table has."""
-    return max(1, min(P, _BLOCK_KEYS // page_size))
-
-
-def _walk_live_pages(pt_ref, slot_ref, live_pages, block, page_copies,
-                     compute):
-    """The copies of a grid step that is row ``b`` of ``B``, sequential.
-    ``live_pages(r)`` pages of row ``r`` are walked in blocks of ``block``;
-    ``page_copies(page, slot, t)`` are the copies that bring pool page
-    ``page`` to place ``t`` of buffer ``slot``. Block ``k + 1`` is started
-    before block ``k`` is waited for and ``compute(k, slot)`` runs; a row's
-    last block starts the next live row's first, so no row waits for its
-    first pages. The slot carries over from row to row in ``slot_ref``."""
-    b = pl.program_id(0)
-    B = pt_ref.shape[0]
-
-    def copies(r, k, slot, do):
-        # what lies past the live pages in the buffer is never computed
-        n = live_pages(r) - k * block
-        for t in range(block):
-            @pl.when(t < n)
-            def _copy():
-                for c in page_copies(pt_ref[r, k * block + t], slot, t):
-                    do(c)
-
-    def start_first_block_after(r, slot):
-        nxt = jax.lax.while_loop(
-            lambda r: (r < B) & (live_pages(jnp.minimum(r, B - 1)) == 0),
-            lambda r: r + 1, r + 1)
-
-        @pl.when(nxt < B)
-        def _start():
-            copies(nxt, 0, slot, lambda c: c.start())
-
-    @pl.when(b == 0)
-    def _first_row():
-        slot_ref[0] = 0
-        start_first_block_after(-1, 0)
-
-    blocks = pl.cdiv(live_pages(b), block)
-
-    def body(k, slot):
-        @pl.when(k + 1 < blocks)
-        def _next_block():
-            copies(b, k + 1, 1 - slot, lambda c: c.start())
-
-        @pl.when(k + 1 == blocks)
-        def _next_row():
-            start_first_block_after(b, 1 - slot)
-
-        copies(b, k, slot, lambda c: c.wait())
-        compute(k, slot)
-        return 1 - slot
-
-    slot_ref[0] = jax.lax.fori_loop(0, blocks, body, slot_ref[0])
 
 
 # -------------------------------------------------------------- selection
@@ -182,7 +120,7 @@ def _select_kernel(pt_ref, pos_ref, q_ref, w_ref, own_ref, pool_ref,
         s = jax.lax.dot_general(
             q_ref[0], buf_ref[slot], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-            precision=_prec(buf_ref.dtype))          # (heads, block * ps)
+            precision=prec(buf_ref.dtype))           # (heads, block * ps)
         hit = jnp.maximum(s, 0.0) * w_ref[0]
         blk = jnp.zeros((block, ps), jnp.float32)
         for t in range(block):                       # a page a row
@@ -191,15 +129,15 @@ def _select_kernel(pt_ref, pos_ref, q_ref, w_ref, own_ref, pool_ref,
         at = (k * block + sub) * ps + lane
         blk = jnp.where(at <= pos, blk / norm, -jnp.inf)
         key_ref[pl.ds(pl.multiple_of(k * block, block), block), :] = \
-            _ordered_key(blk)
+            ordered_key(blk)
 
     # dead blocks hold the lowest key there is
     @pl.when(pos >= topk)
     def _dead():
-        key_ref[...] = _ordered_key(
+        key_ref[...] = ordered_key(
             jnp.full(key_ref.shape, -jnp.inf, jnp.float32))
 
-    _walk_live_pages(pt_ref, slot_ref, live_pages, block, page_copies, score)
+    walk_live_pages(pt_ref, slot_ref, live_pages, block, page_copies, score)
 
     # every seen position is selected while there are at most ``topk``
     @pl.when(pos < topk)
@@ -223,12 +161,12 @@ def _select_kernel(pt_ref, pos_ref, q_ref, w_ref, own_ref, pool_ref,
 
         # the largest T with count(keys >= T) >= topk, a bit a pass from
         # the top. T grows as an UNSIGNED key; the keys compare signed, so
-        # the carry is T with its sign bit turned (zero is ``_MIN``)
+        # the carry is T with its sign bit turned (zero is ``MIN_KEY``)
         def bit_pass(p, prefix):
             cand = prefix ^ jnp.left_shift(jnp.int32(1), 31 - p)
             return jnp.where(count(keys >= cand) >= topk, cand, prefix)
 
-        kth = jax.lax.fori_loop(0, 32, bit_pass, jnp.int32(_MIN))
+        kth = jax.lax.fori_loop(0, 32, bit_pass, jnp.int32(MIN_KEY))
         above = keys > kth
         tie = jnp.logical_and(keys == kth, seen)
         put_code(jnp.logical_or(above, tie))
@@ -375,7 +313,7 @@ def _window_kernel(pt_ref, pos_ref, q_ref, code_ref, k_pool_ref, v_pool_ref,
             keep_ref[pl.ds(pl.multiple_of(k * block, block), block), :]
             .astype(jnp.bfloat16), spread, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-            precision=_prec(jnp.bfloat16))           # (block, cols)
+            precision=prec(jnp.bfloat16))            # (block, cols)
         for t in range(0, block, step):
             @pl.when(t < n)
             def _step():
@@ -383,7 +321,7 @@ def _window_kernel(pt_ref, pos_ref, q_ref, code_ref, k_pool_ref, v_pool_ref,
                 s = jax.lax.dot_general(
                     q_ref[0], kt, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
-                    precision=_prec(kt.dtype))       # (rows, width)
+                    precision=prec(kt.dtype))        # (rows, width)
                 chosen = jnp.concatenate(
                     [keep[u:u + 1] for u in range(t, t + step)], axis=1)
                 # -inf under a running max that starts finite: a column
@@ -391,12 +329,12 @@ def _window_kernel(pt_ref, pos_ref, q_ref, code_ref, k_pool_ref, v_pool_ref,
                 # whatever the row has seen
                 s = jnp.where(jnp.logical_and(own, chosen != 0.0),
                               s * sm_scale, -jnp.inf)
-                _softmax_step(s, v_buf[slot, pl.ds(t * cols, width), :],
-                              m_ref, l_ref, acc_ref)
+                softmax_step(s, v_buf[slot, pl.ds(t * cols, width), :],
+                             m_ref, l_ref, acc_ref)
 
-    _init(m_ref, l_ref, acc_ref)
-    _walk_live_pages(pt_ref, slot_ref, live_pages, block, page_copies,
-                     attend)
+    init_carry(m_ref, l_ref, acc_ref)
+    walk_live_pages(pt_ref, slot_ref, live_pages, block, page_copies,
+                    attend)
     l = jnp.maximum(jnp.sum(l_ref[...], axis=1, keepdims=True), 1e-30)
     o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
@@ -428,8 +366,8 @@ def _dsa_decode_window_impl(q, k_pool, v_pool, page_table, pos, mask,
                 pltpu.VMEM((pages, ps), jnp.float32),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SMEM((1,), jnp.int32),
-                pltpu.VMEM((Hq, 128), jnp.float32),
-                pltpu.VMEM((Hq, math.gcd(ps * Hkv, 128)), jnp.float32),
+                pltpu.VMEM((Hq, LANES), jnp.float32),
+                pltpu.VMEM((Hq, math.gcd(ps * Hkv, LANES)), jnp.float32),
                 pltpu.VMEM((Hq, D), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -26,7 +26,7 @@ from . import _use_interpret
 
 __all__ = ["flash_attention"]
 
-_NEG_INF = -1e30
+NEG_INF = -1e30
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *refs, sm_scale, block_k, kv_len,
@@ -66,7 +66,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, sm_scale, block_k, kv_len,
         mask = k_pos < vl
         if causal:
             mask = jnp.logical_and(mask, k_pos <= q_pos)
-        s = jnp.where(mask, s, _NEG_INF)
+        s = jnp.where(mask, s, NEG_INF)
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - m_new)
@@ -78,7 +78,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, sm_scale, block_k, kv_len,
         )
         return m_new, l_new, acc
 
-    m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
+    m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
     acc0 = jnp.zeros((block_q, d), jnp.float32)
     # blocks past the valid length contribute nothing — skip them
@@ -319,7 +319,7 @@ def _flash_bwd_pallas(q, k, v, vl, out, lse, do, causal, sm_scale,
         pad_rows = jax.lax.broadcasted_iota(
             jnp.int32, (B * H, Sq_p, 1), 1
         ) >= Sq
-        lse_p = jnp.where(pad_rows, jnp.float32(-_NEG_INF), lse_p)
+        lse_p = jnp.where(pad_rows, jnp.float32(-NEG_INF), lse_p)
     delta_p = _pad_to(delta.reshape(B * H, Sq, 1), 1, bq)
     # vl is always a concrete (B,) array here — _bwd_rule and the ring
     # backward materialize full-length vectors when no mask is in play
@@ -448,9 +448,9 @@ def _flash_bwd_xla(q, k, v, vl, out, lse, do, causal, sm_scale, block_k):
         mask = k_pos[None, None] < vl4  # (B,1,1,bk)
         if causal:
             mask = jnp.logical_and(mask, (k_pos <= q_pos)[None, None])
-        s = jnp.where(mask, s, _NEG_INF)
+        s = jnp.where(mask, s, NEG_INF)
         # explicit zero outside the mask: a fully-masked row has lse ~
-        # _NEG_INF too, where exp(s - lse) would wrongly give 1
+        # NEG_INF too, where exp(s - lse) would wrongly give 1
         p = jnp.where(mask, jnp.exp(s - lse[..., None]), 0.0)  # (B,H,Sq,bk)
         dv_b = jnp.einsum("bhqk,bhqd->bhkd", p, dof)
         dp = jnp.einsum("bhqd,bhkd->bhqk", dof, vb)

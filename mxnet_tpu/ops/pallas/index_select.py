@@ -38,21 +38,21 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import _use_interpret
+from .page_walk import LANES
 
 __all__ = ["dsa_index_select", "index_select_tiles"]
 
-_LANES = 128
 # queries a grid step and keys a block of its loops (PERF.md, PR 37)
 _QUERIES = 128
 _KEYS = 256
 _VMEM_LIMIT = 48 * 1024 * 1024
-_MIN = -2 ** 31             # the sign bit: unsigned order from signed
+MIN_KEY = -2 ** 31          # the sign bit: unsigned order from signed
 
 
 def index_select_tiles(C, L):
     """``(queries a grid step, keys a block)`` of the kernel, from the
     shapes alone, or None where they do not divide into its blocks."""
-    if C % _QUERIES or L % _LANES:
+    if C % _QUERIES or L % LANES:
         return None
     return _QUERIES, math.gcd(L, _KEYS)
 
@@ -61,16 +61,16 @@ def index_select_vmem_bytes(tq, kb, L, heads, Di, itemsize):
     """VMEM a grid step holds: the pipeline's two buffers of every block
     (a last axis takes whole lanes), the keys of a query block, the head
     weights spread over the lanes, and one key block's products."""
-    wide = -(-Di // _LANES) * _LANES
+    wide = -(-Di // LANES) * LANES
     blocks = 2 * ((L + heads * tq) * wide * itemsize      # keys, queries
-                  + tq * _LANES * 4                       # head weights
+                  + tq * LANES * 4                       # head weights
                   + tq * L)                               # the selection
-    scratch = tq * L * 4 + heads * tq * _LANES * 4
+    scratch = tq * L * 4 + heads * tq * LANES * 4
     live = heads * tq * kb * 4 + 4 * tq * kb * 4
     return blocks + scratch + live
 
 
-def _ordered_key(x):
+def ordered_key(x):
     """float32 as int32 whose SIGNED order is the floats' (-inf lowest)."""
     bits = pltpu.bitcast(x, jnp.int32)
     return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
@@ -89,20 +89,20 @@ def _index_select_kernel(off_ref, lim_ref, q_ref, w_ref, k_ref, code_ref,
     limit = lim_ref[0]                       # positions the caller walked
     n_live = jnp.minimum(last // kb + 1, nkb)
     position_bits = (nkb * kb - 1).bit_length()
-    col = jax.lax.broadcasted_iota(jnp.int32, (tq, _LANES), 1)
-    q_abs = first + jax.lax.broadcasted_iota(jnp.int32, (tq, _LANES), 0)
-    chunks = range(0, kb, _LANES)
+    col = jax.lax.broadcasted_iota(jnp.int32, (tq, LANES), 1)
+    q_abs = first + jax.lax.broadcasted_iota(jnp.int32, (tq, LANES), 0)
+    chunks = range(0, kb, LANES)
     code_ref[...] = jnp.zeros_like(code_ref)
 
     def lanes(c):
-        return slice(c, c + _LANES)
+        return slice(c, c + LANES)
 
     def seen(j, c):                          # lanes ``c`` of key block j
         return j * kb + c + col <= q_abs
 
     def put_code(j, c, selected):
-        start = pl.multiple_of(j * kb + c, _LANES)
-        code_ref[0, :, pl.ds(start, _LANES)] = jnp.where(
+        start = pl.multiple_of(j * kb + c, LANES)
+        code_ref[0, :, pl.ds(start, LANES)] = jnp.where(
             selected, 1, 0).astype(jnp.int8)
 
     def row_sum(lane_counts):                # (tq, 128) to (tq, 1)
@@ -121,7 +121,7 @@ def _index_select_kernel(off_ref, lim_ref, q_ref, w_ref, k_ref, code_ref,
     @pl.when(last >= topk)
     def _select():
         for h in range(heads):
-            wb_ref[h] = jnp.broadcast_to(w_ref[0, :, h:h + 1], (tq, _LANES))
+            wb_ref[h] = jnp.broadcast_to(w_ref[0, :, h:h + 1], (tq, LANES))
         # operands go to the MXU as they are; a process-wide "highest"
         # precision is not one Mosaic takes for bfloat16
         prec = (jax.lax.Precision.DEFAULT if k_ref.dtype == jnp.bfloat16
@@ -138,7 +138,7 @@ def _index_select_kernel(off_ref, lim_ref, q_ref, w_ref, k_ref, code_ref,
                     preferred_element_type=jnp.float32,
                     precision=prec)                  # (heads * tq, kb)
                 for c in chunks:
-                    acc = jnp.zeros((tq, _LANES), jnp.float32)
+                    acc = jnp.zeros((tq, LANES), jnp.float32)
                     for h in range(heads):
                         acc = acc + jnp.maximum(
                             s[h * tq:(h + 1) * tq, lanes(c)], 0.0) * wb_ref[h]
@@ -146,11 +146,11 @@ def _index_select_kernel(off_ref, lim_ref, q_ref, w_ref, k_ref, code_ref,
                     acc = jnp.where(
                         jnp.logical_and(pos <= q_abs, pos < limit),
                         acc * scale, -jnp.inf)
-                    key_ref[j, :, lanes(c)] = _ordered_key(acc)
+                    key_ref[j, :, lanes(c)] = ordered_key(acc)
 
             @pl.when(start >= limit)
             def _unwalked():
-                key_ref[j] = _ordered_key(
+                key_ref[j] = ordered_key(
                     jnp.full((tq, kb), -jnp.inf, jnp.float32))
             return carry
 
@@ -158,7 +158,7 @@ def _index_select_kernel(off_ref, lim_ref, q_ref, w_ref, k_ref, code_ref,
 
         # the largest T with count(keys >= T) >= topk, found from the top
         # bit down. T grows as an UNSIGNED key; the keys compare signed, so
-        # the carry is T with its sign bit turned (zero is ``_MIN``) and a
+        # the carry is T with its sign bit turned (zero is ``MIN_KEY``) and a
         # candidate turns one more bit of it
         def bit_pass(p, prefix):
             cand = prefix ^ jnp.left_shift(jnp.int32(1), 31 - p)
@@ -172,12 +172,12 @@ def _index_select_kernel(off_ref, lim_ref, q_ref, w_ref, k_ref, code_ref,
             # counts a lane in float32 (exact): adding lane groups is
             # elementwise, a sum a row is paid once a pass
             cnt = jax.lax.fori_loop(0, n_live, count,
-                                    jnp.zeros((tq, _LANES), jnp.float32))
+                                    jnp.zeros((tq, LANES), jnp.float32))
             enough = row_sum(cnt) >= topk
             return jnp.where(enough, cand, prefix)
 
         kth = jax.lax.fori_loop(
-            0, 32, bit_pass, jnp.full((tq, _LANES), _MIN, jnp.int32))
+            0, 32, bit_pass, jnp.full((tq, LANES), MIN_KEY, jnp.int32))
 
         def cut(j, c):      # above the cut; a seen position at the cut
             keys = key_ref[j, :, lanes(c)]
@@ -192,7 +192,7 @@ def _index_select_kernel(off_ref, lim_ref, q_ref, w_ref, k_ref, code_ref,
                 tie_n = tie_n + jnp.where(tie, 1.0, 0.0)
             return above_n, tie_n
 
-        zero = jnp.zeros((tq, _LANES), jnp.float32)
+        zero = jnp.zeros((tq, LANES), jnp.float32)
         above_n, tie_n = jax.lax.fori_loop(0, n_live, emit, (zero, zero))
         room = topk - row_sum(above_n)       # at least 1: kth is the k-th
 
@@ -217,7 +217,7 @@ def _index_select_kernel(off_ref, lim_ref, q_ref, w_ref, k_ref, code_ref,
 
             bound = jax.lax.fori_loop(
                 0, position_bits, bit_pass,
-                jnp.zeros((tq, _LANES), jnp.int32))
+                jnp.zeros((tq, LANES), jnp.int32))
 
             def settle(j, carry):
                 for c in chunks:
@@ -254,7 +254,7 @@ def _dsa_index_select_impl(qi, wi, ki_all, q_offset, limit, topk, tq, kb,
         out_specs=pl.BlockSpec((1, tq, L), lambda r, i, off, lim: (r, i, 0)),
         scratch_shapes=[
             pltpu.VMEM((L // kb, tq, kb), jnp.int32),
-            pltpu.VMEM((J, tq, _LANES), jnp.float32),
+            pltpu.VMEM((J, tq, LANES), jnp.float32),
         ],
     )
     return pl.pallas_call(

@@ -41,68 +41,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import _use_interpret
-from .flash_attention import _NEG_INF
+from .flash_attention import NEG_INF
+from .page_walk import LANES, decode_tiles, init_carry, nt, softmax_step
 
-__all__ = ["mla_latent_decode", "mla_prefill", "decode_tiles",
-           "prefill_tiles"]
+__all__ = ["mla_latent_decode", "mla_prefill", "prefill_tiles"]
 
-_LANES = 128
-# keys a block of the decode kernel: one buffer of its scratch, one step of
-# its softmax carry (PERF.md, PR 34: of 2, 4, 8 and 16 pages of 128 a block
-# the call read 1.00, 0.72, 0.61 and 0.61 ms where its copies alone take
-# 0.58; under 8 the loop's own work on a block's copies shows)
-_DECODE_BLOCK_KEYS = 1024
 _VMEM_LIMIT = 32 * 1024 * 1024
 
 
-def _prec(dtype):
-    # a process-wide "highest" matmul precision (the float32 parity tests
-    # set it) is not one Mosaic takes for bfloat16 operands
-    return jax.lax.Precision.DEFAULT if dtype == jnp.bfloat16 else None
-
-
-def _softmax_step(s, v, m_ref, l_ref, acc_ref):
-    """One block of the online softmax: scores ``s (rows, cols)`` float32
-    (masked already), values ``v (cols, D)``. The denominator stays a sum
-    a lane until the last block: adding lane groups is elementwise, a sum a
-    row is not."""
-    lw = l_ref.shape[-1]
-    m_prev = m_ref[...]                            # (rows, LANES)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, :1])
-    l_new = alpha[:, :lw] * l_ref[...]
-    for c in range(s.shape[1] // lw):
-        l_new = l_new + p[:, c * lw:(c + 1) * lw]
-    l_ref[...] = l_new
-    acc_ref[...] = acc_ref[...] * alpha[:, :1] + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32, precision=_prec(v.dtype))
-    m_ref[...] = m_new
-
-
-def _init(m_ref, l_ref, acc_ref):
-    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
-    acc_ref[...] = jnp.zeros_like(acc_ref)
-
-
-def _nt(a, b):
-    """``a (m, d) . b (n, d)^T`` in float32."""
-    return jax.lax.dot_general(
-        a.astype(b.dtype), b, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32, precision=_prec(b.dtype))
-
-
 # ------------------------------------------------------- decode (absorbed)
-def decode_tiles(P, page_size):
-    """Pages a block of the decode kernel, from the shapes alone: 1,024
-    keys, so pages of 128 positions go 8 a block, and never more than a
-    row's table has. A block is what one buffer of the kernel's scratch
-    holds, one step of its softmax carry and the grain of its copies."""
-    return max(1, min(P, _DECODE_BLOCK_KEYS // page_size))
-
-
 def _latent_decode_kernel(pt_ref, pos_ref, qc_ref, qr_ref, pool_ref, o_ref,
                           buf_ref, sem_ref, slot_ref, m_ref, l_ref, acc_ref,
                           *, block, heads, rank):
@@ -157,7 +104,7 @@ def _latent_decode_kernel(pt_ref, pos_ref, qc_ref, qr_ref, pool_ref, o_ref,
         slot_ref[0] = 0
         start_first_block_after(-1, 0)
 
-    _init(m_ref, l_ref, acc_ref)
+    init_carry(m_ref, l_ref, acc_ref)
     off = pos_ref[b]
     q_abs = off + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // heads
     blocks = pl.cdiv(live_pages(b), block)
@@ -173,10 +120,10 @@ def _latent_decode_kernel(pt_ref, pos_ref, qc_ref, qr_ref, pool_ref, o_ref,
 
         copies(b, k, slot, lambda c: c.wait())
         c, kr = buf_ref[slot, :, :rank], buf_ref[slot, :, rank:]
-        s = _nt(qc_ref[0], c) + _nt(qr_ref[0], kr)         # (rows, cols)
+        s = nt(qc_ref[0], c) + nt(qr_ref[0], kr)           # (rows, cols)
         col = jax.lax.broadcasted_iota(jnp.int32, (1, s.shape[1]), 1)
-        s = jnp.where(k * block * ps + col <= q_abs, s, _NEG_INF)
-        _softmax_step(s, c, m_ref, l_ref, acc_ref)
+        s = jnp.where(k * block * ps + col <= q_abs, s, NEG_INF)
+        softmax_step(s, c, m_ref, l_ref, acc_ref)
         return 1 - slot
 
     slot_ref[0] = jax.lax.fori_loop(0, blocks, body, slot_ref[0])
@@ -208,8 +155,8 @@ def _mla_latent_decode_impl(qc, qr, pool, page_table, pos, rank, block,
                 pltpu.VMEM((2, block * ps, W), pool.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SMEM((1,), jnp.int32),
-                pltpu.VMEM((rows, _LANES), jnp.float32),
-                pltpu.VMEM((rows, math.gcd(ps, _LANES)), jnp.float32),
+                pltpu.VMEM((rows, LANES), jnp.float32),
+                pltpu.VMEM((rows, math.gcd(ps, LANES)), jnp.float32),
                 pltpu.VMEM((rows, rank), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((B, rows, rank), qc.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -254,16 +201,16 @@ def _prefill_kernel(off_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
     r, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     off = off_ref[r]
 
-    pl.when(j == 0)(functools.partial(_init, m_ref, l_ref, acc_ref))
+    pl.when(j == 0)(functools.partial(init_carry, m_ref, l_ref, acc_ref))
 
     @pl.when(j * tk <= off + (i + 1) * tq - 1)
     def _accumulate():
-        s = _nt(qn_ref[0, 0], kn_ref[0]) + _nt(qr_ref[0, 0], kr_ref[0])
+        s = nt(qn_ref[0, 0], kn_ref[0]) + nt(qr_ref[0, 0], kr_ref[0])
         q_pos = off + i * tq + jax.lax.broadcasted_iota(
             jnp.int32, (tq, 1), 0)
         k_pos = j * tk + jax.lax.broadcasted_iota(jnp.int32, (1, tk), 1)
-        s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
-        _softmax_step(s, v_ref[0], m_ref, l_ref, acc_ref)
+        s = jnp.where(k_pos <= q_pos, s, NEG_INF)
+        softmax_step(s, v_ref[0], m_ref, l_ref, acc_ref)
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _finalize():
@@ -300,8 +247,8 @@ def _mla_prefill_impl(qn, qr, kv, kr, q_offset, tq, tk, interpret):
             out_specs=pl.BlockSpec((1, tq, D),
                                    lambda r, h, i, j, off: (r, i, h)),
             scratch_shapes=[
-                pltpu.VMEM((tq, _LANES), jnp.float32),
-                pltpu.VMEM((tq, math.gcd(tk, _LANES)), jnp.float32),
+                pltpu.VMEM((tq, LANES), jnp.float32),
+                pltpu.VMEM((tq, math.gcd(tk, LANES)), jnp.float32),
                 pltpu.VMEM((tq, D), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((R, C, H * D), qn.dtype),
         compiler_params=pltpu.CompilerParams(

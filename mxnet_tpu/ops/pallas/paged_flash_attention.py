@@ -32,7 +32,7 @@ Two kernels, two entry points:
   ``_decode_kernel`` (PR 41), the form ``mla_attention.py`` and
   ``dsa_decode.py`` have: grid ``(B,)``, the pools left whole in HBM, a
   row's LIVE pages walked in blocks by the kernel's own copies, two
-  buffers deep (``dsa_decode._walk_live_pages``), the same product and
+  buffers deep (``page_walk.walk_live_pages``), the same product and
   carry. Mosaic copies no page out of a pool whose rows are 64 wide, so
   heads of 64 (granite, transformer-big) stay on ``_window_kernel`` at
   ``S = 1`` with ``q_offset = pos``, the group on the window axis: their
@@ -57,57 +57,32 @@ way the kernels read it, ``(num_pages, page_size x Hkv, D)`` with
 
 All keep ``MXTPU_FLASH_INTERPRET`` (force/forbid/auto, shared with
 ``flash_attention.py``) and ship a dense jnp reference
-(``*_reference``) used by the tolerance tests; the MODULE-level
-fallback when the kernel gate is off is the attention layer's existing
-gather+dense path, which stays bitwise-unchanged. ``MXTPU_FLASH_PAGED``
-gates routing: force on (``1``/``force``/``on``), force off
-(``0``/``off``/``false``), default auto = on only when the backend is a
-real TPU (the CPU rig would only ever run the kernels interpreted,
-which is slower than the dense path it replaces).
+(``*_reference``) used by the tolerance tests. Whether a caller comes here
+at all is ``ops/paged.kernels_on()``'s to say (a TPU, no multi-device
+mesh); where it says no, the callers' own ``jax.numpy`` forms stand
+(``ops/paged.py``, ``ops/sparse_attention.py``, the attention layer's
+gather + dense path, which stays bitwise-unchanged).
 """
 
 from __future__ import annotations
 
 import functools
 import math
-import os as _os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _partitionable, _use_interpret
-from .dsa_decode import _walk_live_pages
-from .flash_attention import _NEG_INF
-from .mla_attention import _init, _prec, _softmax_step
+from . import _use_interpret
+from .flash_attention import NEG_INF
+from .page_walk import (LANES, init_carry, prec, softmax_step,
+                        walk_live_pages)
 
 __all__ = ["paged_decode_attention", "paged_window_attention",
            "paged_decode_reference", "paged_window_reference",
            "paged_selected_window_attention",
-           "paged_selected_window_reference", "flash_paged_enabled"]
-
-# online-softmax m/l scratch is lane-replicated to the TPU register
-# width (the flash-kernel convention): every lane of a row holds the
-# same running max / denominator, so the elementwise update needs no
-# cross-lane reduction beyond the score-block max itself
-_LANES = 128
-
-
-def flash_paged_enabled() -> bool:
-    """``MXTPU_FLASH_PAGED``: route paged attention through the Pallas
-    kernels (``1``/``true``/``force``/``on``), keep the dense
-    gather fallback (``0``/``false``/``off``), or — default auto —
-    kernels only on a real TPU backend (interpreted kernels on the CPU
-    rig are slower than the dense path they replace). Under a
-    multi-device mesh a compiled kernel is never routed to
-    (``_partitionable``): the gather path is what XLA can partition."""
-    v = _os.environ.get("MXTPU_FLASH_PAGED", "").strip().lower()
-    if v in ("0", "false", "off") or not _partitionable():
-        return False
-    if v in ("1", "true", "force", "on"):
-        return True
-    return jax.default_backend() == "tpu"
+           "paged_selected_window_reference"]
 
 
 # The window kernel's two sizes, in bytes of one pool. A grid step takes
@@ -152,9 +127,9 @@ def _window_vmem_bytes(pages, block, page_size, Hkv, S, D, itemsize):
     the scratch, one block of pages joined, and its scores with their
     exponentials (float32) and their cast for the second product."""
     rows, cols = Hkv * S, block * page_size * Hkv
-    wide = -(-D // _LANES) * _LANES
+    wide = -(-D // LANES) * LANES
     operands = 2 * (2 * rows + 2 * pages * page_size * Hkv) * wide * itemsize
-    scratch = rows * (2 * _LANES + wide) * 4
+    scratch = rows * (2 * LANES + wide) * 4
     live = 2 * cols * wide * itemsize + rows * cols * (4 + 4 + itemsize)
     return operands + scratch + live
 
@@ -188,7 +163,7 @@ def _window_kernel(pt_ref, off_ref, vl_ref, q_ref, *refs, page_size, pages,
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -219,7 +194,7 @@ def _window_kernel(pt_ref, off_ref, vl_ref, q_ref, *refs, page_size, pages,
         col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
         s = jnp.where(jnp.logical_and(col % Hkv == row // window,
                                       first_key + col // Hkv <= q_abs),
-                      s, _NEG_INF)
+                      s, NEG_INF)
         m_prev = m_ref[...]                        # (rows, LANES)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -289,8 +264,8 @@ def _paged_window_impl(q, k_pool, v_pool, page_table, q_offset, window_vl,
         in_specs=[row_spec] + pool_specs + pool_specs,
         out_specs=row_spec,
         scratch_shapes=[
-            pltpu.VMEM((H * S, _LANES), jnp.float32),
-            pltpu.VMEM((H * S, math.gcd(ps * H, _LANES)), jnp.float32),
+            pltpu.VMEM((H * S, LANES), jnp.float32),
+            pltpu.VMEM((H * S, math.gcd(ps * H, LANES)), jnp.float32),
             pltpu.VMEM((H * S, D), jnp.float32),
         ],
     )
@@ -447,15 +422,15 @@ def _decode_kernel(pt_ref, pos_ref, q_ref, k_pool_ref, v_pool_ref, o_ref,
                 s = jax.lax.dot_general(
                     q_ref[0].astype(kt.dtype), kt, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
-                    precision=_prec(kt.dtype)) * sm_scale    # (rows, width)
+                    precision=prec(kt.dtype)) * sm_scale     # (rows, width)
                 seen = key <= pos - (k * block + t) * ps
-                s = jnp.where(jnp.logical_and(own, seen), s, _NEG_INF)
-                _softmax_step(s, v_buf[slot, pl.ds(t * cols, width), :],
-                              m_ref, l_ref, acc_ref)
+                s = jnp.where(jnp.logical_and(own, seen), s, NEG_INF)
+                softmax_step(s, v_buf[slot, pl.ds(t * cols, width), :],
+                             m_ref, l_ref, acc_ref)
 
-    _init(m_ref, l_ref, acc_ref)
-    _walk_live_pages(pt_ref, slot_ref, live_pages, block, page_copies,
-                     attend)
+    init_carry(m_ref, l_ref, acc_ref)
+    walk_live_pages(pt_ref, slot_ref, live_pages, block, page_copies,
+                    attend)
     l = jnp.maximum(jnp.sum(l_ref[...], axis=1, keepdims=True), 1e-30)
     o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
@@ -482,8 +457,8 @@ def _paged_decode_impl(q, k_pool, v_pool, page_table, pos, sm_scale,
                 pltpu.VMEM((2, block * cols, D), v_pool.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SMEM((1,), jnp.int32),
-                pltpu.VMEM((H, _LANES), jnp.float32),
-                pltpu.VMEM((H, math.gcd(cols, _LANES)), jnp.float32),
+                pltpu.VMEM((H, LANES), jnp.float32),
+                pltpu.VMEM((H, math.gcd(cols, LANES)), jnp.float32),
                 pltpu.VMEM((H, D), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -540,7 +515,7 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, pos, *,
     dimension 2 must be aligned to tiling (128), but is 64"), so heads of
     64 keep the pipeline's page operands (``_window_kernel``)."""
     Hkv = k_pool.shape[2] if kv_heads is None else int(kv_heads)
-    form = _decode_window if q.shape[2] % _LANES else _decode_walk
+    form = _decode_window if q.shape[2] % LANES else _decode_walk
     return form(q, k_pool, v_pool, page_table, pos, sm_scale, Hkv)
 
 
@@ -570,7 +545,7 @@ def _selected_window_vmem_bytes(tq, pages, page_size, Hkv, G, D, itemsize):
     blocks = 2 * (2 * Hkv * rows * D * itemsize       # queries, output
                   + 2 * kb * Hkv * D * itemsize       # K and V pages
                   + tq * kb)                          # the mask, int8
-    scratch = Hkv * rows * (2 * _LANES + D) * 4 + 2 * Hkv * kb * D * itemsize
+    scratch = Hkv * rows * (2 * LANES + D) * 4 + 2 * Hkv * kb * D * itemsize
     live = rows * kb * (4 + 4 + itemsize) + tq * kb * 4
     return blocks + scratch + live
 
@@ -593,7 +568,7 @@ def _selected_window_kernel(pt_ref, off_ref, q_ref, *refs, page_size, pages,
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -692,8 +667,8 @@ def _dsa_selected_window_impl(q, k_pool, v_pool, page_table, q_offset,
         + [pl.BlockSpec((1, tq, kb), mask_block)],
         out_specs=rows,
         scratch_shapes=[
-            pltpu.VMEM((Hkv, G * tq, _LANES), jnp.float32),
-            pltpu.VMEM((Hkv, G * tq, math.gcd(kb, _LANES)), jnp.float32),
+            pltpu.VMEM((Hkv, G * tq, LANES), jnp.float32),
+            pltpu.VMEM((Hkv, G * tq, math.gcd(kb, LANES)), jnp.float32),
             pltpu.VMEM((Hkv, G * tq, D), jnp.float32),
             pltpu.VMEM((Hkv, kb, D), k_pool.dtype),
             pltpu.VMEM((Hkv, kb, D), v_pool.dtype),
@@ -743,7 +718,7 @@ def paged_selected_window_reference(q, k_pool, v_pool, page_table,
     v = v_pool[page_table].reshape(B, P * ps, Hkv, D).astype(jnp.float32)
     qg = q.astype(jnp.float32).reshape(B, C, Hkv, Hq // Hkv, D)
     s = jnp.einsum("bcngd,blnd->bngcl", qg, k) * sm_scale
-    probs = jax.nn.softmax(jnp.where(mask[:, None, None], s, _NEG_INF), -1)
+    probs = jax.nn.softmax(jnp.where(mask[:, None, None], s, NEG_INF), -1)
     out = jnp.einsum("bngcl,blnd->bcngd", probs, v)
     return out.reshape(B, C, Hq * D).astype(q.dtype)
 
@@ -760,7 +735,7 @@ def paged_decode_reference(q, k_pool, v_pool, page_table, pos, *,
     v = v_pool[page_table].reshape(B, P * ps, H, D).astype(jnp.float32)
     s = jnp.einsum("bhd,blhd->bhl", q.astype(jnp.float32), k) * sm_scale
     mask = jnp.arange(P * ps)[None, None, :] <= pos[:, None, None]
-    s = jnp.where(mask, s, _NEG_INF)
+    s = jnp.where(mask, s, NEG_INF)
     probs = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhl,blhd->bhd", probs, v).astype(q.dtype)
 
@@ -779,7 +754,7 @@ def paged_window_reference(q, k_pool, v_pool, page_table, q_offset,
     key_abs = jnp.arange(P * ps)[None, None, None, :]
     q_abs = (q_offset[:, None, None, None]
              + jnp.arange(S)[None, None, :, None])
-    s = jnp.where(key_abs <= q_abs, s, _NEG_INF)
+    s = jnp.where(key_abs <= q_abs, s, NEG_INF)
     probs = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhsl,blhd->bshd", probs, v)
     live = jnp.arange(S)[None, :, None, None] < \

@@ -1,29 +1,37 @@
-"""Learned sparse attention over a paged cache: an indexer scores every
-cached position for a query, the ``topk`` best are selected, and attention
-reads the selected set alone.
+"""Learned sparse attention over a paged cache, and attention under a mask.
 
-``I[t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s]) / sqrt(Di * J)`` for
-``s <= t``; the selected set of ``t`` is every ``s <= t`` while ``t + 1 <=
-topk``, else the ``topk`` positions of largest ``I[t, .]``, ties to the
-lower position. Below ``topk + 1`` cached positions both programs are plain
-causal attention.
+Two things live here, the second serving the first and the dense nets both:
 
-Two shapes, as the serving plane has them:
+THE LEARNED SELECTION (keye's alone). An indexer scores every cached
+position for a query, the ``topk`` best are selected, and attention reads
+the selected set alone. ``I[t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s])
+/ sqrt(Di * J)`` for ``s <= t``; the selected set of ``t`` is every ``s <=
+t`` while ``t + 1 <= topk``, else the ``topk`` positions of largest ``I[t,
+.]``, ties to the lower position. Below ``topk + 1`` cached positions both
+programs are plain causal attention. ``window_index_scores``,
+``select_mask``, ``window_select`` for a prefill chunk; ``decode_select``
+and ``selected_decode`` for the decode step.
 
-- the WINDOW (a prefill chunk): ``C`` queries a row at ``q_offset + i``
-  over the row's pages, which already hold the chunk's own keys. Index
-  scores and attention walk the cached keys in blocks and stop at the last
-  block any query can see; the selection is a mask (``window_select``),
-  and attention is a flash pass over the pages masked to it
-  (``selected_window_attention``).
-- DECODE: one query a row over the row's cached positions
-  (``selected_decode``). On the chip the selection is a mask here too and
-  attention walks the row's live pages in place under it; in ``jax.numpy``
-  the ``topk`` positions are gathered from the pools BY TOKEN (not by
-  page), and attention reads those alone.
+ATTENTION UNDER A MASK, in the two shapes the serving plane has:
+
+- the WINDOW (a prefill chunk), ``selected_window_attention``: ``C``
+  queries a row at ``q_offset + i`` over the row's pages, which already
+  hold the chunk's own keys, masked to ``(R, C, L)``. keye hands it the
+  selection; granite and ouro a plain causal mask (zaya's chunk goes
+  through ``ops/paged.window_attention``, whose ``jax.numpy`` half is this
+  function under a causal mask).
+- DECODE, ``selected_decode_attention``: one query a row over ``K``
+  positions gathered from the pools BY TOKEN (not by page). keye hands it
+  the ``topk`` positions it selected; ``ops/paged.decode_attention`` every
+  cached position and a causal mask, which is the dense nets' decode step
+  off the chip.
+
+A row's places in the pools (``token_rows``, ``write_rows``,
+``gather_row_pages``, ``kv_block``) and the answer to whether the kernels
+run (``kernels_on``) are ``ops/paged.py``'s.
 
 Which form runs where. Where the paged kernels are on
-(``flash_paged_enabled``: a TPU, no multi-device mesh) the window is two
+(``paged.kernels_on()``: a TPU, no multi-device mesh) the window is two
 Pallas kernels a layer: ``ops/pallas/index_select.dsa_index_select`` (128
 queries a grid step keep their index scores in VMEM from the products to
 the ``topk``-th largest, found a bit a pass, and settle the ties at the
@@ -67,45 +75,9 @@ import math
 import jax
 import jax.numpy as jnp
 
+from . import paged
+
 NEG = -1e30
-
-
-def kv_block(length: int, chunk: int = 512) -> int:
-    """Keys a block of the window loops: the published ``kv_chunk_size``
-    where it divides a row's cached length, else their largest common
-    divisor."""
-    return math.gcd(int(length), int(chunk))
-
-
-def gather_row_pages(pool, page_tables):
-    """``pool (num_pages, page, ...)`` through ``page_tables (R, P)`` as
-    ``(R, P * page, ...)``: a row's cached positions in order."""
-    got = pool[page_tables]
-    return got.reshape((got.shape[0], got.shape[1] * got.shape[2])
-                       + got.shape[3:])
-
-
-def token_rows(page_tables, positions, page_size):
-    """Rows of the flattened pool ``(num_pages * page, ...)`` that hold
-    ``positions (R, K)`` of each row: the gather by token."""
-    page = jnp.take_along_axis(page_tables, positions // page_size, axis=1)
-    return page * page_size + positions % page_size
-
-
-def write_rows(pool, rows, values):
-    """``pool`` with ``values (N, ...)`` written at flattened rows ``rows
-    (N,)`` (rows of the trash page for what must not land). A pool
-    declared ``(num_pages, page x heads, D)``, a page's (key, head) rows
-    on one axis as the paged window kernel reads them, takes ``values (N,
-    heads, D)``, as many axes as its own: position ``r``'s heads are rows
-    ``r x heads`` onward."""
-    if values.ndim == pool.ndim:
-        heads = values.shape[1]
-        rows = (rows[:, None] * heads
-                + jnp.arange(heads, dtype=rows.dtype)).reshape(-1)
-        values = values.reshape((-1,) + values.shape[2:])
-    flat = pool.reshape((-1,) + pool.shape[2:])
-    return flat.at[rows].set(values.astype(pool.dtype)).reshape(pool.shape)
 
 
 # ----------------------------------------------------------------- window
@@ -186,7 +158,7 @@ def select_mask(scores, q_pos, topk):
 def window_select(qi, wi, ki_all, q_pos, n_blocks, block, topk):
     """The selected set of each window query as a mask ``(R, C, L)``:
     ``select_mask`` of ``window_index_scores``. Where the paged kernels are
-    on (``flash_paged_enabled``: a TPU, no multi-device mesh) and the
+    on (``paged.kernels_on()``: a TPU, no multi-device mesh) and the
     window divides into its blocks, ONE Pallas kernel
     (``ops/pallas/index_select.dsa_index_select``) keeps a query block's
     scores on the chip from the products to the k-th largest, settles
@@ -194,10 +166,9 @@ def window_select(qi, wi, ki_all, q_pos, n_blocks, block, topk):
     the CPU's and a mesh's form and the kernel's reference. The queries of
     a row sit at consecutive positions from ``q_pos[:, 0]``."""
     from .pallas import index_select as _ixs
-    from .pallas import paged_flash_attention as _pfa
 
     C, L = q_pos.shape[1], ki_all.shape[1]
-    if L <= topk or not _pfa.flash_paged_enabled() \
+    if L <= topk or not paged.kernels_on() \
             or _ixs.index_select_tiles(C, L) is None:
         return select_mask(
             window_index_scores(qi, wi, ki_all, q_pos, n_blocks, block),
@@ -212,18 +183,18 @@ def selected_window_attention(q, k_pool, v_pool, page_tables, q_offset,
     c`` over the row's cached positions that ``mask (R, C, L)`` selects;
     query head ``i`` reads key/value head ``i // (Hq // Hkv)``. The Pallas
     kernel reads the pools in place where the paged kernels are on
-    (``flash_paged_enabled``: a TPU, no multi-device mesh); else a flash
+    (``paged.kernels_on()``: a TPU, no multi-device mesh); else a flash
     loop in jnp over the first ``n_blocks`` blocks of ``block`` gathered
     keys. Returns ``(R, C, Hq * D)`` in ``q``'s dtype."""
-    from .pallas import paged_flash_attention as _pfa
+    if paged.kernels_on():
+        from .pallas import paged_flash_attention as _pfa
 
-    if _pfa.flash_paged_enabled():
         return _pfa.paged_selected_window_attention(
             q, k_pool, v_pool, page_tables, q_offset, mask,
             sm_scale=sm_scale)
     R, C, Hq, D = q.shape
-    k_all = gather_row_pages(k_pool, page_tables)
-    v_all = gather_row_pages(v_pool, page_tables)
+    k_all = paged.gather_row_pages(k_pool, page_tables)
+    v_all = paged.gather_row_pages(v_pool, page_tables)
     Hkv = k_all.shape[2]
     G = Hq // Hkv
     qg = q.reshape(R, C, Hkv, G, D)
@@ -264,7 +235,7 @@ def decode_select(qi, wi, ik_pool, page_tables, pos, topk):
     J)``, the row's indexer keys read through ``page_tables``. ``K`` is
     ``topk``, or every cached position where a row holds no more."""
     B, J, Di = qi.shape
-    ki = gather_row_pages(ik_pool, page_tables)             # (B, L, Di)
+    ki = paged.gather_row_pages(ik_pool, page_tables)       # (B, L, Di)
     L = ki.shape[1]
     hit = jax.nn.relu(jnp.einsum("bjd,bsd->bjs", qi, ki,
                                  preferred_element_type=jnp.float32))
@@ -285,7 +256,7 @@ def selected_decode_attention(q, k_pool, v_pool, page_tables, positions,
     Returns ``(B, Hq * D)``."""
     B, Hq, D = q.shape
     page_size, Hkv = k_pool.shape[1], k_pool.shape[2]
-    rows = token_rows(page_tables, positions, page_size)
+    rows = paged.token_rows(page_tables, positions, page_size)
     ks = k_pool.reshape((-1, Hkv, D))[rows]                  # (B, K, Hkv, D)
     vs = v_pool.reshape((-1, Hkv, D))[rows]
     qg = q.reshape(B, Hkv, Hq // Hkv, D)
@@ -307,7 +278,7 @@ def selected_decode(q, qi, wi, ki, k_pool, v_pool, ik_pool, page_tables,
     here, at ``rows`` of the flattened pool (``write_rows``). Returns
     ``(attention (B, Hq * D), ik_pool, keys the active rows selected)``.
 
-    Where the paged kernels are on (``flash_paged_enabled``: a TPU, no
+    Where the paged kernels are on (``paged.kernels_on()``: a TPU, no
     multi-device mesh) and a row can hold more than ``topk`` positions, the
     selection never becomes positions: ``dsa_decode_select`` writes the
     row's key into its page, keeps the row's scores on the chip to the
@@ -318,10 +289,9 @@ def selected_decode(q, qi, wi, ki, k_pool, v_pool, ik_pool, page_tables,
     Else ``write_rows``, ``decode_select`` and ``selected_decode_attention``,
     the CPU's and a mesh's form and the kernels' references."""
     from .pallas import dsa_decode as _dec
-    from .pallas import paged_flash_attention as _pfa
 
     L = page_tables.shape[1] * ik_pool.shape[1]
-    if L > topk and _pfa.flash_paged_enabled():
+    if L > topk and paged.kernels_on():
         # an inactive row stands below 0: it reads nothing, selects nothing
         at = jnp.where(active, pos, -1)
         mask, ik_pool = _dec.dsa_decode_select(qi, wi, ki, ik_pool,
@@ -329,7 +299,7 @@ def selected_decode(q, qi, wi, ki, k_pool, v_pool, ik_pool, page_tables,
         attn = _dec.dsa_decode_window(q, k_pool, v_pool, page_tables, at,
                                       mask, sm_scale=sm_scale)
         return attn, ik_pool, jnp.sum(mask, dtype=jnp.int32)
-    ik_pool = write_rows(ik_pool, rows, ki)
+    ik_pool = paged.write_rows(ik_pool, rows, ki)
     picked, valid = decode_select(qi, wi, ik_pool, page_tables, pos, topk)
     attn = selected_decode_attention(q, k_pool, v_pool, page_tables, picked,
                                      valid, sm_scale)

@@ -13,8 +13,8 @@ XLA program, ``InferStep`` compiles the *serving* hot paths:
   of O(1) incremental steps with the cache DONATED into the loop and an
   early exit once every row has emitted EOS. One jitted dispatch emits up
   to ``max_new_tokens`` tokens — no per-token host round trips
-  (``tools/check_no_sync_in_step.py`` lints ``__call__``/``_dispatch``/
-  ``decode_n``).
+  (``tools/mxlint.py``'s ``no-sync`` pass lints ``__call__``/
+  ``_dispatch``/``decode_n``).
 
 Shape stability reuses the PR-3 machinery: prompts pad to a
 ``FixedBucketSampler.signatures()``-style bucket menu, ``warmup()``
@@ -465,7 +465,7 @@ class InferStep:
 
     def _dispatch(self, staged):
         """Hot dispatch: signature accounting + the jitted call. Must stay
-        free of host syncs (``tools/check_no_sync_in_step.py``)."""
+        free of host syncs (``tools/mxlint.py``, pass ``no-sync``)."""
         sig = ("fwd",) + tuple((a.shape, a.dtype.name) for a in staged)
         self.compile_guard.observe(
             sig, lambda: "fwd " + _cc.aval_summary(staged))
@@ -769,7 +769,7 @@ class InferStep:
         """One admission dispatch: prefill the (padded) admission batch
         INTO pool pages/slot buffers and sample each admitted row's first
         token. Pure staging + dispatch, sync-free by lint
-        (``tools/check_no_sync_in_step.py``) — the scheduler reads the
+        (``tools/mxlint.py``, pass ``no-sync``) — the scheduler reads the
         returned tokens at its designated sync point. Returns
         ``(tok0 (slots,) NDArray, new_state)``.
 

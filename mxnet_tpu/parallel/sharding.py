@@ -31,9 +31,9 @@ the reference's host-side data parallelism (KVStore push/pull per step,
 
 Silent-fallback honesty: ``param_explain`` returns WHY a param got its
 spec (matched rule, fsdp, or a replication fallback with the reason);
-``tools/check_sharding.py`` lints that every param entering the jitted
-step carries its declared sharding and that no rule silently degraded to
-full replication.
+``tools/mxlint.py`` (pass ``sharding-placement``) lints that every param
+entering the jitted step carries its declared sharding and that no rule
+silently degraded to full replication.
 
 Env knobs: ``MXTPU_MESH`` (mesh axes), ``MXTPU_SHARDING`` (rules
 preset), ``MXTPU_FSDP_MIN_SIZE`` (elements below which a param stays

@@ -639,7 +639,7 @@ class TrainStep:
             # lax.cond'd update — a skipped step leaves params, moments,
             # aux states and the bias-correction clock t untouched — and
             # the grow/halve schedule advances on device. No host sync
-            # anywhere on this path (tools/check_amp_purity.py lints it).
+            # anywhere on this path (mxlint's amp-purity pass lints it).
             L = L / scale
             finite = jnp.bool_(True)
             for g in jax.tree.leaves(grads):
@@ -938,7 +938,8 @@ class TrainStep:
         """Dispatch one pre-staged step. The pre-placed feed enters here
         directly, so this body must stay free of host conversion, dict
         rebuilds, and anything that blocks on the device —
-        ``tools/check_no_sync_in_step.py`` lints it (and ``__call__``)."""
+        ``tools/mxlint.py``'s ``no-sync`` pass lints it (and
+        ``__call__``)."""
         nsteps = self._steps_per_call
         sig = tuple((a.shape, a.dtype.name) for a in batch) + (
             (label.shape, label.dtype.name),)

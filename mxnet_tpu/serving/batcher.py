@@ -623,9 +623,9 @@ class ContinuousBatcher:
         self._dstate = engine.init_draft_state(
             self.slots, self.num_pages, self.page_size,
             self.mem_len) if self._spec_on else None
-        from ..ops.pallas import paged_flash_attention as _pfa
+        from ..ops import paged as _paged
         _tel.registry().gauge("infer/flash_kernel").set(
-            1.0 if _pfa.flash_paged_enabled() else 0.0)
+            1.0 if _paged.kernels_on() else 0.0)
         # prefix trie over this pool: retired slots donate their page
         # chains (refcounted, read-only) and admission adopts matched
         # prefixes instead of recomputing them
@@ -2034,7 +2034,7 @@ class ContinuousBatcher:
     def _dispatch(self, live):
         """One decode-iteration dispatch over the slot batch: pure
         staging + the jitted ``InferStep.decode_iter`` call — linted
-        sync-free (``tools/check_no_sync_in_step.py``); the host reads
+        sync-free (``tools/mxlint.py``, pass ``no-sync``); the host reads
         happen in ``_collect`` after the device work is in flight.
 
         With speculation on, the iteration is one draft proposal burst

@@ -51,3 +51,22 @@ def _seed_rng():
     mx.random.seed(42)
     np.random.seed(42)
     yield
+
+
+@pytest.fixture
+def paged_kernels(monkeypatch):
+    """``paged_kernels(on)`` sets what ``ops.paged.kernels_on()`` answers
+    for the rest of the test: True runs the paged attention kernels where
+    the CPU would take the ``jax.numpy`` forms (interpreted here:
+    ``MXTPU_FLASH_INTERPRET`` is every kernel's and stays the
+    environment's), False keeps the ``jax.numpy`` forms where a TPU would
+    take the kernels. Every asker reaches the function as
+    ``paged.kernels_on()`` while it is traced, so the answer holds for
+    programs traced after the call; a trace is cached by the callable, so
+    jit another one after changing it."""
+    from mxnet_tpu.ops import paged
+
+    def force(on):
+        monkeypatch.setattr(paged, "kernels_on", lambda: bool(on))
+
+    return force

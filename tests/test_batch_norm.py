@@ -1,9 +1,11 @@
-"""Parity: fused Pallas BatchNorm reductions vs the two-pass jnp path.
+"""BatchNorm's statistics and its closed-form backward against a two-pass
+mean and variance written here.
 
 The round-3 one-pass BN was reverted for catastrophic cancellation at
-|mean| >> std; these tests pin the shifted one-pass kernel in exactly that
-regime, plus full fwd+bwd parity of the channel-last BatchNorm op with the
-flag on/off.
+|mean| >> std; these tests pin the shifted one-pass statistics
+(``ops_nn._bn_stats``, the only form) in exactly that regime, plus full
+fwd+bwd parity of the channel-last BatchNorm op with a two-pass BatchNorm
+under ``jax.vjp``.
 """
 
 import numpy as np
@@ -11,9 +13,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-import mxnet_tpu as mx
+import mxnet_tpu as mx  # noqa: F401 - the package sets JAX up
 from mxnet_tpu.ops import nn as ops_nn
-from mxnet_tpu.ops.pallas import batch_norm as pbn
 
 
 @pytest.mark.parametrize("shape", [(4, 7, 7, 8), (8, 14, 14, 64),
@@ -24,7 +25,7 @@ def test_bn_stats_parity(shape, mean_scale):
     rng = np.random.default_rng(0)
     C = shape[-1]
     x = rng.normal(mean_scale, 0.7, shape).astype(np.float32)
-    mean, var = pbn.bn_stats(jnp.asarray(x).reshape(-1, C))
+    mean, var, _, _ = ops_nn._bn_stats(jnp.asarray(x), -1)
     xr = x.reshape(-1, C)
     np.testing.assert_allclose(np.asarray(mean), xr.mean(0), rtol=0,
                                atol=1e-4 * max(1.0, mean_scale))
@@ -34,37 +35,19 @@ def test_bn_stats_parity(shape, mean_scale):
 
 def test_bn_stats_cancellation_regime():
     # mean/std = 2000: E[x^2]-E[x]^2 in f32 is useless here; the shifted
-    # kernel must stay at ~1e-4 relative error
+    # form must stay at ~1e-4 relative error
     rng = np.random.default_rng(1)
     x = rng.normal(1000.0, 0.5, (8, 16, 16, 8)).astype(np.float32)
-    _, var = pbn.bn_stats(jnp.asarray(x).reshape(-1, 8))
+    _, var, _, _ = ops_nn._bn_stats(jnp.asarray(x), -1)
     ref = x.reshape(-1, 8).var(0)
     np.testing.assert_allclose(np.asarray(var), ref, rtol=1e-4)
 
 
-def test_bn_bwd_reduce_parity():
-    rng = np.random.default_rng(4)
-    for shape in [(4, 7, 7, 8), (8, 6, 6, 64), (2, 3, 3, 300)]:
-        C = shape[-1]
-        x = rng.normal(2.0, 1.0, shape).astype(np.float32).reshape(-1, C)
-        dy = rng.normal(0, 1, shape).astype(np.float32).reshape(-1, C)
-        mean = x.mean(0)
-        inv = (1.0 / np.sqrt(x.var(0) + 1e-3)).astype(np.float32)
-        sd, sdx = pbn.bn_bwd_reduce(jnp.asarray(x), jnp.asarray(dy),
-                                    jnp.asarray(mean), jnp.asarray(inv))
-        xhat = (x - mean) * inv
-        np.testing.assert_allclose(np.asarray(sd), dy.sum(0), rtol=1e-4,
-                                   atol=1e-4)
-        np.testing.assert_allclose(np.asarray(sdx), (dy * xhat).sum(0),
-                                   rtol=1e-4, atol=1e-4)
-
-
-def test_bn_shifted_onepass_cancellation(monkeypatch):
-    """The default jnp mode ('1') must survive the |mean| >> std regime
-    that killed the round-3 one-pass."""
+def test_bn_shifted_onepass_cancellation():
+    """The statistics must survive the |mean| >> std regime that killed
+    the round-3 one-pass, in either layout."""
     from mxnet_tpu.ops.nn import _bn_stats
 
-    monkeypatch.setenv("MXTPU_FUSED_BN", "1")
     rng = np.random.default_rng(7)
     x = rng.normal(1000.0, 0.5, (8, 16, 16, 8)).astype(np.float32)
     _, var, _, _ = _bn_stats(jnp.asarray(x), -1)
@@ -76,11 +59,21 @@ def test_bn_shifted_onepass_cancellation(monkeypatch):
     np.testing.assert_allclose(np.asarray(var1), ref, rtol=1e-4)
 
 
-@pytest.mark.parametrize("mode", ["1", "pallas"])
+def _two_pass_batch_norm(x, g, b, eps):
+    """Channel-last BatchNorm with a two-pass mean and variance in float32:
+    ``(out in x's dtype, mean, var)``."""
+    xf = x.astype(jnp.float32)
+    m = jnp.mean(xf, axis=(0, 1, 2))
+    v = jnp.mean(jnp.square(xf - m), axis=(0, 1, 2))
+    out = (xf - m) * jax.lax.rsqrt(v + eps) * g + b
+    return out.astype(x.dtype), (m, v)
+
+
 @pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
-def test_batch_norm_op_fwd_bwd_parity_flag(monkeypatch, dtype, mode):
-    """Full op (channel-last axis): shifted-jnp and Pallas modes vs the
-    two-pass reference mode ('0'), fwd + grads."""
+def test_batch_norm_op_fwd_bwd_parity(dtype):
+    """Full op (channel-last axis): the shifted one-pass statistics and the
+    closed-form backward against a two-pass BatchNorm under ``jax.vjp``,
+    fwd + grads."""
     rng = np.random.default_rng(2)
     shape = (4, 6, 6, 16)
     x = rng.normal(1.5, 1.0, shape).astype(np.float32)
@@ -88,23 +81,21 @@ def test_batch_norm_op_fwd_bwd_parity_flag(monkeypatch, dtype, mode):
     b = rng.normal(0.0, 0.1, (16,)).astype(np.float32)
     dy = rng.normal(0, 1, shape).astype(np.float32)
 
-    def run():
-        def f(x_, g_, b_):
-            out, m, v = ops_nn.batch_norm(
-                x_, g_, b_, jnp.zeros(16), jnp.ones(16),
-                eps=1e-3, fix_gamma=False, training=True, axis=-1)
-            return out, (m, v)
+    def op(x_, g_, b_):
+        out, m, v = ops_nn.batch_norm(
+            x_, g_, b_, jnp.zeros(16), jnp.ones(16),
+            eps=1e-3, fix_gamma=False, training=True, axis=-1)
+        return out, (m, v)
 
+    def run(f):
         out, vjp, (m, v) = jax.vjp(f, jnp.asarray(x, dtype),
                                    jnp.asarray(g), jnp.asarray(b),
                                    has_aux=True)
         dx, dg, db = vjp(jnp.asarray(dy, dtype))
         return [np.asarray(t, np.float32) for t in (out, m, v, dx, dg, db)]
 
-    monkeypatch.setenv("MXTPU_FUSED_BN", mode)
-    fused = run()
-    monkeypatch.setenv("MXTPU_FUSED_BN", "0")
-    ref = run()
+    fused = run(op)
+    ref = run(lambda x_, g_, b_: _two_pass_batch_norm(x_, g_, b_, 1e-3))
     tol = 1e-5 if dtype == np.float32 else 2e-2
     for a, r, name in zip(fused, ref, ["out", "mean", "var", "dx", "dg", "db"]):
         np.testing.assert_allclose(a, r, rtol=tol, atol=tol,
@@ -123,10 +114,7 @@ def test_batch_norm_grad_vs_autodiff_reference():
     w = rng.normal(0, 1, x.shape).astype(np.float32)   # non-degenerate loss
 
     def ref(x_, g_, b_):
-        m = jnp.mean(x_, axis=(0, 1, 2), keepdims=True)
-        v = jnp.mean(jnp.square(x_ - m), axis=(0, 1, 2), keepdims=True)
-        out = (x_ - m) * jax.lax.rsqrt(v + 1e-3) * g_.reshape(1, 1, 1, -1) \
-            + b_.reshape(1, 1, 1, -1)
+        out, _ = _two_pass_batch_norm(x_, g_, b_, 1e-3)
         return jnp.sum(out * w)
 
     def mine(x_, g_, b_):
@@ -145,8 +133,8 @@ def test_batch_norm_grad_vs_autodiff_reference():
 
 
 def test_batch_norm_nchw_grad_unchanged():
-    """NCHW (axis=1) takes the jnp path and must keep exact round-3
-    behavior regardless of the flag."""
+    """NCHW (axis=1): the shift works in any layout; the gradient stays
+    finite."""
     rng = np.random.default_rng(5)
     x = rng.normal(0.5, 1.0, (4, 8, 5, 5)).astype(np.float32)
 
@@ -158,10 +146,3 @@ def test_batch_norm_nchw_grad_unchanged():
 
     g = jax.grad(f)(jnp.asarray(x))
     assert np.isfinite(np.asarray(g)).all()
-
-
-def test_supports_gate():
-    assert pbn.supports(jnp.zeros((4, 7, 7, 8)), 3)
-    assert pbn.supports(jnp.zeros((4, 7, 7, 8)), -1)
-    assert not pbn.supports(jnp.zeros((4, 8, 7, 7)), 1)   # channel-first
-    assert not pbn.supports(jnp.zeros((1, 8)), -1)        # M < 2

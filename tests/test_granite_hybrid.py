@@ -385,11 +385,12 @@ def test_what_the_serving_plane_refuses_for_this_net(net):
         GraniteHybridLM(layer_types=("mamba", "mamba"))
 
 
-def test_the_paged_kernels_serve_the_attention_layers(ref, net, monkeypatch):
+def test_the_paged_kernels_serve_the_attention_layers(ref, net, monkeypatch,
+                                                      paged_kernels):
     """With the paged kernels forced on (interpreted here) the attention
     layers go through ``paged_selected_window_attention`` and the grouped
     ``paged_decode_attention``: the same tokens."""
-    monkeypatch.setenv("MXTPU_FLASH_PAGED", "force")
+    paged_kernels(True)
     monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
     prompt = tokens(11, 8)
     served, _ = _serve_by_hand(net, prompt, 4)

@@ -324,8 +324,8 @@ def test_the_scheduler_follows_the_count_a_row(ref, driver, cls, rate):
 
 
 def test_a_burst_runs_the_decode_kernel_and_serves_the_same_tokens(
-        ref, net, monkeypatch):
-    """With the paged kernels routed to (``MXTPU_FLASH_PAGED=1``; here
+        ref, net, monkeypatch, paged_kernels):
+    """With the paged kernels routed to (``paged_kernels(True)``; here
     interpreted) a burst's loop runs ``%mla_latent_decode``, its own copies
     and semaphores under the loop's carry, a call a latent cache a step,
     rows coming and going beside it: the tokens are the ``jax.numpy``
@@ -341,10 +341,10 @@ def test_a_burst_runs_the_decode_kernel_and_serves_the_same_tokens(
     monkeypatch.setattr(kern, "mla_latent_decode", counted)
     prompts = [tokens(n, 20 + n) for n in (5, 23, 9, 16, 3, 38)]
     max_new = [5, 8, 2, 7, 1, 6]
-    monkeypatch.setenv("MXTPU_FLASH_PAGED", "0")
+    paged_kernels(False)
     plain, *_ = _through_batcher(net, prompts, max_new)
     assert not traced
-    monkeypatch.setenv("MXTPU_FLASH_PAGED", "1")
+    paged_kernels(True)
     out, _, stats, _ = _through_batcher(net, prompts, max_new)
     # the three blocks' caches and the module's, in every program traced
     assert traced and len(traced) % 4 == 0

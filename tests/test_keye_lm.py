@@ -16,6 +16,7 @@ import mxnet_tpu as mx  # noqa: F401 - the package sets JAX up
 from mxnet_tpu import nd
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.gluon.model_zoo.keye import KeyeLM
+from mxnet_tpu.ops import paged
 from mxnet_tpu.ops import sparse_attention as dsa
 from mxnet_tpu.ops.pallas import grouped_swiglu as moe
 from mxnet_tpu.parallel import InferStep
@@ -92,13 +93,14 @@ def test_full_forward_logits(ref, net, length):
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
-def test_full_forward_through_the_chips_kernels(ref, net, monkeypatch):
+def test_full_forward_through_the_chips_kernels(ref, net, monkeypatch,
+                                                paged_kernels):
     """256 tokens in one window of 128-position pages, the paged kernels
     forced on: index scores and selection in the Pallas indexer, attention
     in the selected-window kernel (both interpreted here), against the
     reference's logits. ``topk`` 8 falls inside the first query block."""
     monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
-    monkeypatch.setenv("MXTPU_FLASH_PAGED", "force")
+    paged_kernels(True)
     toks = tokens(256, 9)
     x = jnp.asarray(toks[None], jnp.int32)
     assert "dsa_index_select" in str(jax.make_jaxpr(
@@ -159,13 +161,14 @@ def _serve_by_hand(net, prompt, n_new, slots=2, slot=1):
 @pytest.mark.parametrize("length", [3, 8, 9, 21])   # the last chunk ragged
 def test_chunked_prefill_and_decode_follow_the_reference(ref, net, length,
                                                          kernels,
-                                                         monkeypatch):
+                                                         monkeypatch,
+                                                         paged_kernels):
     """``kernels``: the paged kernels forced on (interpreted here), so the
     whole decode step runs through ``dsa_decode_select`` and
     ``dsa_decode_window`` (every cached length here is past ``topk``)."""
     if kernels:
         monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
-        monkeypatch.setenv("MXTPU_FLASH_PAGED", "force")
+        paged_kernels(True)
     prompt, n_new = tokens(length, 10 + length), 6
     served, counts = _serve_by_hand(net, prompt, n_new)
     seq = np.concatenate([prompt, served[:-1]])
@@ -453,7 +456,7 @@ def test_selected_window_kernel_against_its_reference(case, monkeypatch):
     assert got.dtype == dtype and np.isfinite(np.asarray(want)).all()
     np.testing.assert_allclose(np.asarray(got.astype(jnp.float32)),
                                np.asarray(want), atol=w["atol"])
-    block = dsa.kv_block(L)
+    block = paged.kv_block(L)
     loop = dsa.selected_window_attention(*f32, table, off, mask,
                                          L // block, block, D ** -0.5)
     np.testing.assert_allclose(np.asarray(loop), np.asarray(want), atol=2e-5)
@@ -500,14 +503,15 @@ _NEVER_CROWDED = ("offset0-chunk-within-topk", "all-inf-columns",
 
 
 @pytest.mark.parametrize("case", list(SELECT_CASES))
-def test_index_select_kernel_against_its_jnp_form(case, monkeypatch):
+def test_index_select_kernel_against_its_jnp_form(case, monkeypatch,
+                                                  paged_kernels):
     """The Pallas indexer (interpreted here) against ``select_mask`` of
     ``window_index_scores``: the same sets, bit for bit, through
     ``window_select`` with the paged kernels forced on."""
     from mxnet_tpu.ops.pallas import index_select as ixs
 
     monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
-    monkeypatch.setenv("MXTPU_FLASH_PAGED", "force")
+    paged_kernels(True)
     w = dict(_SELECT, **SELECT_CASES[case])
     R, C, J, Di, L, topk = (w[k] for k in ("R", "C", "J", "Di", "L", "topk"))
     dtype, block = jnp.dtype(w["dtype"]), 128
@@ -638,12 +642,13 @@ def test_decode_select_kernel_against_its_jnp_form(case, monkeypatch):
     the lower position, the row's own key in its page and nothing else of
     the pool touched."""
     from mxnet_tpu.ops.pallas import dsa_decode as dec
+    from mxnet_tpu.ops.pallas import page_walk as walk
 
     monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
     w, qi, wi, own, pool, table, rows, pos = _decode_select_inputs(case)
     B, (ps, P, topk) = len(pos), (w[k] for k in ("ps", "P", "topk"))
     L, live = P * ps, pos >= 0
-    written = dsa.write_rows(pool, rows, own)
+    written = paged.write_rows(pool, rows, own)
     picked, valid = dsa.decode_select(qi, wi, written, table,
                                       jnp.asarray(np.maximum(pos, 0)), topk)
     want = np.zeros((B, L), bool)
@@ -651,7 +656,7 @@ def test_decode_select_kernel_against_its_jnp_form(case, monkeypatch):
         want[b, np.asarray(picked)[b][np.asarray(valid)[b]]] = True
     code, got_pool = dec.dsa_decode_select(qi, wi, own, pool, table,
                                            jnp.asarray(pos), topk)
-    assert code.dtype == jnp.int8 and code.shape[1] % dec.decode_tiles(
+    assert code.dtype == jnp.int8 and code.shape[1] % walk.decode_tiles(
         P, ps) == 0 and not np.asarray(code)[:, P:].any()
     got = np.asarray(code)[:, :P].reshape(B, L) != 0
     np.testing.assert_array_equal(got, want)
@@ -663,7 +668,7 @@ def test_decode_select_kernel_against_its_jnp_form(case, monkeypatch):
         np.asarray(written.astype(jnp.float32))[1:])
     # which cases make the kernel settle ties by position, by the jnp
     # form's own arithmetic
-    ki = dsa.gather_row_pages(written, table).astype(jnp.float32)
+    ki = paged.gather_row_pages(written, table).astype(jnp.float32)
     hit = jax.nn.relu(jnp.einsum("bjd,bsd->bjs", qi.astype(jnp.float32), ki))
     scores = jnp.einsum("bjs,bj->bs", hit, wi)
     seen = jnp.arange(L)[None] <= pos[:, None]
@@ -703,6 +708,7 @@ def test_decode_window_kernel_against_its_reference(case, monkeypatch):
     pools read through a shuffled page table, against
     ``selected_decode_attention`` over the same set as positions."""
     from mxnet_tpu.ops.pallas import dsa_decode as dec
+    from mxnet_tpu.ops.pallas import page_walk as walk
 
     monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
     w = dict(_DECODE_WINDOW, **DECODE_WINDOW_CASES[case])
@@ -717,7 +723,7 @@ def test_decode_window_kernel_against_its_reference(case, monkeypatch):
     at = np.arange(L)[None]
     mask = (at <= pos[:, None]) & (rng.random((B, L)) < w["density"])
     mask |= at == pos[:, None]
-    pages = -(-P // dec.decode_tiles(P, ps)) * dec.decode_tiles(P, ps)
+    pages = -(-P // walk.decode_tiles(P, ps)) * walk.decode_tiles(P, ps)
     code = np.zeros((B, pages, ps), np.int8)
     code[:, :P] = mask.reshape(B, P, ps)
     got = dec.dsa_decode_window(q, kp, vp, table, jnp.asarray(pos),
@@ -738,7 +744,8 @@ def test_decode_window_kernel_against_its_reference(case, monkeypatch):
     assert not got[~live].any()         # an inactive row reads nothing
 
 
-def test_decode_counts_and_pools_whichever_form_runs(monkeypatch):
+def test_decode_counts_and_pools_whichever_form_runs(monkeypatch,
+                                                     paged_kernels):
     """``selected_decode`` with the paged kernels forced on and off: the
     same attention, the same indexer pool and the same INTEGER of keys the
     active rows selected (``decode_keys_selected``), an inactive row
@@ -753,19 +760,19 @@ def test_decode_counts_and_pools_whichever_form_runs(monkeypatch):
     active = jnp.asarray(pos >= 0)
     at = jnp.asarray(np.maximum(pos, 0))
 
-    def step(mode):
+    def step(kernels):
         monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
-        monkeypatch.setenv("MXTPU_FLASH_PAGED", mode)
+        paged_kernels(kernels)
         text = str(jax.make_jaxpr(lambda: dsa.selected_decode(
             q, qi, wi, own, kp, vp, pool, table, rows, at, active, topk,
             32 ** -0.5))())
         assert ("dsa_decode_select" in text and "dsa_decode_window" in text) \
-            == (mode == "force")
+            == kernels
         return dsa.selected_decode(q, qi, wi, own, kp, vp, pool, table,
                                    rows, at, active, topk, 32 ** -0.5)
 
-    attn, ip, n = step("force")
-    attn0, ip0, n0 = step("0")
+    attn, ip, n = step(True)
+    attn0, ip0, n0 = step(False)
     assert int(n) == int(n0) == int(np.minimum(pos + 1, topk)[pos >= 0].sum())
     live = pos >= 0
     np.testing.assert_allclose(np.asarray(attn)[live],

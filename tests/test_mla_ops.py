@@ -14,6 +14,7 @@ import mxnet_tpu as mx  # noqa: F401 - the package sets JAX up
 from mxnet_tpu.ops import mla
 from mxnet_tpu.ops.pallas import grouped_swiglu as moe
 from mxnet_tpu.ops.pallas import mla_attention as kern
+from mxnet_tpu.ops.pallas import page_walk as walk
 
 H, RANK, ROPE, D = 4, 32, 8, 16
 PAGE, P = 4, 6
@@ -140,8 +141,8 @@ def test_latent_decode_kernel_matches_its_jnp_form(case, block):
         got[live], want[live], atol=2e-2 if dtype == "bfloat16" else 2e-5)
     # a row with nothing cached reads nothing and yields zeros
     assert not got[np.asarray(pos) + positions - 1 < 0].any()
-    assert kern.decode_tiles(130, 128) == 8
-    assert kern.decode_tiles(P, PAGE) == P
+    assert walk.decode_tiles(130, 128) == 8
+    assert walk.decode_tiles(P, PAGE) == P
 
 
 @pytest.mark.parametrize("offset", [0, 8, -1])
@@ -159,7 +160,7 @@ def test_prefill_kernel_matches_its_jnp_form(offset):
     off = jnp.asarray([offset, max(offset, 0) + 4], jnp.int32)
     last = jnp.max(off) + C - 1
     want, _ = mla.window_attention(qn, qr, pool, wkvb, tables, off, last)
-    lat = mla._dsa.gather_row_pages(pool, tables)
+    lat = mla.paged.gather_row_pages(pool, tables)
     buf = mla.expand_latents(jnp.zeros((R, L, H * 2 * D), jnp.float32), lat,
                              wkvb, last + 1, RANK)
     got = kern._mla_prefill_impl(

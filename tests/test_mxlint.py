@@ -1733,17 +1733,3 @@ class TestServingRaceFixes:
         the helper's behavior)."""
         findings = get_pass("lock-order").run(ctx)
         assert not any(f.key == "Router._replicas" for f in findings)
-
-
-# ==================================================== tool shim compat
-class TestToolShims:
-    def test_shims_reexport_the_framework(self):
-        import check_amp_purity
-        import check_no_sync_in_step
-        import check_sharding
-
-        assert check_no_sync_in_step.find_violations is \
-            no_sync.find_violations
-        assert check_amp_purity.check_step_purity is \
-            amp_purity.check_step_purity
-        assert check_sharding.run_checks is sharding_placement.run_checks

@@ -297,8 +297,8 @@ def test_the_scheduler_serves_the_references_greedy_stream(ref, nets,
 
 
 def test_a_burst_runs_the_paged_kernels_and_serves_the_same_tokens(
-        ref, driver, nets, monkeypatch):
-    """With the paged kernels routed to (``MXTPU_FLASH_PAGED=1``; here
+        ref, driver, nets, monkeypatch, paged_kernels):
+    """With the paged kernels routed to (``paged_kernels(True)``; here
     interpreted) the chunk's selected window and the step's decode kernel
     read a pool flattened over planes and pages through a page table moved
     by the loop's carried index: the tokens are the ``jax.numpy`` form's,
@@ -325,10 +325,10 @@ def test_a_burst_runs_the_paged_kernels_and_serves_the_same_tokens(
     monkeypatch.setattr(pfa, "paged_selected_window_attention", window)
     prompts = [tokens(n, 20 + n) for n in (5, 23, 9, 3)]
     max_new = [5, 8, 2, 6]
-    monkeypatch.setenv("MXTPU_FLASH_PAGED", "0")
+    paged_kernels(False)
     plain, *_ = _through_batcher(nets[2], prompts, max_new)
     assert not traced
-    monkeypatch.setenv("MXTPU_FLASH_PAGED", "1")
+    paged_kernels(True)
     net = build(ref, driver, tiny(2))            # a trace of its own
     out, stats, _ = _through_batcher(net, prompts, max_new)
     # the layers are traced ONCE a program, the plane's pages a traced value

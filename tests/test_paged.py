@@ -601,7 +601,7 @@ class TestFlashPagedKernel:
         assert 1 << 20 < need < pfa._WINDOW_STEP_VMEM_LIMIT
         assert pfa._WINDOW_STEP_VMEM_LIMIT <= 64 << 20
 
-    def test_forced_kernel_paged_step_matches_fallback(self, monkeypatch):
+    def test_forced_kernel_paged_step_matches_fallback(self, paged_kernels):
         """Layer level: ``paged_step`` with the kernel forced (interpret
         mode here) == the dense gather fallback to fp tolerance."""
         mha = MultiHeadAttention(16, 2, dropout=0.0, causal=True)
@@ -612,7 +612,7 @@ class TestFlashPagedKernel:
         x0 = nd.array(rng.randn(2, 1, 16).astype(np.float32))
         outs = {}
         for mode in ("0", "force"):
-            monkeypatch.setenv("MXTPU_FLASH_PAGED", mode)
+            paged_kernels(mode == "force")
             kp, vp = mha.init_page_pool(5, 4)
             _, k, v = mha.prefill(x0)
             kp = kp.at[table[:, 0], 0].set(k[:, 0])
@@ -624,11 +624,12 @@ class TestFlashPagedKernel:
         np.testing.assert_allclose(outs["force"], outs["0"],
                                    rtol=1e-5, atol=1e-5)
 
-    def test_kernel_active_rows_isolated_from_trash_page(self, monkeypatch):
+    def test_kernel_active_rows_isolated_from_trash_page(self,
+                                                         paged_kernels):
         """Inactive rows park their table on trash page 0; the kernel's
         in-place page walk must give active rows identical output no
         matter what garbage page 0 holds."""
-        monkeypatch.setenv("MXTPU_FLASH_PAGED", "force")
+        paged_kernels(True)
         mha = MultiHeadAttention(16, 2, dropout=0.0, causal=True)
         mha.initialize()
         rng = np.random.RandomState(3)

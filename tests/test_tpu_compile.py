@@ -117,6 +117,7 @@ def test_layer_norm_under_a_mesh_takes_the_partitionable_form(
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from mxnet_tpu.ops import nn as ops_nn
+    from mxnet_tpu.ops import paged
     from mxnet_tpu.parallel import mesh_scope
 
     monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "0")
@@ -126,12 +127,14 @@ def test_layer_norm_under_a_mesh_takes_the_partitionable_form(
                              sharding=NamedSharding(mesh, P("data")))
     g = jax.ShapeDtypeStruct((768,), jnp.bfloat16,
                              sharding=NamedSharding(mesh, P()))
-    pfa = _mod("paged_flash_attention")
-    monkeypatch.setenv("MXTPU_FLASH_PAGED", "force")
-    assert pfa.flash_paged_enabled()
+    # the paged kernels' one predicate asks the platform, then the mesh
+    with monkeypatch.context() as on_a_tpu:
+        on_a_tpu.setattr(jax, "default_backend", lambda: "tpu")
+        assert paged.kernels_on()
+        with mesh_scope(mesh):
+            assert not paged.kernels_on()
     with mesh_scope(mesh):
         assert not ln.supports(x, -1)
-        assert not pfa.flash_paged_enabled()  # even when forced on
         compiled = jax.jit(ops_nn.layer_norm).lower(x, g, g).compile()
     assert "tpu_custom_call" not in compiled.as_text()
     assert ln.supports(x, -1)  # no mesh scope: one chip takes the kernel
@@ -260,7 +263,7 @@ def test_selected_window_attention_compiles(for_chip):
     assert "%dsa_selected_window" in text
 
 
-def test_index_select_compiles(for_chip, monkeypatch):
+def test_index_select_compiles(for_chip, paged_kernels):
     """The window's indexer at the published widths (PR 37): a chunk of
     2,048 queries of 16 index heads of 64 over 130 pages of 128 positions,
     ``topk`` 2,048. ONE Mosaic call a layer under the name the benchmark's
@@ -269,16 +272,17 @@ def test_index_select_compiles(for_chip, monkeypatch):
     window's selection holds no loop over the ``(2048, 16640)`` scores any
     more (the radix select's sixteen trips and the score blocks' loop are
     the ``jax.numpy`` form's)."""
+    from mxnet_tpu.ops import paged
     from mxnet_tpu.ops import sparse_attention as dsa
 
     spec, compile_ = for_chip
     ixs = _mod("index_select")
-    monkeypatch.setenv("MXTPU_FLASH_PAGED", "force")
+    paged_kernels(True)
     C, J, Di, L, topk = 2048, 16, 64, 16640, 2048
     assert ixs.index_select_tiles(C, L) == (128, 256)
     need = ixs.index_select_vmem_bytes(128, 256, L, J, Di, itemsize=2)
     assert 16 << 20 < need < ixs._VMEM_LIMIT <= 64 << 20
-    block = dsa.kv_block(L, 512)
+    block = paged.kv_block(L, 512)
 
     def window(qi, wi, ki, off, last):
         q_pos = off[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
@@ -299,13 +303,14 @@ def test_index_select_compiles(for_chip, monkeypatch):
     # with the paged kernels off (the CPU's and a mesh's form) the same
     # function is the two jax.numpy loops over the scores (another
     # callable: a trace is cached by the function, not by the environment)
-    monkeypatch.setenv("MXTPU_FLASH_PAGED", "0")
+    paged_kernels(False)
     plain = jax.jit(lambda *a: window(*a)).lower(*args).compile().as_text()
     assert "tpu_custom_call" not in plain
     assert len(loops_over_the_scores(plain)) >= 2
 
 
-def test_decode_step_selection_and_attention_compile(for_chip, monkeypatch):
+def test_decode_step_selection_and_attention_compile(for_chip,
+                                                     paged_kernels):
     """keye's decode step at the published widths (PR 39): 16 rows, 16
     index heads of 64, 32 query heads over 4 key/value heads of 128, 130
     pages of 128 positions, ``topk`` 2,048, bfloat16. The attention part of
@@ -319,10 +324,10 @@ def test_decode_step_selection_and_attention_compile(for_chip, monkeypatch):
 
     spec, compile_ = for_chip
     dec = _mod("dsa_decode")
-    monkeypatch.setenv("MXTPU_FLASH_PAGED", "force")
+    paged_kernels(True)
     B, J, Di, Hq, Hkv, D, page, P, topk = 16, 16, 64, 32, 4, 128, 128, 130, \
         2048
-    block = dec.decode_tiles(P, page)
+    block = _mod("page_walk").decode_tiles(P, page)
     pages = -(-P // block) * block
     assert (block, pages) == (8, 136)
     # two buffers of a block of pages, the page that takes the row's key,
@@ -350,9 +355,9 @@ def test_decode_step_selection_and_attention_compile(for_chip, monkeypatch):
     # no pool is copied or relaid on its way to a kernel
     assert not [ln for ln in text.splitlines() if " copy(" in ln
                 and f"[{B * P + 1}," in ln.split(" copy(")[0]]
-    # another callable: a trace is cached by the function, not by the
-    # environment
-    monkeypatch.setenv("MXTPU_FLASH_PAGED", "0")
+    # another callable: a trace is cached by the function, not by what
+    # the predicate answers
+    paged_kernels(False)
     plain = jax.jit(lambda *a: step(*a)).lower(*args).compile().as_text()
     assert "tpu_custom_call" not in plain
     assert [ln for ln in plain.splitlines()
@@ -562,7 +567,7 @@ def test_latent_decode_attention_compiles(for_chip, rows, positions):
     spec, compile_ = for_chip
     mla = _mod("mla_attention")
     H, rank, rope, page, P = 32, 512, 128, 128, 130
-    block = mla.decode_tiles(P, page)
+    block = _mod("page_walk").decode_tiles(P, page)
     assert block == 8
     # what the kernel asks of VMEM: two buffers of a block of the pool and
     # the softmax carry (two float32 lane groups and the weighted latents
@@ -690,7 +695,8 @@ BURSTS = {
 
 
 @pytest.mark.parametrize("cell", sorted(BURSTS))
-def test_decode_burst_copies_no_pool(one_chip, monkeypatch, cell):
+def test_decode_burst_copies_no_pool(one_chip, monkeypatch, paged_kernels,
+                                     cell):
     """The burst program (``iter_tokens`` decode steps in one ``while``)
     compiled for the described chip from the cell's own configuration,
     zeros for weights: no pool is copied whole on its way to
@@ -704,7 +710,7 @@ def test_decode_burst_copies_no_pool(one_chip, monkeypatch, cell):
     from perf.harness.loader import load_module
 
     monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "0")
-    monkeypatch.setenv("MXTPU_FLASH_PAGED", "force")
+    paged_kernels(True)
     layers, parent_temporaries, parent_copies = BURSTS[cell]
     perf = os.path.join(REPO_ROOT, "perf")
     with open(os.path.join(perf, "configs", cell + ".json")) as f:

@@ -203,11 +203,11 @@ def _serve_by_hand(net, prompt, n_new, slots=2, slot=1, fills=None):
 
 
 @pytest.fixture(params=["jnp", "kernels"])
-def paged_form(request, monkeypatch):
+def paged_form(request, monkeypatch, paged_kernels):
     """The ``jax.numpy`` forms of attention (the CPU's), and the paged
     window kernel over the pools as they are declared (interpreted here)."""
     if request.param == "kernels":
-        monkeypatch.setenv("MXTPU_FLASH_PAGED", "force")
+        paged_kernels(True)
         monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
     return request.param
 
@@ -248,7 +248,8 @@ def test_chunks_then_decode_follow_the_reference_at_every_position(
         calls * LAYERS * EXPERTS
 
 
-def test_heads_of_128_decode_through_the_walk(ref, driver, monkeypatch):
+def test_heads_of_128_decode_through_the_walk(ref, driver, monkeypatch,
+                                              paged_kernels):
     """At the published head size the decode step's call is the kernel
     that walks a row's live pages with its own copies (interpreted here;
     ``paged_decode_attention`` picks it by ``D % 128``): pools declared
@@ -257,7 +258,7 @@ def test_heads_of_128_decode_through_the_walk(ref, driver, monkeypatch):
     from mxnet_tpu.ops.pallas import paged_flash_attention as pfa
 
     monkeypatch.setitem(TINY, "head_dim", 128)
-    monkeypatch.setenv("MXTPU_FLASH_PAGED", "force")
+    paged_kernels(True)
     monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
     walked, walk = [], pfa._decode_walk
     monkeypatch.setattr(pfa, "_decode_walk", lambda q, k_pool, *a: (

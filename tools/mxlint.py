@@ -23,7 +23,7 @@ code it excused moved or was fixed — and also fails the run (the file
 must stay honest); ``--prune-baseline`` deletes stale entries of the
 executed passes and rewrites the file. jaxpr passes trace real
 TrainStep/InferStep programs — on a bare CPU the script simulates a
-4-device platform first (same trick as the old check_sharding.py).
+4-device platform first (``_ensure_devices``).
 
 Exit codes: 0 clean (or fully baselined), 1 findings or stale baseline
 entries, 2 usage error.
