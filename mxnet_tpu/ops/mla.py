@@ -6,7 +6,15 @@ A layer caches, for each position, ``[c; k_r]``: the normed latent ``c``
 share. A page of the pool is ``(page, rank + rope)``: no head axis. With
 ``q_nope_h``, ``q_rope_h`` a head's query,
 
-    score_h = (q_nope_h . (W^K_h c) + q_rope_h . k_r) / sqrt(nope + rope)
+    score_h = (q_nope_h . (W^K_h c) + q_rope_h . k_r) x scale
+
+where ``scale`` is the CALLER's: queries come in already multiplied by it,
+and it is ``1 / sqrt(nope + rope)`` only where the rotary embedding is not
+stretched (with YaRN the net multiplies it by ``mscale^2``:
+``yarn_mscale``). Likewise ``rope_interleaved`` turns by the inverse
+frequencies it is GIVEN: ``inverse_frequencies`` for a plain ``theta``,
+``yarn_inverse_frequencies`` for a table blended between the original
+frequencies and those of a context ``factor`` times as long.
 
 Two orders of the same sums, as the serving plane has them:
 
@@ -27,8 +35,11 @@ mesh (``paged.kernels_on()``); softmax and scores are float32 everywhere.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from . import paged
 
@@ -36,14 +47,54 @@ NEG = -1e30
 EXPAND_KEYS = 512       # cached positions a step of the expansion's loop
 
 
-def rope_interleaved(x, pos, theta):
+def inverse_frequencies(theta, half):
+    """``theta^(-i / half)`` for the ``half`` rotary pairs ``i``."""
+    return theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+
+
+def yarn_mscale(factor, mscale=1.0):
+    """YaRN's attention factor for a context stretched ``factor`` times:
+    ``0.1 x mscale x ln(factor) + 1`` (1 where nothing is stretched)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inverse_frequencies(theta, half, factor, original, beta_fast,
+                             beta_slow):
+    """YaRN's table of the ``half`` inverse frequencies, as the DeepSeek-V3
+    release computes it (float32, on the host, once a net): pair ``i``
+    keeps ``theta^(-i / half)`` while it turns more than ``beta_fast``
+    times over the ``original`` positions, takes that over ``factor`` where
+    it turns fewer than ``beta_slow`` times, and a linear ramp between the
+    two pairs that bound those (the lower rounded down, the upper up)."""
+    dim = 2 * half
+
+    def pair_of(turns):
+        return dim * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    base = np.float32(theta) ** (np.arange(half, dtype=np.float32)
+                                 / np.float32(half))
+    ramp = np.clip((np.arange(half, dtype=np.float32) - low) / (high - low),
+                   0, 1).astype(np.float32)
+    return ((1 / (factor * base)) * ramp + (1 / base) * (1 - ramp)) \
+        .astype(np.float32)
+
+
+def rope_interleaved(x, pos, inv, factor=1.0):
     """Rotary embedding of ``x (..., D)`` at ``pos`` (broadcast against
     ``x``'s leading axes): dimension ``2i`` pairs with ``2i + 1`` and turns
-    by ``pos x theta^(-2i / D)``."""
+    by ``pos x inv[i]``, ``inv (D / 2,)`` the inverse frequencies as given
+    (``inverse_frequencies``, ``yarn_inverse_frequencies``); ``factor``
+    multiplies cos and sin (YaRN's ``mscale / mscale_all_dim``)."""
     half = x.shape[-1] // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     ang = pos.astype(jnp.float32)[..., None] * inv
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (half, 2))
     a, b = xf[..., 0], xf[..., 1]
     return jnp.stack([a * cos - b * sin, b * cos + a * sin], -1) \

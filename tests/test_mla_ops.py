@@ -71,18 +71,21 @@ def test_absorbed_and_expanded_agree_on_one_cache(positions):
         np.testing.assert_allclose(expanded[2, 0], want, atol=2e-5)
 
 
+INV = mla.inverse_frequencies(1e4, 4)
+
+
 def test_rope_pairs_neighbours_and_keeps_the_dot_product_relative():
     rng = np.random.default_rng(1)
     q = jnp.asarray(rng.standard_normal((1, 8)).astype(np.float32))
     k = jnp.asarray(rng.standard_normal((1, 8)).astype(np.float32))
     dot = lambda a, b: float(jnp.sum(  # noqa: E731
-        mla.rope_interleaved(q, jnp.asarray([a]), 1e4)
-        * mla.rope_interleaved(k, jnp.asarray([b]), 1e4)))
+        mla.rope_interleaved(q, jnp.asarray([a]), INV)
+        * mla.rope_interleaved(k, jnp.asarray([b]), INV)))
     assert dot(7, 3) == pytest.approx(dot(104, 100), abs=1e-4)
     assert abs(dot(7, 3) - dot(7, 4)) > 1e-3
     # dimension 2i turns with 2i + 1: the first pair at the fastest rate
     x = jnp.zeros((1, 8)).at[0, 0].set(1.0)
-    y = np.asarray(mla.rope_interleaved(x, jnp.asarray([1]), 1e4))[0]
+    y = np.asarray(mla.rope_interleaved(x, jnp.asarray([1]), INV))[0]
     np.testing.assert_allclose(y[:2], [np.cos(1.0), np.sin(1.0)], atol=1e-6)
     assert np.abs(y[2:]).max() == 0
 

@@ -602,6 +602,47 @@ def test_latent_prefill_attention_compiles(for_chip):
         spec((1,), "int32")), "%mla_prefill")
 
 
+def test_latent_prefill_attention_compiles_at_rows_of_33k(for_chip):
+    """The same kernel at the rows ``xing4.0-29b-a4b`` serves: 258 pages of
+    128 positions rounded up to whole key blocks, 65 of them a query
+    block."""
+    spec, compile_ = for_chip
+    mla = _mod("mla_attention")
+    H, D, rope, C, L = 32, 128, 128, 2048, 33280
+    assert mla.prefill_tiles(C, L) == (1024, 512)
+    _named(compile_(
+        mla.mla_prefill,
+        spec((1, H, C, D), "bfloat16"), spec((1, H, C, rope), "bfloat16"),
+        spec((1, L, H * 2 * D), "bfloat16"), spec((1, L, rope), "bfloat16"),
+        spec((1,), "int32")), "%mla_prefill")
+
+
+@pytest.mark.parametrize("rows", [2048, 24])
+def test_the_stream_mixing_kernels_compile(for_chip, rows):
+    """The pass over a residual stream four wide at Xing4.0's width (3,584
+    a stream, 14,336 a token): a chunk's 2,048 tokens in tiles of 64, and a
+    decode step's 24 padded to 32, each of the three calls."""
+    from mxnet_tpu.ops import hyper_connection as hc
+
+    spec, compile_ = for_chip
+    k = _mod("mhc_mix")
+    n, C = 4, 3584
+    cfg = hc.HC(n, 20, 1e-6, -30.0, 30.0)
+    T = -(-rows // k.ROWS) * k.ROWS
+    assert k.tiles(T, C) == ((64, 512) if rows == 2048 else (32, 512))
+    mixer = hc.Mixer(spec((n * C, 24), "bfloat16"), spec((3,), "bfloat16"),
+                     spec((24,), "bfloat16"))
+    X, y = spec((T, n * C), "bfloat16"), spec((T, C), "bfloat16")
+    res, post = spec((T, n * n), "float32"), spec((T, n), "float32")
+    _named(compile_(lambda x, *m: k.mhc_enter(x, hc.Mixer(*m), cfg),
+                    y, *mixer), "%mhc_enter")
+    _named(compile_(lambda X, y, r, p, *m: k.mhc_mix(
+        X, y, r, p, hc.Mixer(*m), cfg), X, y, res, post, *mixer),
+        "%mhc_mix")
+    _named(compile_(lambda X, y, r, p: k.mhc_leave(X, y, r, p, cfg),
+                    X, y, res, post), "%mhc_leave")
+
+
 def test_held_experts_product_compiles(for_chip):
     """The grouped expert product over ONE CHIP'S SHARE, 16 experts held of
     256: a decode step's 80 tokens (row tile 16) and a chunk's 2,048 (row
