@@ -21,7 +21,9 @@ Two orders of the same sums, as the serving plane has them:
 - the WINDOW (a prefill chunk), EXPANDED: the row's cached latents up to
   the chunk's last position become per-head keys and values once
   (``expand_latents``), and ``C`` queries a row attend them causally
-  (``window_attention``; ``%mla_prefill`` on the TPU);
+  (``window_attention``; ``%mla_prefill`` on the TPU, which walks the key
+  blocks up to each query block's causal edge and no further, as the
+  expansion's loop stops at the chunk's);
 - DECODE, ABSORBED: ``q'_h = W^K_h^T q_nope_h`` is ``rank`` wide, every
   head of every query position is a row of one product against the latent
   pages, the weighted latents come back and ``W^V_h`` is applied after

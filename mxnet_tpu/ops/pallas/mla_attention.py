@@ -25,6 +25,12 @@ reading that cache:
   and ``q_rope . k_r``, because the score's width (nope + rope) is not the
   value's; the expanded keys and values are read as column blocks of the
   ``(L, heads x (nope + v))`` array the expansion writes, no relayout.
+  The chunk's causal edge decides what the kernel DOES: a grid step is a
+  head's block of queries, which walks the key blocks its last query sees
+  with its own copies, two buffers deep, as the decode kernel walks pages
+  (a grid axis over every key block of the table launched 65 steps a
+  query block in ``xing`` of which a mean of 23 computed, and an empty
+  step costs 0.2 us on a v5e: PERF.md, PR 45).
 
 Queries come in scaled. Each has its dense ``jax.numpy`` form in
 ``ops/mla.py``, which stands on the CPU and under a multi-device mesh.
@@ -184,80 +190,123 @@ def mla_latent_decode(qc, qr, pool, page_table, pos):
 
 # ------------------------------------------------------ prefill (expanded)
 def prefill_tiles(C, L):
-    """``(queries, keys)`` a grid step of the prefill kernel: 1,024 queries
-    where they divide the chunk (the expanded keys are read once a query
-    block), 512 keys where they divide the expanded length."""
+    """``(queries a grid step, keys a block of its walk)`` of the prefill
+    kernel: 1,024 queries where they divide the chunk (the expanded keys
+    are read once a query block), 512 keys where they divide the expanded
+    length."""
     tq = next((t for t in (1024, 512, 256, 128) if C % t == 0), C)
     tk = next((t for t in (512, 256, 128) if L % t == 0), L)
     return tq, tk
 
 
-def _prefill_kernel(off_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
-                    m_ref, l_ref, acc_ref, *, tq, tk):
-    """Grid (R, heads, query blocks, key blocks), key blocks sequential. A
-    step is one head's ``tq`` queries against ``tk`` expanded keys; blocks
-    past the last one the query block sees are neither fetched nor
-    computed."""
-    r, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+def _prefill_kernel(off_ref, qn_ref, qr_ref, kv_ref, kr_ref, o_ref, kv_buf,
+                    kr_buf, sem_ref, slot_ref, m_ref, l_ref, acc_ref,
+                    *, tq, tk):
+    """Grid (R, heads, query blocks), sequential: the scratch, its
+    semaphores and the slot carry over from step to step. A step is one
+    head's ``tq`` queries; it walks the key blocks of ``tk`` expanded keys
+    its LAST query sees and no other. ``kv_ref`` and ``kr_ref`` are whole
+    in HBM: block ``k`` is copied into one half of the scratch (the head's
+    keys and values are adjacent columns: one copy, and one for the rotary
+    keys) while the other half is computed, and a step's last block starts
+    the next step's first, so no step waits for its first keys."""
+    r, h, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    R, H, Q = pl.num_programs(0), pl.num_programs(1), pl.num_programs(2)
+    D = qn_ref.shape[-1]
     off = off_ref[r]
+    # key blocks the query block's last query sees: one at least (the
+    # module's first query stands before position 0), the table's at most
+    blocks = jnp.clip((off + (i + 1) * tq - 1) // tk + 1, 1,
+                      kr_ref.shape[1] // tk)
 
-    pl.when(j == 0)(functools.partial(init_carry, m_ref, l_ref, acc_ref))
+    def copies(r, h, k, slot):
+        # block k of row r, head h <-> scratch half `slot`
+        rows = pl.ds(pl.multiple_of(k * tk, tk), tk)
+        cols = pl.ds(pl.multiple_of(h * 2 * D, 2 * D), 2 * D)
+        return (pltpu.make_async_copy(kv_ref.at[r, rows, cols],
+                                      kv_buf.at[slot], sem_ref.at[slot]),
+                pltpu.make_async_copy(kr_ref.at[r, rows], kr_buf.at[slot],
+                                      sem_ref.at[slot]))
 
-    @pl.when(j * tk <= off + (i + 1) * tq - 1)
-    def _accumulate():
-        s = nt(qn_ref[0, 0], kn_ref[0]) + nt(qr_ref[0, 0], kr_ref[0])
+    def start(r, h, k, slot):
+        for c in copies(r, h, k, slot):
+            c.start()
+
+    @pl.when((r == 0) & (h == 0) & (i == 0))
+    def _first_step():
+        slot_ref[0] = 0
+        start(0, 0, 0, 0)
+
+    init_carry(m_ref, l_ref, acc_ref)
+
+    def body(k, slot):
+        @pl.when(k + 1 < blocks)
+        def _next_block():
+            start(r, h, k + 1, 1 - slot)
+
+        @pl.when(k + 1 == blocks)
+        def _next_step():
+            # block 0 of the step after this one: the next query block,
+            # else the next head's first, else the next row's
+            last_q = i + 1 == Q
+            last_h = last_q & (h + 1 == H)
+            nr = jnp.where(last_h, r + 1, r)
+            nh = jnp.where(last_h, 0, jnp.where(last_q, h + 1, h))
+            pl.when(nr < R)(functools.partial(start, nr, nh, 0, 1 - slot))
+
+        for c in copies(r, h, k, slot):
+            c.wait()
+        s = nt(qn_ref[0, 0], kv_buf[slot, :, :D]) \
+            + nt(qr_ref[0, 0], kr_buf[slot])
         q_pos = off + i * tq + jax.lax.broadcasted_iota(
             jnp.int32, (tq, 1), 0)
-        k_pos = j * tk + jax.lax.broadcasted_iota(jnp.int32, (1, tk), 1)
+        k_pos = k * tk + jax.lax.broadcasted_iota(jnp.int32, (1, tk), 1)
+        # every block is masked: leaving the blocks under the diagonal
+        # unmasked read 0.1 to 0.5 % SLOWER on the chip (PERF.md, PR 45)
         s = jnp.where(k_pos <= q_pos, s, NEG_INF)
-        softmax_step(s, v_ref[0], m_ref, l_ref, acc_ref)
+        softmax_step(s, kv_buf[slot, :, D:], m_ref, l_ref, acc_ref)
+        return 1 - slot
 
-    @pl.when(j == pl.num_programs(3) - 1)
-    def _finalize():
-        l = jnp.maximum(jnp.sum(l_ref[...], axis=1, keepdims=True), 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+    slot_ref[0] = jax.lax.fori_loop(0, blocks, body, slot_ref[0])
+    l = jnp.maximum(jnp.sum(l_ref[...], axis=1, keepdims=True), 1e-30)
+    o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("tq", "tk", "interpret"))
 def _mla_prefill_impl(qn, qr, kv, kr, q_offset, tq, tk, interpret):
     R, H, C, D = qn.shape
-    L, rope = kr.shape[1], kr.shape[2]
+    rope = kr.shape[2]
 
-    def key_block(r, i, j, off):        # an unseen block re-reads nothing
-        return jnp.minimum(j, (off[r] + (i + 1) * tq - 1) // tk)
+    def query_spec(width):
+        return pl.BlockSpec((1, 1, tq, width),
+                            lambda r, h, i, off: (r, h, i, 0))
 
     kernel = functools.partial(_prefill_kernel, tq=tq, tk=tk)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(R, H, C // tq, L // tk),
-            in_specs=[
-                pl.BlockSpec((1, 1, tq, D),
-                             lambda r, h, i, j, off: (r, h, i, 0)),
-                pl.BlockSpec((1, 1, tq, rope),
-                             lambda r, h, i, j, off: (r, h, i, 0)),
-                # head h's keys and values: column blocks 2h and 2h + 1
-                pl.BlockSpec((1, tk, D), lambda r, h, i, j, off: (
-                    r, key_block(r, i, j, off), 2 * h)),
-                pl.BlockSpec((1, tk, rope), lambda r, h, i, j, off: (
-                    r, key_block(r, i, j, off), 0)),
-                pl.BlockSpec((1, tk, D), lambda r, h, i, j, off: (
-                    r, key_block(r, i, j, off), 2 * h + 1))],
+            grid=(R, H, C // tq),
+            in_specs=[query_spec(D), query_spec(rope),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((1, tq, D),
-                                   lambda r, h, i, j, off: (r, i, h)),
+                                   lambda r, h, i, off: (r, i, h)),
             scratch_shapes=[
+                pltpu.VMEM((2, tk, 2 * D), kv.dtype),
+                pltpu.VMEM((2, tk, rope), kr.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
                 pltpu.VMEM((tq, LANES), jnp.float32),
                 pltpu.VMEM((tq, math.gcd(tk, LANES)), jnp.float32),
                 pltpu.VMEM((tq, D), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((R, C, H * D), qn.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="mla_prefill",
-    )(q_offset.astype(jnp.int32), qn, qr, kv, kr, kv)
+    )(q_offset.astype(jnp.int32), qn, qr, kv, kr)
 
 
 def mla_prefill(qn, qr, kv, kr, q_offset):
@@ -268,7 +317,9 @@ def mla_prefill(qn, qr, kv, kr, q_offset):
     ``D`` after them (the value's width is the key's nope width); ``kr (R,
     L, rope)`` the one rotary key a position. Causal: a query reads the
     positions up to its own. ``L`` is a multiple of the key block and ``C``
-    of the query block (``prefill_tiles``). Returns ``(R, C, H x D)``."""
+    of the query block (``prefill_tiles``). The grid is ``(R, H, C //
+    tq)``; how many key blocks a step walks is read from ``q_offset`` when
+    the call runs, row by row. Returns ``(R, C, H x D)``."""
     tq, tk = prefill_tiles(qn.shape[2], kr.shape[1])
     return _mla_prefill_impl(qn, qr, kv, kr, q_offset, tq=tq, tk=tk,
                              interpret=_use_interpret())

@@ -4,11 +4,16 @@ absorbed and the expanded order agree on one cache, the Pallas kernels
 agree with their ``jax.numpy`` forms in interpret mode, and the sigmoid
 router's bias selects without weighing."""
 
+import functools
+import math
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 import mxnet_tpu as mx  # noqa: F401 - the package sets JAX up
 from mxnet_tpu.ops import mla
@@ -148,11 +153,92 @@ def test_latent_decode_kernel_matches_its_jnp_form(case, block):
     assert walk.decode_tiles(P, PAGE) == P
 
 
-@pytest.mark.parametrize("offset", [0, 8, -1])
-def test_prefill_kernel_matches_its_jnp_form(offset):
+def _parent_prefill(qn, qr, kv, kr, q_offset, tq, tk):
+    """``%mla_prefill`` as it was before its walk ended at the causal edge
+    (PR 44's body, kept here as the plain reference): a STATIC grid over
+    every key block of the table, every computed block masked."""
+    R, Hq, C, Dh = qn.shape
+    L, rope = kr.shape[1], kr.shape[2]
+
+    def kernel(off_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
+               m_ref, l_ref, acc_ref):
+        r, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+        off = off_ref[r]
+        pl.when(j == 0)(functools.partial(
+            walk.init_carry, m_ref, l_ref, acc_ref))
+
+        @pl.when(j * tk <= off + (i + 1) * tq - 1)
+        def _accumulate():
+            s = walk.nt(qn_ref[0, 0], kn_ref[0]) \
+                + walk.nt(qr_ref[0, 0], kr_ref[0])
+            q_pos = off + i * tq + jax.lax.broadcasted_iota(
+                jnp.int32, (tq, 1), 0)
+            k_pos = j * tk + jax.lax.broadcasted_iota(jnp.int32, (1, tk), 1)
+            s = jnp.where(k_pos <= q_pos, s, kern.NEG_INF)
+            walk.softmax_step(s, v_ref[0], m_ref, l_ref, acc_ref)
+
+        @pl.when(j == pl.num_programs(3) - 1)
+        def _finalize():
+            l = jnp.maximum(jnp.sum(l_ref[...], axis=1, keepdims=True), 1e-30)
+            o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+    def key_block(r, i, j, off):
+        return jnp.minimum(j, (off[r] + (i + 1) * tq - 1) // tk)
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(R, Hq, C // tq, L // tk),
+            in_specs=[
+                pl.BlockSpec((1, 1, tq, Dh),
+                             lambda r, h, i, j, off: (r, h, i, 0)),
+                pl.BlockSpec((1, 1, tq, rope),
+                             lambda r, h, i, j, off: (r, h, i, 0)),
+                pl.BlockSpec((1, tk, Dh), lambda r, h, i, j, off: (
+                    r, key_block(r, i, j, off), 2 * h)),
+                pl.BlockSpec((1, tk, rope), lambda r, h, i, j, off: (
+                    r, key_block(r, i, j, off), 0)),
+                pl.BlockSpec((1, tk, Dh), lambda r, h, i, j, off: (
+                    r, key_block(r, i, j, off), 2 * h + 1))],
+            out_specs=pl.BlockSpec((1, tq, Dh),
+                                   lambda r, h, i, j, off: (r, i, h)),
+            scratch_shapes=[
+                pltpu.VMEM((tq, walk.LANES), jnp.float32),
+                pltpu.VMEM((tq, math.gcd(tk, walk.LANES)), jnp.float32),
+                pltpu.VMEM((tq, Dh), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((R, C, Hq * Dh), qn.dtype),
+        interpret=True,
+    )(q_offset.astype(jnp.int32), qn, qr, kv, kr, kv)
+
+
+# a chunk of 8 queries a row over a table of 24 positions: (the two rows'
+# offsets, queries a step, keys a step)
+PREFILL_CASES = {
+    "a_prompts_first_chunk": ((0, 4), 4, 4),
+    "a_later_chunk": ((8, 12), 4, 4),
+    "the_module_one_position_early": ((-1, 4), 4, 4),
+    "the_tables_last_chunk": ((16, 16), 4, 4),           # L - C
+    "edges_four_key_blocks_apart": ((0, 16), 4, 4),      # each row its own
+    "the_module_beside_the_last_chunk": ((-1, 15), 4, 4),
+    "two_diagonal_blocks_a_query_block": ((4, 9), 8, 4),  # 1,024 against 512
+    "three_diagonal_blocks_a_query_block": ((5, 10), 8, 4),
+    "a_block_seen_whole_by_one_position": ((3, 7), 4, 4),
+    "a_block_one_position_short_of_whole": ((2, 6), 4, 4),
+    "the_last_query_on_a_blocks_first_key": ((1, 13), 4, 4),
+    "one_key_block_the_table": ((0, 16), 4, 24),
+}
+
+
+@pytest.mark.parametrize("case", list(PREFILL_CASES))
+def test_prefill_kernel_matches_its_jnp_form(case, monkeypatch):
     """``%mla_prefill`` in interpret mode: a chunk of 8 queries at an
-    offset over the expanded keys of its row, key blocks past the chunk's
-    last query skipped; the module's chunk starts one position early."""
+    offset over the expanded keys of its row. Its values are the
+    ``jax.numpy`` form's and, bit for bit, those of the kernel as it was
+    (every key block of the table a grid step, every computed block
+    masked); a query block walks the key blocks its last query sees, each
+    once, and no other, whatever the other row's offset."""
+    offsets, tq, tk = PREFILL_CASES[case]
     rng = np.random.default_rng(7)
     R, C = 2, 8
     L = P * PAGE
@@ -160,18 +246,37 @@ def test_prefill_kernel_matches_its_jnp_form(offset):
     wkvb = jnp.asarray(rng.standard_normal((RANK, H * 2 * D))
                        .astype(np.float32) / np.sqrt(RANK))
     qn, qr = _queries(rng, R, C)
-    off = jnp.asarray([offset, max(offset, 0) + 4], jnp.int32)
+    off = jnp.asarray(offsets, jnp.int32)
     last = jnp.max(off) + C - 1
     want, _ = mla.window_attention(qn, qr, pool, wkvb, tables, off, last)
     lat = mla.paged.gather_row_pages(pool, tables)
     buf = mla.expand_latents(jnp.zeros((R, L, H * 2 * D), jnp.float32), lat,
                              wkvb, last + 1, RANK)
-    got = kern._mla_prefill_impl(
-        jnp.swapaxes(qn, 1, 2), jnp.swapaxes(qr, 1, 2), buf,
-        lat[..., RANK:], off, tq=4, tk=4, interpret=True)
+    args = (jnp.swapaxes(qn, 1, 2), jnp.swapaxes(qr, 1, 2), buf,
+            lat[..., RANK:], off)
+
+    # what ran: a softmax step tells as it runs
+    ran = []
+
+    def softmax_step(*a):
+        jax.debug.callback(lambda: ran.append(1))
+        walk.softmax_step(*a)
+
+    monkeypatch.setattr(kern, "softmax_step", softmax_step)
+    got = kern._mla_prefill_impl.__wrapped__(*args, tq=tq, tk=tk,
+                                             interpret=True)
+    jax.effects_barrier()
+
     seen = np.asarray(off)[:, None] + np.arange(C)[None] >= 0
     np.testing.assert_allclose(np.asarray(got)[seen], np.asarray(want)[seen],
                                atol=2e-5)
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(_parent_prefill(*args, tq=tq, tk=tk)))
+    # from the positions themselves: a head computes a key block where the
+    # query block's last query sees the block's first key, and no other
+    computed = [(o, i, j) for o in offsets for i in range(C // tq)
+                for j in range(L // tk) if j * tk <= o + (i + 1) * tq - 1]
+    assert len(ran) == H * len(computed)
     assert kern.prefill_tiles(2048, 16896) == (1024, 512)
 
 

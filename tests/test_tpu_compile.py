@@ -159,12 +159,12 @@ OURO = (10, 4, 16, 16, 128, (4 * 41, 128, 16, 128))
 GRANITE = (64, 12, 32, 8, 64, (64 * 12 + 1, 128, 8, 64))
 
 
-def _named_once(compiled):
+def _named_once(compiled, name="%paged_window"):
     """One call, under the name the ledger's breakdown lists it by; its
     operands' shapes."""
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
-    assert "%paged_window" in text
+    assert name in text
     call = next(ln for ln in text.splitlines()
                 if 'custom_call_target="tpu_custom_call"' in ln)
     return re.findall(r"\w+\[[\d,]*\]", call.split(
@@ -615,6 +615,29 @@ def test_latent_prefill_attention_compiles_at_rows_of_33k(for_chip):
         spec((1, H, C, D), "bfloat16"), spec((1, H, C, rope), "bfloat16"),
         spec((1, L, H * 2 * D), "bfloat16"), spec((1, L, rope), "bfloat16"),
         spec((1,), "int32")), "%mla_prefill")
+
+
+def test_latent_prefill_attention_walks_to_the_causal_edge(for_chip):
+    """What PR 45 changed is in the compiled call: the grid has THREE axes
+    (rows, heads, query blocks: the key blocks are a loop inside, as long
+    as the offset says) and the expansion goes in ONCE, whole, as it lies
+    (a static grid's call took it twice, as keys and as values): no
+    temporary, no copy."""
+    spec, compile_ = for_chip
+    mla = _mod("mla_attention")
+    H, D, rope, C, L = 32, 128, 128, 2048, 33280
+    args = (spec((1, H, C, D), "bfloat16"), spec((1, H, C, rope), "bfloat16"),
+            spec((1, L, H * 2 * D), "bfloat16"),
+            spec((1, L, rope), "bfloat16"), spec((1,), "int32"))
+    compiled = compile_(mla.mla_prefill, *args)
+    assert _named_once(compiled, "%mla_prefill") == [
+        "s32[1]", "bf16[1,32,2048,128]", "bf16[1,32,2048,128]",
+        "bf16[1,33280,8192]", "bf16[1,33280,128]"]
+    text = compiled.as_text()
+    assert "%mla_prefill.1 = bf16[1,2048,4096]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+    assert "copy(%kv" not in text
+    assert "grid=(1, 32, 2)," in str(jax.make_jaxpr(mla.mla_prefill)(*args))
 
 
 @pytest.mark.parametrize("rows", [2048, 24])

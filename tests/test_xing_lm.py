@@ -302,13 +302,15 @@ def test_the_clamp_and_the_twenty_passes_move_the_logits(ref, driver,
 # ------------------------------------------------ the family's other net
 # sha256 of ``str(jax.make_jaxpr(...))`` of JoyAILM's three programs at the
 # sizes below, taken from the commit BEFORE its parts moved to
-# ``latent_lm.py`` (8c77c6c): the lifting changed no equation. A later
-# change to what JoyAI computes recomputes them (the function below prints
-# what it finds; the text depends on the suite's JAX settings, so take them
-# from a run under pytest).
+# ``latent_lm.py`` (8c77c6c): the lifting changed no equation; the chunk's
+# with the kernels on is PR 45's (``%mla_prefill`` walks its key blocks to
+# the causal edge itself: 82f46bae371bce41 before), the other five as they
+# were. A later change to what JoyAI computes recomputes them (the function
+# below prints what it finds; the text depends on the suite's JAX settings,
+# so take them from a run under pytest).
 JOYAI_PROGRAMS = {
     (False, "chunk"): "0c2ef9a4d982da15", (False, "decode"): "cf678205657964af",
-    (False, "full"): "7a75609e9bd4513f", (True, "chunk"): "82f46bae371bce41",
+    (False, "full"): "7a75609e9bd4513f", (True, "chunk"): "a01680a5c4754f75",
     (True, "decode"): "90d766a4adaa5048", (True, "full"): "7a75609e9bd4513f"}
 
 
