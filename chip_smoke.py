@@ -209,9 +209,10 @@ def phase_train(size, on_tpu):
 
 
 # ------------------------------------------------------------------- serve
-def _paged_vs_reference(state, slots, pages_per_slot, on_tpu):
+def _paged_vs_reference(state, heads, slots, pages_per_slot, on_tpu):
     """Both paged entry points against their references at the serving
-    shapes: this engine's pools, slot count and suffix-window menu."""
+    shapes: this engine's pools (``heads`` heads a position, however the
+    pool declares them), slot count and suffix-window menu."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -219,7 +220,8 @@ def _paged_vs_reference(state, slots, pages_per_slot, on_tpu):
     from mxnet_tpu.ops.pallas import paged_flash_attention as pfa
 
     pool = state["k_pools"][0]
-    n_pool, ps, H, D = pool.shape
+    n_pool, ps = pool.shape[:2]
+    H, D = heads, math.prod(pool.shape[2:]) // heads
     dt = pool.dtype
     k = jax.random.split(jax.random.PRNGKey(1), 3)
     k_pool = jax.random.normal(k[0], pool.shape, jnp.float32).astype(dt)
@@ -356,7 +358,8 @@ def phase_serve(size, on_tpu, seed):
     if on_tpu:
         check(kernels_in_decode > 0, "the serving decode program holds no "
               "Mosaic kernel: paged attention was replaced or interpreted")
-    paged = _paged_vs_reference(state, SLOTS, bat.pages_per_slot, on_tpu)
+    paged = _paged_vs_reference(state, size["xf"]["num_heads"], SLOTS,
+                                bat.pages_per_slot, on_tpu)
     say("serve", model=size["xf_zoo"], units=net._units,
         batcher=type(bat).__name__, buckets=list(BUCKETS), slots=SLOTS,
         max_new_tokens=MAX_NEW, max_prefix_tokens=MAX_PREFIX,

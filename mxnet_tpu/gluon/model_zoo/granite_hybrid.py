@@ -221,13 +221,14 @@ class GraniteHybridLM(HybridBlock):
     # ------------------------------------------------------ paged protocol
     def init_paged_state(self, slots, num_pages, page_size, mem_len,
                          dtype=None):
-        """K/V pools ``(num_pages, page, Hkv, D)`` for the attention layers
-        alone (page 0 is the trash page), and for each state-space layer
-        its slots' recurrent state ``(slots, heads, head_dim, d_state)`` in
-        the state's own dtype and convolution tail ``(slots, d_conv - 1,
-        conv_dim)``."""
+        """K/V pools ``(num_pages, page, Hkv x D)`` for the attention layers
+        alone (page 0 is the trash page; a page's (head, d) on the lanes,
+        as the paged kernels read heads of 64: ``ops/paged.py``), and for
+        each state-space layer its slots' recurrent state ``(slots, heads,
+        head_dim, d_state)`` in the state's own dtype and convolution tail
+        ``(slots, d_conv - 1, conv_dim)``."""
         dt = jnp.dtype(dtype if dtype is not None else self.embed.dtype)
-        kv = (int(num_pages), int(page_size), self._nkv, self._d)
+        kv = (int(num_pages), int(page_size), self._nkv * self._d)
         n_attn = self._types.count("attention")
         n_ssm = len(self._types) - n_attn
         ssm = (int(slots), self._mh, self._mp, self._mn)

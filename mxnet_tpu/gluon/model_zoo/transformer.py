@@ -356,7 +356,8 @@ class TransformerModel(HybridBlock):
 
     # -------------------------------------------------------- paged decode
     # The paged protocol (continuous batching, ISSUE 8): K/V live in shared
-    # per-layer (num_pages, page_size, H, D) pools with per-slot page
+    # per-layer (num_pages, page_size, H, D) pools (declared (num_pages,
+    # page_size, H x D) at heads narrower than the lanes) with per-slot page
     # tables; cross-attention memory sits in per-slot (slots, mem_len, H,
     # D) buffers written once at admission. The batch dimension is the
     # SLOT menu — static shape, dynamic occupancy.
@@ -415,10 +416,12 @@ class TransformerModel(HybridBlock):
         k_pools, v_pools, cross_k, cross_v = [], [], [], []
         for i in range(self.decoder._n):
             k_s, v_s = self_parts[i]
-            kp = state["k_pools"][i].at[first_pages, 0].set(
-                k_s[:, 0].astype(state["k_pools"][i].dtype))
-            vp = state["v_pools"][i].at[first_pages, 0].set(
-                v_s[:, 0].astype(state["v_pools"][i].dtype))
+            kp, vp = state["k_pools"][i], state["v_pools"][i]
+            row = (k_s.shape[0],) + kp.shape[2:]    # as the pool is declared
+            kp = kp.at[first_pages, 0].set(
+                k_s[:, 0].astype(kp.dtype).reshape(row))
+            vp = vp.at[first_pages, 0].set(
+                v_s[:, 0].astype(vp.dtype).reshape(row))
             k_pools.append(kp)
             v_pools.append(vp)
             k_m, v_m = cross_parts[i]

@@ -319,7 +319,8 @@ class MultiHeadAttention(HybridBlock):
     # ------------------------------------------------------------ paged mode
     # Paged KV cache (Kwon et al., PagedAttention, SOSP 2023): instead of a
     # dense (max_len, B, H, D) slab per dispatch, K/V live in a shared
-    # (num_pages, page_size, H, D) pool; each batch row owns a PAGE TABLE
+    # (num_pages, page_size, H, D) pool (declared (num_pages, page_size,
+    # H x D) at heads narrower than the lanes); each batch row owns a PAGE TABLE
     # row mapping its logical token positions to pool pages. Reads gather
     # through the table, writes scatter through it — so a request holds
     # only ceil(len/page_size) pages, freed the moment it retires. Page 0
@@ -328,14 +329,20 @@ class MultiHeadAttention(HybridBlock):
     # with no masking branches. serving.pages.PagePool owns the free list.
 
     def init_page_pool(self, num_pages, page_size, dtype=None):
-        """Zeroed ``(num_pages, page_size, H, D)`` K/V pool pair shared by
-        every request decoding through this layer. ``dtype`` defaults to
-        the layer's parameter dtype (AMP engines get compute-dtype pools).
-        """
+        """Zeroed K/V pool pair shared by every request decoding through
+        this layer: ``(num_pages, page_size, H, D)`` at heads of whole
+        lanes, and ``(num_pages, page_size, H x D)`` at narrower heads (a
+        page's (head, d) on the lanes, the declaration the paged kernels
+        read such heads in and the chip keeps row-major: ``ops/paged.py``;
+        every writer below lays a position's heads side by side, which is
+        the same numbers in the same order). ``dtype`` defaults to the
+        layer's parameter dtype (AMP engines get compute-dtype pools)."""
         if dtype is None:
             dtype = self.out_proj.weight.dtype
         shape = (int(num_pages), int(page_size), self._num_heads,
                  self._head_dim)
+        if self._head_dim % 128:
+            shape = shape[:2] + (self._num_heads * self._head_dim,)
         # two buffers, never one array twice: the serving engine donates
         # the pools into every dispatch, and a buffer donated twice in one
         # call is refused off the CPU
@@ -384,8 +391,10 @@ class MultiHeadAttention(HybridBlock):
         slot = jnp.where(active, pos // page_size, 0)
         page = jnp.where(active, page_table[rows, slot], 0)
         off = jnp.where(active, pos % page_size, 0)
-        k_pool = k_pool.at[page, off].set(k_t)
-        v_pool = v_pool.at[page, off].set(v_t)
+        k_pool = k_pool.at[page, off].set(k_t.reshape(
+            (B,) + k_pool.shape[2:]))
+        v_pool = v_pool.at[page, off].set(v_t.reshape(
+            (B,) + v_pool.shape[2:]))
         from ...ops import paged
         from ...ops.pallas import paged_flash_attention as _pfa
 
@@ -454,8 +463,10 @@ class MultiHeadAttention(HybridBlock):
         slot = jnp.where(live, abs_pos // page_size, 0)
         page = jnp.where(live, page_table[rows, slot], 0)
         off = jnp.where(live, abs_pos % page_size, 0)
-        k_pool = k_pool.at[page, off].set(k_t)
-        v_pool = v_pool.at[page, off].set(v_t)
+        k_pool = k_pool.at[page, off].set(k_t.reshape(
+            (B, S) + k_pool.shape[2:]))
+        v_pool = v_pool.at[page, off].set(v_t.reshape(
+            (B, S) + v_pool.shape[2:]))
         if self._causal and paged.kernels_on():
             out = NDArray(_pfa.paged_window_attention(
                 q.data, k_pool, v_pool, page_table, pos, window_vl,

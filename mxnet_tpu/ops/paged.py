@@ -2,12 +2,23 @@
 the pools, dense causal attention over them, and the ONE answer to whether
 the Pallas kernels run.
 
-The serving plane keeps a layer's keys and values in pools ``(num_pages,
-page, heads, D)`` (or ``(num_pages, page x heads, D)``, a page's (key, head)
-rows on one axis, as the paged kernels read it) and a page table ``(R, P)``
-a row. A net says what it attends over; which form runs is decided here and
-in the two modules beside this one, from the platform and the mesh and from
-nothing else:
+The serving plane keeps a layer's keys and values in pools and a page
+table ``(R, P)`` a row. A pool is declared one of three ways, the same
+numbers in the same order, told apart by their shapes alone:
+
+- ``(num_pages, page, heads, D)``, the plain one;
+- ``(num_pages, page x heads, D)``, a page's (key, head) rows on one axis,
+  as the kernels of heads of whole lanes read it (``D`` a multiple of 128:
+  zaya, PR 40);
+- ``(num_pages, page, heads x D)``, a page's positions on the rows and
+  (head, d) on the lanes, as the kernels read heads NARROWER than the
+  lanes (granite's and transformer-big's heads of 64, PR 46): the last
+  axis is whole lanes, so the chip keeps the pool row-major as declared
+  and no program copies it to another layout and back.
+
+A net says what it attends over; which form runs is decided here and in
+the two modules beside this one, from the platform and the mesh, the pool's
+declaration and the heads' width, and from nothing else:
 
 - ``kernels_on()``: a TPU and no multi-device mesh. ``ops/paged.py``,
   ``ops/sparse_attention.py`` (learned selection, attention under a mask),
@@ -76,11 +87,13 @@ def token_rows(page_tables, positions, page_size):
 def write_rows(pool, rows, values):
     """``pool`` with ``values (N, ...)`` written at flattened rows ``rows
     (N,)`` (rows of the trash page for what must not land). A pool
-    declared ``(num_pages, page x heads, D)``, a page's (key, head) rows
-    on one axis as the paged window kernel reads them, takes ``values (N,
-    heads, D)``, as many axes as its own: position ``r``'s heads are rows
-    ``r x heads`` onward."""
-    if values.ndim == pool.ndim:
+    declared in three axes takes ``values (N, heads, D)``, as many axes as
+    its own: where it is ``(num_pages, page x heads, D)`` position ``r``'s
+    heads are rows ``r x heads`` onward, where it is ``(num_pages, page,
+    heads x D)`` they lie side by side on row ``r``."""
+    if values.ndim == pool.ndim and pool.shape[-1] != values.shape[-1]:
+        values = values.reshape(values.shape[0], -1)
+    elif values.ndim == pool.ndim:
         heads = values.shape[1]
         rows = (rows[:, None] * heads
                 + jnp.arange(heads, dtype=rows.dtype)).reshape(-1)
@@ -89,13 +102,16 @@ def write_rows(pool, rows, values):
     return flat.at[rows].set(values.astype(pool.dtype)).reshape(pool.shape)
 
 
-def _by_head(pool, kv_heads):
-    """A pool declared ``(num_pages, page x heads, D)`` as ``(num_pages,
-    page, heads, D)``, for the ``jax.numpy`` forms (off the chip a free
-    view)."""
+def by_head(pool, D, kv_heads=None):
+    """A pool of heads of ``D`` as ``(num_pages, page, heads, D)`` however
+    it is declared, for the ``jax.numpy`` forms (off the chip a free
+    view); ``kv_heads`` says the heads of one declared ``(num_pages, page
+    x heads, D)``."""
     if pool.ndim == 4:
         return pool
-    return pool.reshape(pool.shape[0], -1, kv_heads, pool.shape[2])
+    if pool.shape[2] != D:
+        return pool.reshape(pool.shape[:2] + (-1, D))
+    return pool.reshape(pool.shape[0], -1, kv_heads, D)
 
 
 # ------------------------------------------------------------------- decode
@@ -104,13 +120,15 @@ def decode_attention(q, k_pool, v_pool, page_tables, pos, sm_scale,
     """One query a row, ``q (B, Hq, D)`` at ``pos (B,)``, over every cached
     position of its row up to ``pos`` (the caller has written it); query
     head ``i`` reads key/value head ``i // (Hq // Hkv)``. Pools ``(num_pages,
-    page, Hkv, D)``, or ``(num_pages, page x Hkv, D)`` with ``kv_heads``
-    saying ``Hkv``. Returns ``(B, Hq * D)``.
+    page, Hkv, D)`` or ``(num_pages, page, Hkv x D)``, or ``(num_pages,
+    page x Hkv, D)`` with ``kv_heads`` saying ``Hkv``. Returns ``(B, Hq *
+    D)``.
 
     Where the kernels run, ``paged_decode_attention`` reads the pools in
     place (a row's live pages walked at heads of 128, the pipeline's page
-    operands at heads of 64: its own choice). Else a row gathers every
-    cached position by token and masks (``selected_decode_attention``)."""
+    operands at narrower heads, a page ``(positions, Hkv x D)`` as it is
+    declared: its own choice). Else a row gathers every cached position by
+    token and masks (``selected_decode_attention``)."""
     B, Hq, D = q.shape
     if kernels_on():
         return _pfa.paged_decode_attention(
@@ -118,7 +136,8 @@ def decode_attention(q, k_pool, v_pool, page_tables, pos, sm_scale,
             kv_heads=kv_heads).reshape(B, Hq * D)
     from . import sparse_attention as _dsa  # it stands on this module
 
-    k_pool, v_pool = _by_head(k_pool, kv_heads), _by_head(v_pool, kv_heads)
+    k_pool, v_pool = by_head(k_pool, D, kv_heads), \
+        by_head(v_pool, D, kv_heads)
     L = page_tables.shape[1] * k_pool.shape[1]
     every = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
     return _dsa.selected_decode_attention(
@@ -156,7 +175,8 @@ def window_attention(q, k_pool, v_pool, page_tables, q_offset, real,
         return out.reshape(R, C, Hq * D)
     from . import sparse_attention as _dsa
 
-    k_pool, v_pool = _by_head(k_pool, kv_heads), _by_head(v_pool, kv_heads)
+    k_pool, v_pool = by_head(k_pool, D, kv_heads), \
+        by_head(v_pool, D, kv_heads)
     L = page_tables.shape[1] * k_pool.shape[1]
     block = kv_block(L, kv_chunk)
     q_pos = q_offset[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]

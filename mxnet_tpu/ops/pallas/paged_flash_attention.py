@@ -33,16 +33,27 @@ Two kernels, two entry points:
   ``dsa_decode.py`` have: grid ``(B,)``, the pools left whole in HBM, a
   row's LIVE pages walked in blocks by the kernel's own copies, two
   buffers deep (``page_walk.walk_live_pages``), the same product and
-  carry. Mosaic copies no page out of a pool whose rows are 64 wide, so
-  heads of 64 (granite, transformer-big) stay on ``_window_kernel`` at
-  ``S = 1`` with ``q_offset = pos``, the group on the window axis: their
-  programs are what they were.
+  carry. Narrower heads (granite's, transformer-big's and the smoke run's
+  of 64) take the window's form at ``S = 1`` with ``q_offset = pos``, the
+  group on the window axis.
 
-Both take the pool as ``(num_pages, page_size, Hkv, D)`` or, declared the
-way the kernels read it, ``(num_pages, page_size x Hkv, D)`` with
-``kv_heads=`` saying ``Hkv`` (``_page_size``; ZAYA's two heads of 128, PR
-40), and both take grouped query heads: a window of ``S`` positions by
-``G`` heads a key/value head rides the window kernel's window axis whole.
+Both take the pool in one of three declarations, the same numbers in the
+same order, told apart by their shapes (``_page_size``, ``_lanes_packed``):
+``(num_pages, page_size, Hkv, D)``; ``(num_pages, page_size x Hkv, D)``
+with ``kv_heads=`` saying ``Hkv``, as the kernels above read heads of whole
+lanes (ZAYA's two heads of 128, PR 40); and ``(num_pages, page_size, Hkv x
+D)``, a page's positions on the rows and (head, d) on the lanes, which is
+how heads NARROWER than the lanes are declared since PR 46: a last axis of
+64 stands on 128 lanes at twice the bytes, XLA therefore kept such a pool
+in a layout of its own and copied it to the kernels' and back around every
+burst, and Mosaic copies no page out of it; ``heads x 64`` is whole lanes,
+the pool lies row-major as declared and ``_lane_window_kernel`` /
+``_lane_selected_window_kernel`` read its pages as they lie: the query rows
+stand on their own head's lanes with zeros beside them, so one product
+with the page, contracted over the lanes, gives each row its own head's
+scores. Both entry points take grouped query heads: a window of ``S``
+positions by ``G`` heads a key/value head rides the window kernel's window
+axis whole.
 
 - ``paged_selected_window_attention`` — the window over a SELECTED set
   of cached positions (learned sparse attention), for grouped-query
@@ -52,8 +63,10 @@ way the kernels read it, ``(num_pages, page_size x Hkv, D)`` with
   step takes a block of several pages (1,024 keys), relays it head-major
   once, gives each key/value head one product with all the query rows
   that read it and updates the softmax carry once; blocks past the last
-  one a query block can see are neither fetched nor computed. The pools'
-  layout in HBM is the serving stack's, as for the other two.
+  one a query block can see are neither fetched nor computed. Pools ``(
+  num_pages, page, Hkv, D)`` (keye, ouro: heads of 128), or ``(num_pages,
+  page, Hkv x D)`` at narrower heads (granite's chunk under a causal
+  mask), where nothing is relaid and two heads of 64 share a product.
 
 All keep ``MXTPU_FLASH_INTERPRET`` (force/forbid/auto, shared with
 ``flash_attention.py``) and ship a dense jnp reference
@@ -76,7 +89,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import _use_interpret
 from .flash_attention import NEG_INF
-from .page_walk import (LANES, init_carry, prec, softmax_step,
+from .page_walk import (LANES, init_carry, nt, prec, softmax_step,
                         walk_live_pages)
 
 __all__ = ["paged_decode_attention", "paged_window_attention",
@@ -99,12 +112,13 @@ __all__ = ["paged_decode_attention", "paged_window_attention",
 # 456 at blocks of 64 / 128 / 256 / 512 KiB, every row full 762 / 581 /
 # 499 / 456. What is left there is the pipeline's own work on 2 x 16 page
 # operands a row, about 3 us, however they are spread over grid steps (7.5
-# us a row at zaya's 2 x 32: PR 40). Since PR 41 this form serves the
-# windows of ``S > 1`` (every cell's chunks, admission prefill, speculative
-# verification) and the decode step at heads of 64, granite's and
-# transformer-big's, whose pages Mosaic cannot copy out of a pool left in
-# HBM; the decode step at heads of 128 walks its live pages
-# (``_decode_kernel``, below, with its own numbers)
+# us a row at zaya's 2 x 32: PR 40). Since PR 41 the decode step at heads
+# of 128 walks its live pages (``_decode_kernel``, below, with its own
+# numbers) and this form serves the windows of ``S > 1`` (zaya's chunks)
+# and whatever pool of narrower heads is still declared in four axes; since
+# PR 46 granite, transformer-big and the smoke run declare theirs ``(pages,
+# page, heads x 64)`` and run ``_lane_window_kernel`` on the same two sizes
+# (the numbers above are the four-axis form's, on the padded page)
 _WINDOW_STEP_BYTES = 2 * 1024 * 1024
 _WINDOW_BLOCK_BYTES = 128 * 1024
 _WINDOW_STEP_VMEM_LIMIT = 32 * 1024 * 1024
@@ -224,6 +238,26 @@ def _window_kernel(pt_ref, off_ref, vl_ref, q_ref, *refs, page_size, pages,
                              0.0).astype(o_ref.dtype)
 
 
+def _window_page(t, ps, P, pages, extra, one_step=False):
+    """The index map of page operand ``t`` of the window kernels: page
+    ``t`` of step ``j``, if the row's last query (``extra`` positions past
+    its offset) sees it; else the page this operand held the step before,
+    so that nothing is copied, and in step 0 pool page 0 (masked whatever
+    it holds), which the next short row then finds in place too.
+    ``one_step`` says the row is one grid step (``P <= pages``): the step
+    is then 0 by construction, and leaving its arithmetic out takes a
+    division by ``pages`` off every operand's map, 750 of the 3,300
+    bundles a row of granite's call spends before its first product (the
+    lane forms say so; the four-axis form keeps the program it had)."""
+    def index(b, j, pt, off, vl):
+        last = jnp.clip((off[b] + extra) // ps, 0, P - 1)
+        if one_step:
+            return (jnp.where(t <= last, pt[b, t], 0), 0, 0)
+        jj = jnp.minimum(j, jnp.maximum(last - t, 0) // pages)
+        return (jnp.where(t <= last, pt[b, jj * pages + t], 0), 0, 0)
+    return index
+
+
 # jitted so that one trace serves every layer and every program of a
 # shape: the page operands' index maps are traced one by one, which cost
 # transformer-big's warm-up (25 programs of six layers) 4 s of ``setup_s``
@@ -233,25 +267,14 @@ def _paged_window_impl(q, k_pool, v_pool, page_table, q_offset, window_vl,
                        sm_scale, shared_position, pages, block, interpret,
                        group=1):
     B, S, H, D = q.shape
-    N, ps = k_pool.shape[0], _page_size(k_pool, H)
+    N, ps = k_pool.shape[0], _page_size(k_pool, H, D)
     P = page_table.shape[1]
     # query rows (head, query); a page as its (key, head) rows: with 8 or
     # 16 heads of bfloat16 the pool's tiles in HBM are these rows already
     rows = jnp.swapaxes(q, 1, 2).reshape(B, H * S, D)
     extra = 0 if shared_position else S // group - 1
-
-    def page(t):
-        # page t of step j, if the row's last query sees it; else the
-        # page this operand held the step before, so that nothing is
-        # copied, and in step 0 pool page 0 (masked whatever it holds),
-        # which the next short row then finds in place too
-        def index(b, j, pt, off, vl):
-            last = jnp.clip((off[b] + extra) // ps, 0, P - 1)
-            jj = jnp.minimum(j, jnp.maximum(last - t, 0) // pages)
-            return (jnp.where(t <= last, pt[b, jj * pages + t], 0), 0, 0)
-        return index
-
-    pool_specs = [pl.BlockSpec((1, ps * H, D), page(t))
+    pool_specs = [pl.BlockSpec((1, ps * H, D),
+                               _window_page(t, ps, P, pages, extra))
                   for t in range(pages)]
     row_spec = pl.BlockSpec((1, H * S, D),
                             lambda b, j, pt, off, vl: (b, 0, 0))
@@ -285,14 +308,179 @@ def _paged_window_impl(q, k_pool, v_pool, page_table, q_offset, window_vl,
     return jnp.swapaxes(out.reshape(B, H, S, D), 1, 2)   # (B, S, H, D)
 
 
-def _page_size(pool, heads):
+# --------------------------- heads narrower than the lanes: the page as
+# ``(positions, heads x D)``
+# A pool of heads of 64 declared ``(num_pages, page, Hkv, 64)`` stands a
+# head on half a lane row: XLA keeps such a parameter in a layout of its
+# own and copied every pool to these kernels' layout before a burst's
+# ``%while`` and back after it (granite: 16 copies of 101 MB a burst,
+# PERF.md 7 (u)). Declared ``(num_pages, page, Hkv x D)`` a page is its
+# positions on the rows and (head, d) on whole lanes, row-major as it
+# stands, and the forms below read it so. Rows a product of the window may
+# have before the heads are taken a few at a time:
+_LANE_ROWS = 256
+
+
+def _lanes_packed(pool, D):
+    """Is ``pool`` declared ``(num_pages, page, heads x D)``, a page's
+    (head, d) on the lanes? Told from the shapes alone: the other 3-D
+    declaration, ``(num_pages, page x heads, D)``, ends in ``D`` (with one
+    head the two are the same array)."""
+    return pool.ndim == 3 and pool.shape[2] != D
+
+
+def _lane_heads(Hkv, D, rows_a_head=None):
+    """Key/value heads a product of the lane forms takes: as few as fill
+    whole lanes (two heads of 64), so that the zero lanes cost the MXU no
+    more than a head of 64 costs it anyway; or, where ``rows_a_head`` is
+    given and all the heads' query rows are at most ``_LANE_ROWS`` (a
+    decode step, a verification window), all of them: one product, one
+    softmax carry."""
+    if rows_a_head is not None and Hkv * rows_a_head <= _LANE_ROWS:
+        return Hkv
+    return math.gcd(Hkv, max(1, LANES // D))
+
+
+def _own_lanes(x):
+    """``x (..., hp, n, D)`` as ``(..., hp x n, hp x D)``: row ``(e, i)``
+    holds ``x[e, i]`` on head ``e``'s ``D`` lanes and zeros on the others,
+    so that its product with a page ``(keys, hp x D)``, contracted over the
+    lanes, is head ``e``'s scores alone."""
+    *lead, hp, n, D = x.shape
+    own = jnp.eye(hp, dtype=bool)[:, None, :, None]
+    return jnp.where(own, x[..., None, :], 0).reshape(*lead, hp * n, hp * D)
+
+
+def _lane_window_tiles(P, page_size, Hkv, D, itemsize):
+    """``_window_tiles`` for a page ``(positions, heads x D)``: a block's
+    scores are ``(rows, keys)``, so it takes at least 128 keys where the
+    step has them (transformer-big's pages of 16 go 8 a block)."""
+    pages, block = _window_tiles(P, page_size, Hkv, D, itemsize)
+    return pages, min(pages, max(block, -(-LANES // page_size)))
+
+
+def _lane_window_kernel(pt_ref, off_ref, vl_ref, q_ref, *refs, page_size,
+                        pages, block, sm_scale, window, heads,
+                        shared_position=False, group=1):
+    """``_window_kernel`` over pages ``(page_size, Hkv x D)``: the grid,
+    the page operands, the blocks under their ``pl.when`` and the carry are
+    its own. The ``Hkv * window`` query rows (head ``r // window``) come
+    ``heads`` key/value heads at a time, each row with its query on its own
+    head's ``D`` lanes of the ``heads x D`` and zeros beside it
+    (``_own_lanes``): ONE product with those lanes of the block,
+    contracted over the lanes, gives ``(rows, keys)`` scores that are the
+    row's own head's, with no foreign column to mask; the second product
+    gives ``(rows, heads x D)`` of which a row keeps its head's lanes at
+    the end."""
+    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * pages:]
+    b, j = pl.program_id(0), pl.program_id(1)
+    rows, width = q_ref.shape[1], q_ref.shape[2]
+    D = o_ref.shape[2]
+    per = heads * window                     # rows a product takes
+    span = window // group                   # positions the window holds
+
+    @pl.when(j == 0)
+    def _init():
+        init_carry(m_ref, l_ref, acc_ref)
+
+    off = off_ref[b]
+    row = jax.lax.broadcasted_iota(jnp.int32, (per, 1), 0)
+    q_abs = off if shared_position else off + row % span
+    last = off + (0 if shared_position else span - 1)
+
+    def accumulate(k_blk, v_blk, first_key):
+        keys = len(k_blk) * page_size
+        seen = first_key + jax.lax.broadcasted_iota(
+            jnp.int32, (1, keys), 1) <= q_abs
+        for p in range(rows // per):
+            def joined(page_refs):
+                got = [r[0, :, p * width:(p + 1) * width] for r in page_refs]
+                return got[0] if len(got) == 1 else jnp.concatenate(got, 0)
+
+            at = pl.ds(p * per, per)
+            s = nt(q_ref[0, at, :], joined(k_blk)) * sm_scale
+            softmax_step(jnp.where(seen, s, NEG_INF), joined(v_blk),
+                         m_ref.at[at], l_ref.at[at], acc_ref.at[at])
+
+    for lo in range(0, pages, block):
+        hi = min(pages, lo + block)
+        first_key = (j * pages + lo) * page_size
+        pl.when(first_key <= last)(functools.partial(
+            accumulate, k_refs[lo:hi], v_refs[lo:hi], first_key))
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        every = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        l = jnp.maximum(jnp.sum(l_ref[...], axis=1, keepdims=True), 1e-30)
+        out = jnp.where(every % span < vl_ref[b], acc_ref[...] / l,
+                        0.0).astype(o_ref.dtype)
+        for h in range(rows // window):
+            e = h % heads
+            o_ref[0, h * window:(h + 1) * window, :] = \
+                out[h * window:(h + 1) * window, e * D:(e + 1) * D]
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "sm_scale", "shared_position", "pages", "block", "heads", "interpret",
+    "group"))
+def _lane_window_impl(q, k_pool, v_pool, page_table, q_offset, window_vl,
+                      sm_scale, shared_position, pages, block, heads,
+                      interpret, group=1):
+    B, S, H, D = q.shape
+    ps, P = k_pool.shape[1], page_table.shape[1]
+    width = heads * D
+    rows = _own_lanes(jnp.swapaxes(q, 1, 2).reshape(
+        B, H // heads, heads, S, D)).reshape(B, H * S, width)
+    extra = 0 if shared_position else S // group - 1
+    pool_specs = [pl.BlockSpec((1, ps, H * D),
+                               _window_page(t, ps, P, pages, extra,
+                                            one_step=P <= pages))
+                  for t in range(pages)]
+
+    def row_spec(last):
+        return pl.BlockSpec((1, H * S, last),
+                            lambda b, j, pt, off, vl: (b, 0, 0))
+
+    kernel = functools.partial(
+        _lane_window_kernel, page_size=ps, pages=pages, block=block,
+        sm_scale=sm_scale, window=S, heads=heads,
+        shared_position=shared_position, group=group)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, pl.cdiv(P, pages)),
+            in_specs=[row_spec(width)] + pool_specs + pool_specs,
+            out_specs=row_spec(D),
+            scratch_shapes=[
+                pltpu.VMEM((H * S, LANES), jnp.float32),
+                pltpu.VMEM((H * S, math.gcd(
+                    ps * math.gcd(block, pages), LANES)), jnp.float32),
+                pltpu.VMEM((H * S, width), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, H * S, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_WINDOW_STEP_VMEM_LIMIT),
+        interpret=interpret,
+        name="paged_window",
+    )(page_table.astype(jnp.int32), q_offset.astype(jnp.int32),
+      window_vl.astype(jnp.int32), rows, *([k_pool] * pages),
+      *([v_pool] * pages))
+    return jnp.swapaxes(out.reshape(B, H, S, D), 1, 2)   # (B, S, H, D)
+
+
+def _page_size(pool, heads, D):
     """Positions a page of ``pool`` holds: its second axis, or, where the
-    pool is declared as the kernel reads it, ``(num_pages, page x heads,
-    D)`` (a page's (key, head) rows on ONE axis: two heads of 128 are then
-    whole ``(16, 128)`` tiles on the chip, where an axis of 2 heads would
-    be padded to 16 rows, eight times the bytes), that axis over the
-    heads."""
-    return pool.shape[1] if pool.ndim == 4 else pool.shape[1] // heads
+    pool is declared as the kernel of heads of whole lanes reads it,
+    ``(num_pages, page x heads, D)`` (a page's (key, head) rows on ONE
+    axis: two heads of 128 are then whole ``(16, 128)`` tiles on the chip,
+    where an axis of 2 heads would be padded to 16 rows, eight times the
+    bytes), that axis over the heads."""
+    if pool.ndim == 4 or _lanes_packed(pool, D):
+        return pool.shape[1]
+    return pool.shape[1] // heads
 
 
 def paged_window_attention(q, k_pool, v_pool, page_table, q_offset,
@@ -307,30 +495,38 @@ def paged_window_attention(q, k_pool, v_pool, page_table, q_offset,
     ``window_vl`` ``(B,)`` optionally marks queries ``>= window_vl[b]``
     as padding (their outputs are zeroed). ``shared_position`` puts every
     query of the window at ``q_offset[b]`` (``paged_decode_attention``'s
-    grouped query heads). Pools are ``(num_pages, page, Hkv, D)`` or
-    ``(num_pages, page x Hkv, D)`` (``_page_size``); ``kv_heads`` says
+    grouped query heads). Pools are ``(num_pages, page, Hkv, D)``,
+    ``(num_pages, page x Hkv, D)`` or ``(num_pages, page, Hkv x D)``
+    (``_page_size``; the last runs ``_lane_window_kernel``); ``kv_heads`` says
     ``Hkv`` where ``H`` is ``G`` times it (grouped-query heads: query
     head ``i`` reads key/value head ``i // G``): the ``G`` heads of a
     group then ride the kernel's window axis beside the ``S`` positions,
     one softmax carry for the ``G x S`` rows of a key/value head. Returns
     ``(B, S, H, D)``."""
     B, S, H, D = q.shape
-    Hkv = H if kv_heads is None else int(kv_heads)
+    lanes = _lanes_packed(k_pool, D)
+    Hkv = (k_pool.shape[2] // D if lanes else H) if kv_heads is None \
+        else int(kv_heads)
     G = H // Hkv
     if window_vl is None:
         window_vl = jnp.full((B,), S, jnp.int32)
-    pages, block = _window_tiles(
-        page_table.shape[1], _page_size(k_pool, Hkv), Hkv, D,
+    tiles = _lane_window_tiles if lanes else _window_tiles
+    pages, block = tiles(
+        page_table.shape[1], _page_size(k_pool, Hkv, D), Hkv, D,
         k_pool.dtype.itemsize)
     if G > 1:
         # (B, G x S, Hkv, D): window row g * S + i is head g of its group
         # at position i
         q = jnp.transpose(q.reshape(B, S, Hkv, G, D),
                           (0, 3, 1, 2, 4)).reshape(B, G * S, Hkv, D)
-    out = _paged_window_impl(
+    impl, more = _paged_window_impl, {}
+    if lanes:
+        impl, more = _lane_window_impl, {"heads": _lane_heads(Hkv, D, G * S)}
+    out = impl(
         q, k_pool, v_pool, page_table, q_offset, window_vl,
         sm_scale=float(sm_scale), shared_position=bool(shared_position),
-        pages=pages, block=block, interpret=_use_interpret(), group=G)
+        pages=pages, block=block, interpret=_use_interpret(), group=G,
+        **more)
     if G > 1:
         out = jnp.transpose(out.reshape(B, G, S, Hkv, D),
                             (0, 2, 3, 1, 4)).reshape(B, S, H, D)
@@ -440,7 +636,8 @@ def _decode_kernel(pt_ref, pos_ref, q_ref, k_pool_ref, v_pool_ref, o_ref,
 def _paged_decode_impl(q, k_pool, v_pool, page_table, pos, sm_scale,
                        kv_heads, block, step, interpret):
     B, H, D = q.shape
-    N, cols = k_pool.shape[0], _page_size(k_pool, kv_heads) * kv_heads
+    N, cols = k_pool.shape[0], \
+        _page_size(k_pool, kv_heads, D) * kv_heads
     kernel = functools.partial(_decode_kernel, block=block, step=step,
                                group=H // kv_heads, sm_scale=sm_scale)
     row = pl.BlockSpec((1, H, D), lambda b, pt, at: (b, 0, 0))
@@ -473,8 +670,8 @@ def _paged_decode_impl(q, k_pool, v_pool, page_table, pos, sm_scale,
 def _decode_walk(q, k_pool, v_pool, page_table, pos, sm_scale, Hkv):
     """``paged_decode_attention`` through ``_decode_kernel``."""
     block, step = _decode_tiles(
-        page_table.shape[1], _page_size(k_pool, Hkv), Hkv, q.shape[2],
-        k_pool.dtype.itemsize)
+        page_table.shape[1], _page_size(k_pool, Hkv, q.shape[2]), Hkv,
+        q.shape[2], k_pool.dtype.itemsize)
     return _paged_decode_impl(
         q, k_pool, v_pool, page_table, pos, sm_scale=float(sm_scale),
         kv_heads=Hkv, block=block, step=step, interpret=_use_interpret())
@@ -500,9 +697,10 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, pos, *,
                            sm_scale, kv_heads=None):
     """Single-token paged attention, pools read in place.
 
-    q ``(B, H, D)``; pools ``(num_pages, page_size, Hkv, D)``, or
-    ``(num_pages, page_size x Hkv, D)`` with ``kv_heads`` saying ``Hkv``
-    (``_page_size``); ``page_table`` ``(B, P)`` int32; ``pos`` ``(B,)``
+    q ``(B, H, D)``; pools ``(num_pages, page_size, Hkv, D)`` or
+    ``(num_pages, page_size, Hkv x D)``, or ``(num_pages, page_size x Hkv,
+    D)`` with ``kv_heads`` saying ``Hkv`` (``_page_size``); ``page_table``
+    ``(B, P)`` int32; ``pos`` ``(B,)``
     int32 — row ``b`` attends keys at absolute positions ``<= pos[b]``
     (the caller has already scattered position ``pos`` into the pool).
     Returns ``(B, H, D)``. Where ``H`` is ``G`` times ``Hkv``
@@ -510,12 +708,17 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, pos, *,
     G``.
 
     Heads of whole lanes (``D`` a multiple of 128) walk the row's live
-    pages with the kernel's own copies (``_decode_kernel``); Mosaic copies
-    no page out of a pool whose rows are narrower ("slice shape along
-    dimension 2 must be aligned to tiling (128), but is 64"), so heads of
-    64 keep the pipeline's page operands (``_window_kernel``)."""
-    Hkv = k_pool.shape[2] if kv_heads is None else int(kv_heads)
-    form = _decode_window if q.shape[2] % LANES else _decode_walk
+    pages with the kernel's own copies (``_decode_kernel``); narrower heads
+    keep the pipeline's page operands: ``_lane_window_kernel`` where the
+    pool is declared ``(num_pages, page_size, Hkv x D)``, else
+    ``_window_kernel`` on a page of 64-wide rows, out of which Mosaic
+    copies nothing itself ("slice shape along dimension 2 must be aligned
+    to tiling (128), but is 64")."""
+    D = q.shape[2]
+    lanes = _lanes_packed(k_pool, D)
+    Hkv = k_pool.shape[2] // (D if lanes else 1) if kv_heads is None \
+        else int(kv_heads)
+    form = _decode_window if D % LANES or lanes else _decode_walk
     return form(q, k_pool, v_pool, page_table, pos, sm_scale, Hkv)
 
 
@@ -628,6 +831,29 @@ def _selected_window_kernel(pt_ref, off_ref, q_ref, *refs, page_size, pages,
         o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
+def _selected_blocks(tq, pages, ps, P, rest):
+    """``(page, mask_block)``: the index maps of page operand ``t`` (a
+    block ``(1,) + `` the pool's other axes whole, ``rest`` their zeros)
+    and of the mask's block in the selected window's grid ``(b, i, j)``.
+    Past the last key block a query block's LAST query sees, both stay
+    where they were: an unseen block re-reads nothing."""
+    kb, nkb = pages * ps, pl.cdiv(P, pages)
+
+    def last_seen(b, i, off):       # the block's last query's position
+        return off[b] + (i + 1) * tq - 1
+
+    def page(t):
+        return lambda b, i, j, pt, off: (pt[b, jnp.minimum(
+            j * pages + t, jnp.minimum(last_seen(b, i, off) // ps, P - 1))],
+            ) + rest
+
+    def mask_block(b, i, j, pt, off):
+        return (b, i, jnp.minimum(
+            j, jnp.minimum(last_seen(b, i, off) // kb, nkb - 1)))
+
+    return page, mask_block
+
+
 @functools.partial(jax.jit, static_argnames=("sm_scale", "tq", "pages"))
 def _dsa_selected_window_impl(q, k_pool, v_pool, page_table, q_offset,
                               mask, sm_scale, tq, pages):
@@ -640,19 +866,7 @@ def _dsa_selected_window_impl(q, k_pool, v_pool, page_table, q_offset,
     # group, query) for each key/value head
     qb = q.reshape(B, nq, tq, Hkv, G, D).transpose(0, 1, 3, 4, 2, 5) \
         .reshape(B, nq, Hkv, G * tq, D)
-
-    def last_seen(b, i, off):       # the block's last query's position
-        return off[b] + (i + 1) * tq - 1
-
-    def page(t):                    # an unseen page re-reads nothing
-        return lambda b, i, j, pt, off: (pt[b, jnp.minimum(
-            j * pages + t, jnp.minimum(last_seen(b, i, off) // ps, P - 1))],
-            0, 0, 0)
-
-    def mask_block(b, i, j, pt, off):
-        return (b, i, jnp.minimum(
-            j, jnp.minimum(last_seen(b, i, off) // kb, nkb - 1)))
-
+    page, mask_block = _selected_blocks(tq, pages, ps, P, (0, 0, 0))
     pool_specs = [pl.BlockSpec((1, ps, Hkv, D), page(t))
                   for t in range(pages)]
     rows = pl.BlockSpec((1, 1, Hkv, G * tq, D),
@@ -689,11 +903,115 @@ def _dsa_selected_window_impl(q, k_pool, v_pool, page_table, q_offset,
         .reshape(B, C, Hq * D)
 
 
+def _lane_selected_window_kernel(pt_ref, off_ref, q_ref, *refs, page_size,
+                                 pages, length, sm_scale, tq, groups):
+    """``_selected_window_kernel`` over pages ``(page_size, Hkv x D)``: the
+    grid, the page operands, the mask and the carry are its own, and
+    nothing is relaid. The key/value heads come as many at a time as fill
+    whole lanes (``hp``: two heads of 64), a set's ``hp * groups * tq``
+    query rows (head of the set, query head of its group, query) each with
+    its query on its own head's ``D`` lanes and zeros beside it
+    (``_own_lanes``): one product with the set's lanes of the block gives
+    every row its own head's scores, at the depth of 128 a product of
+    heads of 64 costs the MXU anyway; of the second product's ``hp x D``
+    lanes a row keeps its head's at the end."""
+    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
+    mask_ref, o_ref, m_ref, l_ref, acc_ref = refs[2 * pages:]
+    b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    kb = pages * page_size
+    sets, per, width = q_ref.shape[2:]
+    D = o_ref.shape[4]
+    hp = width // D
+
+    @pl.when(j == 0)
+    def _init():
+        init_carry(m_ref, l_ref, acc_ref)
+
+    @pl.when(j * kb <= off_ref[b] + (i + 1) * tq - 1)
+    def _accumulate():
+        keep = mask_ref[0].astype(jnp.int32)       # (tq, keys)
+        if length % kb:
+            pos = j * kb + jax.lax.broadcasted_iota(jnp.int32, (tq, kb), 1)
+            keep = jnp.where(pos < length, keep, 0)
+        bias = jnp.where(keep != 0, 0.0, -jnp.inf).astype(jnp.float32)
+        for p in range(sets):
+            def joined(page_refs):
+                return jnp.concatenate(
+                    [r[0, :, p * width:(p + 1) * width] for r in page_refs],
+                    axis=0)                        # (keys, hp x D)
+
+            s = nt(q_ref[0, 0, p], joined(k_refs))
+            s = (s.reshape(hp * groups, tq, kb) * sm_scale + bias[None]) \
+                .reshape(per, kb)
+            softmax_step(s, joined(v_refs), m_ref.at[p], l_ref.at[p],
+                         acc_ref.at[p])
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finalize():
+        l = jnp.maximum(jnp.sum(l_ref[...], axis=2, keepdims=True), 1e-30)
+        out = (acc_ref[...] / l).astype(o_ref.dtype)   # (sets, per, width)
+        rows = groups * tq
+        for h in range(sets * hp):
+            p, e = divmod(h, hp)
+            o_ref[0, 0, h] = out[p, e * rows:(e + 1) * rows,
+                                 e * D:(e + 1) * D]
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "tq", "pages"))
+def _lane_selected_window_impl(q, k_pool, v_pool, page_table, q_offset,
+                               mask, sm_scale, tq, pages):
+    B, C, Hq, D = q.shape
+    ps, Hkv = k_pool.shape[1], k_pool.shape[2] // D
+    P, L = page_table.shape[1], mask.shape[2]
+    G, nq = Hq // Hkv, C // tq
+    hp = _lane_heads(Hkv, D)                  # heads that fill whole lanes
+    sets, per, width = Hkv // hp, hp * G * tq, hp * D
+    kb, nkb = pages * ps, pl.cdiv(P, pages)
+    # (B, nq, sets, hp x G x tq, hp x D): a set's rows are (head of the
+    # set, query head of its group, query), each on its head's lanes
+    qb = _own_lanes(
+        q.reshape(B, nq, tq, sets, hp, G, D).transpose(0, 1, 3, 4, 5, 2, 6)
+        .reshape(B, nq, sets, hp, G * tq, D))
+    page, mask_block = _selected_blocks(tq, pages, ps, P, (0, 0))
+    pool_specs = [pl.BlockSpec((1, ps, Hkv * D), page(t))
+                  for t in range(pages)]
+    kernel = functools.partial(_lane_selected_window_kernel, page_size=ps,
+                               pages=pages, length=L, sm_scale=sm_scale,
+                               tq=tq, groups=G)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, nq, nkb),
+            in_specs=[pl.BlockSpec((1, 1, sets, per, width),
+                                   lambda b, i, j, pt, off: (b, i, 0, 0, 0))]
+            + pool_specs + pool_specs
+            + [pl.BlockSpec((1, tq, kb), mask_block)],
+            out_specs=pl.BlockSpec((1, 1, Hkv, G * tq, D),
+                                   lambda b, i, j, pt, off: (b, i, 0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((sets, per, LANES), jnp.float32),
+                pltpu.VMEM((sets, per, math.gcd(kb, LANES)), jnp.float32),
+                pltpu.VMEM((sets, per, width), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, nq, Hkv, G * tq, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_WINDOW_VMEM_LIMIT),
+        interpret=_use_interpret(),
+        name="dsa_selected_window",
+    )(page_table.astype(jnp.int32), q_offset.astype(jnp.int32), qb,
+      *([k_pool] * pages), *([v_pool] * pages), mask.astype(jnp.int8))
+    return out.reshape(B, nq, Hkv, G, tq, D).transpose(0, 1, 4, 2, 3, 5) \
+        .reshape(B, C, Hq * D)
+
+
 def paged_selected_window_attention(q, k_pool, v_pool, page_table,
                                     q_offset, mask, *, sm_scale):
     """A ``C``-query window a row over a SELECTED set of its cached
     positions, pools read in place. ``q (B, C, Hq, D)``; pools
-    ``(num_pages, page, Hkv, D)`` with ``Hq`` a multiple of ``Hkv`` (query
+    ``(num_pages, page, Hkv, D)`` or ``(num_pages, page, Hkv x D)`` (the
+    lane form, nothing relaid) with ``Hq`` a multiple of ``Hkv`` (query
     head ``i`` reads key/value head ``i // (Hq // Hkv)``); ``mask (B, C,
     L)`` true where query ``c`` of row ``b`` reads cached position ``l``
     (the caller keeps it causal: nothing past ``q_offset[b] + c``).
@@ -703,9 +1021,13 @@ def paged_selected_window_attention(q, k_pool, v_pool, page_table,
     # every query head, took 13.2 (PERF.md, PR 30)
     tq, pages = _selected_window_tiles(q.shape[1], page_table.shape[1],
                                        k_pool.shape[1])
-    return _dsa_selected_window_impl(q, k_pool, v_pool, page_table,
-                                     q_offset, mask, sm_scale=sm_scale,
-                                     tq=tq, pages=pages)
+    impl = _dsa_selected_window_impl
+    if _lanes_packed(k_pool, q.shape[3]):
+        # a product takes the rows of as many heads as fill the lanes: 128
+        # queries a block keep its scores what 256 queries of one head are
+        impl, tq = _lane_selected_window_impl, math.gcd(tq, 128)
+    return impl(q, k_pool, v_pool, page_table, q_offset, mask,
+                sm_scale=sm_scale, tq=tq, pages=pages)
 
 
 def paged_selected_window_reference(q, k_pool, v_pool, page_table,

@@ -193,8 +193,8 @@ def selected_window_attention(q, k_pool, v_pool, page_tables, q_offset,
             q, k_pool, v_pool, page_tables, q_offset, mask,
             sm_scale=sm_scale)
     R, C, Hq, D = q.shape
-    k_all = paged.gather_row_pages(k_pool, page_tables)
-    v_all = paged.gather_row_pages(v_pool, page_tables)
+    k_all = paged.gather_row_pages(paged.by_head(k_pool, D), page_tables)
+    v_all = paged.gather_row_pages(paged.by_head(v_pool, D), page_tables)
     Hkv = k_all.shape[2]
     G = Hq // Hkv
     qg = q.reshape(R, C, Hkv, G, D)

@@ -42,6 +42,12 @@ from mxnet_tpu.serving import (Backpressure, ContinuousBatcher,
 from mxnet_tpu.serving import pages as pages_mod
 
 
+def _rows(x):
+    """``x (B, H, D)`` as a row of the attention layer's pools holds it:
+    heads of less than whole lanes lie side by side, ``(B, H x D)``."""
+    return x.reshape(x.shape[0], -1)
+
+
 def _make_transformer(V=61, units=16, layers=2, seed=0, **kw):
     np.random.seed(seed)
     net = TransformerModel(src_vocab=V, tgt_vocab=V, units=units,
@@ -140,8 +146,8 @@ class TestPagedParity:
                                           (0, 0, 0, 0))
         kp, vp = mha.init_page_pool(5, 4)
         table = jnp.asarray(np.array([[1, 2], [3, 4]], np.int32))
-        kp = kp.at[table[:, 0], 0].set(k[:, 0])
-        vp = vp.at[table[:, 0], 0].set(v[:, 0])
+        kp = kp.at[table[:, 0], 0].set(_rows(k[:, 0]))
+        vp = vp.at[table[:, 0], 0].set(_rows(v[:, 0]))
         for p in range(1, S):
             od, kc, vc = mha.step(x[:, p:p + 1], kc, vc, jnp.int32(p))
             op, kp, vp = mha.paged_step(
@@ -615,8 +621,8 @@ class TestFlashPagedKernel:
             paged_kernels(mode == "force")
             kp, vp = mha.init_page_pool(5, 4)
             _, k, v = mha.prefill(x0)
-            kp = kp.at[table[:, 0], 0].set(k[:, 0])
-            vp = vp.at[table[:, 0], 0].set(v[:, 0])
+            kp = kp.at[table[:, 0], 0].set(_rows(k[:, 0]))
+            vp = vp.at[table[:, 0], 0].set(_rows(v[:, 0]))
             o, _, _ = mha.paged_step(x, kp, vp, table,
                                      jnp.ones((2,), jnp.int32),
                                      jnp.ones((2,), bool))
@@ -639,8 +645,8 @@ class TestFlashPagedKernel:
         kp, vp = mha.init_page_pool(5, 4)
         _, k, v = mha.prefill(nd.array(
             rng.randn(2, 1, 16).astype(np.float32)))
-        kp = kp.at[table[:, 0], 0].set(k[:, 0])
-        vp = vp.at[table[:, 0], 0].set(v[:, 0])
+        kp = kp.at[table[:, 0], 0].set(_rows(k[:, 0]))
+        vp = vp.at[table[:, 0], 0].set(_rows(v[:, 0]))
         o_clean, kp2, _ = mha.paged_step(x, kp, vp, table,
                                          jnp.ones((2,), jnp.int32), active)
         # poison the trash page with huge values and replay

@@ -406,6 +406,77 @@ def test_hybrid_chunk_attention_compiles(for_chip):
         spec((1,), "int32"), spec((1, C, P * page), "bool"))
 
 
+# ---- pools of heads of 64 as granite, transformer-big and the smoke run
+# declare them since PR 46: ``(num_pages, page, heads x 64)``, a page's
+# (head, d) on whole lanes
+LANES = {
+    # cell: (rows, pages a row, query heads, key/value heads, head size,
+    # page, pool pages)
+    "granite": (64, 12, 32, 8, 64, 128, 64 * 12 + 1),
+    "transformer-big": (128, 9, 16, 16, 64, 16, 1153),
+    "smoke": (SLOTS, PAGES_PER_SLOT, HEADS, HEADS, HEAD_DIM, PAGE,
+              SLOTS * PAGES_PER_SLOT + 1),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(LANES))
+def test_decode_attention_over_heads_on_the_lanes_compiles(for_chip, cell):
+    """The decode call of the cells of heads of 64 over a pool ``(pages,
+    page, heads x 64)``: ONE call named ``%paged_window`` whose page
+    operands are ``(1, page, heads x 64)`` blocks of the pool AS DECLARED
+    (Mosaic takes such a page: its last axis is whole lanes), the query
+    rows on their own head's lanes of the ``heads x 64``."""
+    pfa = _mod("paged_flash_attention")
+    B, P, Hq, Hkv, D, page, pool_pages = LANES[cell]
+    pages, block = pfa._lane_window_tiles(P, page, Hkv, D, 2)
+    assert (pages, block) == {"granite": (12, 1), "transformer-big": (9, 8),
+                              "smoke": (2, 2)}[cell]
+    assert pfa._lane_heads(Hkv, D, Hq // Hkv) == Hkv   # one product a block
+    operands = _decode_call(for_chip, B, P, Hq, Hkv, D,
+                            (pool_pages, page, Hkv * D), "bfloat16")
+    assert len(operands) == 4 + 2 * pages
+    assert operands[3] == f"bf16[{B},{Hq},{Hkv * D}]"
+    assert set(operands[4:]) == {f"bf16[{pool_pages},{page},{Hkv * D}]"}
+
+
+@pytest.mark.parametrize("window", [3, 4, 16, 32, 128])
+def test_window_attention_over_heads_on_the_lanes_compiles(for_chip, window):
+    """transformer-big's windows (suffix replay, speculative verification)
+    over its pool ``(1153, 16, 1024)``: the whole block-diagonal to 256
+    query rows (16 heads x 16 positions), two heads a product past it,
+    under the limit the kernel hands the compiler."""
+    spec, compile_ = for_chip
+    pfa = _mod("paged_flash_attention")
+    _, P, H, _, D, page, pool_pages = LANES["transformer-big"]
+    assert pfa._lane_heads(H, D, window) == (H if window <= 16 else 2)
+    B = 8
+    pool = spec((pool_pages, page, H * D), "bfloat16")
+    rows = spec((B,), "int32")
+    _named_once(compile_(
+        lambda q, k, v, pt, off, vl: pfa.paged_window_attention(
+            q, k, v, pt, off, vl, sm_scale=D ** -0.5),
+        spec((B, window, H, D), "bfloat16"), pool, pool,
+        spec((B, P), "int32"), rows, rows))
+
+
+def test_hybrid_chunk_attention_over_heads_on_the_lanes_compiles(for_chip):
+    """granite's chunk attention as it runs since PR 46: the selected
+    window under a causal mask over pools ``(769, 128, 512)``, two heads
+    of 64 a product, 128 queries a block, nothing relaid."""
+    spec, compile_ = for_chip
+    pfa = _mod("paged_flash_attention")
+    C, Hq, Hkv, D, page, P = 512, 32, 8, 64, 128, 12
+    pool = spec((64 * P + 1, page, Hkv * D), "bfloat16")
+    operands = _named_once(compile_(
+        lambda q, k, v, pt, off, m: pfa.paged_selected_window_attention(
+            q, k, v, pt, off, m, sm_scale=1 / 64),
+        spec((1, C, Hq, D), "bfloat16"), pool, pool, spec((1, P), "int32"),
+        spec((1,), "int32"), spec((1, C, P * page), "bool")),
+        "%dsa_selected_window")
+    # (blocks of 128 queries, sets of two heads, 2 x 4 x 128 rows, 128 lanes)
+    assert operands[2] == "bf16[1,4,4,1024,128]"
+
+
 @pytest.mark.parametrize("which", ["step", "chunk"])
 def test_attention_over_a_pool_of_planes_compiles(for_chip, which):
     """Ouro-2.6B's two attention calls over pools with a plane a pass
@@ -722,7 +793,7 @@ def test_attention_over_a_pool_of_key_head_rows_compiles(for_chip, which):
     B, P, Hq, Hkv, D, pool_shape = ZAYA
     page = pool_shape[1] // Hkv
     pool = spec(pool_shape, "bfloat16")
-    assert pfa._page_size(pool, Hkv) == page
+    assert pfa._page_size(pool, Hkv, D) == page
     if which == "step":
         assert _decode_call(for_chip, *ZAYA, "bfloat16")[3:] == \
             ["bf16[2049,256,128]"] * 2
@@ -744,29 +815,28 @@ def test_attention_over_a_pool_of_key_head_rows_compiles(for_chip, which):
 # ---- the decode bursts of the three cells whose step calls
 # ``paged_decode_attention``, at the configurations' own widths, slots and
 # pages and a few layers (the whole depths, compiled in a scratch script:
-# PERF.md section 6, PR 41)
+# PERF.md section 6, PR 41 and PR 46)
 BURSTS = {
-    # cell: (layers kept, temporaries the parent's burst of the WHOLE depth
-    # reads in bytes (PERF.md sections 4 and 7 (u)), copies of a pool's
-    # shape the parent's burst makes a layer that has pools)
-    "zaya1-8b": (2, 0.047e9, 0),
-    "ouro-2.6b": (2, 0.07e9, 0),
-    # granite's pools of heads of 64 are copied to the kernel's layout
-    # before the loop and back after it (ROADMAP S3): not this PR's, and
-    # not to grow. Six layers hold ONE attention layer
-    "granite-4.0-h-micro": (6, 1.6e9, 4),
+    # cell: (layers kept, a bound on the temporaries in bytes: what the
+    # burst of the WHOLE depth reads (PERF.md sections 4 and 6))
+    "zaya1-8b": (2, 0.047e9),
+    "ouro-2.6b": (2, 0.07e9),
+    # granite's pools of heads of 64 were copied to the kernel's layout
+    # before the loop and back after it, 16 copies of 101 MB and 1.6 GB of
+    # temporaries at the whole depth (PERF.md 7 (u)), until PR 46 declared
+    # them ``(pages, page, heads x 64)``: 0.171 GB at the whole depth since.
+    # Six layers hold ONE attention layer
+    "granite-4.0-h-micro": (6, 0.2e9),
 }
+# the chunk program of the cell whose pools PR 46 moved: the parent's copied
+# the 16 pools too (1.505 GB of temporaries at the whole depth, 0.379 since)
+CHUNKS = {"granite-4.0-h-micro": (6, 0.4e9)}
 
 
-@pytest.mark.parametrize("cell", sorted(BURSTS))
-def test_decode_burst_copies_no_pool(one_chip, monkeypatch, paged_kernels,
-                                     cell):
-    """The burst program (``iter_tokens`` decode steps in one ``while``)
-    compiled for the described chip from the cell's own configuration,
-    zeros for weights: no pool is copied whole on its way to
-    ``%paged_window`` (one such copy a layer is 268 MB in zaya, which holds
-    14.92 of 16.9 GB), and the temporaries stay under what the parent's
-    burst reads at the whole depth."""
+def _cell_engine(monkeypatch, paged_kernels, cell, layers):
+    """``(engine, paged state as shapes, serving block, pages a slot)`` of a
+    cell's own configuration cut to ``layers``, zeros for weights, the
+    paged kernels on and interpret mode forbidden."""
     from mxnet_tpu import nd
     from mxnet_tpu.parallel import InferStep
 
@@ -775,7 +845,6 @@ def test_decode_burst_copies_no_pool(one_chip, monkeypatch, paged_kernels,
 
     monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "0")
     paged_kernels(True)
-    layers, parent_temporaries, parent_copies = BURSTS[cell]
     perf = os.path.join(REPO_ROOT, "perf")
     with open(os.path.join(perf, "configs", cell + ".json")) as f:
         cfg = json.load(f)
@@ -797,27 +866,124 @@ def test_decode_burst_copies_no_pool(one_chip, monkeypatch, paged_kernels,
     srv = cfg["serving"]
     slots, page = srv["slots"], srv["page_size"]
     P = -(-(max(srv["prompt_buckets"]) + srv["max_new_tokens"]) // page)
-
-    def placed(x):
-        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
-
     state = jax.eval_shape(
         lambda: eng.init_paged_state(slots, slots * P, page, 0))
-    rows = jax.ShapeDtypeStruct((slots,), jnp.int32)
-    compiled = eng._get_decode_iter_fn(srv["iter_tokens"], "greedy", 0).lower(
-        *jax.tree_util.tree_map(placed, (
-            eng._values, state,
-            jax.ShapeDtypeStruct((slots, P), jnp.int32), rows, rows,
-            jax.ShapeDtypeStruct((slots,), jnp.bool_),
-            jax.ShapeDtypeStruct((), jnp.int32),
-            jax.ShapeDtypeStruct((), jnp.float32)))).compile()
-    text = compiled.as_text()
-    with_pools = len(state["k_pools"])       # layers that keep K/V pools
-    assert text.count("%paged_window") >= with_pools
-    # whatever view of a pool is copied, it has the pool's size
+    return eng, state, srv, P
+
+
+def _compiled_for(one_chip, fn, *operands):
+    return fn.lower(*jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        operands)).compile()
+
+
+def _pool_copies(compiled, state):
+    """The lines of the compiled program that copy something of a K/V
+    pool's size, whatever view of the pool it is."""
     sizes = {p.size for p in state["k_pools"]}
-    copies = [ln for ln in text.splitlines() if " copy(" in ln and sizes & {
-        math.prod(map(int, dims.split(","))) for dims in re.findall(
-            r"\[([\d,]+)\]", ln.split(" copy(")[0])}]
-    assert len(copies) <= parent_copies * with_pools, copies[:3]
-    assert compiled.memory_analysis().temp_size_in_bytes < parent_temporaries
+    return [ln for ln in compiled.as_text().splitlines()
+            if " copy(" in ln and sizes & {
+                math.prod(map(int, dims.split(","))) for dims in re.findall(
+                    r"\[([\d,]+)\]", ln.split(" copy(")[0])}]
+
+
+def _int(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+
+@pytest.mark.parametrize("cell", sorted(BURSTS))
+def test_decode_burst_copies_no_pool(one_chip, monkeypatch, paged_kernels,
+                                     cell):
+    """The burst program (``iter_tokens`` decode steps in one ``while``)
+    compiled for the described chip from the cell's own configuration,
+    zeros for weights: no pool is copied whole on its way to
+    ``%paged_window`` (one such copy a layer is 268 MB in zaya, which holds
+    14.92 of 16.9 GB; 101 MB in granite, where the parent made four a
+    layer), and the temporaries stay under what the burst reads at the
+    whole depth."""
+    layers, temporaries = BURSTS[cell]
+    eng, state, srv, P = _cell_engine(monkeypatch, paged_kernels, cell,
+                                      layers)
+    slots = srv["slots"]
+    compiled = _compiled_for(
+        one_chip, eng._get_decode_iter_fn(srv["iter_tokens"], "greedy", 0),
+        eng._values, state, _int(slots, P), _int(slots), _int(slots),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_), _int(),
+        jax.ShapeDtypeStruct((), jnp.float32))
+    assert compiled.as_text().count("%paged_window") >= len(state["k_pools"])
+    assert _pool_copies(compiled, state) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < temporaries
+
+
+@pytest.mark.parametrize("cell", sorted(CHUNKS))
+def test_chunk_program_copies_no_pool(one_chip, monkeypatch, paged_kernels,
+                                      cell):
+    """The chunk program (one row of ``prefill_chunk`` positions, as the
+    scheduler dispatches it) of the cell whose pools are declared
+    ``(pages, page, heads x 64)``: the pools go to ``write_rows``' scatter
+    and to ``%dsa_selected_window`` as they lie, none copied, none among
+    the temporaries."""
+    layers, temporaries = CHUNKS[cell]
+    eng, state, srv, P = _cell_engine(monkeypatch, paged_kernels, cell,
+                                      layers)
+    assert {p.shape for p in state["k_pools"]} == {(769, 128, 512)}
+    compiled = _compiled_for(
+        one_chip, eng._get_suffix_fn("greedy", 0, True), eng._values, state,
+        _int(1, srv["prefill_chunk"]), _int(1), _int(1), _int(1, P), _int(1),
+        jax.ShapeDtypeStruct((1,), jnp.bool_), _int(),
+        jax.ShapeDtypeStruct((), jnp.float32))
+    assert compiled.as_text().count("%dsa_selected_window") >= \
+        len(state["k_pools"])
+    assert _pool_copies(compiled, state) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < temporaries
+
+
+@pytest.mark.parametrize("which", ["burst", "admission"])
+def test_transformer_big_programs_copy_no_pool(one_chip, monkeypatch,
+                                               paged_kernels, which):
+    """transformer-big at its whole depth, zeros for weights: the decode
+    burst and the admission prefill (which writes ONE position a row into
+    the pools) over pools ``(1153, 16, 1024)``. The parent's made 24 copies
+    of 38 MB in each (0.92 GB of temporaries in the burst: PERF.md 7
+    (u))."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import nd
+    from mxnet_tpu.parallel import InferStep
+
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "0")
+    paged_kernels(True)
+    with open(os.path.join(REPO_ROOT, "perf", "configs",
+                           "transformer-big.json")) as f:
+        cfg = json.load(f)
+    prog, srv = cfg["program"], cfg["serving"]
+    mod, cls = prog["model"].split(":")
+    net = getattr(importlib.import_module(mod), cls)(
+        **{k: cfg[v] for k, v in prog["kwargs"].items()})
+    net.initialize(mx.initializer.Zero())
+    net._probe_shapes(nd.zeros((2, 8), dtype="int32"),
+                      nd.zeros((2, 8), dtype="int32"))
+    eng = InferStep(net, amp=cfg["precision"]["weights"],
+                    max_len=srv["max_len"])
+    slots, page, bucket = srv["slots"], srv["page_size"], \
+        max(srv["prompt_buckets"])
+    P = -(-(1 + srv["max_new_tokens"]) // page)
+    state = jax.eval_shape(
+        lambda: eng.init_paged_state(slots, slots * P, page, bucket))
+    assert {p.shape for p in state["k_pools"]} == {(1153, 16, 1024)}
+    flags = jax.ShapeDtypeStruct((slots,), jnp.bool_)
+    scalars = (_int(), jax.ShapeDtypeStruct((), jnp.float32))
+    if which == "burst":
+        compiled = _compiled_for(
+            one_chip, eng._get_decode_iter_fn(8, "greedy", 0), eng._values,
+            state, _int(slots, P), _int(slots), _int(slots), flags, *scalars)
+        assert compiled.as_text().count("%paged_window") >= 6
+        temporaries = 0.1e9
+    else:
+        # 128 rows of 128 source positions: the encoder's own activations
+        temporaries = 0.5e9
+        compiled = _compiled_for(
+            one_chip, eng._get_paged_prefill_fn("greedy", 0), eng._values,
+            state, _int(slots, bucket), _int(slots), _int(slots),
+            _int(slots), flags, *scalars)
+    assert _pool_copies(compiled, state) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < temporaries
