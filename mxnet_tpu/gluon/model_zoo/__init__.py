@@ -11,8 +11,10 @@ from . import joyai  # noqa: F401
 from . import xing  # noqa: F401
 from . import ouro  # noqa: F401
 from . import zaya  # noqa: F401
+from . import olmo_hybrid  # noqa: F401
 from . import ssd  # noqa: F401
 from . import faster_rcnn  # noqa: F401
 
 __all__ = ["vision", "bert", "transformer", "keye", "granite_hybrid",
-           "latent_lm", "joyai", "xing", "ouro", "zaya", "ssd", "faster_rcnn"]
+           "latent_lm", "joyai", "xing", "ouro", "zaya", "olmo_hybrid", "ssd",
+           "faster_rcnn"]

@@ -987,3 +987,70 @@ def test_transformer_big_programs_copy_no_pool(one_chip, monkeypatch,
             _int(slots), flags, *scalars)
     assert _pool_copies(compiled, state) == []
     assert compiled.memory_analysis().temp_size_in_bytes < temporaries
+
+
+# ---- the gated delta rule (PR 48): the step's kernel at olmo-hybrid-7b's
+# own shapes (16 rows of 30 heads of 96 x 192), and the cell's two programs
+# at one period of its layers
+def test_gated_delta_step_kernel_compiles(for_chip):
+    """``%gated_delta_step`` takes a row's 30 states (2.2 MB) a grid step, a
+    head's key a COLUMN (``(d_k, heads)`` operands) and its decay a row: a
+    number broadcast along sublanes AND lanes is what Mosaic refused."""
+    spec, compile_ = for_chip
+    gd = _mod("gated_delta")
+    B, H, dk, dv = 16, 30, 96, 192
+    compiled = compile_(
+        gd.gated_delta_step, spec((B, H, dk, dv), "float32"),
+        spec((B, H, dk), "float32"), spec((B, H, dk), "float32"),
+        spec((B, H, dv), "float32"), spec((B, H), "float32"),
+        spec((B, H), "float32"), spec((B,), "bool"))
+    _named(compiled, "%gated_delta_step")
+
+
+@pytest.mark.parametrize("which", ["burst", "chunk"])
+def test_olmo_programs_copy_no_pool_and_no_state(one_chip, monkeypatch,
+                                                 paged_kernels, which):
+    """The cell's two programs at the configuration's own widths, slots and
+    pages and ONE period of its layers (three delta-rule layers and a
+    full-attention layer; the whole depth, compiled in a scratch script,
+    reads 13.82 GB of arguments, 0.05 GB of temporaries in the burst and
+    0.76 in a chunk of 1,024: PERF.md section 6, PR 48), zeros for weights: the
+    pools ``(641, 128, 3840)`` reach the lane forms of ``%paged_window``
+    as they lie (a page of (key, head) rows on one axis made the chunk's
+    call ask 77 MB of VMEM: 30 query heads against 30 key heads in one
+    product), the slots' states ``(16, 30, 96, 192)`` reach
+    ``%gated_delta_step`` aliased onto themselves (the chunk's blocked rule
+    is XLA's own: its kernel lost on the chip and went), and no program
+    copies either."""
+    eng, state, srv, P = _cell_engine(monkeypatch, paged_kernels,
+                                      "olmo-hybrid-7b", 4)
+    slots = srv["slots"]
+    assert {p.shape for p in state["k_pools"]} == {(641, 128, 3840)}
+    assert {p.shape for p in state["delta"]} == {(16, 30, 96, 192)}
+    if which == "burst":
+        compiled = _compiled_for(
+            one_chip,
+            eng._get_decode_iter_fn(srv["iter_tokens"], "greedy", 0),
+            eng._values, state, _int(slots, P), _int(slots), _int(slots),
+            jax.ShapeDtypeStruct((slots,), jnp.bool_), _int(),
+            jax.ShapeDtypeStruct((), jnp.float32))
+        kernel, temporaries = "%gated_delta_step", 0.05e9
+    else:
+        compiled = _compiled_for(
+            one_chip, eng._get_suffix_fn("greedy", 0, True), eng._values,
+            state, _int(1, srv["prefill_chunk"]), _int(1), _int(1),
+            _int(1, P), _int(1), jax.ShapeDtypeStruct((1,), jnp.bool_),
+            _int(), jax.ShapeDtypeStruct((), jnp.float32))
+        # a chunk of 2,048 since the second hand-in (0.69 GB at one period
+        # of layers; 0.34 at the issue's first chunk of 1,024)
+        kernel, temporaries = "%paged_window", 0.9e9
+    text = compiled.as_text()
+    assert text.count("%paged_window") >= 1 and text.count(kernel) >= 1
+    assert "%gated_delta_chunk" not in text
+    assert _pool_copies(compiled, state) == []
+    assert _pool_copies(compiled, {"k_pools": state["delta"]}) == []
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < temporaries
+    # one period's weights, the head and the embedding, the pools and the
+    # slot arrays: what the configuration reckons, a period for four
+    assert 4.55e9 < memory.argument_size_in_bytes < 4.65e9
